@@ -1,7 +1,8 @@
 # Developer entry points (reference analog: the upstream Makefile).
-# Tests force the CPU-simulated 8-device mesh via tests/conftest.py.
+# Tests force the CPU-simulated 8-device mesh via tests/conftest.py;
+# smoke and bench need a TPU.
 
-.PHONY: test test-quick lint docs docs-site bench bench-all notebooks dryrun
+.PHONY: test test-quick lint docs docs-site smoke bench bench-all notebooks dryrun
 
 docs:
 	python scripts/gen_api_reference.py
@@ -13,7 +14,7 @@ docs-site:
 test:
 	python -m pytest tests/ -x -q
 
-# the measured sub-minute spec-path modules (<5 min total on the 1-core
+# the measured sub-minute spec-path modules (<5 min total on the
 # simulated mesh) — the iteration/CI-sharding tier; `make test` remains
 # the full matrix of record
 test-quick:
@@ -30,6 +31,11 @@ lint:
 	else \
 		echo "flake8/ruff not installed; lint_basics covered the correctness subset"; \
 	fi
+
+# the standing proof that train and serve still start on the chip
+# (needs one TPU; `python chip_smoke.py --rehearse` walks it on the CPU)
+smoke:
+	python chip_smoke.py
 
 bench:
 	python bench.py
@@ -59,4 +65,4 @@ notebooks:
 	python scripts/myst_to_ipynb.py docs/tutorials/*.md
 
 dryrun:
-	JAX_PLATFORMS=cpu python __graft_entry__.py 8
+	python __graft_entry__.py 8

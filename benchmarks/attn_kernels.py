@@ -1,4 +1,4 @@
-"""Attention-kernel selection benchmark (BASELINE.md kernel table).
+"""Attention-kernel selection benchmark.
 
 Times each attention implementation (fwd+bwd, one jit program over a
 12-layer chain) at the two regimes that drive the `attn_impl` defaults:

@@ -18,11 +18,6 @@ import os
 import time
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    # pre-registered TPU plugins override the env var; the config API wins
-    jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp
 import numpy as np
 
@@ -89,21 +84,18 @@ def trainer(
     steps: int = 100,
     warmup: int = 10,
 ) -> TrainState:
-    """Timed fine-tune loop (BASELINE.md methodology: warmup, >=100-step
-    window on TPU, window terminated by a host readback data-dependent on
-    the donated final state)."""
+    """Timed fine-tune loop: warmup, then a >=100-step window on TPU
+    that ends in ``block_until_ready`` on the donated final state."""
     ids = jnp.asarray(features[:batch_size])
     labels = jnp.asarray(targets[:batch_size])
-    from benchmarks._timing import drain
-
     step = jax.jit(classification_step(_ctx["module"]), donate_argnums=0)
     for _ in range(warmup):
         state, metrics = step(state, (ids, labels))
-    drain(state)
+    jax.block_until_ready(state)
     t0 = time.perf_counter()
     for _ in range(steps):
         state, metrics = step(state, (ids, labels))
-    drain(state)  # param-element fence, see benchmarks/_timing.py
+    jax.block_until_ready(state)
     dt = time.perf_counter() - t0
     _ctx["samples_per_sec"] = batch_size * steps / dt
     return state

@@ -68,8 +68,8 @@ def main() -> None:
     )
     parser.add_argument(
         "--prefill-impl", choices=("cached", "flash"), default="cached",
-        help="flash = Pallas monolithic prefill for FULL prefills "
-        "(BASELINE.md round 5). Unlike serve_latency, this COMPOSES with "
+        help="flash = Pallas monolithic prefill for FULL prefills. "
+        "Unlike serve_latency, this COMPOSES with "
         "--prefill-chunk here: bucketed serving runs flash on monolithic "
         "admissions while chunk-ruled long buckets stay chunked-cached. "
         "Ignored by the speculative presets (their module pair is built "
@@ -237,8 +237,8 @@ def main() -> None:
 
     mode_decision = None
     if args.mode == "auto":
-        # encode the measured crossover (BASELINE.md round 3) instead of
-        # making the operator choose blind: engine iff one decode chunk
+        # encode the crossover rule instead of making the operator
+        # choose blind: engine iff one decode chunk
         # costs at least one host<->device round trip
         from unionml_tpu.serving.auto import choose_serving_mode
 

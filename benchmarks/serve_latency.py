@@ -1,6 +1,6 @@
-"""Serving-latency benchmark: Llama generation p50/p95 (BASELINE.md).
+"""Serving-latency benchmark: Llama generation p50/p95.
 
-Reproduces the BASELINE.md serving rows: jitted prefill + scan decode
+Jitted prefill + scan decode
 via :func:`unionml_tpu.models.make_generator` on a ~1.5B-param Llama-3
 geometry (the largest that fits one v5e chip in bf16; the 8B config
 needs the tensor-parallel path). Prints one JSON line per
@@ -225,8 +225,7 @@ def main() -> None:
     parser.add_argument("--new-tokens", type=int, default=32)
     parser.add_argument(
         "--prefill-impl", choices=("cached", "flash"), default="cached",
-        help="flash = Pallas monolithic prefill (the long-prompt lever; "
-        "BASELINE.md round 5: 1.68x at 1.5B x 4k)",
+        help="flash = Pallas monolithic prefill (the long-prompt lever)",
     )
     parser.add_argument(
         "--prefill-chunk", type=int, default=None,

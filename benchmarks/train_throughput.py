@@ -1,16 +1,15 @@
 """Training-throughput benchmarks beyond the headline ViT (bench.py).
 
-Reproduces the remaining BASELINE.md training rows on one chip:
+Two training configs on one chip:
 
 - ``bert_ft``  — BERT-base classification fine-tune (batch 32, seq 128),
-  samples/sec/chip; the config that exposed the donated-optax-adamw
-  pathology (BASELINE.md) — uses the donation-safe ``adamw`` chain.
+  samples/sec/chip — uses the donation-safe ``adamw`` chain.
 - ``llama_lc`` — long-context LM training (0.19B-param Llama geometry,
   batch 2, seq 4096, Pallas flash attention), tokens/sec/chip.
 
-Prints one JSON line per config. Timing follows the BASELINE.md
-methodology: warmup, >=100-step window on TPU, end with a host readback
-data-dependent on the final donated state.
+Prints one JSON line per config. Timing: warmup, then a >=100-step
+window on TPU that ends in ``block_until_ready`` on the final donated
+state.
 """
 
 from __future__ import annotations
@@ -25,15 +24,15 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 
 def _time_steps(step, state, batch, steps, warmup):
-    from benchmarks._timing import drain
+    import jax
 
     for _ in range(warmup):
         state, metrics = step(state, batch)
-    drain(state)
+    jax.block_until_ready(state)
     t0 = time.perf_counter()
     for _ in range(steps):
         state, metrics = step(state, batch)
-    drain(state)
+    jax.block_until_ready(state)
     return time.perf_counter() - t0
 
 

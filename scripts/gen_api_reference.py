@@ -40,8 +40,8 @@ MODULES = [
     ("unionml_tpu.exporters", "OTLP export"),
     ("unionml_tpu.slo", "SLO watchdog"),
     ("unionml_tpu.introspection", "Program introspection & profiling"),
+    ("unionml_tpu.compile_cache", "Compile cache"),
     ("unionml_tpu.parallel", "Parallelism"),
-    ("unionml_tpu.parallel.compat", "JAX version compatibility"),
     ("unionml_tpu.parallel.sharding", "Sharding config"),
     ("unionml_tpu.parallel.mesh", "Device meshes"),
     ("unionml_tpu.parallel.pipeline", "Pipeline parallelism"),

@@ -56,7 +56,7 @@ from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
 DEFAULT_PATHS = ["unionml_tpu", "tests", "benchmarks", "scripts", "bench.py",
-                 "__graft_entry__.py"]
+                 "chip_smoke.py", "__graft_entry__.py"]
 MAX_LINE = 110
 
 # repo-relative prefixes where time.time() is banned (monotonic-clock

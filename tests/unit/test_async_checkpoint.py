@@ -23,10 +23,6 @@ from unionml_tpu.checkpoint import AsyncCheckpointManager, make_checkpoint_manag
 from unionml_tpu.checkpoint.async_writer import AsyncCheckpointWriter, is_committed
 from unionml_tpu.telemetry import MetricsRegistry
 
-# NOTE: this module runs with the persistent compilation cache OFF —
-# see _PERSISTENT_CACHE_UNSAFE in tests/conftest.py (warm-cache reads
-# crash the donated elastic-step executables on jax 0.4.37/CPU).
-
 
 def _state(scale: float = 1.0):
     return {"w": jnp.arange(8, dtype=jnp.float32) * scale,

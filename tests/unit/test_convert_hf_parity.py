@@ -2,9 +2,8 @@
 *torch* reference models load through models/convert.py and reproduce the
 torch logits.
 
-This is the output-sanity proof for the ingestion path (VERDICT round 3,
-Missing #1): the mapping, layout transforms, and the rotary/GELU/norm
-conventions are all exercised end-to-end against an independent
+This is the output-sanity proof for the ingestion path: the mapping,
+layout transforms, and the rotary/GELU/norm conventions are all exercised end-to-end against an independent
 implementation — a transposed kernel, permuted head, or mismatched RoPE
 convention shifts logits by O(1), far outside the tolerances here. Real
 pretrained checkpoints use the exact same tensor names and layouts; only

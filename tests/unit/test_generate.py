@@ -53,8 +53,7 @@ def test_flash_prefill_matches_cached_prefill(tiny_llama):
     the Pallas kernel — no [B,H,S,max_len] score buffer) must generate
     the cached path's tokens on a ragged LEFT-PADDED batch. Exact here
     (fp32 interpret on CPU); on TPU the kernel's bf16 p@v cast makes it
-    tolerance-equivalent, like the training flash path (measured 1.43-
-    1.62x prefill speedup at 4k — BASELINE.md round 5)."""
+    tolerance-equivalent, like the training flash path."""
     module, params = tiny_llama
     cfg_f = dataclasses.replace(module.config, prefill_impl="flash")
     fmod = Llama(cfg_f)
@@ -409,7 +408,7 @@ def test_lm_predictor_ragged_prompts(tiny_llama):
 def test_lm_predictor_warmup_compiles_all_shapes(tiny_llama):
     """warmup() pre-compiles every (bucket, power-of-two batch) executable
     so a live server never stalls a request behind a first-hit XLA
-    compile (measured 17.9s p95 -> 0.3s on the 1.5B config, BASELINE.md)."""
+    compile."""
     module, params = tiny_llama
     pred = make_lm_predictor(module, max_new_tokens=4, bucket_lens=(8, 16), max_len=32)
     n = pred.warmup(params, max_batch=4)

@@ -54,8 +54,8 @@ def make_tracker(clock=None, **kwargs):
 # ---------------------------------------------------------- bucket math
 
 
-def test_bucket_taxonomy_is_closed():
-    # report() keys mirror the documented taxonomy exactly — a bucket
+def test_bucket_set_is_closed():
+    # report() keys mirror the documented bucket set exactly — a bucket
     # outside it could silently leak out of the attribution sum
     tracker, _ = make_tracker()
     rep = tracker.report()

@@ -124,11 +124,21 @@ def test_tracker_reset_keeps_learned_costs():
 # ------------------------------------------------------- peaks and MFU
 
 
-def test_peak_table_resolution_on_cpu():
-    peaks = resolve_device_peaks()
-    assert peaks["platform"] == "cpu"
+class _V5e:
+    platform = "tpu"
+    device_kind = "TPU v5 lite"
+
+
+def test_peak_table_resolution():
+    """The chip's `device_kind` resolves from the table; the CPU has no
+    row — a nominal one would put made-up ratios under device names."""
+    peaks = resolve_device_peaks(_V5e())
     assert peaks["source"] == "table"
-    assert peaks["peak_flops"] and peaks["peak_bytes_per_s"]
+    assert (peaks["peak_flops"], peaks["peak_bytes_per_s"]) == (197e12, 819e9)
+    cpu = resolve_device_peaks()
+    assert cpu["platform"] == "cpu"
+    assert cpu["source"] == "unknown"
+    assert cpu["peak_flops"] is None and cpu["peak_bytes_per_s"] is None
 
 
 def test_peak_env_override(monkeypatch):
@@ -164,7 +174,7 @@ def test_peak_env_override(monkeypatch):
 
 def test_malformed_peak_override_falls_back(monkeypatch):
     monkeypatch.setenv(introspection.PEAK_FLOPS_ENV, "not-a-number")
-    peaks = resolve_device_peaks()
+    peaks = resolve_device_peaks(_V5e())
     assert peaks["source"] == "table"  # malformed override ignored
 
 

@@ -3,7 +3,7 @@
 No reference counterpart (the reference trains whatever the user's
 sklearn/torch/keras trainer does — reference: unionml/model.py:425-440);
 LoRA is the TPU-native fine-tuning path for the serving flagship (int8
-frozen base + adapters = single-chip 8B fine-tune, BASELINE.md round 3).
+frozen base + adapters = single-chip 8B fine-tune).
 """
 
 import jax

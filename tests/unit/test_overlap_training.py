@@ -184,7 +184,7 @@ def test_bucketed_psum_matches_plain_psum():
     """Bucketing changes how many collectives XLA sees, never the
     values: bitwise equal to leaf-wise psum under shard_map, for bucket
     sizes that split the tree anywhere from one-bucket to one-per-leaf."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     cfg = ShardingConfig(data=8)
@@ -199,13 +199,13 @@ def test_bucketed_psum_matches_plain_psum():
     def reduce_with(bucket_bytes):
         fn = shard_map(
             lambda t: bucketed_psum(t, "data", bucket_bytes=bucket_bytes),
-            mesh, in_specs=(P("data"),), out_specs=P(), check_rep=False,
+            mesh=mesh, in_specs=(P("data"),), out_specs=P(), check_vma=False,
         )
         return fn(tree)
 
     plain = shard_map(
         lambda t: jax.lax.psum(t, "data"),
-        mesh, in_specs=(P("data"),), out_specs=P(), check_rep=False,
+        mesh=mesh, in_specs=(P("data"),), out_specs=P(), check_vma=False,
     )(tree)
     for bucket_bytes in (1, 600, 1 << 20):
         out = reduce_with(bucket_bytes)

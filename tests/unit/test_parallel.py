@@ -41,7 +41,7 @@ def test_make_mesh_hybrid_dcn_axes():
     mesh = make_mesh({"data": 4, "tensor": 2}, dcn_axes={"data": 2})
     assert mesh.shape == {"data": 4, "tensor": 2}
 
-    from unionml_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     x = jnp.arange(8.0)
 

@@ -20,7 +20,7 @@ from unionml_tpu.serving.auto import (
 
 
 def test_decide_mode_both_ways():
-    # tunneled-backend regime: RTT >> chunk compute → batcher
+    # slow-link regime: RTT >> chunk compute → batcher
     assert decide_mode(rtt_ms=119.0, decode_chunk_ms=26.0) == "batcher"
     # directly-attached or big-model regime: chunk >= RTT → engine
     assert decide_mode(rtt_ms=0.5, decode_chunk_ms=26.0) == "engine"

@@ -2,7 +2,7 @@
 
 The contract under test: per-token ITL attribution never double-counts
 across a preemption-resume boundary, dispatcher passes classify into
-the closed PASS_KINDS taxonomy on a synthetic trace, a tail exemplar's
+the closed PASS_KINDS set on a synthetic trace, a tail exemplar's
 rid resolves end-to-end into the stitched trace over the stdlib
 transport, the regression watchdog fires/holds/clears on synthetic
 values, and a plane-off engine records nothing.

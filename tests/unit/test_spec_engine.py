@@ -4,7 +4,7 @@ The contract: a DecodeEngine built with ``draft_module`` emits tokens
 IDENTICAL to plain greedy decoding of the target — for any draft —
 while slots advance by variable per-round acceptance. (The
 make_speculative_generator acceptance rule, restructured for the
-resident slot batch; round-4 VERDICT item 3.)
+resident slot batch.)
 """
 
 import numpy as np

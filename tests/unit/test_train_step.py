@@ -16,10 +16,6 @@ from flax.training import train_state
 from unionml_tpu import Dataset, Model
 from unionml_tpu.parallel import ShardingConfig
 
-# NOTE: this module runs with the persistent compilation cache OFF —
-# see _PERSISTENT_CACHE_UNSAFE in tests/conftest.py (warm-cache runs
-# intermittently return garbage in the donated `step` counter).
-
 
 class MLP(nn.Module):
     hidden: int = 32

@@ -105,6 +105,9 @@ def deploy(app_str: str, app_version, allow_uncommitted: bool, patch: bool):
 @click.option("--app-version", default=None)
 def train(app_str: str, inputs: str, app_version):
     """Train on the backend (reference: cli.py:85-103)."""
+    from unionml_tpu.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     model = _get_model(app_str)
     kwargs = json.loads(inputs)
     artifact = model.remote_train(app_version=app_version, wait=True, **kwargs)
@@ -180,6 +183,9 @@ def serve(app_str: str, model_path, host: str, port: int, batch: bool, row_lists
         if not Path(model_path).exists():
             raise click.ClickException(f"model path {model_path} does not exist")
         os.environ["UNIONML_MODEL_PATH"] = str(model_path)
+    from unionml_tpu.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     target = _get_model(app_str)
     from unionml_tpu.model import Model
     from unionml_tpu.serving.http import ServingApp

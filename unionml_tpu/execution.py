@@ -520,7 +520,7 @@ def run_step_trainer(
                     elif window_closed:
                         # force a readback data-dependent on this step so the
                         # window measures compute, not async dispatch (step()
-                        # only enqueues work; see BASELINE.md on tunnel timing)
+                        # only enqueues work)
                         leaves = jax.tree_util.tree_leaves(metrics)
                         if leaves:
                             np.asarray(leaves[0])

@@ -93,7 +93,7 @@ def phase_scope(tracker: Optional["GoodputTracker"], name: str):
 #: compile debit) counts toward goodput.
 COMPUTE_PHASE = "compute"
 
-#: The badput taxonomy (docs/observability.md "Training goodput").
+#: The badput bucket set (docs/observability.md "Training goodput").
 #: Any phase name outside COMPUTE_PHASE + BADPUT_CAUSES is rejected —
 #: an unknown bucket would silently leak out of the attribution sum.
 BADPUT_CAUSES = (

@@ -70,8 +70,8 @@ PEAK_HBM_ENV = "UNIONML_TPU_PEAK_HBM_GBPS"
 # per-chip peaks: (dense bf16 FLOP/s, HBM bytes/s), keyed on a
 # lowercase substring of `device.device_kind` (longest key wins, so
 # "tpu v5 lite" matches before "tpu v5"). Sources: public TPU spec
-# sheets; the CPU row is a NOMINAL placeholder so CPU test runs produce
-# finite ratios — it is not a meaningful roofline.
+# sheets. A device that is not here (the CPU included) has no roofline:
+# it resolves to source "unknown" and the ratio gauges report 0.
 DEVICE_PEAKS: Dict[str, Tuple[float, float]] = {
     "tpu v2": (45e12, 700e9),
     "tpu v3": (123e12, 900e9),
@@ -82,7 +82,6 @@ DEVICE_PEAKS: Dict[str, Tuple[float, float]] = {
     "tpu v5": (459e12, 2765e9),
     "tpu v6 lite": (918e12, 1640e9),
     "tpu v6e": (918e12, 1640e9),
-    "cpu": (5e10, 2e10),
 }
 
 
@@ -496,10 +495,9 @@ def capture_profile(
     Blocks the calling thread for the capture window (the transports
     serve it from a request thread, so in-flight traffic keeps running
     — that traffic is exactly what the trace is for). Builds on
-    :func:`unionml_tpu.diagnostics.trace`, so an unsupported backend
-    degrades to an empty artifact directory with a log line instead of
-    a 500. One capture at a time: raises :class:`ProfileInProgress`
-    when another is running."""
+    :func:`unionml_tpu.diagnostics.trace`: a profiler that will not
+    start raises. One capture at a time: raises
+    :class:`ProfileInProgress` when another is running."""
     seconds = float(seconds)
     if not seconds > 0:
         raise ValueError(f"seconds must be positive, got {seconds}")

@@ -89,9 +89,8 @@ class Int4DenseGeneral(nn.Module):
     is set (group-wise scales, the 4-bit quality recipe; the distinct
     name keeps the 2D leaf's partition rules separate from the 1D
     scale's). Decode-sized row counts run the Pallas kernel so HBM
-    weight reads stay at the packed width — measured 1.54x over int8 on
-    the streamed MLP probe (BASELINE.md round 4); other shapes take the
-    XLA unpack path with identical semantics.
+    weight reads stay at the packed width; other shapes take the XLA
+    unpack path with identical semantics.
 
     ``shards``: the tensor-parallel degree the packing tile must
     survive (``tile_for``'s shard-aligned slab rule) — set it on

@@ -81,7 +81,7 @@ def sequence_parallel_lm_step(
     ``ShardingConfig(data=m, sequence=n)`` — parameters replicate, the
     batch spec shards [B, S] over (data, sequence)).
     """
-    from unionml_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     sp_cfg = sequence_parallel_config(cfg, attn=attn, seq_axis=seq_axis)

@@ -31,11 +31,11 @@ def adamw(learning_rate: float, *, weight_decay: float = 0.0,
           mu_dtype: Optional[Any] = None):
     """AdamW as an explicit optax chain.
 
-    Mathematically identical to ``optax.adamw``, but ``optax.adamw``
-    triggers a ~4x whole-step slowdown under buffer donation on TPU
-    (measured on v5e, BERT-base 110M params: 83.5 ms/step vs 20.3 ms for
-    this chain — see BASELINE.md); the explicit composition compiles
-    clean under donated state.
+    Mathematically identical to ``optax.adamw``, written out because
+    ``optax.adamw`` was seen to slow a whole step several-fold under
+    buffer donation on TPU (on an older JAX; not re-measured on the
+    current stack); the explicit composition compiles clean under
+    donated state.
 
     ``mu_dtype`` (e.g. ``jnp.bfloat16``) stores the FIRST moment at
     reduced precision — 25% of adam-state memory and its HBM traffic.
@@ -278,7 +278,7 @@ def _shard_map_accumulated(
     behind the next backward.
     """
     from jax import lax
-    from unionml_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from unionml_tpu.parallel.collectives import bucketed_psum
@@ -347,10 +347,10 @@ def _shard_map_accumulated(
         return (loss / n, mean(aux)), grads
 
     fn = shard_map(
-        local, overlap.mesh,
+        local, mesh=overlap.mesh,
         in_specs=(P(), P(None, axes if len(axes) > 1 else axes[0])),
         out_specs=((P(), P()), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(params, batch)
 
@@ -425,7 +425,7 @@ def lm_step(
     scan-accumulated in fp32, and the optimizer updates once. This is
     the HBM-bound long-context knob: the 16k-context leg runs microbatch
     1 per device; accumulation restores the effective batch without the
-    activation memory (BASELINE.md long-context table).
+    activation memory.
     """
 
     def loss_fn(params, microbatch):

@@ -94,7 +94,7 @@ def _grouped_cache_attention(
 
     ``k``/``v``: [B, S, Hk, D] (bf16, or int8 with ``k_scale``/``v_scale``
     fp32 [B, S, Hk] per-(position, head) dequant scales). Three design
-    rules, each from a measured failure (BASELINE.md round 3):
+    rules, each from a failure seen at the 8B geometry:
 
     - **No GQA repeat.** The group dim folds into the einsums (q reshaped
       to [B, Sq, Hk, G, D]) so the cache is read at its own byte size; a

@@ -1,9 +1,8 @@
 """Fused LayerNorm / RMSNorm Pallas kernels (+ residual-add variant).
 
-Why a kernel at all: the ViT-B step decomposition (BASELINE.md "Where
-the remaining gap lives") put ~22 ms of the 53.8 ms step in VPU
-elementwise work — LayerNorm among the biggest bandwidth consumers.
-XLA's LayerNorm is already a fused reduce+normalize, but its BACKWARD
+Why a kernel at all: much of a ViT-B step is VPU elementwise work, with
+LayerNorm among the biggest bandwidth consumers (not measured on the
+current chip). XLA's LayerNorm is already a fused reduce+normalize, but its BACKWARD
 materializes the saved mean/rstd and runs separate reduction passes for
 dgamma/dbeta and dx; this kernel pair instead:
 

@@ -1,15 +1,10 @@
 """Packed-int4 weight-only matmul (Pallas) — the decode bandwidth lever.
 
-8B int8 serving sits at the HBM bound: every decoded token streams the
-full weight set (BASELINE.md rounds 2-4; p50 468 ms is within ~3% of
-the int8-traffic bound). int4 weights halve the bytes again — but this
-backend cannot move native ``jnp.int4`` across the jit boundary (plugin
-arg-signature recursion) and XLA materializes any unpack it is shown
-(measured 0.65-1.02x — worse or nil). So the int4 path stores TWO
-NIBBLES PER int8 BYTE and a Pallas kernel unpacks in VMEM, feeding the
-MXU directly — HBM reads stay at the packed width. Measured on the
-decode-faithful stream probe (32 layers of resident MLP weights per
-step, one v5e): int8 20.1 ms/step → int4 **13.0 ms/step (1.54x)**.
+8B int8 serving is bound by HBM: every decoded token streams the full
+weight set. int4 weights halve the bytes again — but XLA materializes
+any unpack it is shown, so the int4 path stores TWO NIBBLES PER int8
+BYTE and a Pallas kernel unpacks in VMEM, feeding the MXU directly —
+HBM reads stay at the packed width. Not measured on the current chip.
 
 Packing layout (``pack_int4``): output channels are tiled by ``TILE_N``;
 within tile ``j`` the LOW nibbles hold channels ``[j*T, j*T + T/2)`` and

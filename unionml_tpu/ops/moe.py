@@ -29,7 +29,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from unionml_tpu.parallel import compat
 from flax import linen as nn
 from jax import lax
 
@@ -141,7 +140,7 @@ def expert_parallel_moe_sharded(
     [E_local, ...] with E_global = axis_size * E_local. Returns the local
     output shard [T_local, d] and the group-mean aux loss (replicated).
     """
-    ep = compat.axis_size(axis)
+    ep = lax.axis_size(axis)
     t_local, d = x.shape
     e_global = router_kernel.shape[-1]
     assert w_gate.shape[0] * ep == e_global, (
@@ -191,7 +190,7 @@ def expert_parallel_moe(
     """
     import functools
 
-    from unionml_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     body = functools.partial(

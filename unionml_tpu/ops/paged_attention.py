@@ -30,15 +30,24 @@ Two implementations behind one dispatcher:
   block iterations, the same scheme as
   :mod:`~unionml_tpu.ops.flash_attention`; blocks entirely past a
   row's length are predicated out with ``pl.when``. GQA reads the pool
-  at kv-head width (no head repeat); int8 KV pools fold their
-  per-(row, head) dequant scales into the score/weight math in-kernel
-  (never a dequantized pool copy) — the same numerics contract as the
-  existing kernels: fp32 softmax statistics, MXU matmuls in the input
-  dtype with fp32 accumulation, outputs equal to the reference up to
-  float reduction order.
+  at kv-head width (no head repeat): a block tile is viewed as
+  ``[block * Hk, D]`` (a bitcast of the pool), ONE matmul scores every
+  q head against every (position, kv head) row, and a mask keeps each
+  q head's own kv head — Mosaic cannot lay out per-head sublane slices
+  of a ``[block, Hk, D]`` tile, and the step is bound by the HBM read,
+  not the MXU. int8 KV pools fold their per-(row, head) dequant scales
+  into the score/weight math in-kernel (never a dequantized pool copy;
+  the fp32 scale planes ride as one lane-dense ``[1, block * Hk]`` row
+  per block, which costs an XLA relayout of the planes per call) — the
+  same numerics contract as the existing kernels: fp32 softmax
+  statistics, MXU matmuls in the input dtype with fp32 accumulation,
+  outputs equal to the reference up to float reduction order.
 
 ``impl="auto"`` picks the kernel on TPU and the reference elsewhere
 (CPU tests run the kernel in interpreter mode only when asked).
+Interpret mode proves the math, not that Mosaic accepts the kernel:
+``tests/unit/test_tpu_compile.py`` compiles it for v5e at the Llama-3-8B
+and 16/16-MHA geometries, and ``chip_smoke.py`` runs it there.
 Block-size tuning is data-driven via the paged leg of
 ``benchmarks/attn_kernels.py``.
 """
@@ -147,6 +156,8 @@ def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         o_ref, acc_ref, m_ref, l_ref = rest
     b = pl.program_id(0)
     w = pl.program_id(1)
+    q_heads = kv_heads * group
+    cols = block * kv_heads
 
     @pl.when(w == 0)
     def _init():
@@ -163,49 +174,53 @@ def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     @pl.when(run)
     def _compute():
         q = q_ref[0]                               # [Hq, D] input dtype
-        k = k_ref[0]                               # [block, Hk, D]
-        v = v_ref[0]
-        pos = w * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
-        valid = pos < length                       # [1, block]
-        # kv heads unrolled (static, small): each group of q heads
-        # shares one kv head's block tile — the no-repeat GQA read
-        for h in range(kv_heads):
-            rows = slice(h * group, (h + 1) * group)
-            kh = k[:, h, :].astype(q.dtype)        # [block, D]
-            s = jax.lax.dot_general(
-                q[rows], kh, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale                              # [G, block] fp32
-            if quantized:
-                # int8 pool: per-(row, head) dequant scale folds into
-                # the scores (k) and softmax weights (v) — the
-                # _grouped_cache_attention contract, in-kernel
-                s = s * ks_ref[0][:, h][None, :]
-            s = jnp.where(valid, s, NEG_INF)
-            m_prev = m_ref[rows]                   # [G, 1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
-            p = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
-            corr = jnp.exp(
-                jnp.where(m_prev == NEG_INF, NEG_INF, m_prev - m_safe)
-            )
-            # the normalizer sums the UNSCALED softmax weights; the
-            # v dequant scale rides only the weighted-value matmul
-            # (the _grouped_cache_attention contract)
-            l_ref[rows] = l_ref[rows] * corr + jnp.sum(
-                p, axis=-1, keepdims=True
-            )
-            if quantized:
-                p = p * vs_ref[0][:, h][None, :]
-            # zero invalid value rows: 0-weight x garbage must stay 0
-            vh = jnp.where(
-                valid.reshape(block, 1), v[:, h, :].astype(q.dtype), 0
-            )
-            acc_ref[rows] = acc_ref[rows] * corr + jax.lax.dot_general(
-                p.astype(q.dtype), vh, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            m_ref[rows] = m_new
+        # the block tile arrives flattened [block * Hk, D]: row
+        # r = pos * Hk + head. ONE matmul scores every q head against
+        # every (pos, head) row and the mask keeps each q head's own
+        # kv head — the no-repeat GQA read without per-head sublane
+        # slices of the tile (which Mosaic cannot lay out). The
+        # off-head columns are wasted MXU work on a step the HBM read
+        # bounds.
+        k = k_ref[0].astype(q.dtype)
+        v = v_ref[0].astype(q.dtype)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+        q_head = jax.lax.broadcasted_iota(jnp.int32, (q_heads, 1), 0)
+        valid = (col % kv_heads == q_head // group) & (
+            w * block + col // kv_heads < length
+        )                                          # [Hq, cols]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale                                  # [Hq, cols] fp32
+        if quantized:
+            # int8 pool: per-(row, head) dequant scale folds into
+            # the scores (k) and softmax weights (v) — the
+            # _grouped_cache_attention contract, in-kernel
+            s = s * ks_ref[0]
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_ref[:]                          # [Hq, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
+        p = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
+        corr = jnp.exp(
+            jnp.where(m_prev == NEG_INF, NEG_INF, m_prev - m_safe)
+        )
+        # the normalizer sums the UNSCALED softmax weights; the
+        # v dequant scale rides only the weighted-value matmul
+        # (the _grouped_cache_attention contract)
+        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        if quantized:
+            p = p * vs_ref[0]
+        # zero invalid value rows: 0-weight x garbage must stay 0.
+        # The row-oriented mask comes from its own iota — reshaping
+        # the [1, cols] one is a lane->sublane cast Mosaic refuses.
+        row = jax.lax.broadcasted_iota(jnp.int32, (cols, 1), 0)
+        v = jnp.where(w * block + row // kv_heads < length, v, 0)
+        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
+            p.astype(q.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[:] = m_new
 
     @pl.when(w == num_blocks - 1)
     def _finalize():
@@ -222,29 +237,36 @@ def _paged_pallas(q, k, v, block_table, lengths, *, k_scale, v_scale,
     num_pool_blocks, block, kv_heads, _ = k.shape
     w = block_table.shape[1]
     group = q_heads // kv_heads
+    cols = block * kv_heads
     quantized = k_scale is not None
 
     def kv_map(b, wi, table, lens):
-        return (table[b, wi], 0, 0, 0)
-
-    def scale_map(b, wi, table, lens):
         return (table[b, wi], 0, 0)
 
     def q_map(b, wi, table, lens):
         return (b, 0, 0)
 
+    # [N, block, Hk, D] -> [N, block * Hk, D] merges the two middle
+    # dims under an unchanged minor dim: a bitcast of the pool on TPU
+    # (checked in the compiled HLO at head_dim 128), never a copy
     in_specs = [
         pl.BlockSpec((1, q_heads, head_dim), q_map),
-        pl.BlockSpec((1, block, kv_heads, head_dim), kv_map),
-        pl.BlockSpec((1, block, kv_heads, head_dim), kv_map),
+        pl.BlockSpec((1, cols, head_dim), kv_map),
+        pl.BlockSpec((1, cols, head_dim), kv_map),
     ]
-    operands = [q, k, v]
+    operands = [
+        q,
+        k.reshape(num_pool_blocks, cols, head_dim),
+        v.reshape(num_pool_blocks, cols, head_dim),
+    ]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, block, kv_heads), scale_map),
-            pl.BlockSpec((1, block, kv_heads), scale_map),
+        # scale planes ride as one lane-dense row per block, matching
+        # the score columns
+        in_specs += [pl.BlockSpec((1, 1, cols), kv_map)] * 2
+        operands += [
+            k_scale.reshape(num_pool_blocks, 1, cols),
+            v_scale.reshape(num_pool_blocks, 1, cols),
         ]
-        operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -270,7 +292,11 @@ def _paged_pallas(q, k, v, block_table, lengths, *, k_scale, v_scale,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, q_heads, head_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
         interpret=interpret,
+        name="paged_attention",
     )(
         block_table.astype(jnp.int32), lengths.astype(jnp.int32), *operands
     )

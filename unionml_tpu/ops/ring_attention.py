@@ -28,7 +28,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from unionml_tpu.parallel import compat
 from jax import lax
 
 from unionml_tpu.ops.attention import NEG_INF, _blockwise_accumulate, _repeat_kv
@@ -49,7 +48,7 @@ def ring_attention_sharded(
     ``q, k, v``: local shards [B, S_local, H, D]; returns the local output
     shard. Requires every device's shard to have equal length.
     """
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
     my_idx = lax.axis_index(axis)
     batch, s_local, num_q_heads, head_dim = q.shape
     # NOTE: GQA kv shards rotate un-repeated — _blockwise_accumulate expands
@@ -100,7 +99,7 @@ def ring_attention(
     Shards the sequence axis over ``mesh[axis]``, runs the ring, and
     returns the globally-shaped output (sharded the same way).
     """
-    from unionml_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P(None, axis, None, None)
@@ -165,7 +164,7 @@ def _ring_flash_fwd_steps(q_bhsd, k0, v0, *, axis, causal, scale, block_q, block
                           num_heads):
     """Run the ring. ``q_bhsd``: [B*H, S_loc, D]; ``k0, v0``: 4D
     [B, S_loc, KVH, D] (rotate unrepeated). Returns (out fp32, lse)."""
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
     my_idx = lax.axis_index(axis)
     perm = [(i, (i + 1) % n) for i in range(n)]
     interpret = _interpret()
@@ -231,7 +230,7 @@ def _ring_flash_bwd(axis, causal, scale, block_q, block_kv, residuals, g):
     q, k, v, out_bhsd, lse = residuals
     b, s_loc, h, d = q.shape
     kv_heads = k.shape[2]
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
     my_idx = lax.axis_index(axis)
     perm = [(i, (i + 1) % n) for i in range(n)]
     interpret = _interpret()
@@ -342,7 +341,7 @@ def ring_flash_attention(
     scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Ring flash attention over globally-shaped [B,S,H,D] tensors."""
-    from unionml_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P(None, axis, None, None)

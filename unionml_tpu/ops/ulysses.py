@@ -16,7 +16,6 @@ from typing import Optional
 
 import jax.numpy as jnp
 
-from unionml_tpu.parallel import compat
 from jax import lax
 
 
@@ -49,7 +48,7 @@ def ulysses_attention_sharded(
     """
     from unionml_tpu.ops.attention import attention
 
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
     for name, t in (("q", q), ("k", k), ("v", v)):
         if t.shape[2] % n:
             raise ValueError(
@@ -79,7 +78,7 @@ def ulysses_attention(
     block_size: int = 512,
 ) -> jnp.ndarray:
     """Ulysses attention over globally-shaped [B,S,H,D] tensors."""
-    from unionml_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P(None, axis, None, None)

@@ -14,8 +14,9 @@ first-class replacement: strategies compose as axes of one
 - :mod:`unionml_tpu.parallel.pipeline` — pipeline-parallel stage executor.
 """
 
+from jax import shard_map
+
 from unionml_tpu.parallel.collectives import bucketed_psum
-from unionml_tpu.parallel.compat import shard_map
 from unionml_tpu.parallel.mesh import (
     cpu_multiprocess_supported,
     make_mesh,

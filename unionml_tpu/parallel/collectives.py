@@ -18,7 +18,6 @@ from typing import Any, List, Sequence, Union
 
 from jax import lax
 
-from unionml_tpu.parallel import compat
 
 AxisName = Union[str, Sequence[str]]
 
@@ -95,7 +94,7 @@ def reduce_scatter(x: Any, axis: AxisName, *, scatter_axis: int = 0):
 
 def ppermute_shift(x: Any, axis: str, *, shift: int = 1):
     """Rotate shards around a ring (ring-attention KV rotation over ICI)."""
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis, perm)
 
@@ -111,4 +110,4 @@ def axis_index(axis: str):
 
 
 def axis_size(axis: str):
-    return compat.axis_size(axis)
+    return lax.axis_size(axis)

@@ -26,7 +26,6 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from unionml_tpu.parallel import compat
 from jax import lax
 
 
@@ -51,7 +50,7 @@ def pipeline_spmd(
     Returns [M, mb, ...] outputs, valid on the LAST stage (zeros elsewhere —
     callers psum or mask; see :func:`pipeline_apply`).
     """
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     num_micro, mb = microbatches.shape[0], microbatches.shape[1:]
     fn = jax.checkpoint(stage_fn) if remat else stage_fn
@@ -110,7 +109,7 @@ def pipeline_apply(
     stage weights; ppermute/psum stay on the ``pipeline`` axis), so the
     per-device microbatch is ``B / num_microbatches / mesh.shape[data_axis]``.
     """
-    from unionml_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.shape[axis]

@@ -54,7 +54,7 @@ __all__ = [
     "ServingRegressionWatchdog",
 ]
 
-#: The device-pass taxonomy (docs/observability.md "Serving goodput &
+#: The device-pass kinds (docs/observability.md "Serving goodput &
 #: tail attribution"). Every dispatcher pass is exactly one of these.
 PASS_KINDS = (
     "full_batch",     # all slots occupied: pure useful decode
