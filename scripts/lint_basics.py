@@ -311,6 +311,17 @@ REQUEST_LABEL_EXEMPT = ("unionml_tpu/serving/usage.py",)
 TRACE_SPAN_NAMES = (
     # engine request lifecycle
     "queue", "prefill", "harvest", "recover",
+    # the dispatcher's host-side admission work inside prefill
+    # (TraceRecorder.span: also on the profiler's clock as
+    # engine.admit / engine.admit.enqueue)
+    "admit", "admit.enqueue",
+    # host spans that go to an open profiler session only
+    # (TraceRecorder.span(None, ...); docs/observability.md "Host spans
+    # on the profiler's clock") — chipbench/hostspans.py reads these
+    "engine.pass", "engine.admit", "engine.admit.enqueue",
+    "engine.dispatch_chunk", "engine.dispatch_chunk.enqueue",
+    "engine.poll", "engine.harvest_wait", "engine.harvest_process",
+    "train.feed_wait", "train.step",
     # micro-batcher
     "predict",
     # fleet router decision machinery (docs/observability.md
@@ -364,7 +375,8 @@ def _span_name_literal(node: ast.Call):
 
 
 def check_span_names(package_root: Path) -> list:
-    """Every literal span name at a ``record_span`` call site must be
+    """Every literal span name at a ``record_span`` or ``span`` call
+    site (the name, and ``span``'s ``annotation=``) must be
     in :data:`TRACE_SPAN_NAMES` (constants) or open with a
     :data:`TRACE_SPAN_PREFIXES` family (f-strings), and the whole
     vocabulary must be documented in docs/observability.md — the
@@ -387,12 +399,18 @@ def check_span_names(package_root: Path) -> list:
             if not (
                 isinstance(node, ast.Call)
                 and isinstance(node.func, ast.Attribute)
-                and node.func.attr == "record_span"
+                and node.func.attr in ("record_span", "span")
             ):
                 continue
             kind, name = _span_name_literal(node)
             if kind is None:
                 continue  # variable name: runtime-enforced vocabulary
+            for kw in node.keywords:
+                # span(rid, name, annotation=...): the profiler's name
+                if kw.arg == "annotation" and isinstance(
+                    kw.value, ast.Constant
+                ) and kw.value.value not in TRACE_SPAN_NAMES:
+                    kind, name = "const", kw.value.value
             if kind == "const" and name in TRACE_SPAN_NAMES:
                 continue
             if kind == "prefix" and name and any(

@@ -560,8 +560,16 @@ def test_http_overload_answers_429_with_retry_after():
     """THE transport acceptance scenario: drive the engine queue past
     ``max_queue_depth`` and observe 429 + ``Retry-After`` at the HTTP
     layer, then 503 (+ ``Retry-After``) once the app drains."""
+    # request "a" must hold the only slot until the 429 has been seen: on
+    # the tiny model its 48 tokens take tens of milliseconds once the
+    # programs are compiled, less than the /health GET and the third POST
+    # below take on a loaded host — "a" then retired, "b" left the queue
+    # and the third POST got 200. Every decode-chunk dispatch stalls
+    # (24 chunks x 0.25 s hold the slot for 6 s) until the 429 is in hand.
+    fi = FaultInjector()
+    fi.arm("engine.dispatch", count=10 ** 6, delay_s=0.25)
     app, engine = _engine_serving_app(
-        slots=1, max_new_tokens=48, max_queue_depth=1,
+        slots=1, max_new_tokens=48, max_queue_depth=1, fault_injector=fi,
     )
     host, port = app.serve(port=0, blocking=False)
     url = f"http://{host}:{port}"
@@ -587,6 +595,7 @@ def test_http_overload_answers_429_with_retry_after():
         assert r.status_code == 429
         assert "queue is full" in r.json()["error"]
         assert int(r.headers["retry-after"]) >= 1
+        fi.disarm()  # the slot may go now: "a" and "b" finish at full speed
         t1.join(timeout=120)
         t2.join(timeout=120)
         assert results["a"].status_code == 200

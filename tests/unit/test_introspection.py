@@ -76,6 +76,51 @@ def test_tracker_records_cost_and_compiles_per_signature():
     assert row.rsplit(" ", 1)[1] == "2"
 
 
+def test_ratio_series_are_absent_not_zero_without_a_cost_analysis(monkeypatch):
+    """On the TPU a lowering's ``cost_analysis()`` is ``None``: the program
+    then publishes no MFU / HBM ratio series (a 0 would read as an idle
+    chip), while its calls and compiles are still counted. An opaque
+    callable has no cost either. A program whose analysis answers keeps
+    its series."""
+    import jax
+    import jax.numpy as jnp
+    from jax._src import stages
+
+    reg = MetricsRegistry()
+    tracker = ProgramTracker(registry=reg, component="t2")
+    known = tracker.wrap("t.known", jax.jit(lambda x: (x @ x).sum()))
+    known(jnp.ones((8, 8)))
+    monkeypatch.setattr(stages.Lowered, "cost_analysis", lambda self: None)
+    unknown = tracker.wrap("t.unknown", jax.jit(lambda x: (x + 1).sum()))
+    unknown(jnp.ones((8, 8)))
+    unknown(jnp.ones((8, 8)))
+    opaque = tracker.wrap("t.opaque", lambda x: x)
+    opaque(1)
+
+    def ratio_rows(program):
+        return [
+            line for line in reg.exposition().splitlines()
+            if line.startswith(
+                ("unionml_program_mfu_ratio{", "unionml_program_hbm_ratio{"))
+            and f'program="{program}"' in line
+        ]
+
+    assert len(ratio_rows("t.known")) == 2
+    assert ratio_rows("t.unknown") == [] and ratio_rows("t.opaque") == []
+    stats = tracker.stats()
+    assert stats["t.known"]["cost_known"] is True
+    assert stats["t.unknown"]["cost_known"] is False
+    assert stats["t.unknown"]["calls"] == 2
+    assert stats["t.unknown"]["compiles"] == 1
+    assert stats["t.unknown"]["flops_per_call"] == 0.0
+    calls = next(
+        line for line in reg.exposition().splitlines()
+        if line.startswith("unionml_program_calls_total{")
+        and 'program="t.unknown"' in line
+    )
+    assert calls.rsplit(" ", 1)[1] == "2"
+
+
 def test_tracker_detects_recompiles_and_survives_donation():
     """A shape revisited after jit cache behavior is stable stays
     cached (no phantom recompiles), and cost analysis works for donated

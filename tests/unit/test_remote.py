@@ -108,6 +108,7 @@ def test_app_version_dirty_tree_guard(tmp_path, monkeypatch):
 
 import os
 import subprocess
+import threading
 
 REPO_ROOT = Path(__file__).parent.parent.parent
 
@@ -133,6 +134,11 @@ def _fake_transport(monkeypatch, backend, fail_hosts=(), capture=None, stub=Fals
     bash, so the real runner executes in the pushed workdir.
     """
 
+    # every fake "host" shares this one file system, and deploy() provisions
+    # hosts concurrently: one host's `rm -rf <workdir>` must not run inside
+    # another's `cp -r` into the same directory (real hosts have their own)
+    shared_fs = threading.Lock()
+
     def fake_run_ssh(host, command):
         if capture is not None:
             capture.append(("run_ssh", host, command))
@@ -142,7 +148,8 @@ def _fake_transport(monkeypatch, backend, fail_hosts=(), capture=None, stub=Fals
             # remote docker isn't available in the fake environment in
             # either mode; the capture records the pull for assertions
             return subprocess.CompletedProcess([], 0, "", "")
-        return subprocess.run(["bash", "-c", command], capture_output=True, text=True)
+        with shared_fs:
+            return subprocess.run(["bash", "-c", command], capture_output=True, text=True)
 
     def fake_scp_to(host, src, dst):
         if capture is not None:
@@ -151,7 +158,8 @@ def _fake_transport(monkeypatch, backend, fail_hosts=(), capture=None, stub=Fals
         # the very dir it comes from — a no-op copy, not an error
         if Path(src.rstrip("/.")).resolve() == Path(dst).resolve():
             return
-        subprocess.run(["bash", "-c", f"mkdir -p {dst} && cp -r {src} {dst}"], check=True)
+        with shared_fs:
+            subprocess.run(["bash", "-c", f"mkdir -p {dst} && cp -r {src} {dst}"], check=True)
 
     def fake_scp_from(host, src, dst):
         if capture is not None:

@@ -229,13 +229,17 @@ def test_trace_recorder_bounds_completed_ring():
 
 
 def test_engine_request_spans_reach_tracer(tiny_llama_engine):
-    """A served request's spans follow queue → prefill → decode-chunk[i]
-    → harvest, and the Chrome export is structurally Perfetto-valid."""
+    """A served request's spans follow queue → admit (around its
+    admit.enqueue) → prefill → decode-chunk[i] → harvest, and the Chrome
+    export is structurally Perfetto-valid."""
     engine, params, tracer = tiny_llama_engine
     engine.generate(params, [[1, 2, 3]])
     chrome = json.loads(json.dumps(tracer.export_chrome()))
     names = [e["name"] for e in chrome["traceEvents"] if e["ph"] == "X"]
-    assert names[0] == "queue" and names[1] == "prefill"
+    # the export orders by start: admit opens inside the queue wait's
+    # last microseconds, prefill starts where queue ends
+    assert names[0] == "queue"
+    assert set(names[1:4]) == {"admit", "prefill", "admit.enqueue"}
     assert any(n.startswith("decode-chunk[") for n in names)
     assert names[-1] == "harvest"
 

@@ -227,6 +227,9 @@ def batch_indices(
         yield order[i * batch_size : (i + 1) * batch_size]
 
 
+_FEED_END = object()  # the feed's exhaustion, told apart from any batch
+
+
 def run_step_trainer(
     *,
     step_fn: Callable,
@@ -507,11 +510,23 @@ def run_step_trainer(
         double_buffer=double_buffer,
     )
     try:
+        # host spans on the profiler's clock (docs/observability.md):
+        # train.feed_wait around the feed's next(), train.step (a
+        # StepTraceAnnotation carrying the step number) around the
+        # step's dispatch; both only exist while a profiler session is
+        # open and cost a check otherwise
+        span = telemetry.get_tracer().span
         with ctx, overlap_ctx, contextlib.closing(feed):
-            for batch in feed:
+            batches = iter(feed)
+            while True:
+                with span(None, "train.feed_wait", step_num=steps):
+                    batch = next(batches, _FEED_END)
+                if batch is _FEED_END:
+                    break
                 t_step = time.perf_counter()
                 with phase_scope(tracker, "compute"):
-                    state, metrics = step(state, batch)
+                    with span(None, "train.step", step=steps):
+                        state, metrics = step(state, batch)
                     window_closed = timer.closes_window()
                     if measure_device_time:
                         # opt-in sync point: the step_ms sample below then

@@ -36,6 +36,9 @@ Everything degrades gracefully: a non-jitted callable is tracked
 opaquely (calls and wall time, no cost analysis), a backend without
 profiling support captures an empty trace with a log line, and cost
 analysis failures record zeros instead of failing the serving path.
+A program whose cost no analysis answered for (opaque, or any program
+on the TPU, where a lowering has no cost analysis) publishes no MFU /
+HBM ratio series at all rather than a 0.
 CPU-testable end to end (``cost_analysis`` works on CPU jit).
 """
 
@@ -160,7 +163,7 @@ class _Program:
 
     __slots__ = (
         "key", "calls", "compiles", "cum_flops", "cum_bytes",
-        "cost_by_sig", "last_cost", "window", "last_t",
+        "cost_by_sig", "last_cost", "window", "last_t", "cost_known",
         "m_calls", "m_compiles", "m_flops", "m_bytes", "h_compile",
     )
 
@@ -177,6 +180,9 @@ class _Program:
         self.last_cost: Tuple[float, float] = (0.0, 0.0)
         self.window: "deque[Tuple[float, float, float]]" = deque(maxlen=256)
         self.last_t = 0.0
+        # a cost analysis has answered for this program: only then do
+        # its MFU / HBM ratio series exist (absent, not 0, otherwise)
+        self.cost_known = False
 
 
 class ProgramTracker:
@@ -269,14 +275,26 @@ class ProgramTracker:
                 prog.m_flops = self._f_flops.labels(*lbl)
                 prog.m_bytes = self._f_bytes.labels(*lbl)
                 prog.h_compile = self._f_compile_ms.labels(*lbl)
-                self._f_mfu.labels(*lbl).set_function(
-                    lambda p=prog: self._utilization(p)[0]
-                )
-                self._f_hbm.labels(*lbl).set_function(
-                    lambda p=prog: self._utilization(p)[1]
-                )
                 self._programs[key] = prog
             return prog
+
+    def _publish_ratios(self, prog: _Program) -> None:
+        """Create ``prog``'s MFU / HBM ratio series, at the first cost
+        analysis that answers. A program whose cost is unknown (an
+        opaque callable; any program on a backend whose lowering has no
+        cost analysis, the TPU among them) publishes NO ratio series: a
+        0 there would read as an idle chip, not as an unknown."""
+        with self._lock:
+            if prog.cost_known:
+                return
+            prog.cost_known = True
+        lbl = (self.component, prog.key)
+        self._f_mfu.labels(*lbl).set_function(
+            lambda p=prog: self._utilization(p)[0]
+        )
+        self._f_hbm.labels(*lbl).set_function(
+            lambda p=prog: self._utilization(p)[1]
+        )
 
     def wrap(
         self,
@@ -319,17 +337,32 @@ class ProgramTracker:
         """Compile event (rare, off the steady-state path): record the
         compile and run the abstract-args cost analysis for the new
         signature. Lowering re-traces but never re-compiles, and the
-        abstract skeleton sidesteps donated buffers."""
+        abstract skeleton sidesteps donated buffers.
+
+        On the TPU the lowering's cost analysis is ``None`` (only a
+        compiled executable has one there), and the executable this
+        call just compiled lives in jit's own cache with no handle to
+        it: asking again (``lower().compile()``) is a second compile or
+        a cache load at serve time, which this tracker never pays. The
+        program then counts calls and compiles only, and publishes no
+        MFU / HBM ratio series."""
         cost = (0.0, 0.0)
         try:
             a_args, a_kwargs = _abstract_args(args, kwargs)
             analysis = fn.lower(*a_args, **a_kwargs).cost_analysis()
             if isinstance(analysis, (list, tuple)):
-                analysis = analysis[0] if analysis else {}
-            cost = (
-                float(analysis.get("flops", 0.0) or 0.0),
-                float(analysis.get("bytes accessed", 0.0) or 0.0),
-            )
+                analysis = analysis[0] if analysis else None
+            if analysis is None:
+                logger.info(
+                    f"no cost analysis for {prog.key} on this backend: "
+                    "its MFU / HBM ratio series are not published"
+                )
+            else:
+                cost = (
+                    float(analysis.get("flops", 0.0) or 0.0),
+                    float(analysis.get("bytes accessed", 0.0) or 0.0),
+                )
+                self._publish_ratios(prog)
         except Exception as exc:
             logger.info(f"cost analysis unavailable for {prog.key}: {exc!r}")
         with self._lock:
@@ -436,6 +469,7 @@ class ProgramTracker:
                 entry = {
                     "calls": prog.calls,
                     "compiles": prog.compiles,
+                    "cost_known": prog.cost_known,
                     "flops_per_call": prog.last_cost[0],
                     "bytes_per_call": prog.last_cost[1],
                     "flops_total": prog.cum_flops,

@@ -89,7 +89,7 @@ import threading
 import time
 from collections import OrderedDict, deque
 from dataclasses import dataclass, field
-from typing import Any, List, Optional, Sequence
+from typing import Any, Callable, List, Optional, Sequence
 
 import numpy as np
 
@@ -888,6 +888,14 @@ class DecodeEngine:
         # harvest-span anchor: set at the top of each _process_entry
         # (harvester thread only), read by _finish_if_done under the lock
         self._harvest_t0 = 0.0
+        # dispatcher thread only: the open engine.pass span, this
+        # iteration's seconds by phase, whether its chunk dispatch
+        # found the pipeline's credits taken, and the admissions
+        # completed since the previous chunk (count, prefill tokens)
+        self._pass_span = None
+        self._it_admit_s = self._it_dispatch_s = self._it_enqueue_s = 0.0
+        self._no_credit = False
+        self._admitted_since_chunk = [0, 0]
         self._build_programs()
         if self.introspect:
             self._instrument_programs()
@@ -2643,16 +2651,21 @@ class DecodeEngine:
             )
         if st is None:
             st = self._init_state()
-        if self.paged:
-            new_state, first = self._prefill(
-                self._params, st, jnp.int32(slot), jnp.asarray(ids),
-                jnp.asarray(padded), jnp.int32(len(req.prompt)), key,
-            )
-        else:
-            new_state, first = self._prefill(
-                self._params, st, jnp.int32(slot), jnp.asarray(padded),
-                jnp.int32(len(req.prompt)), key,
-            )
+        with self._tracer.span(
+            req.rid, "admit.enqueue", annotation="engine.admit.enqueue",
+            program="prefill",
+        ) as sp:
+            if self.paged:
+                new_state, first = self._prefill(
+                    self._params, st, jnp.int32(slot), jnp.asarray(ids),
+                    jnp.asarray(padded), jnp.int32(len(req.prompt)), key,
+                )
+            else:
+                new_state, first = self._prefill(
+                    self._params, st, jnp.int32(slot), jnp.asarray(padded),
+                    jnp.int32(len(req.prompt)), key,
+                )
+        self._it_enqueue_s += sp.end_s - sp.start_s
         _start_host_copy(first)
         if self._usage is not None:
             # the monolithic prefill's cost-analysis FLOPs, accumulated
@@ -2680,6 +2693,8 @@ class DecodeEngine:
             # accounting continues from them (fresh admissions: 0 + 1)
             req._expected = len(req.tokens) + 1
             self._m_slots_busy.set(self._slots_in_use_locked())
+        self._admitted_since_chunk[0] += 1
+        self._admitted_since_chunk[1] += req._prefilled_tokens
         # admission segment: dispatch start → prefill program enqueued
         # (host-side admission machinery; the device part of prefill
         # lands in prefill_ms at harvest)
@@ -3148,51 +3163,75 @@ class DecodeEngine:
             return
         if entry[0] == "prefill":
             _, _, slot, req, first = entry
-            tok = int(np.asarray(first))
-            now = time.perf_counter()  # after the readback: prefill_ms
-            with self._lock:           # includes its in-flight lag
-                req.prefill_ms = (now - req._dispatch_t) * 1e3
-                if req.ttft_ms == 0.0:
-                    # a RESUMED stream's first token already happened;
-                    # its ttft must stay the first segment's
-                    req.ttft_ms = (now - req.submitted) * 1e3
-                req._prefill_end = now
-                # ITL anchor: the next decode chunk's harvest spacing
-                # measures from this first token (re-anchored here on
-                # resume too, so the evict→resume gap never counts)
-                req._itl_anchor = now
-                self._tracer.record_span(
-                    req.rid, "prefill", req._dispatch_t, now,
-                    tokens=req._prefilled_tokens,
-                )
-                req.tokens.append(tok)
-                req.emit([tok])
-                if self._perf is not None:
-                    self._perf.note_tokens(1)
-                self._finish_if_done(slot, tok)
-            if self._usage is not None:
-                # the prefill's exclusive pipeline window (consecutive-
-                # harvest spacing) + its dispatched programs' FLOPs,
-                # billed wholly to the admitting tenant; the sampled
-                # first token is that tenant's first served token
-                device_s = max(
-                    0.0,
-                    now - max(req._dispatch_t, self._last_harvest_end),
-                )
-                self._last_harvest_end = now
-                self._usage.attribute(
-                    {req.tenant: 1}, device_s=device_s,
-                    flops=req._attr_flops,
-                )
-                # drained: a resumed stream's next prefill segment
-                # must not re-bill the first segment's programs
-                req._attr_flops = 0.0
+            with self._tracer.span(
+                None, "engine.harvest_wait", kind="prefill", req=req.rid,
+            ):
+                tok = int(np.asarray(first))
+            with self._tracer.span(
+                None, "engine.harvest_process", kind="prefill", req=req.rid,
+            ):
+                self._process_prefill(slot, req, tok)
             return
         _, _, mask, gens, toks, dispatched, seq = entry
-        if self.draft is not None:
-            self._process_spec_chunk(mask, gens, toks, dispatched)
-            return
-        toks = np.asarray(toks)
+        with self._tracer.span(
+            None, "engine.harvest_wait", kind="chunk", seq=seq,
+        ):
+            if isinstance(toks, tuple):  # the speculative chunk's outputs
+                toks = tuple(np.asarray(x) for x in toks)
+            else:
+                toks = np.asarray(toks)
+        with self._tracer.span(
+            None, "engine.harvest_process", kind="chunk", seq=seq,
+        ):
+            if self.draft is not None:
+                self._process_spec_chunk(mask, gens, toks, dispatched)
+            else:
+                self._process_chunk(mask, gens, toks, dispatched, seq)
+
+    def _process_prefill(self, slot: int, req: _Request, tok: int) -> None:
+        """Account a harvested prefill: the request's first token."""
+        now = time.perf_counter()  # after the readback: prefill_ms
+        with self._lock:           # includes its in-flight lag
+            req.prefill_ms = (now - req._dispatch_t) * 1e3
+            if req.ttft_ms == 0.0:
+                # a RESUMED stream's first token already happened;
+                # its ttft must stay the first segment's
+                req.ttft_ms = (now - req.submitted) * 1e3
+            req._prefill_end = now
+            # ITL anchor: the next decode chunk's harvest spacing
+            # measures from this first token (re-anchored here on
+            # resume too, so the evict→resume gap never counts)
+            req._itl_anchor = now
+            self._tracer.record_span(
+                req.rid, "prefill", req._dispatch_t, now,
+                tokens=req._prefilled_tokens,
+            )
+            req.tokens.append(tok)
+            req.emit([tok])
+            if self._perf is not None:
+                self._perf.note_tokens(1)
+            self._finish_if_done(slot, tok)
+        if self._usage is not None:
+            # the prefill's exclusive pipeline window (consecutive-
+            # harvest spacing) + its dispatched programs' FLOPs,
+            # billed wholly to the admitting tenant; the sampled
+            # first token is that tenant's first served token
+            device_s = max(
+                0.0,
+                now - max(req._dispatch_t, self._last_harvest_end),
+            )
+            self._last_harvest_end = now
+            self._usage.attribute(
+                {req.tenant: 1}, device_s=device_s,
+                flops=req._attr_flops,
+            )
+            # drained: a resumed stream's next prefill segment
+            # must not re-bill the first segment's programs
+            req._attr_flops = 0.0
+
+    def _process_chunk(self, mask, gens, toks, dispatched, seq) -> None:
+        """Account one harvested decode chunk's tokens (``toks`` is on
+        the host): emit, retire, sweep the deferred frees it fenced."""
         now = time.perf_counter()  # readback complete: the chunk landed
         self._h_harvest.observe((now - self._harvest_t0) * 1e3)
         tenant_tokens: dict = {}
@@ -3326,7 +3365,10 @@ class DecodeEngine:
         import jax.numpy as jnp
 
         if not self._chunk_credits.acquire(blocking=False):
-            return False  # pipeline_depth chunks already awaiting harvest
+            # pipeline_depth chunks already awaiting harvest: the poll
+            # that follows is the dispatcher waiting for the chip
+            self._no_credit = True
+            return False
         seq = 0
         table_np = None
         with self._lock:
@@ -3338,31 +3380,52 @@ class DecodeEngine:
             ep0 = self._epoch
             st = self._state
             proceed = bool(mask.any()) and needed and st is not None
-            if proceed and self.paged:
+            if proceed:
                 # grow tables + snapshot + assign this chunk's fence seq
                 # under ONE lock hold: a retirement racing this dispatch
                 # fences its deferred frees at _dispatch_seq, which now
                 # covers the snapshot we are about to launch — the
                 # in-flight chunk can never write a recycled block
-                table_np = self._grow_tables_locked()
+                # (contiguous engines have no fence; there the seq only
+                # numbers the chunk for its spans)
+                if self.paged:
+                    table_np = self._grow_tables_locked()
                 self._dispatch_seq += 1
                 seq = self._dispatch_seq
         if not proceed:
             self._chunk_credits.release()
             return False
+        self._enter_pass()
+        with self._tracer.span(
+            None, "engine.dispatch_chunk", seq=seq, live_slots=int(mask.sum()),
+        ) as sp:
+            self._launch_chunk(mask, st, ep0, table_np, seq)
+        self._it_dispatch_s += sp.end_s - sp.start_s
+        return True
+
+    def _launch_chunk(self, mask, st, ep0, table_np, seq) -> None:
+        """Enqueue the decode chunk that :meth:`_dispatch_chunk` decided
+        on (it holds a pipeline credit) and hand its readback to the
+        harvester."""
+        import jax.numpy as jnp
+
         t_dispatch = time.perf_counter()
         try:
             self._fire("engine.dispatch")
-            keys = jnp.stack(self._next_key(self.chunk_steps))
-            if self.paged:
-                new_state, toks = self._decode_chunk(
-                    self._params, st, jnp.asarray(mask),
-                    jnp.asarray(table_np), keys,
-                )
-            else:
-                new_state, toks = self._decode_chunk(
-                    self._params, st, jnp.asarray(mask), keys
-                )
+            with self._tracer.span(
+                None, "engine.dispatch_chunk.enqueue", seq=seq,
+            ) as sp:
+                keys = jnp.stack(self._next_key(self.chunk_steps))
+                if self.paged:
+                    new_state, toks = self._decode_chunk(
+                        self._params, st, jnp.asarray(mask),
+                        jnp.asarray(table_np), keys,
+                    )
+                else:
+                    new_state, toks = self._decode_chunk(
+                        self._params, st, jnp.asarray(mask), keys
+                    )
+            self._it_enqueue_s += sp.end_s - sp.start_s
             for leaf in toks if isinstance(toks, tuple) else (toks,):
                 _start_host_copy(leaf)
             self._h_dispatch.observe((time.perf_counter() - t_dispatch) * 1e3)
@@ -3378,7 +3441,7 @@ class DecodeEngine:
                 # (self._state stays the recovery's None) and drop the
                 # readback; the requests it covered are already failed
                 self._chunk_credits.release()
-                return True
+                return
             self._state = new_state
             for slot in np.flatnonzero(mask):
                 if self._occupant[slot] is not None:
@@ -3405,8 +3468,12 @@ class DecodeEngine:
             if self._perf is not None:
                 # goodput ring: classify this pass (full batch /
                 # padded slots / prefill-mix) + KV pool pressure
+                admitted, prefill_tokens = self._admitted_since_chunk
+                self._admitted_since_chunk = [0, 0]
                 self._perf.note_pass(
                     occupied_now,
+                    waiting=self._room.qsize(),
+                    admitted=admitted, prefill_tokens=prefill_tokens,
                     prefill_mix=self._admission is not None,
                     kv_in_use=(
                         self.kv_pool.in_use
@@ -3418,7 +3485,6 @@ class DecodeEngine:
                     ),
                 )
         self._inflight.put(("chunk", ep0, mask, gens, toks, t_dispatch, seq))
-        return True
 
     def _pop_request(self) -> Optional[_Request]:
         """Atomically dequeue a request and mark it as mid-admission, so
@@ -3853,9 +3919,14 @@ class DecodeEngine:
             toks = jnp.asarray(adm.padded[None, start: start + adm.chunk])
             if adm.next_chunk < adm.n_chunks - 1:
                 t0 = time.perf_counter()
-                adm.fresh = self._prefill_step(
-                    self._params, adm.fresh, toks, jnp.int32(start)
-                )
+                with self._tracer.span(
+                    req.rid, "admit.enqueue",
+                    annotation="engine.admit.enqueue", program="prefill_step",
+                ) as sp:
+                    adm.fresh = self._prefill_step(
+                        self._params, adm.fresh, toks, jnp.int32(start)
+                    )
+                self._it_enqueue_s += sp.end_s - sp.start_s
                 if self._usage is not None:
                     req._attr_flops += self._program_cost(
                         "engine.prefill_chunk", tuple(toks.shape)
@@ -3882,17 +3953,23 @@ class DecodeEngine:
                 # instead would strand the admission (never completed,
                 # never dropped) and wedge the engine
                 st = self._init_state()
-            if self.paged:
-                new_state, first = self._prefill_final(
-                    self._params, st, adm.fresh, jnp.int32(adm.slot),
-                    jnp.asarray(adm.pool_ids), toks, jnp.int32(start),
-                    jnp.int32(len(req.prompt)), key,
-                )
-            else:
-                new_state, first = self._prefill_final(
-                    self._params, st, adm.fresh, jnp.int32(adm.slot),
-                    toks, jnp.int32(start), jnp.int32(len(req.prompt)), key,
-                )
+            with self._tracer.span(
+                req.rid, "admit.enqueue", annotation="engine.admit.enqueue",
+                program="prefill_final",
+            ) as sp:
+                if self.paged:
+                    new_state, first = self._prefill_final(
+                        self._params, st, adm.fresh, jnp.int32(adm.slot),
+                        jnp.asarray(adm.pool_ids), toks, jnp.int32(start),
+                        jnp.int32(len(req.prompt)), key,
+                    )
+                else:
+                    new_state, first = self._prefill_final(
+                        self._params, st, adm.fresh, jnp.int32(adm.slot),
+                        toks, jnp.int32(start), jnp.int32(len(req.prompt)),
+                        key,
+                    )
+            self._it_enqueue_s += sp.end_s - sp.start_s
             _start_host_copy(first)
             if self._usage is not None:
                 req._attr_flops += self._program_cost(
@@ -3914,6 +3991,8 @@ class DecodeEngine:
                 req._expected = len(req.tokens) + 1
                 self._admitting -= 1
                 self._m_slots_busy.set(self._slots_in_use_locked())
+            self._admitted_since_chunk[0] += 1
+            self._admitted_since_chunk[1] += req._prefilled_tokens
             # admission segment: dispatch start → final prefill chunk
             # enqueued (covers every interleaved lead chunk + splice)
             req.admission_ms = (
@@ -3946,12 +4025,12 @@ class DecodeEngine:
         chunks still interleave every pass."""
         budget = self._mix_budget
         if budget is None:
-            self._advance_admission(adm)
+            self._admit_step(self._advance_admission, adm, adm.req)
             return
         remaining = budget
         while self._admission is adm:
             was_splice = adm.next_splice < len(adm.splice_rows)
-            self._advance_admission(adm)
+            self._admit_step(self._advance_admission, adm, adm.req)
             if not was_splice:
                 remaining -= adm.chunk
                 if remaining <= 0:
@@ -3964,6 +4043,7 @@ class DecodeEngine:
         host↔device round trip, so overlapping dispatch with harvest
         is what keeps the chip busy.
         """
+        t_iter = time.perf_counter()
         while not self._stop.is_set():
             try:
                 progressed = False
@@ -3973,6 +4053,7 @@ class DecodeEngine:
                     # configured prefill token budget of admission
                     # steps per pass, then a decode chunk — resident
                     # slots keep streaming under any budget
+                    self._enter_pass()
                     self._advance_admission_budgeted(adm)
                     progressed = True
                 else:
@@ -3989,7 +4070,8 @@ class DecodeEngine:
                     if req is None:
                         req = self._pop_request()
                     if req is not None:
-                        self._start_admission(req)
+                        self._enter_pass()
+                        self._admit_step(self._start_admission, req, req)
                         if self._room.is_parked(req):
                             # pool exhausted: EVICTING a strictly
                             # lower-priority resident is progress;
@@ -4002,7 +4084,9 @@ class DecodeEngine:
                             # and preempt on its own behalf)
                             breq = self._pop_bypass(req)
                             if breq is not None:
-                                self._start_admission(breq)
+                                self._admit_step(
+                                    self._start_admission, breq, breq
+                                )
                                 if self._room.is_parked(breq):
                                     progressed = (
                                         self._maybe_preempt(breq)
@@ -4012,18 +4096,103 @@ class DecodeEngine:
                                     progressed = True
                         else:
                             progressed = True
+                self._no_credit = False
                 if self._dispatch_chunk():
                     progressed = True
+                poll = None
                 if not progressed:
                     # nothing admittable or dispatchable: arrivals and
                     # harvest-freed slots are picked up next pass (2 ms
                     # keeps the 1-core host responsive without spinning)
-                    if self._perf is not None:
-                        # goodput ring: the device is parked this pass
-                        self._perf.note_idle()
-                    time.sleep(0.002)
+                    poll = "no_credit" if self._no_credit else "no_work"
+                t_iter = self._end_iteration(t_iter, poll)
             except BaseException as exc:  # pragma: no cover - engine crash
+                self._close_pass()
                 self._recover(exc)
+
+    # -- where the dispatcher's time goes (dispatcher thread only) ---------
+    #
+    # Every span below goes through the tracer's seam, so it is on the
+    # profiler's clock while a session is open (docs/observability.md
+    # "Host spans on the profiler's clock"); its perf_counter reads are
+    # the ones the perf plane's ``dispatcher_s`` sums.
+
+    def _enter_pass(self) -> None:
+        """Open this iteration's ``engine.pass`` span at the first point
+        where the iteration is certain to do work (an admission step or
+        a chunk dispatch); an iteration that finds nothing has none."""
+        if self._pass_span is None:
+            with self._lock:
+                free = self._occupant.count(None)
+            self._pass_span = self._tracer.span(
+                None, "engine.pass", waiting=self._room.qsize(),
+                free_slots=free, live_slots=self.slots - free,
+            )
+            self._pass_span.__enter__()
+
+    def _close_pass(self) -> None:
+        span, self._pass_span = self._pass_span, None
+        if span is not None:
+            span.__exit__(None, None, None)
+
+    def _end_iteration(self, t_iter: float, poll: Optional[str]) -> float:
+        """Close the iteration that began at ``t_iter``: end its pass
+        span, sleep the poll if it made no progress, and hand the perf
+        plane the iteration's seconds by phase. Returns the next
+        iteration's start."""
+        self._close_pass()
+        poll_s = 0.0
+        if poll is not None:
+            if (
+                poll == "no_work" and self._perf is not None
+                and self._engine_empty()
+            ):
+                # goodput ring: the device is parked this pass. A poll
+                # with the pipeline full, or with residents waiting for
+                # their last harvest, is not: the chip is busy
+                self._perf.note_idle()
+            with self._tracer.span(None, "engine.poll", reason=poll) as sp:
+                time.sleep(0.002)
+            poll_s = sp.end_s - sp.start_s
+        now = time.perf_counter()
+        if self._perf is not None:
+            admit_s, dispatch_s = self._it_admit_s, self._it_dispatch_s
+            self._perf.note_dispatcher(
+                admit_s=admit_s, dispatch_s=dispatch_s,
+                enqueue_s=self._it_enqueue_s, poll_s=poll_s,
+                other_s=max(
+                    0.0, now - t_iter - admit_s - dispatch_s - poll_s
+                ),
+                poll=poll,
+            )
+        self._it_admit_s = self._it_dispatch_s = self._it_enqueue_s = 0.0
+        return now
+
+    def _engine_empty(self) -> bool:
+        """No resident, nothing queued or parked, nothing in flight."""
+        with self._lock:
+            if self._admission is not None or any(
+                r is not None for r in self._occupant
+            ):
+                return False
+        return self._room.empty() and self._inflight.empty()
+
+    def _admit_step(self, step: Callable, arg, req: _Request) -> None:
+        """One host-side admission step (``_start_admission(req)`` or
+        ``_advance_admission(adm)``) under its ``engine.admit`` span,
+        which is also the request's ``admit`` span."""
+        with self._tracer.span(
+            req.rid, "admit", annotation="engine.admit",
+            bucket=self._bucket_for(len(req.prompt)),
+            prompt_tokens=len(req.prompt),
+        ) as sp:
+            step(arg)
+            sp.note(cached_tokens=req._saved_tokens)
+            if self._room.is_parked(req):
+                # pool exhausted: the admission is retried every pass;
+                # only the try that gets through is the request's span
+                sp.discard()
+        self._it_admit_s += sp.end_s - sp.start_s
 
     def _harvest_loop(self):
         """Harvester: block on the oldest in-flight readback, account its

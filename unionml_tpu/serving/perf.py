@@ -13,8 +13,16 @@ of :data:`PASS_KINDS`:
 - ``prefill_mix`` — the chunk ran while a chunked admission was
   interleaving prefill into the decode cadence (useful, but decode
   throughput is degraded by the mixed program).
-- ``idle`` — the dispatcher found no work at all (queue empty, no
-  occupants); wall time with the device parked.
+- ``idle`` — no resident, nothing queued and nothing in flight:
+  wall time with the device parked. A dispatcher poll that found the
+  pipeline's credits taken (or only a parked admission) is NOT a pass:
+  the chip is busy then, and the poll is counted under ``polls``.
+
+Beside the ring, the plane keeps plain sums since :meth:`reset` that a
+wrapped ring cannot cut short: dispatched / occupied / starved
+slot-steps, admissions, prefill tokens, dispatcher polls by reason
+(:data:`POLL_REASONS`) and the dispatcher thread's seconds by phase
+(:data:`DISPATCHER_PHASES`).
 
 :class:`ServingPerfPlane` owns the ring, publishes the
 ``unionml_serving_goodput_ratio`` / ``unionml_serving_occupancy_ratio``
@@ -48,7 +56,9 @@ from unionml_tpu import telemetry
 from unionml_tpu.goodput import StepTimeRegressionDetector
 
 __all__ = [
+    "DISPATCHER_PHASES",
     "PASS_KINDS",
+    "POLL_REASONS",
     "PERF_REGRESSION_REASONS",
     "ServingPerfPlane",
     "ServingRegressionWatchdog",
@@ -62,6 +72,21 @@ PASS_KINDS = (
     "prefill_mix",    # chunked admission interleaved into the cadence
     "idle",           # no work at all: device parked
 )
+
+#: Why the dispatcher slept a pass away: ``no_credit`` = all
+#: ``pipeline_depth`` chunks are awaiting harvest (the chip is busy and
+#: the dispatcher is ahead of it); ``no_work`` = nothing admittable or
+#: dispatchable (an empty engine, residents waiting only for their last
+#: harvest, or an admission parked on the KV pool).
+POLL_REASONS = ("no_work", "no_credit")
+
+#: Where the dispatcher thread's wall time goes. ``admit``, ``dispatch``,
+#: ``poll`` and ``other`` are disjoint and add up to the thread's time;
+#: ``enqueue`` is the part of ``admit`` + ``dispatch`` spent inside the
+#: jitted calls themselves (prefill and decode chunk, with their
+#: host-to-device arguments) — near the whole window it means an
+#: enqueue blocks and the pass is paced by the device.
+DISPATCHER_PHASES = ("admit", "dispatch", "enqueue", "poll", "other")
 
 #: Closed reasons vocabulary for ``perf_regression`` flight events —
 #: lint-enforced both ways against the docs table, like
@@ -185,8 +210,11 @@ class ServingPerfPlane:
     """Bounded-ring device-pass accountant for one decode engine.
 
     The engine's dispatcher calls :meth:`note_pass` after every chunk
-    dispatch and :meth:`note_idle` on every no-work pass; the
-    harvester calls :meth:`note_tokens` per harvested chunk. The ring
+    dispatch, :meth:`note_idle` on a pass that found the engine empty
+    (no resident, nothing queued, nothing in flight) and
+    :meth:`note_dispatcher` once per loop iteration with where its
+    time went; the harvester calls :meth:`note_tokens` per harvested
+    chunk. The ring
     (newest ``ring`` passes) is the goodput window: ratios are over
     *recent* passes, so a burst of idle at startup ages out instead of
     depressing the gauge forever.
@@ -234,6 +262,7 @@ class ServingPerfPlane:
         self._tokens = 0
         self._t0 = clock()
         self._kv_pressure = 0.0
+        self._zero_window_locked()
         self.watchdog = (
             watchdog
             if watchdog is not None
@@ -279,10 +308,18 @@ class ServingPerfPlane:
         prefill_mix: bool = False,
         kv_in_use: int = 0,
         kv_capacity: int = 0,
+        waiting: int = 0,
+        admitted: int = 0,
+        prefill_tokens: int = 0,
     ) -> None:
         """One dispatched decode chunk: ``occupied`` slots carried live
         requests (of the engine's ``slots``); ``prefill_mix`` flags a
-        chunk that ran while chunked admission was interleaving."""
+        chunk that ran while chunked admission was interleaving.
+        ``waiting`` is the waiting room's depth at the dispatch — the
+        chunk's empty slot-steps are *starved* when it is above 0 —
+        and ``admitted`` / ``prefill_tokens`` are the admissions
+        completed and prompt tokens prefilled since the previous
+        chunk."""
         occupied = min(self._slots, max(0, int(occupied)))
         if prefill_mix:
             kind = "prefill_mix"
@@ -296,6 +333,12 @@ class ServingPerfPlane:
         with self._lock:
             self._append_locked(kind, occ, total)
             self._passes += 1
+            self._win_disp_steps += total
+            self._win_occ_steps += occ
+            if waiting > 0:
+                self._win_starved_steps += total - occ
+            self._win_admissions += int(admitted)
+            self._win_prefill_tokens += int(prefill_tokens)
             if kv_capacity > 0:
                 self._kv_pressure = min(
                     1.0, max(0.0, kv_in_use / kv_capacity)
@@ -306,12 +349,39 @@ class ServingPerfPlane:
             self.watchdog.observe_goodput(goodput)
 
     def note_idle(self) -> None:
-        """One dispatcher pass that found no work: the whole batch's
-        slot-steps are classified idle."""
+        """One dispatcher pass that found the engine empty (no resident,
+        nothing queued, nothing in flight): the whole batch's
+        slot-steps are classified idle. A poll with the chip busy is
+        :meth:`note_dispatcher`'s, not this."""
         total = self._slots * self._chunk_steps
         with self._lock:
             self._append_locked("idle", 0, total)
             self._passes += 1
+
+    def note_dispatcher(
+        self,
+        *,
+        admit_s: float = 0.0,
+        dispatch_s: float = 0.0,
+        enqueue_s: float = 0.0,
+        poll_s: float = 0.0,
+        other_s: float = 0.0,
+        poll: Optional[str] = None,
+    ) -> None:
+        """One iteration of the dispatcher loop: its seconds by phase
+        (:data:`DISPATCHER_PHASES`; ``enqueue_s`` lies inside
+        ``admit_s`` + ``dispatch_s``) and, when the iteration slept,
+        why (:data:`POLL_REASONS`). Polls enter neither the ring nor
+        the lost slot-steps."""
+        with self._lock:
+            d = self._win_dispatcher_s
+            d["admit"] += admit_s
+            d["dispatch"] += dispatch_s
+            d["enqueue"] += enqueue_s
+            d["poll"] += poll_s
+            d["other"] += other_s
+            if poll is not None:
+                self._win_polls[poll] = self._win_polls.get(poll, 0) + 1
 
     def note_tokens(self, n: int) -> None:
         """``n`` tokens harvested (the achieved-throughput numerator)."""
@@ -329,6 +399,16 @@ class ServingPerfPlane:
             self.watchdog.observe_itl(itl_mean_ms)
 
     # -- reporting ---------------------------------------------------------
+
+    def _zero_window_locked(self) -> None:
+        # plain sums since reset(): a wrapped ring cannot cut them short
+        self._win_disp_steps = 0
+        self._win_occ_steps = 0
+        self._win_starved_steps = 0
+        self._win_admissions = 0
+        self._win_prefill_tokens = 0
+        self._win_polls = {reason: 0 for reason in POLL_REASONS}
+        self._win_dispatcher_s = {phase: 0.0 for phase in DISPATCHER_PHASES}
 
     def _append_locked(self, kind, occ, total) -> None:
         # deque(maxlen) evicts silently on append, which would desync
@@ -362,14 +442,31 @@ class ServingPerfPlane:
     def report(self) -> dict:
         """The ``/debug/goodput`` body for this engine: ring
         classification counts + slot-step sums, the three ratios,
-        achieved tokens/s since construction (or :meth:`reset`), and
-        the watchdog advisory."""
+        achieved tokens/s since construction (or :meth:`reset`), the
+        plain sums since then (``window_*``, ``starved_slot_steps``,
+        ``admissions``, ``prefill_tokens``, ``polls``,
+        ``dispatcher_s``), and the watchdog advisory. The ring wrapped
+        when ``total_passes > ring_passes``: the ratios then cover the
+        newest passes only, the sums still the whole window."""
         with self._lock:
             ring = list(self._ring)
             passes = self._passes
             tokens = self._tokens
             elapsed = max(1e-9, self._clock() - self._t0)
             ratios = self._ratios_locked()
+            window = {
+                "window_s": round(elapsed, 6),
+                "window_dispatched_slot_steps": self._win_disp_steps,
+                "window_occupied_slot_steps": self._win_occ_steps,
+                "starved_slot_steps": self._win_starved_steps,
+                "admissions": self._win_admissions,
+                "prefill_tokens": self._win_prefill_tokens,
+                "polls": dict(self._win_polls),
+                "dispatcher_s": {
+                    phase: round(s, 6)
+                    for phase, s in self._win_dispatcher_s.items()
+                },
+            }
         counts = {kind: 0 for kind in PASS_KINDS}
         slot_steps = {kind: 0 for kind in PASS_KINDS}
         occupied = 0
@@ -393,6 +490,7 @@ class ServingPerfPlane:
             "kv_pressure_ratio": round(pressure, 6),
             "tokens": tokens,
             "tokens_per_s": round(tokens / elapsed, 3),
+            **window,
             "watchdog": self.watchdog.advisory(),
         }
 
@@ -408,3 +506,4 @@ class ServingPerfPlane:
             self._tokens = 0
             self._t0 = self._clock()
             self._kv_pressure = 0.0
+            self._zero_window_locked()

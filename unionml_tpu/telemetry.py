@@ -1034,9 +1034,35 @@ class TraceRecorder:
         if dropped:
             self._count_dropped()
 
-    def span(self, rid: str, name: str, **args: Any):
-        """Context manager measuring one span around its body."""
-        return _SpanContext(self, rid, name, args)
+    def span(
+        self,
+        rid: Optional[str],
+        name: str,
+        *,
+        annotation: Optional[str] = None,
+        step: Optional[int] = None,
+        **args: Any,
+    ):
+        """Context manager measuring one span around its body, on both
+        clocks: the span lands in ``rid``'s timeline in
+        ``time.perf_counter()`` seconds (as :meth:`record_span`), and a
+        ``jax.profiler.TraceAnnotation`` of the same name, with ``rid``
+        and ``args`` as its metadata, lands on the calling thread's
+        line of an open profiler session, in the session's nanoseconds.
+        The two starts of one span are one (``perf_counter``, trace-ns)
+        pair, which is how a trace reader joins the clocks.
+
+        ``rid=None`` is a span that is no request's (a dispatcher pass,
+        a harvester wait): it goes to the profiler only and nothing is
+        kept in memory. ``annotation`` names the profiler's event where
+        the timeline's name would be ambiguous there (``admit`` in a
+        request's timeline, ``engine.admit`` among every thread's
+        events); ``step`` makes it a ``StepTraceAnnotation`` with that
+        step number. With no session open the annotation costs one
+        is-the-profiler-on check; without jax loaded, nothing. The
+        context exposes ``start_s`` / ``end_s`` (``perf_counter``) so
+        a caller's accounting shares the span's clock reads."""
+        return _SpanContext(self, rid, name, args, annotation, step)
 
     def record_event(
         self, rid: str, name: str, t_s: Optional[float] = None, **args: Any
@@ -1234,21 +1260,91 @@ class TraceRecorder:
 
 
 class _SpanContext:
-    def __init__(self, recorder: TraceRecorder, rid: str, name: str, args: dict):
+    __slots__ = ("_recorder", "_rid", "_name", "_args", "_ann", "start_s", "end_s")
+
+    def __init__(
+        self,
+        recorder: TraceRecorder,
+        rid: Optional[str],
+        name: str,
+        args: dict,
+        annotation: Optional[str] = None,
+        step: Optional[int] = None,
+    ):
         self._recorder = recorder
         self._rid = rid
         self._name = name
         self._args = args
-        self._t0 = 0.0
+        # built here, entered in __enter__: a span is made where it is
+        # entered, so "is a session open" is asked at its start
+        self._ann = _profiler_annotation(annotation or name, rid, step, args)
+        self.start_s = 0.0
+        self.end_s = 0.0
 
     def __enter__(self) -> "_SpanContext":
-        self._t0 = time.perf_counter()
+        # the two clock reads back to back: their distance is the
+        # error of the (perf_counter, trace-ns) pair
+        self.start_s = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__enter__()
         return self
 
+    def note(self, **args: Any) -> None:
+        """Add ``args`` known only inside the body (what an admission
+        found in the prefix cache) to the span on both clocks."""
+        self._args = {**self._args, **args}
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+
+    def discard(self) -> None:
+        """Keep this span out of the request's timeline (the profiler
+        still shows it): for work that is retried every few
+        milliseconds, where only the try that got through is the
+        request's span."""
+        self._rid = None
+
     def __exit__(self, *exc) -> None:
-        self._recorder.record_span(
-            self._rid, self._name, self._t0, time.perf_counter(), **self._args
-        )
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self.end_s = time.perf_counter()
+        if self._rid is not None:
+            self._recorder.record_span(
+                self._rid, self._name, self.start_s, self.end_s, **self._args
+            )
+
+
+# jax.profiler's annotation classes, resolved at the first span opened
+# after jax was imported by somebody else: telemetry never imports jax
+# itself (no jax loaded = no profiler session to write to)
+_annotation_classes: Optional[tuple] = None
+
+
+def _profiler_annotation(
+    name: str, rid: Optional[str], step: Optional[int], args: dict
+):
+    """An un-entered profiler annotation for one span, or ``None`` when
+    no profiler session is open (or jax is not loaded)."""
+    global _annotation_classes
+    classes = _annotation_classes
+    if classes is None:
+        if "jax" not in sys.modules:
+            return None
+        try:
+            from jax.profiler import StepTraceAnnotation, TraceAnnotation
+        except Exception:  # a jax without the profiler: spans stay host-only
+            classes = _annotation_classes = ()
+        else:
+            classes = _annotation_classes = (
+                TraceAnnotation, StepTraceAnnotation,
+            )
+    if not classes or not classes[0].is_enabled():
+        return None
+    meta = dict(args)
+    if rid is not None:
+        meta["rid"] = rid
+    if step is not None:
+        return classes[1](name, step_num=int(step), **meta)
+    return classes[0](name, **meta)
 
 
 def stitched_trace(
