@@ -101,6 +101,7 @@ def _sizes(rehearse: bool) -> dict:
             llama=LlamaConfig.tiny(vocab_size=256),
             prompt_len=12, bucket=16, new_tokens=8,
             kernel_geoms=[(4, 2, 16, 8), (4, 4, 16, 8)],
+            kernel_wide=(4, 2, 16, 8, 12, 75),
             lm_batch=4, lm_seq=32,
         )
     return dict(
@@ -114,6 +115,8 @@ def _sizes(rehearse: bool) -> dict:
             (32, 8, 128, 16), (32, 8, 128, 32), (32, 8, 128, 64),
             (16, 16, 128, 16),
         ],
+        # (..., rows, table width): the mixtral_chat_decode cell's shape
+        kernel_wide=(32, 8, 128, 16, 32, 101),
         lm_batch=4, lm_seq=256,
     )
 
@@ -522,12 +525,9 @@ def phase_serve(sz: dict) -> None:
 
 
 def phase_paged_kernel(sz: dict) -> None:
-    import jax.numpy as jnp
     import numpy as np
 
-    from unionml_tpu.ops.paged_attention import paged_attention, paged_attention_reference
-
-    batch, width, n_blocks = 8, 11, 96
+    batch, width = 8, 11
     rng = np.random.default_rng(SEED)
     for hq, hk, hd, block in sz["kernel_geoms"]:
         # ragged: a dead slot, one row, a block edge on either side, full
@@ -535,28 +535,49 @@ def phase_paged_kernel(sz: dict) -> None:
             [0, 1, block, block + 1, 3 * block - 1, 5 * block + 3,
              width * block - 1, width * block], np.int32,
         )
-        table = rng.integers(1, n_blocks, (batch, width)).astype(np.int32)
-        for b in range(batch):  # entries past coverage park on the trash block
-            table[b, -(-int(lengths[b]) // block):] = 0
-        q = jnp.asarray(rng.standard_normal((batch, hq, hd)), jnp.bfloat16)
-        kv = rng.standard_normal((2, n_blocks, block, hk, hd)).astype(np.float32)
-        kv[:, 0] = 100.0  # the trash block holds garbage
-        scales = (rng.random((2, n_blocks, block, hk)) * 0.02 + 1e-3).astype(np.float32)
-        for quant in (False, True):
-            if quant:
-                k, v = (jnp.asarray(np.clip(x * 40, -127, 127), jnp.int8) for x in kv)
-                kw = dict(k_scale=jnp.asarray(scales[0]), v_scale=jnp.asarray(scales[1]))
-            else:
-                k, v = (jnp.asarray(x, jnp.bfloat16) for x in kv)
-                kw = {}
-            args = (q, k, v, jnp.asarray(table), jnp.asarray(lengths))
-            got = paged_attention(*args, impl="pallas", **kw).astype(jnp.float32)
-            want = paged_attention_reference(*args, **kw).astype(jnp.float32)
-            live = lengths > 0  # a dead slot's row is garbage by contract, but finite
-            err = float(jnp.max(jnp.abs(got - want)[live]))
-            check(bool(jnp.all(jnp.isfinite(got))) and err <= BF16_TOL,
-                  f"paged kernel {hq}/{hk} heads, head_dim {hd}, block {block}, "
-                  f"{'int8' if quant else 'bf16'} pool vs reference: max err {err:.5f}")
+        _paged_kernel_case(rng, hq, hk, hd, block, lengths, width, n_blocks=96)
+    # the benchmark's serving shape: 32 slots over a table 101 blocks wide
+    # (several groups of pool blocks a row, the last one partial), ragged
+    # lengths, and retired slots: table all trash block, length stale
+    hq, hk, hd, block, batch, width = sz["kernel_wide"]
+    lengths = rng.integers(1, width * block + 1, batch).astype(np.int32)
+    lengths[:4] = [1, width * block, 512, 513]
+    dead = np.zeros(batch, bool)
+    dead[5::6] = True
+    _paged_kernel_case(rng, hq, hk, hd, block, lengths, width, n_blocks=3 * width, dead=dead)
+
+
+def _paged_kernel_case(rng, hq, hk, hd, block, lengths, width, n_blocks, dead=None) -> None:
+    import jax.numpy as jnp
+    import numpy as np
+
+    from unionml_tpu.ops.paged_attention import paged_attention, paged_attention_reference
+
+    batch = len(lengths)
+    dead = np.zeros(batch, bool) if dead is None else dead
+    table = rng.integers(1, n_blocks, (batch, width)).astype(np.int32)
+    for b in range(batch):  # entries past coverage park on the trash block
+        table[b, 0 if dead[b] else -(-int(lengths[b]) // block):] = 0
+    q = jnp.asarray(rng.standard_normal((batch, hq, hd)), jnp.bfloat16)
+    kv = rng.standard_normal((2, n_blocks, block, hk, hd)).astype(np.float32)
+    kv[:, 0] = 100.0  # the trash block holds garbage
+    scales = (rng.random((2, n_blocks, block, hk)) * 0.02 + 1e-3).astype(np.float32)
+    for quant in (False, True):
+        if quant:
+            k, v = (jnp.asarray(np.clip(x * 40, -127, 127), jnp.int8) for x in kv)
+            kw = dict(k_scale=jnp.asarray(scales[0]), v_scale=jnp.asarray(scales[1]))
+        else:
+            k, v = (jnp.asarray(x, jnp.bfloat16) for x in kv)
+            kw = {}
+        args = (q, k, v, jnp.asarray(table), jnp.asarray(lengths))
+        got = paged_attention(*args, impl="pallas", **kw).astype(jnp.float32)
+        want = paged_attention_reference(*args, **kw).astype(jnp.float32)
+        # a dead slot's row is garbage by contract, but finite
+        live = (lengths > 0) & ~dead
+        err = float(jnp.max(jnp.abs(got - want)[live]))
+        check(bool(jnp.all(jnp.isfinite(got))) and err <= BF16_TOL,
+              f"paged kernel {hq}/{hk} heads, head_dim {hd}, block {block}, {batch} rows x "
+              f"{width} blocks, {'int8' if quant else 'bf16'} pool vs reference: max err {err:.5f}")
 
 
 # ---------------------------------------------------------- four chips

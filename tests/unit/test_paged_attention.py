@@ -14,6 +14,8 @@ import jax.numpy as jnp
 
 from unionml_tpu.ops.attention import cached_attention, quantized_cache_attention
 from unionml_tpu.ops.paged_attention import (
+    _ROWS_PER_STEP,
+    _pages_per_step,
     paged_attention,
     paged_attention_reference,
 )
@@ -70,31 +72,87 @@ def test_reference_bit_identical_int8():
     assert bool(jnp.all(ref == contig))
 
 
+# Table widths for the kernel's grouping (it handles P pool blocks a grid
+# step, P from the shapes: test_pages_per_step_follows_the_shapes): the
+# short table is one group a row (W < P), the wide one two groups with a
+# partial last one (W not a multiple of P)
+P = _ROWS_PER_STEP // BS
+WIDE = P + 6
+WIDTHS = pytest.mark.parametrize("width", [W, WIDE], ids=["short-table", "wide-table"])
+# (q heads, kv heads): grouped-query and one kv head per q head
+HEADS = pytest.mark.parametrize("heads", [(H, KVH), (H, H)], ids=["gqa", "mha"])
+
+
+def _ragged_setup(width, heads, dtype, quantized, seed):
+    """Six rows over a ``width``-block table: a zero-length row, one
+    visible row, a length at a group boundary and one past it (a block
+    boundary on the short table), the full table, and a dead row (every
+    table entry the trash block 0, its length stale)."""
+    q_heads, kv_heads = heads
+    rng = np.random.default_rng(seed)
+    n_blocks = 2 * width
+    edge = min(P, width - 1) * BS
+    lengths = np.array([0, 1, edge, edge + 1, width * BS, edge + 3], np.int32)
+    dead = np.array([False] * 5 + [True])
+    table = rng.integers(1, n_blocks, (len(lengths), width)).astype(np.int32)
+    for b, n in enumerate(lengths):  # entries past coverage park on the trash block
+        table[b, 0 if dead[b] else -(-int(n) // BS):] = 0
+    q = jnp.asarray(rng.standard_normal((len(lengths), q_heads, D)), dtype)
+    shape = (n_blocks, BS, kv_heads, D)
+    kw = {}
+    if quantized:
+        k = rng.integers(-127, 128, shape).astype(np.int8)
+        v = rng.integers(-127, 128, shape).astype(np.int8)
+        kw = dict(
+            k_scale=jnp.asarray(rng.random(shape[:3]) * 0.02 + 1e-3, jnp.float32),
+            v_scale=jnp.asarray(rng.random(shape[:3]) * 0.02 + 1e-3, jnp.float32),
+        )
+    else:
+        k, v = rng.standard_normal((2,) + shape).astype(np.float32)
+    k[0] = v[0] = 100 if quantized else 1e4  # the trash block holds garbage
+    args = (q, jnp.asarray(k, None if quantized else dtype),
+            jnp.asarray(v, None if quantized else dtype),
+            jnp.asarray(table), jnp.asarray(lengths))
+    return args, kw, (lengths > 0) & ~dead
+
+
+def _gap(args, kw, rows):
+    ref = paged_attention(*args, impl="reference", **kw).astype(jnp.float32)
+    pal = paged_attention(*args, impl="pallas", **kw).astype(jnp.float32)
+    # zero-length and dead rows are garbage by contract, but finite
+    assert bool(jnp.all(jnp.isfinite(pal)))
+    return float(jnp.max(jnp.abs(pal - ref)[rows]))
+
+
+@HEADS
+@WIDTHS
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_pallas_matches_reference(dtype):
-    q, k, v, table, lengths = _setup(dtype=dtype)
-    ref = paged_attention(q, k, v, table, lengths, impl="reference")
-    pal = paged_attention(q, k, v, table, lengths, impl="pallas")
+def test_pallas_matches_reference(dtype, width, heads):
+    args, kw, rows = _ragged_setup(width, heads, dtype, False, seed=4)
     tol = 1e-6 if dtype == jnp.float32 else 2e-2
-    assert float(
-        jnp.max(jnp.abs(pal.astype(jnp.float32) - ref.astype(jnp.float32)))
-    ) < tol
+    assert _gap(args, kw, rows) < tol
 
 
-def test_pallas_matches_reference_int8():
-    rng = np.random.default_rng(2)
-    q, _, _, table, lengths = _setup(seed=2)
-    kq = jnp.asarray(rng.integers(-127, 128, (N, BS, KVH, D)), jnp.int8)
-    vq = jnp.asarray(rng.integers(-127, 128, (N, BS, KVH, D)), jnp.int8)
-    ks = jnp.asarray(rng.random((N, BS, KVH)) * 0.02 + 1e-3, jnp.float32)
-    vs = jnp.asarray(rng.random((N, BS, KVH)) * 0.02 + 1e-3, jnp.float32)
-    ref = paged_attention(
-        q, kq, vq, table, lengths, k_scale=ks, v_scale=vs, impl="reference"
-    )
-    pal = paged_attention(
-        q, kq, vq, table, lengths, k_scale=ks, v_scale=vs, impl="pallas"
-    )
-    assert float(jnp.max(jnp.abs(pal - ref))) < 1e-5
+@HEADS
+@WIDTHS
+def test_pallas_matches_reference_int8(width, heads):
+    args, kw, rows = _ragged_setup(width, heads, jnp.float32, True, seed=5)
+    assert _gap(args, kw, rows) < 1e-5
+
+
+@pytest.mark.parametrize(
+    "block,kv_heads,head_dim,itemsize,width,pages",
+    [
+        (16, 8, 128, 2, 101, 32),   # the benchmark's chat cell: 512 rows a step
+        (16, 8, 128, 2, 11, 11),    # never more than the table is wide
+        (64, 8, 128, 2, 101, 8),    # 512 rows whatever the pool's block
+        (16, 16, 128, 2, 101, 16),  # MHA rows are twice as wide: the VMEM budget halves the step
+        (16, 16, 128, 1, 101, 32),  # ... and an int8 pool's are not
+        (1024, 8, 128, 2, 4, 1),    # a block larger than a step: one block a step
+    ],
+)
+def test_pages_per_step_follows_the_shapes(block, kv_heads, head_dim, itemsize, width, pages):
+    assert _pages_per_step(block, kv_heads, head_dim, itemsize, width) == pages
 
 
 def test_zero_length_rows_are_finite():

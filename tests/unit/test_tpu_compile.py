@@ -9,6 +9,7 @@ run: ``chip_smoke.py`` is what runs them.
 """
 
 import os
+import re
 
 import pytest
 
@@ -63,17 +64,28 @@ def _assert_mosaic(chip, fn, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 # Llama-3-8B decode geometry (32 q / 8 kv heads, head_dim 128) at the
-# three pool block sizes, and the OLMoE 16/16 MHA shape
+# three pool block sizes and the OLMoE 16/16 MHA shape, on a short table
+# (one group of pool blocks a row); then the shape the benchmark's
+# mixtral_chat_decode cell runs: 32 slots, a table 101 blocks wide (four
+# groups, the last one partial) over its 1.5 GB pool
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize(
-    "q_heads,kv_heads,block",
-    [(32, 8, 16), (32, 8, 32), (32, 8, 64), (16, 16, 16)],
+    "q_heads,kv_heads,block,batch,width,n_blocks",
+    [
+        (32, 8, 16, 8, 11, 512), (32, 8, 32, 8, 11, 512),
+        (32, 8, 64, 8, 11, 512), (16, 16, 16, 8, 11, 512),
+        (32, 8, 16, 32, 101, 2861),
+    ],
+    ids=["32-8-16", "32-8-32", "32-8-64", "16-16-16", "cell-32x101"],
 )
-def test_paged_attention_compiles(chip, q_heads, kv_heads, block, quantized):
-    batch, width, n_blocks, hd = 8, 11, 512, 128
+def test_paged_attention_compiles(
+    chip, q_heads, kv_heads, block, batch, width, n_blocks, quantized
+):
+    hd = 128
     pool = ((n_blocks, block, kv_heads, hd), jnp.int8 if quantized else jnp.bfloat16)
     shapes = [
         ((batch, q_heads, hd), jnp.bfloat16), pool, pool,
@@ -88,7 +100,10 @@ def test_paged_attention_compiles(chip, q_heads, kv_heads, block, quantized):
             impl="pallas",
         )
 
-    _assert_mosaic(chip, fn, *shapes)
+    text = _assert_mosaic(chip, fn, *shapes)
+    # chipbench's paged_attn_ms_per_step finds the kernel's device time
+    # by this instruction name
+    assert re.search(r"%paged_attention(\.\d+)* = ", text)
 
 
 QKV = ((1, 2048, 32, 128), jnp.bfloat16)

@@ -20,36 +20,49 @@ Two implementations behind one dispatcher:
   the outputs are **bit-identical** to the contiguous cache path on the
   same values — the CPU/tier-1 parity anchor every paged-engine test
   asserts against.
-- the Pallas kernel (``impl="pallas"``) — grid ``(batch, table_width)``
-  with the block dimension innermost: the block table rides in as a
-  **scalar-prefetch** operand so each grid step's BlockSpec index map
-  selects the pool block to DMA (no gathered copy of the cache is ever
-  materialized — the entire point: decode reads exactly the blocks a
-  sequence owns). fp32 online-softmax accumulators (running max /
-  normalizer / weighted sum) live in VMEM scratch and carry across the
-  block iterations, the same scheme as
-  :mod:`~unionml_tpu.ops.flash_attention`; blocks entirely past a
-  row's length are predicated out with ``pl.when``. GQA reads the pool
-  at kv-head width (no head repeat): a block tile is viewed as
-  ``[block * Hk, D]`` (a bitcast of the pool), ONE matmul scores every
-  q head against every (position, kv head) row, and a mask keeps each
-  q head's own kv head — Mosaic cannot lay out per-head sublane slices
-  of a ``[block, Hk, D]`` tile, and the step is bound by the HBM read,
-  not the MXU. int8 KV pools fold their per-(row, head) dequant scales
-  into the score/weight math in-kernel (never a dequantized pool copy;
-  the fp32 scale planes ride as one lane-dense ``[1, block * Hk]`` row
-  per block, which costs an XLA relayout of the planes per call) — the
-  same numerics contract as the existing kernels: fp32 softmax
-  statistics, MXU matmuls in the input dtype with fp32 accumulation,
-  outputs equal to the reference up to float reduction order.
+- the Pallas kernel (``impl="pallas"``) — grid ``(batch, groups)``: a
+  grid step handles a **group** of P pool blocks of one row, P worked
+  out from the shapes (:func:`_pages_per_step`: 512 KV rows a step,
+  fewer where the gather buffers would outgrow their VMEM budget, never
+  more than the table is wide). A grid step costs the same whatever it
+  scores, so with one block a step the kernel's time followed the
+  table's width (``max_new_tokens`` and the largest bucket); with a
+  group a step it follows the live KV. The pools stay in HBM
+  (``memory_space=pl.ANY``, no gathered copy of the cache is ever
+  materialized) and the block table and lengths ride in as
+  **scalar-prefetch** operands: the step reads the group's table
+  entries and starts one async copy per pool block that holds visible
+  rows — blocks are not contiguous in the pool — into a double-buffered
+  VMEM scratch, and the next group's copies (the same row's, or the
+  next row's first group) fly while this one is scored. A group wholly
+  past a row's length starts no copy and does no work; the table's last
+  group may be partial, and no entry past the table is read. fp32
+  online-softmax accumulators (running max / normalizer / weighted sum)
+  live in VMEM scratch and carry across a row's groups, the same scheme
+  as :mod:`~unionml_tpu.ops.flash_attention`. GQA reads the pool at
+  kv-head width (no head repeat): the gathered group is viewed as
+  ``[P * block * Hk, D]``, ONE matmul scores every q head against every
+  (position, kv head) row, and a mask keeps each q head's own kv head —
+  Mosaic cannot lay out per-head sublane slices of a ``[block, Hk, D]``
+  tile, and the MXU's time is the loading of the K tiles either way.
+  int8 KV pools fold their per-(row, head) dequant scales into the
+  score/weight math in-kernel (never a dequantized pool copy; the fp32
+  scale planes ride as one lane-dense ``[1, block * Hk]`` row per block,
+  which costs an XLA relayout of the planes per call) — the same
+  numerics contract as the existing kernels: fp32 softmax statistics,
+  MXU matmuls in the input dtype with fp32 accumulation, outputs equal
+  to the reference up to float reduction order. Both grid dimensions
+  run in order (a step prefetches for the next one, rows included), so
+  a two-core chip does not split the batch.
 
 ``impl="auto"`` picks the kernel on TPU and the reference elsewhere
 (CPU tests run the kernel in interpreter mode only when asked).
 Interpret mode proves the math, not that Mosaic accepts the kernel:
 ``tests/unit/test_tpu_compile.py`` compiles it for v5e at the Llama-3-8B
-and 16/16-MHA geometries, and ``chip_smoke.py`` runs it there.
-Block-size tuning is data-driven via the paged leg of
-``benchmarks/attn_kernels.py``.
+and 16/16-MHA geometries and at the benchmark's serving shape (32 rows
+over a 101-block table), and ``chip_smoke.py`` runs it there. What it
+costs on the chip is the benchmark's ``paged_attn_ms_per_step``
+(``chipbench/layer_metrics/``).
 """
 
 from __future__ import annotations
@@ -147,47 +160,124 @@ def paged_attention_reference(
     return out[:, 0]
 
 
-def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                  scale, block, kv_heads, group, num_blocks, quantized):
-    if quantized:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, acc_ref, m_ref, l_ref = rest
-    b = pl.program_id(0)
-    w = pl.program_id(1)
-    q_heads = kv_heads * group
-    cols = block * kv_heads
+# KV rows (positions) one grid step gathers and scores. A step costs a
+# fixed price (the grid step itself, the DMA issue, two matmuls' latency)
+# whatever it scores, so the step is made wide enough that the price is
+# paid a few times a row and not once a pool block. On a v5e 256 and 512
+# tie at chat lengths (a few hundred rows) and 512 wins on long rows;
+# 128 and 1024 lose at both (PERF.md, section 6, PR 26).
+_ROWS_PER_STEP = 512
+# what the two double-buffered K and V gather buffers may take of VMEM
+_KV_BUFFER_BYTES = 4 * 1024 * 1024
 
-    @pl.when(w == 0)
+
+def _pages_per_step(block, kv_heads, head_dim, itemsize, width):
+    """Pool blocks one grid step handles, from what the call can see:
+    as many as make ``_ROWS_PER_STEP`` KV rows, fewer where four buffers
+    of that many rows (K and V, each double-buffered) would pass
+    ``_KV_BUFFER_BYTES``, never more than the table is wide."""
+    row_bytes = kv_heads * head_dim * itemsize
+    rows = min(_ROWS_PER_STEP, _KV_BUFFER_BYTES // (4 * row_bytes))
+    return max(1, min(rows // block, width))
+
+
+def _paged_kernel(table_ref, len_ref, q_ref, *rest, scale, block, kv_heads,
+                  group, width, pages, quantized):
+    from jax.experimental.pallas import tpu as pltpu
+
+    # K and V pools (and, for int8 pools, their scale planes) in HBM,
+    # the output, then one double-buffered gather buffer per pool
+    n = 4 if quantized else 2
+    pools, o_ref, bufs = rest[:n], rest[n], rest[n + 1:2 * n + 1]
+    sem, state, acc_ref, m_ref, l_ref = rest[2 * n + 1:]
+    k_buf, v_buf, *scale_bufs = bufs
+    b = pl.program_id(0)
+    g = pl.program_id(1)
+    batch = pl.num_programs(0)
+    q_heads = kv_heads * group
+    rows = pages * block                   # KV positions a step scores
+    cols = rows * kv_heads                 # (position, kv head) columns
+
+    def visible(row):
+        # a stale length may not reach past the table
+        return jnp.minimum(len_ref[row], width * block)
+
+    def live_pages(row, grp):
+        """Pool blocks of ``row``'s group ``grp`` that hold visible rows."""
+        return jnp.clip(pl.cdiv(visible(row), block) - grp * pages, 0, pages)
+
+    def copies(row, grp, slot, fn):
+        """``fn`` on every page copy of (row, grp) into buffer ``slot``:
+        the same descriptors start a gather and wait for it."""
+        def page(j, carry):
+            src = table_ref[row, grp * pages + j]
+            for pool, buf in zip(pools, bufs):
+                fn(pltpu.make_async_copy(pool.at[src], buf.at[slot, j], sem.at[slot]))
+            return carry
+        jax.lax.fori_loop(0, live_pages(row, grp), page, 0)
+
+    # state[0]: the buffer this step reads; state[1]: whether an earlier
+    # step already started this step's gather
+    @pl.when((b == 0) & (g == 0))
+    def _reset():
+        state[0] = 0
+        state[1] = 0
+
+    @pl.when(g == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    length = len_ref[b]
-    # skip blocks entirely past this row's visible rows (pl.when: no
-    # MXU work issued; the DMA fetched the trash block the host parks
-    # out-of-range table entries on)
-    run = w * block < length
+    length = visible(b)
 
-    @pl.when(run)
+    # a group wholly past the row's visible rows starts no copy and does
+    # no work
+    @pl.when(g * rows < length)
     def _compute():
+        slot = state[0]
+
+        @pl.when(state[1] == 0)
+        def _start_own():
+            copies(b, g, slot, lambda c: c.start())
+
+        # the next group that will run: this row's next one, else the
+        # first group of the next row that sees anything. Its gather
+        # flies while this group is scored.
+        same_row = (g + 1) * rows < length
+        nxt_b = jax.lax.while_loop(
+            lambda r: (r < batch) & (len_ref[jnp.minimum(r, batch - 1)] <= 0),
+            lambda r: r + 1,
+            b + 1,
+        )
+        nxt_b = jnp.where(same_row, b, nxt_b)
+        nxt_g = jnp.where(same_row, g + 1, 0)
+        has_next = nxt_b < batch
+
+        @pl.when(has_next)
+        def _start_next():
+            copies(nxt_b, nxt_g, 1 - slot, lambda c: c.start())
+
+        state[0] = 1 - slot
+        state[1] = has_next.astype(jnp.int32)
+        copies(b, g, slot, lambda c: c.wait())
+
         q = q_ref[0]                               # [Hq, D] input dtype
-        # the block tile arrives flattened [block * Hk, D]: row
+        # the gathered pages lie flattened [pages * block * Hk, D]: row
         # r = pos * Hk + head. ONE matmul scores every q head against
         # every (pos, head) row and the mask keeps each q head's own
         # kv head — the no-repeat GQA read without per-head sublane
-        # slices of the tile (which Mosaic cannot lay out). The
-        # off-head columns are wasted MXU work on a step the HBM read
-        # bounds.
-        k = k_ref[0].astype(q.dtype)
-        v = v_ref[0].astype(q.dtype)
+        # slices of the tile (which Mosaic cannot lay out). The MXU's
+        # time is the loading of the K tiles either way; the off-head
+        # columns cost vector work on the score tile.
+        k = k_buf[slot].reshape(cols, -1).astype(q.dtype)
+        v = v_buf[slot].reshape(cols, -1).astype(q.dtype)
         col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
         q_head = jax.lax.broadcasted_iota(jnp.int32, (q_heads, 1), 0)
-        valid = (col % kv_heads == q_head // group) & (
-            w * block + col // kv_heads < length
-        )                                          # [Hq, cols]
+        # pages past live_pages were not copied (the buffer holds an
+        # earlier group's rows there): the length mask covers them
+        seen = g * rows + col // kv_heads < length  # [1, cols]
+        valid = (col % kv_heads == q_head // group) & seen  # [Hq, cols]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -196,7 +286,7 @@ def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, *rest,
             # int8 pool: per-(row, head) dequant scale folds into
             # the scores (k) and softmax weights (v) — the
             # _grouped_cache_attention contract, in-kernel
-            s = s * ks_ref[0]
+            s = s * scale_bufs[0][slot].reshape(1, cols)
         s = jnp.where(valid, s, NEG_INF)
         m_prev = m_ref[:]                          # [Hq, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -210,19 +300,21 @@ def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         # (the _grouped_cache_attention contract)
         l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
         if quantized:
-            p = p * vs_ref[0]
+            # an uncopied page's scale is whatever the buffer held, and
+            # 0 x NaN is NaN
+            p = p * jnp.where(seen, scale_bufs[1][slot].reshape(1, cols), 0.0)
         # zero invalid value rows: 0-weight x garbage must stay 0.
         # The row-oriented mask comes from its own iota — reshaping
         # the [1, cols] one is a lane->sublane cast Mosaic refuses.
         row = jax.lax.broadcasted_iota(jnp.int32, (cols, 1), 0)
-        v = jnp.where(w * block + row // kv_heads < length, v, 0)
+        v = jnp.where(g * rows + row // kv_heads < length, v, 0)
         acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
             p.astype(q.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         m_ref[:] = m_new
 
-    @pl.when(w == num_blocks - 1)
+    @pl.when(g == pl.num_programs(1) - 1)
     def _finalize():
         o_ref[0] = (
             acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
@@ -236,64 +328,70 @@ def _paged_pallas(q, k, v, block_table, lengths, *, k_scale, v_scale,
     batch, q_heads, head_dim = q.shape
     num_pool_blocks, block, kv_heads, _ = k.shape
     w = block_table.shape[1]
-    group = q_heads // kv_heads
-    cols = block * kv_heads
+    page_cols = block * kv_heads
     quantized = k_scale is not None
+    pages = _pages_per_step(block, kv_heads, head_dim, k.dtype.itemsize, w)
 
-    def kv_map(b, wi, table, lens):
-        return (table[b, wi], 0, 0)
-
-    def q_map(b, wi, table, lens):
+    def q_map(b, g, table, lens):
         return (b, 0, 0)
 
-    # [N, block, Hk, D] -> [N, block * Hk, D] merges the two middle
-    # dims under an unchanged minor dim: a bitcast of the pool on TPU
-    # (checked in the compiled HLO at head_dim 128), never a copy
-    in_specs = [
-        pl.BlockSpec((1, q_heads, head_dim), q_map),
-        pl.BlockSpec((1, cols, head_dim), kv_map),
-        pl.BlockSpec((1, cols, head_dim), kv_map),
-    ]
+    # the pools stay in HBM and the kernel gathers a group's pages
+    # itself. [N, block, Hk, D] -> [N, block * Hk, D] merges the two
+    # middle dims under an unchanged minor dim: a bitcast of the pool on
+    # TPU (checked in the compiled HLO at head_dim 128), never a copy
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec((1, q_heads, head_dim), q_map), hbm, hbm]
     operands = [
         q,
-        k.reshape(num_pool_blocks, cols, head_dim),
-        v.reshape(num_pool_blocks, cols, head_dim),
+        k.reshape(num_pool_blocks, page_cols, head_dim),
+        v.reshape(num_pool_blocks, page_cols, head_dim),
+    ]
+    scratch = [
+        pltpu.VMEM((2, pages, page_cols, head_dim), k.dtype),
+        pltpu.VMEM((2, pages, page_cols, head_dim), v.dtype),
     ]
     if quantized:
         # scale planes ride as one lane-dense row per block, matching
         # the score columns
-        in_specs += [pl.BlockSpec((1, 1, cols), kv_map)] * 2
+        in_specs += [hbm, hbm]
         operands += [
-            k_scale.reshape(num_pool_blocks, 1, cols),
-            v_scale.reshape(num_pool_blocks, 1, cols),
+            k_scale.reshape(num_pool_blocks, 1, page_cols),
+            v_scale.reshape(num_pool_blocks, 1, page_cols),
         ]
+        scratch += [pltpu.VMEM((2, pages, 1, page_cols), jnp.float32)] * 2
+    scratch += [
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.SMEM((2,), jnp.int32),
+        pltpu.VMEM((q_heads, head_dim), jnp.float32),
+        pltpu.VMEM((q_heads, 1), jnp.float32),
+        pltpu.VMEM((q_heads, 1), jnp.float32),
+    ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(batch, w),
+        grid=(batch, pl.cdiv(w, pages)),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, q_heads, head_dim), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((q_heads, head_dim), jnp.float32),
-            pltpu.VMEM((q_heads, 1), jnp.float32),
-            pltpu.VMEM((q_heads, 1), jnp.float32),
-        ],
+        scratch_shapes=scratch,
     )
     kernel = functools.partial(
         _paged_kernel,
         scale=scale,
         block=block,
         kv_heads=kv_heads,
-        group=group,
-        num_blocks=w,
+        group=q_heads // kv_heads,
+        width=w,
+        pages=pages,
         quantized=quantized,
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, q_heads, head_dim), q.dtype),
+        # a step starts the next step's gather, rows included: both grid
+        # dimensions run in order
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
+            dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,
         name="paged_attention",
