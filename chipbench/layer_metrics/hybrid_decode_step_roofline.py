@@ -1,0 +1,27 @@
+"""Per-layer metric ``hybrid_decode_step_roofline``: layer "kernels", unit %, moves ``tpot_ms_p50``."""
+
+from chipbench import opsbytes_hybrid
+from chipbench.yardstick import roofline_s, say
+
+LAYER = "kernels"
+UNIT = "%"
+MOVES = "tpot_ms_p50"
+SOURCE = "device_trace"
+
+
+def read(run):
+    """The least time one decode step of the hybrid model could take on this
+    chip for the sequences live in the traced seconds and their cached
+    positions (weights once, every live state read and written, keys and
+    values of the full-attention layers; ``opsbytes_hybrid.decode_step_cost``)
+    over the traced step time."""
+    step = run.decode_step_s()
+    load = opsbytes_hybrid.traced_load(run)
+    if not step or load is None or "layer_types" not in run.config:
+        return None
+    flops, moved = opsbytes_hybrid.decode_step_cost(run.config, *load)
+    least, bound = roofline_s(flops, moved, run.peaks)
+    say(f"hybrid decode step: {flops / 1e9:.1f} GFLOP, {moved / 1e9:.2f} GB for {load[0]:.1f} live "
+        f"sequences holding {load[1]:.0f} positions; {bound}-bound, least {least * 1e3:.3f} ms, "
+        f"traced {step * 1e3:.3f} ms")
+    return 100.0 * least / step

@@ -170,9 +170,14 @@ def test_starved_slot_steps_and_admissions_are_plain_sums():
     assert report["window_dispatched_slot_steps"] == 3 * 8
     assert report["window_occupied_slot_steps"] == (1 + 2 + 4) * 2
     assert report["admissions"] == 2 and report["prefill_tokens"] == 64
+    # an admission that found a slot and no pool blocks, counted when it parks
+    assert report["admissions_parked_on_pool"] == 0 and report["state_bytes_resident"] == 0
+    plane.note_parked()
+    assert plane.report()["admissions_parked_on_pool"] == 1
     plane.reset()
     report = plane.report()
     assert report["starved_slot_steps"] == 0 and report["admissions"] == 0
+    assert report["admissions_parked_on_pool"] == 0
     assert report["polls"] == {reason: 0 for reason in POLL_REASONS}
     assert report["dispatcher_s"] == {phase: 0.0 for phase in DISPATCHER_PHASES}
 
@@ -356,6 +361,7 @@ def test_engine_and_train_loop_open_the_documented_spans(tiny_llama, tmp_path):
         assert {"queue", "admit", "admit.enqueue", "prefill", "harvest"} <= set(recorded)
         (ann,) = [e for e in by_name["engine.admit"] if e[3]["rid"] == rid]
         assert ann[3]["prompt_tokens"] == recorded["admit"]["args"]["prompt_tokens"]
+        assert ann[3]["state_layers"] == 0  # every layer of a Llama caches keys and values
         diffs.append(recorded["admit"]["start_s"] - ann[1])
         (enq,) = [e for e in enqueues if e[3]["rid"] == rid]
         diffs.append(recorded["admit.enqueue"]["start_s"] - enq[1])

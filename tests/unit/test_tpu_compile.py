@@ -27,6 +27,7 @@ from unionml_tpu.ops import (
     flash_attention,
     fused_attention,
     fused_norm,
+    gated_delta,
     int4_matmul,
     paged_attention,
 )
@@ -50,7 +51,7 @@ def _compile_for_the_chip(monkeypatch):
     written there but cannot be read back without one."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
-    for module in (flash_attention, fused_attention, fused_norm, paged_attention):
+    for module in (flash_attention, fused_attention, fused_norm, gated_delta, paged_attention):
         monkeypatch.setattr(module, "_interpret", lambda: False)
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -71,16 +72,18 @@ def _assert_mosaic(chip, fn, *shapes):
 # three pool block sizes and the OLMoE 16/16 MHA shape, on a short table
 # (one group of pool blocks a row); then the shape the benchmark's
 # mixtral_chat_decode cell runs: 32 slots, a table 101 blocks wide (four
-# groups, the last one partial) over its 1.5 GB pool
+# groups, the last one partial) over its 1.5 GB pool; then the
+# olmo_hybrid_longgen_decode cell's: 30 x 128 MHA held as 32 heads
+# (OlmoHybridConfig.kv_cache_heads), 32 slots, a table 261 wide
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize(
     "q_heads,kv_heads,block,batch,width,n_blocks",
     [
         (32, 8, 16, 8, 11, 512), (32, 8, 32, 8, 11, 512),
         (32, 8, 64, 8, 11, 512), (16, 16, 16, 8, 11, 512),
-        (32, 8, 16, 32, 101, 2861),
+        (32, 8, 16, 32, 101, 2861), (32, 32, 16, 32, 261, 1621),
     ],
-    ids=["32-8-16", "32-8-32", "32-8-64", "16-16-16", "cell-32x101"],
+    ids=["32-8-16", "32-8-32", "32-8-64", "16-16-16", "cell-32x101", "hybrid-cell-32x261"],
 )
 def test_paged_attention_compiles(
     chip, q_heads, kv_heads, block, batch, width, n_blocks, quantized
@@ -104,6 +107,27 @@ def test_paged_attention_compiles(
     # chipbench's paged_attn_ms_per_step finds the kernel's device time
     # by this instruction name
     assert re.search(r"%paged_attention(\.\d+)* = ", text)
+    # the kernel's [blocks, block * heads, head_dim] view of the pool is a
+    # bitcast, never a copy of the pool (at 30 heads, which a bfloat16 array
+    # pads to 32, it was a copy of every layer's pool every step)
+    assert not re.search(rf"\[{n_blocks},{block * kv_heads},{hd}\]\S* copy\(", text)
+
+
+def test_gated_delta_step_compiles(chip):
+    """The decode kernel of the gated delta rule at the hybrid cell's shape:
+    32 slots, 30 heads of 96 x 192, two heads a state row."""
+    batch, heads, dk, dv = 32, 30, 96, 192
+    state = ((batch,) + gated_delta.state_shape(heads, dk, dv), jnp.float32)
+    assert state[0] == (32, 15, 96, 384)
+    text = _assert_mosaic(
+        chip,
+        lambda *args: gated_delta.gated_delta_step(*args, impl="pallas"),
+        ((batch, heads, dk), jnp.bfloat16), ((batch, heads, dk), jnp.bfloat16),
+        ((batch, heads, dv), jnp.bfloat16), ((batch, heads), jnp.float32), ((batch, heads), jnp.float32),
+        state, ((batch,), jnp.bool_),
+    )
+    # chipbench's gdn_state_ms_per_step finds the kernel by this name
+    assert re.search(r"%gated_delta_step(\.\d+)* = ", text)
 
 
 QKV = ((1, 2048, 32, 128), jnp.bfloat16)

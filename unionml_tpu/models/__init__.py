@@ -26,6 +26,7 @@ from unionml_tpu.models.llama import (
     LlamaConfig,
     init_cache,
 )
+from unionml_tpu.models.olmo_hybrid import OlmoHybrid, OlmoHybridConfig
 from unionml_tpu.models.encdec import (
     ENCDEC_PARTITION_RULES,
     EncDecConfig,
@@ -99,6 +100,7 @@ __all__ = [
     "BertEncoder", "BertClassifier", "BertMlm", "BertConfig",
     "BERT_PARTITION_RULES", "make_mlm_batch", "mlm_step",
     "Llama", "LlamaConfig", "init_cache", "LLAMA_PARTITION_RULES",
+    "OlmoHybrid", "OlmoHybridConfig",
     "EncoderDecoder", "EncDecConfig", "ENCDEC_PARTITION_RULES",
     "init_decoder_cache", "make_seq2seq_generator", "make_seq2seq_predictor", "seq2seq_step",
     "LLAMA_QUANT_PARTITION_RULES", "LLAMA_MOE_PARTITION_RULES",

@@ -22,6 +22,7 @@ Design notes (TPU):
 
 from __future__ import annotations
 
+from dataclasses import dataclass
 from typing import Any, Optional, Tuple
 
 import jax
@@ -33,6 +34,62 @@ from unionml_tpu.ops.attention import attention as xla_attention
 from unionml_tpu.ops.attention import blockwise_attention
 
 Dtype = Any
+
+
+# ---- what a decoder tells a serving engine about each layer's cache ----
+# A cache-capable decoder has a ``cache_layout()`` method that returns one
+# of these per layer, in layer order; its ``__call__`` takes and returns a
+# per-layer ``cache`` tuple whose entries are what ``init`` builds.
+
+
+@dataclass(frozen=True)
+class KVRows:
+    """A layer whose cache grows by one row a token: keys and values of
+    ``kv_heads x head_dim``, bf16 (or int8 with fp32 per-(row, head)
+    scales). A paged engine gives such a layer rows of its block pool."""
+
+    kv_heads: int
+    head_dim: int
+    quantized: bool = False
+
+    def init(self, batch: int, rows: int, dtype: Dtype = jnp.bfloat16):
+        shape = (batch, rows, self.kv_heads, self.head_dim)
+        if self.quantized:
+            q, s = jnp.zeros(shape, jnp.int8), jnp.ones(shape[:-1], jnp.float32)
+            return (q, q, s, s)
+        zeros = jnp.zeros(shape, dtype)
+        return (zeros, zeros)
+
+    def row_nbytes(self) -> int:
+        """Bytes one cached position takes in this layer."""
+        per_head = (self.head_dim + 4) if self.quantized else 2 * self.head_dim
+        return 2 * self.kv_heads * per_head
+
+
+@dataclass(frozen=True)
+class SlotState:
+    """A layer whose cache is a state of fixed size, whatever the
+    sequence's length (a recurrent or linear-attention layer): arrays of
+    ``shapes`` / ``dtypes`` per sequence. An engine keeps one per slot,
+    writes it whole when a prefill ends, and a block prefix of the
+    sequence does not restore it."""
+
+    shapes: Tuple[Tuple[int, ...], ...]
+    dtypes: Tuple[str, ...]
+
+    def init(self, batch: int, rows: int = 0):
+        """``rows`` is taken for ``KVRows.init``'s sake: the state has none."""
+        return tuple(
+            jnp.zeros((batch,) + shape, jnp.dtype(dt))
+            for shape, dt in zip(self.shapes, self.dtypes)
+        )
+
+    def nbytes(self) -> int:
+        """Bytes one sequence's state takes in this layer."""
+        return sum(
+            int(np.prod(shape)) * jnp.dtype(dt).itemsize
+            for shape, dt in zip(self.shapes, self.dtypes)
+        )
 
 
 def make_dense(
@@ -274,6 +331,11 @@ class Attention(nn.Module):
     head_dim: Optional[int] = None
     rope: bool = False
     rope_theta: float = 10_000.0
+    # RMS-normalise q and k over their FULL projected width, before the
+    # heads are split (the Olmo 2/3 convention); ``qk_norm_eps`` is the
+    # norms' epsilon. Adds ``q_norm`` / ``k_norm`` scales.
+    qk_norm: bool = False
+    qk_norm_eps: float = 1e-6
     # llama3-type long-context frequency rescale:
     # (factor, low_freq_factor, high_freq_factor, original_max_len)
     rope_scaling: Optional[Tuple[float, float, float, int]] = None
@@ -397,6 +459,26 @@ class Attention(nn.Module):
             )(out)
         k = dense((kv_heads, head_dim), "k", self.int4_tp)(x)
         v = dense((kv_heads, head_dim), "v", self.int4_tp)(x)
+        if self.qk_norm:
+            def full_width_norm(t, name):
+                flat = t.reshape(batch, seq, -1)
+                flat = RMSNorm(eps=self.qk_norm_eps, dtype=self.dtype, name=name)(flat)
+                return flat.reshape(t.shape)
+
+            q, k = full_width_norm(q, "q_norm"), full_width_norm(k, "k_norm")
+        # a cache may hold more heads than the layer has (its owner
+        # rounded a head count up to one that tiles densely on a TPU,
+        # e.g. 30 -> 32): the extra heads are zeros through the cache and
+        # the attention, and are dropped before the output projection
+        cache_heads = kv_heads if cache is None else cache[0].shape[-2]
+        if cache_heads != kv_heads:
+            if self.num_heads != kv_heads or cache_heads < kv_heads:
+                raise ValueError(
+                    f"a cache of {cache_heads} heads for {kv_heads} kv heads "
+                    "needs as many q as kv heads (and no fewer cache heads)"
+                )
+            extra = ((0, 0), (0, 0), (0, cache_heads - kv_heads), (0, 0))
+            q, k, v = jnp.pad(q, extra), jnp.pad(k, extra), jnp.pad(v, extra)
 
         if positions is None:
             base = jnp.asarray(cache_index if cache_index is not None else 0)
@@ -570,7 +652,7 @@ class Attention(nn.Module):
             lora_rank=self.lora_rank, lora_alpha=self.lora_alpha,
             use_bias=self.use_bias, weight_bits=self.weight_bits,
             int4_group=self.int4_group,
-        )(out)
+        )(out if out.shape[2] == self.num_heads else out[:, :, :self.num_heads])
         if cache is not None:
             return out, new_cache
         return out

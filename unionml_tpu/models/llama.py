@@ -24,7 +24,7 @@ from typing import Any, Optional, Tuple
 import jax.numpy as jnp
 from flax import linen as nn
 
-from unionml_tpu.models.layers import Attention, MlpBlock, RMSNorm, make_dense
+from unionml_tpu.models.layers import Attention, KVRows, MlpBlock, RMSNorm, make_dense
 from unionml_tpu.ops.moe import MoEMlp
 from unionml_tpu.parallel.sharding import PartitionRule
 
@@ -203,6 +203,11 @@ class LlamaBlock(nn.Module):
 class Llama(nn.Module):
     config: LlamaConfig = field(default_factory=LlamaConfig)
 
+    def cache_layout(self) -> Tuple[KVRows, ...]:
+        """Every layer caches keys and values (see ``layers.KVRows``)."""
+        cfg = self.config
+        return (KVRows(cfg.num_kv_heads, cfg.head_dim, cfg.kv_quant),) * cfg.num_layers
+
     @nn.compact
     def __call__(
         self,
@@ -291,20 +296,15 @@ def init_cache(
     — half the HBM of the bf16 form (int8 bytes + 1/32 scale overhead).
     """
     max_len = max_len or config.max_len
-    shape = (batch, max_len, config.num_kv_heads, config.head_dim)
-    if config.kv_quant:
-        if dtype != jnp.bfloat16:
-            # the dtype arg governs the bf16 cache form only; silently
-            # dropping an explicit request would be a trap
-            raise ValueError(
-                f"kv_quant caches are int8 + fp32 scales; dtype={dtype} "
-                "cannot apply (drop the dtype argument or kv_quant)"
-            )
-        q = jnp.zeros(shape, jnp.int8)
-        s = jnp.ones(shape[:-1], jnp.float32)
-        return tuple((q, q, s, s) for _ in range(config.num_layers))
-    zeros = jnp.zeros(shape, dtype)
-    return tuple((zeros, zeros) for _ in range(config.num_layers))
+    if config.kv_quant and dtype != jnp.bfloat16:
+        # the dtype arg governs the bf16 cache form only; silently
+        # dropping an explicit request would be a trap
+        raise ValueError(
+            f"kv_quant caches are int8 + fp32 scales; dtype={dtype} "
+            "cannot apply (drop the dtype argument or kv_quant)"
+        )
+    layer = KVRows(config.num_kv_heads, config.head_dim, config.kv_quant).init(batch, max_len, dtype)
+    return (layer,) * config.num_layers
 
 
 LLAMA_PARTITION_RULES = (

@@ -95,6 +95,7 @@ import numpy as np
 
 from unionml_tpu import telemetry
 from unionml_tpu._logging import logger
+from unionml_tpu.models.layers import KVRows
 from unionml_tpu.serving.faults import (
     DeadlineExceeded,
     EngineUnavailable,
@@ -129,6 +130,20 @@ def _start_host_copy(arr) -> None:
         arr.copy_to_host_async()
     except AttributeError:
         pass
+
+
+def _cache_layout(module):
+    """What each layer of ``module`` caches (``models/layers.py``: a
+    ``KVRows`` or a ``SlotState`` per layer): the module says, the engine
+    does not assume."""
+    layout = getattr(module, "cache_layout", None)
+    if layout is None:
+        raise TypeError(
+            f"{type(module).__name__} has no cache_layout(): a decoder the "
+            "engine can serve says what each of its layers caches "
+            "(unionml_tpu.models.layers.KVRows / SlotState)"
+        )
+    return tuple(layout())
 
 
 def _splice_rows(dst_tree, src_tree, b_start, r_start):
@@ -553,6 +568,30 @@ class DecodeEngine:
             raise ValueError("need at least one slot")
         if not prompt_buckets:
             raise ValueError("need at least one prompt bucket")
+        # what each layer of the module caches (models/layers.py): rows of
+        # keys and values, which a paged engine keeps in its block pool,
+        # or a state of fixed size, which it keeps per slot
+        self._layout = _cache_layout(module)
+        self._owns_rows = tuple(isinstance(l, KVRows) for l in self._layout)
+        self._state_layers = len(self._layout) - sum(self._owns_rows)
+        # bytes of recurrent state one slot keeps on the device
+        self._state_bytes_per_slot = sum(
+            l.nbytes() for l, kv in zip(self._layout, self._owns_rows) if not kv
+        )
+        if not any(self._owns_rows):
+            raise ValueError(
+                "no layer of this module caches keys and values: the engine's "
+                "buckets and pool are sized from those layers"
+            )
+        self._first_rows = self._owns_rows.index(True)
+        for given, what in (
+            (prefix_cache not in (None, False), "prefix_cache="),
+            (system_prefix is not None, "system_prefix="),
+            (draft_module is not None, "draft_module="),
+            (scheduler is not None and scheduler.preempt, "SchedulerConfig(preempt=True)"),
+        ):
+            if given:
+                self._refuse_recurrent(what)
         # serving phase (docs/serving.md "Disaggregated serving"):
         # which half of a generative request this engine's pool owns.
         # The engine itself serves any request either way — the label
@@ -678,6 +717,7 @@ class DecodeEngine:
                 registry=self._registry, flight=self._flight,
                 engine=self.instance, phase=self.phase,
                 slots=self.slots, chunk_steps=self.chunk_steps,
+                state_bytes=self._state_bytes_per_slot * slots,
             )
         self._perf = perf or None
         # harvester-thread clock: end of the previous readback, so each
@@ -1022,6 +1062,13 @@ class DecodeEngine:
             "unionml_engine_queue_depth",
             "Requests queued awaiting admission.", ("engine",),
         ).labels(**lbl)
+        R.gauge(
+            "unionml_engine_recurrent_state_bytes",
+            "Device bytes of per-slot recurrent state (the layers that "
+            "cache a state of fixed size, not keys and values), all slots; "
+            "0 for a module whose every layer caches keys and values.",
+            ("engine",),
+        ).labels(**lbl).set(self._state_bytes_per_slot * self.slots)
         self._h_drain = hist(
             "unionml_engine_drain_ms",
             "drain() wall time: stop-admissions to queue+slots idle.",
@@ -1390,16 +1437,50 @@ class DecodeEngine:
         return block, align
 
     def _kv_block_nbytes(self, blk: int) -> int:
-        """Device bytes of one pool block across every layer and buffer
-        (mirrors ``init_cache``'s layout: bf16 k/v, or int8 k/v + fp32
+        """Device bytes of one pool block across every layer that owns
+        pool rows (``KVRows.row_nbytes``: bf16 k/v, or int8 k/v + fp32
         per-(row, head) scales under ``kv_quant``)."""
-        cfg = self.cfg
-        rows = blk * cfg.num_kv_heads
-        if getattr(cfg, "kv_quant", False):
-            per_layer = 2 * (rows * cfg.head_dim * 1 + rows * 4)
-        else:
-            per_layer = 2 * rows * cfg.head_dim * 2
-        return cfg.num_layers * per_layer
+        return blk * sum(
+            l.row_nbytes() for l, kv in zip(self._layout, self._owns_rows) if kv
+        )
+
+    def _refuse_recurrent(self, what: str) -> None:
+        """``what`` restores a sequence from its KV blocks alone; refuse
+        it for a module with recurrent layers."""
+        if self._state_layers:
+            raise ValueError(
+                f"{what} rebuilds a sequence from its KV blocks, and "
+                f"{self._state_layers} of this module's {len(self._layout)} "
+                "layers keep a recurrent state that a block prefix does not "
+                "restore. State snapshots at block boundaries are not built "
+                "(ROADMAP.md, Queue 2): serve this module without it"
+            )
+
+    def _live_kw(self, live) -> dict:
+        """A module with state layers is told which rows of a decode step
+        are live: a dead slot's state is neither read nor written."""
+        return {"live": live} if self._state_layers else {}
+
+    def _init_layers(self, batch: int, rows: int, owns_rows=None):
+        """Zeroed caches of the layers (all, or those that own pool rows
+        or do not), ``batch`` sequences of ``rows`` positions."""
+        return tuple(
+            l.init(batch, rows) for l, kv in zip(self._layout, self._owns_rows)
+            if owns_rows is None or kv == owns_rows
+        )
+
+    def _join_layers(self, rows, states):
+        """The module's per-layer cache from the pool layers' entries and
+        the state layers', in layer order."""
+        rows, states = iter(rows), iter(states)
+        return tuple(next(rows) if kv else next(states) for kv in self._owns_rows)
+
+    def _split_layers(self, cache):
+        """``(pool layers' entries, state layers')`` of a per-layer cache."""
+        return (
+            tuple(c for c, kv in zip(cache, self._owns_rows) if kv),
+            tuple(c for c, kv in zip(cache, self._owns_rows) if not kv),
+        )
 
     # ------------------------------------------------------------------ #
     # device programs (compiled once per shape)
@@ -1408,8 +1489,6 @@ class DecodeEngine:
     def _build_programs(self):
         import jax
         import jax.numpy as jnp
-
-        from unionml_tpu.models.llama import init_cache
 
         if self.draft is not None:
             self._build_spec_programs()
@@ -1421,10 +1500,11 @@ class DecodeEngine:
         cfg, L, B = self.cfg, self.cache_len, self.slots
         module, sample = self.module, self._sample
         eos_id, pad_id = self.eos_id, self.pad_id
+        init_layers, first_rows, live_kw = self._init_layers, self._first_rows, self._live_kw
 
         def init_state():
             return {
-                "cache": init_cache(cfg, B, L),
+                "cache": init_layers(B, L),
                 "kv_mask": jnp.zeros((B, L), bool),
                 # empty slots idle at row 0: dead slots still run the
                 # decode apply and write garbage k/v at their fill row —
@@ -1450,7 +1530,7 @@ class DecodeEngine:
             cache into ``slot`` — cached-prefix rows spliced before the
             chunks ran are carried along; garbage rows above ``true_len``
             stay masked False in the resident kv_mask."""
-            bucket = fresh[0][0].shape[1]
+            bucket = fresh[first_rows][0].shape[1]
             c = toks.shape[1]
             kv_mask = (jnp.arange(bucket) < true_len)[None, :]
             logits, filled = module.apply(
@@ -1485,7 +1565,7 @@ class DecodeEngine:
         def prefill(params, state, slot, tokens, true_len, key):
             """Monolithic admission: fresh build + full-bucket finish in
             ONE program (short buckets; one dispatch per admission)."""
-            fresh = init_cache(cfg, 1, tokens.shape[0])
+            fresh = init_layers(1, tokens.shape[0])
             return finish_prefill(
                 params, state, fresh, slot, tokens[None], jnp.int32(0),
                 true_len, key, **_full_kwargs,
@@ -1503,7 +1583,7 @@ class DecodeEngine:
 
         @functools.partial(jax.jit, static_argnames=("bucket",))
         def init_fresh(*, bucket):
-            return init_cache(cfg, 1, bucket)
+            return init_layers(1, bucket)
 
         self._init_fresh = init_fresh
 
@@ -1511,7 +1591,7 @@ class DecodeEngine:
             """One lead chunk: tokens are fully real (the host only runs
             chunks covering the true length; the final, possibly padded,
             chunk goes through ``finish_prefill``)."""
-            lf = fresh[0][0].shape[1]          # bucket (static)
+            lf = fresh[first_rows][0].shape[1]  # bucket (static)
             c = toks.shape[1]
             kv_mask = (jnp.arange(lf) < start + c)[None, :]
             _, fresh = module.apply(
@@ -1545,7 +1625,7 @@ class DecodeEngine:
                 logits, cache = module.apply(
                     {"params": params}, state["last_tok"][:, None],
                     cache=state["cache"], cache_index=fill,
-                    kv_mask=kv_mask,
+                    kv_mask=kv_mask, **live_kw(live),
                 )
                 nxt = sample(logits[:, -1], key)
                 nxt = jnp.where(live, nxt, pad_id)
@@ -1601,17 +1681,21 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        from unionml_tpu.models.llama import init_cache
-
         cfg, L, B = self.cfg, self.cache_len, self.slots
         blk = self._kv_block_size
         n_pool = self.kv_pool.num_blocks
         module, sample = self.module, self._sample
         eos_id, pad_id = self.eos_id, self.pad_id
+        init_layers, first_rows, live_kw = self._init_layers, self._first_rows, self._live_kw
+        join, split = self._join_layers, self._split_layers
 
         def init_state():
             return {
-                "pool": init_cache(cfg, n_pool, blk),
+                "pool": init_layers(n_pool, blk, owns_rows=True),
+                # the state layers' per-slot states (none for a module
+                # whose every layer caches keys and values): written
+                # whole when a prefill ends, updated in place by decode
+                "rec": init_layers(B, 0, owns_rows=False),
                 # empty slots idle at row 0 with all-trash table rows:
                 # dead slots still run the decode apply, but their
                 # writes land in the trash block (step_table masking)
@@ -1645,7 +1729,7 @@ class DecodeEngine:
             first-token sampling as the contiguous path (logits are
             bit-identical), then the per-block pool scatter in place of
             the contiguous row splice."""
-            bucket = fresh[0][0].shape[1]
+            bucket = fresh[first_rows][0].shape[1]
             c = toks.shape[1]
             kv_mask = (jnp.arange(bucket) < true_len)[None, :]
             logits, filled = module.apply(
@@ -1656,9 +1740,13 @@ class DecodeEngine:
                 **apply_kwargs,
             )
             first = sample(logits[:, 0], key)[0]
-            pool = scatter_blocks(state["pool"], filled, ids)
+            rows, states = split(filled)
+            pool = scatter_blocks(state["pool"], rows, ids)
             return {
                 "pool": pool,
+                # the slot's states, whole: whatever its last occupant left
+                # is overwritten (dst [slots, ...] <- src [1, ...])
+                "rec": _splice_rows(state["rec"], states, slot, 0),
                 "fill": state["fill"].at[slot].set(true_len),
                 "last_tok": state["last_tok"].at[slot].set(first),
                 "done": state["done"].at[slot].set(False),
@@ -1669,7 +1757,7 @@ class DecodeEngine:
         )
 
         def prefill(params, state, slot, ids, tokens, true_len, key):
-            fresh = init_cache(cfg, 1, tokens.shape[0])
+            fresh = init_layers(1, tokens.shape[0])
             return finish_prefill(
                 params, state, fresh, slot, ids, tokens[None],
                 jnp.int32(0), true_len, key, **_full_kwargs,
@@ -1679,7 +1767,7 @@ class DecodeEngine:
 
         @functools.partial(jax.jit, static_argnames=("bucket",))
         def init_fresh(*, bucket):
-            return init_cache(cfg, 1, bucket)
+            return init_layers(1, bucket)
 
         self._init_fresh = init_fresh
 
@@ -1687,7 +1775,7 @@ class DecodeEngine:
             """One lead chunk against the contiguous fresh cache —
             verbatim the contiguous engine's program (the workspace
             layout did not change, only residency did)."""
-            lf = fresh[0][0].shape[1]
+            lf = fresh[first_rows][0].shape[1]
             c = toks.shape[1]
             kv_mask = (jnp.arange(lf) < start + c)[None, :]
             _, fresh = module.apply(
@@ -1725,11 +1813,12 @@ class DecodeEngine:
                 live = active & ~state["done"]
                 fill = state["fill"]
                 step_table = jnp.where(live[:, None], table, 0)
-                logits, pool = module.apply(
+                logits, cache = module.apply(
                     {"params": params}, state["last_tok"][:, None],
-                    cache=state["pool"], cache_index=fill,
-                    block_table=step_table,
+                    cache=join(state["pool"], state["rec"]), cache_index=fill,
+                    block_table=step_table, **live_kw(live),
                 )
+                pool, rec = split(cache)
                 nxt = sample(logits[:, -1], key)
                 nxt = jnp.where(live, nxt, pad_id)
                 done = state["done"]
@@ -1739,6 +1828,7 @@ class DecodeEngine:
                 done = done | (live & ~advance)
                 return {
                     "pool": pool,
+                    "rec": rec,
                     "fill": fill + advance.astype(jnp.int32),
                     "last_tok": jnp.where(live, nxt, state["last_tok"]),
                     "done": done,
@@ -2226,6 +2316,7 @@ class DecodeEngine:
         handoff degrades, never errors. Billing is exactly a normal
         1-token request's: the prefill window goes to the admitting
         tenant under this engine's ``phase`` label."""
+        self._refuse_recurrent("prefill_export")
         if self.prefix_cache is None:
             raise ValueError(
                 "prefill_export needs a prefix cache — the harvested "
@@ -2292,6 +2383,7 @@ class DecodeEngine:
         may still be attaching the final blocks — whatever is covered
         when the budget expires is exported (the decode side
         recomputes the rest: degrade, never error)."""
+        self._refuse_recurrent("kv_export")
         cache = self.prefix_cache
         if cache is None:
             raise ValueError(
@@ -2311,6 +2403,7 @@ class DecodeEngine:
         handler / the router's cross-store transfer): each entry
         rides the normal insert budget/eviction machinery; returns
         blocks newly attached."""
+        self._refuse_recurrent("kv_import")
         cache = self.prefix_cache
         if cache is None:
             raise ValueError(
@@ -2437,6 +2530,12 @@ class DecodeEngine:
             out["prefix_cache"] = self.prefix_cache.stats()
         if self.kv_pool is not None:
             out["kv_pool"] = self.kv_pool.stats()
+        if self._state_layers:
+            out["state"] = {
+                "layers": self._state_layers,
+                "bytes_per_slot": self._state_bytes_per_slot,
+                "bytes_resident": self._state_bytes_per_slot * self.slots,
+            }
         if self._usage is not None:
             # the compact per-tenant view (GET /debug/usage has the
             # full per-tenant resource vectors)
@@ -3766,6 +3865,9 @@ class DecodeEngine:
                         self._room.park(req)
                         if not req._park_logged:
                             req._park_logged = True
+                            if self._perf is not None:
+                                # a slot was free: the pool bound the batch
+                                self._perf.note_parked()
                             resident = [
                                 r for r in self._occupant if r is not None
                             ]
@@ -4184,7 +4286,7 @@ class DecodeEngine:
         with self._tracer.span(
             req.rid, "admit", annotation="engine.admit",
             bucket=self._bucket_for(len(req.prompt)),
-            prompt_tokens=len(req.prompt),
+            prompt_tokens=len(req.prompt), state_layers=self._state_layers,
         ) as sp:
             step(arg)
             sp.note(cached_tokens=req._saved_tokens)

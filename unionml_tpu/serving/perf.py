@@ -236,6 +236,7 @@ class ServingPerfPlane:
         phase: str = "colocated",
         slots: int = 1,
         chunk_steps: int = 1,
+        state_bytes: int = 0,
         ring: int = 2048,
         clock: Callable[[], float] = time.perf_counter,
         watchdog: Optional[ServingRegressionWatchdog] = None,
@@ -247,6 +248,7 @@ class ServingPerfPlane:
         self._phase = phase
         self._slots = max(1, int(slots))
         self._chunk_steps = max(1, int(chunk_steps))
+        self._state_bytes = int(state_bytes)  # recurrent state, all slots
         self._clock = clock
         self._lock = threading.Lock()
         # ring entries: (kind, occupied_slot_steps, total_slot_steps),
@@ -383,6 +385,12 @@ class ServingPerfPlane:
             if poll is not None:
                 self._win_polls[poll] = self._win_polls.get(poll, 0) + 1
 
+    def note_parked(self) -> None:
+        """One admission found a free slot and no pool blocks, and parked
+        (counted once an admission, however often it is retried)."""
+        with self._lock:
+            self._win_parked += 1
+
     def note_tokens(self, n: int) -> None:
         """``n`` tokens harvested (the achieved-throughput numerator)."""
         with self._lock:
@@ -406,6 +414,7 @@ class ServingPerfPlane:
         self._win_occ_steps = 0
         self._win_starved_steps = 0
         self._win_admissions = 0
+        self._win_parked = 0
         self._win_prefill_tokens = 0
         self._win_polls = {reason: 0 for reason in POLL_REASONS}
         self._win_dispatcher_s = {phase: 0.0 for phase in DISPATCHER_PHASES}
@@ -444,8 +453,8 @@ class ServingPerfPlane:
         classification counts + slot-step sums, the three ratios,
         achieved tokens/s since construction (or :meth:`reset`), the
         plain sums since then (``window_*``, ``starved_slot_steps``,
-        ``admissions``, ``prefill_tokens``, ``polls``,
-        ``dispatcher_s``), and the watchdog advisory. The ring wrapped
+        ``admissions``, ``admissions_parked_on_pool``, ``prefill_tokens``,
+        ``polls``, ``dispatcher_s``), and the watchdog advisory. The ring wrapped
         when ``total_passes > ring_passes``: the ratios then cover the
         newest passes only, the sums still the whole window."""
         with self._lock:
@@ -460,6 +469,7 @@ class ServingPerfPlane:
                 "window_occupied_slot_steps": self._win_occ_steps,
                 "starved_slot_steps": self._win_starved_steps,
                 "admissions": self._win_admissions,
+                "admissions_parked_on_pool": self._win_parked,
                 "prefill_tokens": self._win_prefill_tokens,
                 "polls": dict(self._win_polls),
                 "dispatcher_s": {
@@ -488,6 +498,7 @@ class ServingPerfPlane:
             "goodput_ratio": round(goodput, 6),
             "occupancy_ratio": round(occupancy, 6),
             "kv_pressure_ratio": round(pressure, 6),
+            "state_bytes_resident": self._state_bytes,
             "tokens": tokens,
             "tokens_per_s": round(tokens / elapsed, 3),
             **window,
