@@ -1,0 +1,110 @@
+"""The paged decode attention kernel alone, at the benchmark's two serving shapes.
+
+    chiprun -- python3 benchmarks/paged_kernel.py            # the tree's kernel
+    PYTHONPATH=<other checkout> python3 benchmarks/paged_kernel.py
+
+One JSON line a case: microseconds a call, from a jitted loop of ``--reps``
+dependent calls timed on the host's clock around ``block_until_ready`` (the
+launch is paid once a loop). A case is a shape (``chipbench``'s
+``mixtral_chat_decode``: 32 slots, GQA 32/8, a table 101 blocks wide;
+``olmo_hybrid_longgen_decode``: 32 slots, MHA 32/32, a table 261 wide) and
+what the rows hold:
+
+- ``live+zero``: the live rows at their lengths, every other row length 0
+  (what an engine hands the kernel when it says which rows are live);
+- ``live+stale``: the other rows keep a retired sequence's length over a
+  table of trash-block entries (what it handed before);
+- ``dead``: every row length 0 — what walking the batch costs by itself.
+
+``--rows N`` overrides the KV rows a kernel step takes (the module's
+``_ROWS_PER_STEP`` and a VMEM budget to match), to re-derive them. Fails
+without a TPU unless ``--rehearse`` (tiny shapes, interpret mode: the
+numbers then mean nothing).
+"""
+
+import argparse
+import json
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from unionml_tpu.ops import paged_attention as pa
+
+BLOCK, HEAD_DIM, SLOTS = 16, 128, 32
+# (name, q heads, kv heads, table width, pool blocks, live rows, mean live length)
+SHAPES = [
+    ("mixtral_chat_decode", 32, 8, 101, 2861, 13, 260),
+    ("olmo_hybrid_longgen_decode", 32, 32, 261, 1621, 12, 850),
+]
+
+
+def _case(rng, width, n_blocks, live, mean_len, stale):
+    """Table, lengths and the live positions: ``live`` rows among the low
+    slots (an engine takes the lowest free slot) at lengths around
+    ``mean_len``; the rest over trash-block entries, at length 0 or, if
+    ``stale``, at ``mean_len``."""
+    lengths = np.full(SLOTS, mean_len if stale else 0, np.int32)
+    table = np.zeros((SLOTS, width), np.int32)
+    blocks = iter(rng.permutation(np.arange(1, n_blocks)))
+    rows = rng.choice(min(SLOTS, 2 * live), size=live, replace=False)
+    for b in rows:
+        lengths[b] = np.clip(rng.normal(mean_len, 0.4 * mean_len), 16, width * BLOCK)
+        need = -(-int(lengths[b]) // BLOCK)
+        table[b, :need] = [next(blocks) for _ in range(need)]
+    return jnp.asarray(table), jnp.asarray(lengths), int(lengths[rows].sum())
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--reps", type=int, default=200)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--rows", type=int, default=0)
+    ap.add_argument("--rehearse", action="store_true")
+    args = ap.parse_args()
+    device = jax.devices()[0]
+    if device.platform != "tpu" and not args.rehearse:
+        raise SystemExit(f"needs a TPU, found {device.platform}")
+    if args.rows:  # with room for four buffers of that many 32-head bf16 rows
+        pa._ROWS_PER_STEP = args.rows
+        pa._KV_BUFFER_BYTES = max(pa._KV_BUFFER_BYTES, 4 * args.rows * 32 * HEAD_DIM * 2)
+    reps = 2 if args.rehearse else args.reps
+
+    for name, q_heads, kv_heads, width, n_blocks, live, mean_len in SHAPES:
+        if args.rehearse:
+            width, n_blocks, mean_len = 40, 200, 60
+        rng = np.random.default_rng(args.seed)
+        pool_shape = (n_blocks, BLOCK, kv_heads, HEAD_DIM)
+        k = jnp.asarray(rng.standard_normal(pool_shape), jnp.bfloat16)
+        v = jnp.asarray(rng.standard_normal(pool_shape), jnp.bfloat16)
+        q = jnp.asarray(rng.standard_normal((SLOTS, q_heads, HEAD_DIM)), jnp.bfloat16)
+
+        @jax.jit
+        def loop(q, k, v, table, lengths):
+            def body(_, x):
+                return pa.paged_attention(x, k, v, table, lengths, impl="pallas")
+            return jax.lax.fori_loop(0, reps, body, q)
+
+        cases = (("live+zero", live, False), ("live+stale", live, True), ("dead", 0, False))
+        for what, n_live, stale in cases:
+            table, lengths, positions = _case(
+                np.random.default_rng(args.seed + 1), width, n_blocks, n_live, mean_len, stale,
+            )
+            loop(q, k, v, table, lengths).block_until_ready()
+            times = []
+            for _ in range(5):
+                t0 = time.perf_counter()
+                loop(q, k, v, table, lengths).block_until_ready()
+                times.append((time.perf_counter() - t0) / reps)
+            print(json.dumps({
+                "shape": name, "rows": what, "us_per_call": round(1e6 * float(np.median(times)), 2),
+                "us_min": round(1e6 * min(times), 2), "live_rows": n_live, "live_positions": positions,
+                "kv_mb": round(positions * 2 * kv_heads * HEAD_DIM * 2 / 1e6, 2),
+                "pages_per_step": pa._pages_per_step(BLOCK, kv_heads, HEAD_DIM, 2, width),
+                "device": device.device_kind, "platform": device.platform,
+            }), flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -29,7 +29,8 @@ Phases (one chip):
   solo greedy), paged KV under ``paged_impl="reference"`` (tokens ==
   contiguous) and under the default ``paged_impl`` (the Pallas kernel).
 - **paged kernel** — ``paged_attention(impl="pallas")`` against
-  ``paged_attention_reference`` at the 8B and 16/16-MHA geometries.
+  ``paged_attention_reference`` at the 8B and 16/16-MHA geometries and
+  at the benchmark's two serving shapes, rows of length 0 among the rest.
 
 With ``--chips 4``: one ``compile_step(lm_step(Llama))`` step over a
 dp2 x tensor2 mesh against the same step on one chip, and a
@@ -101,7 +102,7 @@ def _sizes(rehearse: bool) -> dict:
             llama=LlamaConfig.tiny(vocab_size=256),
             prompt_len=12, bucket=16, new_tokens=8,
             kernel_geoms=[(4, 2, 16, 8), (4, 4, 16, 8)],
-            kernel_wide=(4, 2, 16, 8, 12, 75),
+            kernel_wide=[(4, 2, 16, 8, 12, 75), (4, 4, 16, 8, 12, 75)],
             lm_batch=4, lm_seq=32,
         )
     return dict(
@@ -116,7 +117,8 @@ def _sizes(rehearse: bool) -> dict:
             (16, 16, 128, 16),
         ],
         # (..., rows, table width): the mixtral_chat_decode cell's shape
-        kernel_wide=(32, 8, 128, 16, 32, 101),
+        # and the olmo_hybrid_longgen_decode cell's
+        kernel_wide=[(32, 8, 128, 16, 32, 101), (32, 32, 128, 16, 32, 261)],
         lm_batch=4, lm_seq=256,
     )
 
@@ -536,15 +538,19 @@ def phase_paged_kernel(sz: dict) -> None:
              width * block - 1, width * block], np.int32,
         )
         _paged_kernel_case(rng, hq, hk, hd, block, lengths, width, n_blocks=96)
-    # the benchmark's serving shape: 32 slots over a table 101 blocks wide
-    # (several groups of pool blocks a row, the last one partial), ragged
-    # lengths, and retired slots: table all trash block, length stale
-    hq, hk, hd, block, batch, width = sz["kernel_wide"]
-    lengths = rng.integers(1, width * block + 1, batch).astype(np.int32)
-    lengths[:4] = [1, width * block, 512, 513]
-    dead = np.zeros(batch, bool)
-    dead[5::6] = True
-    _paged_kernel_case(rng, hq, hk, hd, block, lengths, width, n_blocks=3 * width, dead=dead)
+    # the benchmark's serving shapes: 32 slots over a table 101 or 261
+    # blocks wide (a row walks several groups of pool blocks, the last
+    # one partial), ragged lengths, retired slots the engine's way (length
+    # 0: first, between live rows and last) and the old way (table all
+    # trash block, length stale)
+    for hq, hk, hd, block, batch, width in sz["kernel_wide"]:
+        lengths = rng.integers(1, width * block + 1, batch).astype(np.int32)
+        lengths[:6] = [0, 0, 1, width * block, 512, 513]
+        lengths[8::5] = 0
+        lengths[-2:] = 0
+        dead = np.zeros(batch, bool)
+        dead[5::6] = True
+        _paged_kernel_case(rng, hq, hk, hd, block, lengths, width, n_blocks=3 * width, dead=dead)
 
 
 def _paged_kernel_case(rng, hq, hk, hd, block, lengths, width, n_blocks, dead=None) -> None:

@@ -72,28 +72,52 @@ def test_reference_bit_identical_int8():
     assert bool(jnp.all(ref == contig))
 
 
-# Table widths for the kernel's grouping (it handles P pool blocks a grid
-# step, P from the shapes: test_pages_per_step_follows_the_shapes): the
-# short table is one group a row (W < P), the wide one two groups with a
-# partial last one (W not a multiple of P)
+# Table widths for the kernel's grouping (a row walks its table P pool
+# blocks at a time, P from the shapes: test_pages_per_step_follows_the_shapes):
+# the short table is narrower than one group (W < P), the wide one two
+# groups with a partial last one (W not a multiple of P)
 P = _ROWS_PER_STEP // BS
 WIDE = P + 6
 WIDTHS = pytest.mark.parametrize("width", [W, WIDE], ids=["short-table", "wide-table"])
-# (q heads, kv heads): grouped-query and one kv head per q head
-HEADS = pytest.mark.parametrize("heads", [(H, KVH), (H, H)], ids=["gqa", "mha"])
+# (q heads, kv heads): grouped-query and one kv head per q head, at toy
+# width and at the two served geometries' head counts
+HEADS = pytest.mark.parametrize(
+    "heads", [(H, KVH), (H, H), (32, 8), (32, 32)], ids=["gqa", "mha", "gqa-32-8", "mha-32-32"]
+)
 
 
-def _ragged_setup(width, heads, dtype, quantized, seed):
-    """Six rows over a ``width``-block table: a zero-length row, one
-    visible row, a length at a group boundary and one past it (a block
-    boundary on the short table), the full table, and a dead row (every
-    table entry the trash block 0, its length stale)."""
+def _edges(width):
+    """A zero-length row, one visible row, a length at a group boundary
+    and one past it (a block boundary on the short table), the full
+    table, and a dead row (every table entry the trash block 0, its
+    length stale)."""
+    edge = min(P, width - 1) * BS
+    return [0, 1, edge, edge + 1, width * BS, edge + 3], [False] * 5 + [True]
+
+
+def _holes(width):
+    """Rows that see nothing first (two: the search for the next row that
+    sees anything has to skip both), between live rows and last; a length
+    that ends inside a group and inside a block; the whole table; a stale
+    length past a full table (the kernel clamps it, the reference sees the
+    whole table either way); a dead row with a stale length."""
+    inside = (min(P, width - 1) - 1) * BS + 3
+    full = width * BS
+    return [0, 0, inside, 0, full, 0, full + 37, inside + 5, 0, 0], [False] * 7 + [True] + [False] * 2
+
+
+LAYOUTS = pytest.mark.parametrize("layout", [_edges, _holes], ids=["edges", "holes"])
+
+
+def _ragged_setup(width, heads, dtype, quantized, seed, layout=_edges):
+    """Rows of ``layout`` over a ``width``-block table of a pool whose
+    trash block holds garbage; the rows to compare are those that see
+    anything and are not dead."""
     q_heads, kv_heads = heads
     rng = np.random.default_rng(seed)
     n_blocks = 2 * width
-    edge = min(P, width - 1) * BS
-    lengths = np.array([0, 1, edge, edge + 1, width * BS, edge + 3], np.int32)
-    dead = np.array([False] * 5 + [True])
+    lengths, dead = (np.array(x) for x in layout(width))
+    lengths = lengths.astype(np.int32)
     table = rng.integers(1, n_blocks, (len(lengths), width)).astype(np.int32)
     for b, n in enumerate(lengths):  # entries past coverage park on the trash block
         table[b, 0 if dead[b] else -(-int(n) // BS):] = 0
@@ -119,24 +143,28 @@ def _ragged_setup(width, heads, dtype, quantized, seed):
 def _gap(args, kw, rows):
     ref = paged_attention(*args, impl="reference", **kw).astype(jnp.float32)
     pal = paged_attention(*args, impl="pallas", **kw).astype(jnp.float32)
-    # zero-length and dead rows are garbage by contract, but finite
+    # dead rows are garbage by contract, but finite; a row that sees
+    # nothing reads nothing and comes out as zeros
     assert bool(jnp.all(jnp.isfinite(pal)))
+    assert not bool(jnp.any(pal[np.asarray(args[4]) == 0]))
     return float(jnp.max(jnp.abs(pal - ref)[rows]))
 
 
+@LAYOUTS
 @HEADS
 @WIDTHS
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_pallas_matches_reference(dtype, width, heads):
-    args, kw, rows = _ragged_setup(width, heads, dtype, False, seed=4)
+def test_pallas_matches_reference(dtype, width, heads, layout):
+    args, kw, rows = _ragged_setup(width, heads, dtype, False, seed=4, layout=layout)
     tol = 1e-6 if dtype == jnp.float32 else 2e-2
     assert _gap(args, kw, rows) < tol
 
 
+@LAYOUTS
 @HEADS
 @WIDTHS
-def test_pallas_matches_reference_int8(width, heads):
-    args, kw, rows = _ragged_setup(width, heads, jnp.float32, True, seed=5)
+def test_pallas_matches_reference_int8(width, heads, layout):
+    args, kw, rows = _ragged_setup(width, heads, jnp.float32, True, seed=5, layout=layout)
     assert _gap(args, kw, rows) < 1e-5
 
 
@@ -158,12 +186,14 @@ def test_pages_per_step_follows_the_shapes(block, kv_heads, head_dim, itemsize, 
 def test_zero_length_rows_are_finite():
     """Dead slots decode with length 0 (everything masked): the output
     is garbage by contract but must be FINITE — NaN would poison the
-    residual stream of live slots through layer norms."""
+    residual stream of live slots through layer norms. A batch of
+    nothing but such rows starts no copy in the kernel at all."""
     q, k, v, table, _ = _setup()
     lengths = jnp.zeros((B,), jnp.int32)
     for impl in ("reference", "pallas"):
         out = paged_attention(q, k, v, table, lengths, impl=impl)
         assert bool(jnp.all(jnp.isfinite(out.astype(jnp.float32))))
+    assert not bool(jnp.any(out))
 
 
 def test_gqa_groups_share_kv_head():

@@ -513,3 +513,72 @@ def test_paged_eos_retires_and_frees(tiny_llama):
         finally:
             engine.close()
     assert outs[True] == outs[False]
+
+
+def test_paged_dead_slots_reach_the_kernel_with_length_zero(tiny_llama, monkeypatch):
+    """The engine says which rows of a decode step are live, and the
+    others reach the paged kernel with length 0 whatever ``fill`` their
+    last occupant left: the kernel gathers nothing for them. Live rows
+    decode as before, and a reused slot decodes as a fresh one."""
+    from unionml_tpu.ops import paged_attention as pa
+
+    module, params = tiny_llama
+    seen = []  # (table row is the trash block's, length) per row of a kernel call
+    real = pa.paged_attention
+
+    def spy(q, k, v, block_table, lengths, **kw):
+        jax.debug.callback(
+            lambda t, n: seen.append((~np.asarray(t).any(axis=1), np.asarray(n))),
+            block_table, lengths,
+        )
+        return real(q, k, v, block_table, lengths, **kw)
+
+    monkeypatch.setattr(pa, "paged_attention", spy)
+    engine = _paged_engine(
+        module, slots=2, max_new_tokens=6, prompt_buckets=(16,), chunk_steps=3,
+    )
+    try:
+        rng = np.random.default_rng(13)
+        first = [rng.integers(1, 97, size=n).tolist() for n in (9, 14)]
+        outs = engine.generate(params, first)
+        for prompt, out in zip(first, outs):
+            assert out == _solo(module, params, prompt, 6, max_len=engine.cache_len)
+        _assert_pool_drained(engine)
+        jax.effects_barrier()
+        # both slots are retired, and their fills are their occupants' still
+        stale = np.asarray(engine._state["fill"])
+        assert (stale >= 9).all()
+        seen.clear()
+        # one request: it takes slot 0 (the lowest free), slot 1 stays dead
+        again = rng.integers(1, 97, size=5).tolist()
+        assert engine.generate(params, [again])[0] == _solo(
+            module, params, again, 6, max_len=engine.cache_len
+        )
+        jax.effects_barrier()
+        assert np.asarray(engine._state["fill"])[1] == stale[1]
+    finally:
+        engine.close()
+    assert seen
+    for trash, lengths in seen:
+        assert trash[1] and lengths[1] == 0
+        # a row is handed its table and its length together, or neither
+        assert ((lengths == 0) == trash).all()
+    assert any(lengths[0] > 0 for _, lengths in seen)
+
+
+def test_paged_stats_say_what_the_kernel_walks(tiny_llama):
+    """``stats()["kv_pool"]`` carries the table's width and the pool
+    blocks a group of the decode kernel takes at this pool's shapes, so a
+    deployment can reckon how many groups its longest row walks."""
+    module, _ = tiny_llama
+    engine = _paged_engine(
+        module, slots=2, max_new_tokens=40, prompt_buckets=(16,), chunk_steps=4,
+        kv_block_size=8,
+    )
+    try:
+        st = engine.stats()["kv_pool"]
+        assert st["table_width"] == engine.cache_len // 8
+        # 512 KV rows a group, never more than the table is wide
+        assert st["kernel_blocks_per_group"] == min(64, st["table_width"])
+    finally:
+        engine.close()

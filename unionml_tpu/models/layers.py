@@ -379,6 +379,7 @@ class Attention(nn.Module):
         kv_mask: Optional[jnp.ndarray] = None,
         block_table: Optional[jnp.ndarray] = None,
         full_prefill: bool = False,
+        live: Optional[jnp.ndarray] = None,
     ):
         """Returns ``out`` or ``(out, new_cache)`` when a cache is given.
 
@@ -393,7 +394,11 @@ class Attention(nn.Module):
         through :func:`~unionml_tpu.ops.paged_attention.paged_attention`
         (``paged_impl`` picks the kernel) with ``lengths = fill + 1``
         (the just-written row sees itself). ``kv_mask`` must be None —
-        visibility is derived from the fills.
+        visibility is derived from the fills. ``live``: bool [batch] —
+        the rows of this paged decode step whose output is used. The
+        others read with length 0 (their ``cache_index`` may be a retired
+        sequence's), so the kernel gathers nothing for them; their k/v
+        row is still written where the table says (the trash block).
 
         ``full_prefill``: STATIC caller promise that this multi-token
         cached call covers the entire visible history — the cache is
@@ -571,14 +576,17 @@ class Attention(nn.Module):
                 # path's self-visible kv_mask row
                 from unionml_tpu.ops.paged_attention import paged_attention
 
+                lengths = index + 1
+                if live is not None:
+                    lengths = jnp.where(live, lengths, 0)
                 if len(cache) == 4:
                     out = paged_attention(
-                        q[:, 0], ck, cv, block_table, index + 1,
+                        q[:, 0], ck, cv, block_table, lengths,
                         k_scale=ks, v_scale=vs, impl=self.paged_impl,
                     )[:, None]
                 else:
                     out = paged_attention(
-                        q[:, 0], ck, cv, block_table, index + 1,
+                        q[:, 0], ck, cv, block_table, lengths,
                         impl=self.paged_impl,
                     )[:, None]
             if full_prefill and seq > 1 and self.prefill_impl == "flash":

@@ -136,7 +136,7 @@ class LlamaBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, *, positions=None, cache=None, cache_index=None,
-                 kv_mask=None, block_table=None, full_prefill=False):
+                 kv_mask=None, block_table=None, full_prefill=False, live=None):
         cfg = self.config
         dtype = jnp.dtype(cfg.dtype)
         attn = Attention(
@@ -165,7 +165,7 @@ class LlamaBlock(nn.Module):
             a, new_cache = attn(
                 h, positions=positions, cache=cache, cache_index=cache_index,
                 kv_mask=kv_mask, block_table=block_table,
-                full_prefill=full_prefill,
+                full_prefill=full_prefill, live=live,
             )
         else:
             if kv_mask is not None:
@@ -220,6 +220,7 @@ class Llama(nn.Module):
         block_table: Optional[jnp.ndarray] = None,
         logit_index: Optional[jnp.ndarray] = None,
         full_prefill: bool = False,
+        live: Optional[jnp.ndarray] = None,
     ):
         """logits [B,S,V]; with ``cache`` returns (logits, new_cache).
 
@@ -229,6 +230,9 @@ class Llama(nn.Module):
         (``seq == 1``, vector ``cache_index``). See
         :class:`~unionml_tpu.models.layers.Attention`.
 
+        ``live``: bool [B] — the rows of a decode step whose logits are
+        used (an engine's occupied, unfinished slots); a paged step reads
+        no KV for the others.
         ``kv_mask``: bool (batch, max_len) — False cache slots are never
         attended to (left-padded prompts in generation).
         ``full_prefill``: static caller promise that this cached call
@@ -264,7 +268,7 @@ class Llama(nn.Module):
             x, c = block_cls(cfg, name=f"block_{i}")(
                 x, positions=positions, cache=layer_cache, cache_index=cache_index,
                 kv_mask=kv_mask, block_table=block_table,
-                full_prefill=full_prefill,
+                full_prefill=full_prefill, live=live,
             )
             new_cache.append(c)
         if logit_index is not None:

@@ -22,8 +22,8 @@ configuration carries the Hugging Face keys one to one (``layer_types``,
 
 :class:`OlmoHybrid` takes the same call arguments as
 :class:`~unionml_tpu.models.llama.Llama` (``cache``, ``cache_index``,
-``kv_mask``, ``block_table``, ``logit_index``) and ``live`` besides: the
-rows of a decode step whose state may change. ``cache_layout()`` tells a
+``kv_mask``, ``block_table``, ``logit_index``, ``live``: the rows of a
+decode step whose state may change). ``cache_layout()`` tells a
 serving engine which layers own key/value rows and which a state
 (``layers.KVRows`` / ``layers.SlotState``). In a cached multi-token call a
 row's tokens must be real from its first position on (right padding, as
@@ -261,7 +261,7 @@ class OlmoHybridBlock(nn.Module):
             if cache is not None:
                 kwargs.update(
                     cache=cache, cache_index=cache_index, kv_mask=kv_mask,
-                    block_table=block_table, full_prefill=full_prefill,
+                    block_table=block_table, full_prefill=full_prefill, live=live,
                 )
             elif kv_mask is not None:
                 raise ValueError("kv_mask requires a cache (generation path)")

@@ -20,26 +20,31 @@ Two implementations behind one dispatcher:
   the outputs are **bit-identical** to the contiguous cache path on the
   same values — the CPU/tier-1 parity anchor every paged-engine test
   asserts against.
-- the Pallas kernel (``impl="pallas"``) — grid ``(batch, groups)``: a
-  grid step handles a **group** of P pool blocks of one row, P worked
-  out from the shapes (:func:`_pages_per_step`: 512 KV rows a step,
-  fewer where the gather buffers would outgrow their VMEM budget, never
-  more than the table is wide). A grid step costs the same whatever it
-  scores, so with one block a step the kernel's time followed the
-  table's width (``max_new_tokens`` and the largest bucket); with a
-  group a step it follows the live KV. The pools stay in HBM
-  (``memory_space=pl.ANY``, no gathered copy of the cache is ever
-  materialized) and the block table and lengths ride in as
-  **scalar-prefetch** operands: the step reads the group's table
+- the Pallas kernel (``impl="pallas"``) — the grid is the rows
+  (``(batch,)``, in order), and a row's **groups** are a loop inside its
+  grid step: a group is P pool blocks, P worked out from the shapes
+  (:func:`_pages_per_step`: 512 KV rows a group, fewer where the gather
+  buffers would outgrow their VMEM budget, never more than the table is
+  wide), and the loop's trip count is ``cdiv(min(length, W * block),
+  P * block)``, read from the scalar-prefetched lengths. So a call costs
+  its rows plus the groups that hold visible KV, whatever the table's
+  width (``max_new_tokens`` and the largest bucket); as a grid dimension
+  the groups cost a step each, scored or not. A row of length 0 — what
+  :class:`~unionml_tpu.models.layers.Attention` hands over for the rows
+  the engine says are not live — starts no copy, runs no iteration and
+  writes zeros: on a v5e about 0.3 us (PERF.md, section 6, PR 28). The
+  pools stay in HBM (``memory_space=pl.ANY``, no gathered copy of the
+  cache is ever materialized) and the block table and lengths ride in as
+  **scalar-prefetch** operands: an iteration reads the group's table
   entries and starts one async copy per pool block that holds visible
   rows — blocks are not contiguous in the pool — into a double-buffered
   VMEM scratch, and the next group's copies (the same row's, or the
-  next row's first group) fly while this one is scored. A group wholly
-  past a row's length starts no copy and does no work; the table's last
-  group may be partial, and no entry past the table is read. fp32
-  online-softmax accumulators (running max / normalizer / weighted sum)
-  live in VMEM scratch and carry across a row's groups, the same scheme
-  as :mod:`~unionml_tpu.ops.flash_attention`. GQA reads the pool at
+  first group of the next row that sees anything) fly while this one is
+  scored. The table's last group may be partial, and no entry past the
+  table is read. fp32 online-softmax accumulators (running max /
+  normalizer / weighted sum) live in VMEM scratch and carry across a
+  row's groups, the same scheme as
+  :mod:`~unionml_tpu.ops.flash_attention`. GQA reads the pool at
   kv-head width (no head repeat): the gathered group is viewed as
   ``[P * block * Hk, D]``, ONE matmul scores every q head against every
   (position, kv head) row, and a mask keeps each q head's own kv head —
@@ -51,16 +56,17 @@ Two implementations behind one dispatcher:
   which costs an XLA relayout of the planes per call) — the same
   numerics contract as the existing kernels: fp32 softmax statistics,
   MXU matmuls in the input dtype with fp32 accumulation, outputs equal
-  to the reference up to float reduction order. Both grid dimensions
-  run in order (a step prefetches for the next one, rows included), so
-  a two-core chip does not split the batch.
+  to the reference up to float reduction order. The grid runs in order
+  (a row prefetches for the next one), so a two-core chip does not split
+  the batch.
 
 ``impl="auto"`` picks the kernel on TPU and the reference elsewhere
 (CPU tests run the kernel in interpreter mode only when asked).
 Interpret mode proves the math, not that Mosaic accepts the kernel:
 ``tests/unit/test_tpu_compile.py`` compiles it for v5e at the Llama-3-8B
-and 16/16-MHA geometries and at the benchmark's serving shape (32 rows
-over a 101-block table), and ``chip_smoke.py`` runs it there. What it
+and 16/16-MHA geometries and at the benchmark's two serving shapes (32
+rows over a 101-block table at GQA 32/8, over a 261-block one at MHA
+32/32), and ``chip_smoke.py`` runs it there. What it
 costs on the chip is the benchmark's ``paged_attn_ms_per_step``
 (``chipbench/layer_metrics/``).
 """
@@ -160,19 +166,21 @@ def paged_attention_reference(
     return out[:, 0]
 
 
-# KV rows (positions) one grid step gathers and scores. A step costs a
-# fixed price (the grid step itself, the DMA issue, two matmuls' latency)
-# whatever it scores, so the step is made wide enough that the price is
-# paid a few times a row and not once a pool block. On a v5e 256 and 512
-# tie at chat lengths (a few hundred rows) and 512 wins on long rows;
-# 128 and 1024 lose at both (PERF.md, section 6, PR 26).
+# KV rows (positions) one group gathers and scores. A group costs a fixed
+# price (the DMA issue, two matmuls' latency) whatever it scores, so it is
+# made wide enough that the price is paid a few times a row and not once
+# a pool block. On a v5e 256 and 512 tie at chat lengths (a few hundred
+# rows) and 512 wins on long rows; 128 and 1024 lose at both (PERF.md,
+# section 6, PR 26). At MHA the VMEM budget below makes it 128 rows, and
+# 256 there (8 MiB of buffers) is no faster: a full group is bound by its
+# 2 MB of copies, not by the fixed price (PERF.md, section 6, PR 28).
 _ROWS_PER_STEP = 512
 # what the two double-buffered K and V gather buffers may take of VMEM
 _KV_BUFFER_BYTES = 4 * 1024 * 1024
 
 
 def _pages_per_step(block, kv_heads, head_dim, itemsize, width):
-    """Pool blocks one grid step handles, from what the call can see:
+    """Pool blocks one group of a row holds, from what the call can see:
     as many as make ``_ROWS_PER_STEP`` KV rows, fewer where four buffers
     of that many rows (K and V, each double-buffered) would pass
     ``_KV_BUFFER_BYTES``, never more than the table is wide."""
@@ -192,76 +200,43 @@ def _paged_kernel(table_ref, len_ref, q_ref, *rest, scale, block, kv_heads,
     sem, state, acc_ref, m_ref, l_ref = rest[2 * n + 1:]
     k_buf, v_buf, *scale_bufs = bufs
     b = pl.program_id(0)
-    g = pl.program_id(1)
     batch = pl.num_programs(0)
     q_heads = kv_heads * group
-    rows = pages * block                   # KV positions a step scores
+    rows = pages * block                   # KV positions a group holds
     cols = rows * kv_heads                 # (position, kv head) columns
 
     def visible(row):
         # a stale length may not reach past the table
-        return jnp.minimum(len_ref[row], width * block)
-
-    def live_pages(row, grp):
-        """Pool blocks of ``row``'s group ``grp`` that hold visible rows."""
-        return jnp.clip(pl.cdiv(visible(row), block) - grp * pages, 0, pages)
+        return jnp.clip(len_ref[row], 0, width * block)
 
     def copies(row, grp, slot, fn):
-        """``fn`` on every page copy of (row, grp) into buffer ``slot``:
-        the same descriptors start a gather and wait for it."""
+        """``fn`` on the copy of every pool block of (row, grp) that holds
+        visible rows, into buffer ``slot``: the same descriptors start a
+        gather and wait for it."""
         def page(j, carry):
             src = table_ref[row, grp * pages + j]
             for pool, buf in zip(pools, bufs):
                 fn(pltpu.make_async_copy(pool.at[src], buf.at[slot, j], sem.at[slot]))
             return carry
-        jax.lax.fori_loop(0, live_pages(row, grp), page, 0)
+        live_pages = jnp.minimum(pl.cdiv(visible(row), block) - grp * pages, pages)
+        jax.lax.fori_loop(0, live_pages, page, 0)
 
-    # state[0]: the buffer this step reads; state[1]: whether an earlier
-    # step already started this step's gather
-    @pl.when((b == 0) & (g == 0))
+    # state[0]: the buffer the next group to run reads; state[1]: whether
+    # an earlier row already started that group's gather
+    @pl.when(b == 0)
     def _reset():
         state[0] = 0
         state[1] = 0
 
-    @pl.when(g == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
     length = visible(b)
+    groups = pl.cdiv(length, rows)         # this row's trip count
 
-    # a group wholly past the row's visible rows starts no copy and does
-    # no work
-    @pl.when(g * rows < length)
-    def _compute():
-        slot = state[0]
-
-        @pl.when(state[1] == 0)
-        def _start_own():
-            copies(b, g, slot, lambda c: c.start())
-
-        # the next group that will run: this row's next one, else the
-        # first group of the next row that sees anything. Its gather
-        # flies while this group is scored.
-        same_row = (g + 1) * rows < length
-        nxt_b = jax.lax.while_loop(
-            lambda r: (r < batch) & (len_ref[jnp.minimum(r, batch - 1)] <= 0),
-            lambda r: r + 1,
-            b + 1,
-        )
-        nxt_b = jnp.where(same_row, b, nxt_b)
-        nxt_g = jnp.where(same_row, g + 1, 0)
-        has_next = nxt_b < batch
-
-        @pl.when(has_next)
-        def _start_next():
-            copies(nxt_b, nxt_g, 1 - slot, lambda c: c.start())
-
-        state[0] = 1 - slot
-        state[1] = has_next.astype(jnp.int32)
-        copies(b, g, slot, lambda c: c.wait())
-
+    def score(g, slot):
+        """Fold group ``g`` of this row, gathered in buffer ``slot``, into
+        the online-softmax state."""
         q = q_ref[0]                               # [Hq, D] input dtype
         # the gathered pages lie flattened [pages * block * Hk, D]: row
         # r = pos * Hk + head. ONE matmul scores every q head against
@@ -274,7 +249,7 @@ def _paged_kernel(table_ref, len_ref, q_ref, *rest, scale, block, kv_heads,
         v = v_buf[slot].reshape(cols, -1).astype(q.dtype)
         col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
         q_head = jax.lax.broadcasted_iota(jnp.int32, (q_heads, 1), 0)
-        # pages past live_pages were not copied (the buffer holds an
+        # pages past the row's last were not copied (the buffer holds an
         # earlier group's rows there): the length mask covers them
         seen = g * rows + col // kv_heads < length  # [1, cols]
         valid = (col % kv_heads == q_head // group) & seen  # [Hq, cols]
@@ -314,11 +289,45 @@ def _paged_kernel(table_ref, len_ref, q_ref, *rest, scale, block, kv_heads,
         )
         m_ref[:] = m_new
 
-    @pl.when(g == pl.num_programs(1) - 1)
-    def _finalize():
-        o_ref[0] = (
-            acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
-        ).astype(o_ref.dtype)
+    # a row that sees nothing starts no copy and runs no group
+    @pl.when(groups > 0)
+    def _walk():
+        first = state[0]
+
+        @pl.when(state[1] == 0)
+        def _start_own():
+            copies(b, 0, first, lambda c: c.start())
+
+        # the next row that sees anything: its first group is gathered
+        # while this row's last one is scored
+        nxt_b = jax.lax.while_loop(
+            lambda r: (r < batch) & (len_ref[jnp.minimum(r, batch - 1)] <= 0),
+            lambda r: r + 1,
+            b + 1,
+        )
+        has_next = nxt_b < batch
+
+        def one_group(g, slot):
+            last = g + 1 == groups
+
+            # the next group that will run; its gather flies while this
+            # one is scored
+            @pl.when(jnp.logical_not(last) | has_next)
+            def _start_next():
+                copies(
+                    jnp.where(last, nxt_b, b), jnp.where(last, 0, g + 1),
+                    1 - slot, lambda c: c.start(),
+                )
+
+            copies(b, g, slot, lambda c: c.wait())
+            score(g, slot)
+            return 1 - slot
+
+        state[0] = jax.lax.fori_loop(0, groups, one_group, first)
+        state[1] = has_next.astype(jnp.int32)
+
+    # zeros for a row that saw nothing
+    o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
 
 
 def _paged_pallas(q, k, v, block_table, lengths, *, k_scale, v_scale,
@@ -332,7 +341,7 @@ def _paged_pallas(q, k, v, block_table, lengths, *, k_scale, v_scale,
     quantized = k_scale is not None
     pages = _pages_per_step(block, kv_heads, head_dim, k.dtype.itemsize, w)
 
-    def q_map(b, g, table, lens):
+    def q_map(b, table, lens):
         return (b, 0, 0)
 
     # the pools stay in HBM and the kernel gathers a group's pages
@@ -369,7 +378,7 @@ def _paged_pallas(q, k, v, block_table, lengths, *, k_scale, v_scale,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(batch, pl.cdiv(w, pages)),
+        grid=(batch,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, q_heads, head_dim), q_map),
         scratch_shapes=scratch,
@@ -388,10 +397,10 @@ def _paged_pallas(q, k, v, block_table, lengths, *, k_scale, v_scale,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, q_heads, head_dim), q.dtype),
-        # a step starts the next step's gather, rows included: both grid
-        # dimensions run in order
+        # a row starts the gather of the next row's first group: the
+        # grid runs in order
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")
+            dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
         name="paged_attention",
@@ -418,7 +427,9 @@ def paged_attention(
     ``k``/``v`` [num_blocks, block, Hk, D] pools (bf16, or int8 with
     fp32 ``k_scale``/``v_scale`` [num_blocks, block, Hk]);
     ``block_table`` [B, W] int32 (entries past a row's coverage point
-    at the trash block); ``lengths`` [B] int32 visible rows. Returns
+    at the trash block); ``lengths`` [B] int32 visible rows (0 for a row
+    whose output nobody reads: the kernel gathers nothing for it and
+    writes zeros, the reference an average of the table's rows). Returns
     [B, Hq, D] in ``q.dtype``.
 
     ``impl``: ``"reference"`` (pure JAX gather — bit-identical to the

@@ -1456,11 +1456,6 @@ class DecodeEngine:
                 "(ROADMAP.md, Queue 2): serve this module without it"
             )
 
-    def _live_kw(self, live) -> dict:
-        """A module with state layers is told which rows of a decode step
-        are live: a dead slot's state is neither read nor written."""
-        return {"live": live} if self._state_layers else {}
-
     def _init_layers(self, batch: int, rows: int, owns_rows=None):
         """Zeroed caches of the layers (all, or those that own pool rows
         or do not), ``batch`` sequences of ``rows`` positions."""
@@ -1500,7 +1495,7 @@ class DecodeEngine:
         cfg, L, B = self.cfg, self.cache_len, self.slots
         module, sample = self.module, self._sample
         eos_id, pad_id = self.eos_id, self.pad_id
-        init_layers, first_rows, live_kw = self._init_layers, self._first_rows, self._live_kw
+        init_layers, first_rows = self._init_layers, self._first_rows
 
         def init_state():
             return {
@@ -1625,7 +1620,7 @@ class DecodeEngine:
                 logits, cache = module.apply(
                     {"params": params}, state["last_tok"][:, None],
                     cache=state["cache"], cache_index=fill,
-                    kv_mask=kv_mask, **live_kw(live),
+                    kv_mask=kv_mask, live=live,
                 )
                 nxt = sample(logits[:, -1], key)
                 nxt = jnp.where(live, nxt, pad_id)
@@ -1686,7 +1681,7 @@ class DecodeEngine:
         n_pool = self.kv_pool.num_blocks
         module, sample = self.module, self._sample
         eos_id, pad_id = self.eos_id, self.pad_id
-        init_layers, first_rows, live_kw = self._init_layers, self._first_rows, self._live_kw
+        init_layers, first_rows = self._init_layers, self._first_rows
         join, split = self._join_layers, self._split_layers
 
         def init_state():
@@ -1816,7 +1811,7 @@ class DecodeEngine:
                 logits, cache = module.apply(
                     {"params": params}, state["last_tok"][:, None],
                     cache=join(state["pool"], state["rec"]), cache_index=fill,
-                    block_table=step_table, **live_kw(live),
+                    block_table=step_table, live=live,
                 )
                 pool, rec = split(cache)
                 nxt = sample(logits[:, -1], key)
@@ -2529,7 +2524,19 @@ class DecodeEngine:
         if self.prefix_cache is not None:
             out["prefix_cache"] = self.prefix_cache.stats()
         if self.kv_pool is not None:
-            out["kv_pool"] = self.kv_pool.stats()
+            from unionml_tpu.ops.paged_attention import _pages_per_step
+
+            rows = next(l for l in self._layout if isinstance(l, KVRows))
+            out["kv_pool"] = {
+                **self.kv_pool.stats(),
+                # what the decode kernel walks: a live row's visible
+                # blocks (at most the table's width), this many a group
+                "table_width": self._table_width,
+                "kernel_blocks_per_group": _pages_per_step(
+                    self._kv_block_size, rows.kv_heads, rows.head_dim,
+                    1 if rows.quantized else 2, self._table_width,
+                ),
+            }
         if self._state_layers:
             out["state"] = {
                 "layers": self._state_layers,
