@@ -2,7 +2,7 @@
 # Tests force the CPU-simulated 8-device mesh via tests/conftest.py;
 # smoke and bench need a TPU.
 
-.PHONY: test test-quick lint docs docs-site smoke bench bench-all notebooks dryrun
+.PHONY: test test-quick lint docs docs-site smoke bench notebooks dryrun
 
 docs:
 	python scripts/gen_api_reference.py
@@ -39,27 +39,6 @@ smoke:
 
 bench:
 	python bench.py
-
-bench-all: bench
-	python benchmarks/train_throughput.py
-	UNIONML_TPU_BENCH_PRESET=train_goodput python benchmarks/train_throughput.py
-	UNIONML_TPU_BENCH_PRESET=train_overlap python benchmarks/train_throughput.py
-	python benchmarks/serve_latency.py
-	UNIONML_TPU_BENCH_PRESET=serve_moe python benchmarks/serve_latency.py
-	UNIONML_TPU_BENCH_PRESET=serve_8b python benchmarks/serve_latency.py
-	UNIONML_TPU_BENCH_PRESET=serve_paged python benchmarks/serve_latency.py
-	UNIONML_TPU_BENCH_PRESET=serve_usage python benchmarks/serve_latency.py
-	UNIONML_TPU_BENCH_PRESET=serve_preempt python benchmarks/serve_latency.py
-	UNIONML_TPU_BENCH_PRESET=serve_router python benchmarks/serve_latency.py
-	UNIONML_TPU_BENCH_PRESET=serve_disagg python benchmarks/serve_latency.py
-	UNIONML_TPU_BENCH_PRESET=serve_autoscale python benchmarks/serve_latency.py
-	UNIONML_TPU_BENCH_PRESET=serve_fleet_obs python benchmarks/serve_latency.py
-	UNIONML_TPU_BENCH_PRESET=serve_perf python benchmarks/serve_latency.py
-	UNIONML_TPU_BENCH_PRESET=serve_rollout python benchmarks/serve_latency.py
-	python benchmarks/serve_http.py
-	UNIONML_TPU_BENCH_PRESET=serve_8b python benchmarks/serve_http.py
-	python benchmarks/attn_kernels.py
-	PYTHONPATH=.:$$PYTHONPATH python benchmarks/remote_bert/app.py
 
 notebooks:
 	python scripts/myst_to_ipynb.py docs/tutorials/*.md

@@ -43,6 +43,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import math
 import os
 import shutil
 import statistics
@@ -50,6 +51,7 @@ import sys
 import time
 import urllib.request
 from concurrent.futures import ThreadPoolExecutor
+from functools import partial
 from pathlib import Path
 
 # bf16 tolerance of the single-op comparisons below: kernel outputs
@@ -470,12 +472,77 @@ def _check_greedy(runs: dict, prompts, deficits, num_layers: int) -> None:
               f"the reference's best logit (worst deficit {worst:.4f}, max |logit| {scale:.2f})")
 
 
+def random_quantized_params(qmodule, seed: int = 0):
+    """Synthetic weights with the quantized module's exact tree/dtypes.
+
+    The 8B bf16 master tree (16 GB) cannot be materialized on one v5e
+    chip to run ``quantize_params`` over, and decode latency is
+    weight-VALUE-independent (HBM traffic + MXU work depend only on
+    shapes/dtypes — TPUs have no denormal slow paths), so the 8B bench
+    fills each leaf directly on device: random int8 kernels, lecun-scaled
+    fp32 scales, N(0, 0.02) embeddings, ones for norm gains. Leaves are
+    created one at a time — peak transient memory is one leaf's int32
+    sample buffer, never a second full tree.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    shapes = jax.eval_shape(
+        qmodule.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
+    # leaf-name -> sibling-names map: a "scale" leaf is quant metadata only
+    # next to its int8 kernel (RMSNorm gains are ALSO named "scale" and
+    # must get ones, not the tiny dequant constant)
+    sibling_names = {}
+    for path, _ in flat:
+        parent = tuple(p.key if hasattr(p, "key") else str(p) for p in path[:-1])
+        sibling_names.setdefault(parent, set()).add(
+            path[-1].key if hasattr(path[-1], "key") else str(path[-1])
+        )
+
+    @partial(jax.jit, static_argnums=(1,))
+    def int8_leaf(key, shape):
+        return jax.random.randint(key, shape, -127, 128, jnp.int32).astype(jnp.int8)
+
+    @partial(jax.jit, static_argnums=(1, 2))
+    def embed_leaf(key, shape, dtype):
+        return (0.02 * jax.random.normal(key, shape)).astype(dtype)
+
+    key = jax.random.PRNGKey(seed)
+    leaves = []
+    for path, s in flat:
+        name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
+        parent = tuple(p.key if hasattr(p, "key") else str(p) for p in path[:-1])
+        siblings = sibling_names[parent]
+        is_quant_scale = (
+            name in ("scale", "scale_g")
+            and ("kernel_q" in siblings or "kernel_p" in siblings)
+        ) or (
+            name.endswith("_scale") and f"{name[: -len('_scale')]}_q" in siblings
+        )
+        key, sub = jax.random.split(key)
+        if s.dtype == jnp.int8:
+            leaves.append(int8_leaf(sub, s.shape))
+        elif is_quant_scale:
+            # uniform int8 in [-127,127] has std ~73; scale so the
+            # effective weight std lands near lecun 1/sqrt(K)
+            k_in = qmodule.config.hidden_dim
+            leaves.append(
+                jnp.full(s.shape, 1.0 / (73.0 * math.sqrt(k_in)), jnp.float32)
+            )
+        elif name == "embedding":
+            leaves.append(embed_leaf(sub, s.shape, s.dtype))
+        else:
+            leaves.append(jnp.ones(s.shape, s.dtype))
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
 def phase_serve(sz: dict) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmarks.serve_latency import random_quantized_params
     from unionml_tpu.models import Llama, make_generator
 
     dev = jax.devices()[0]

@@ -37,6 +37,10 @@ richer gate where installed):
   a client controls the value — route the increment through
   ``UsageLedger.label_for`` instead (docs/observability.md "Usage
   metering & cost attribution").
+- the decode engine's layering (repo-wide; :func:`check_engine_layering`):
+  ``serving/programs.py`` imports nothing of the engine's host side
+  (``engine``, ``scheduler``, ``kv_pool``, ``telemetry``, ``perf``) and
+  no ``threading``, and ``serving/engine.py`` holds no ``jax.jit(``.
 - metrics-doc drift (repo-wide, when the default paths are linted):
   every ``unionml_*`` metric registered under ``unionml_tpu/`` must be
   documented in ``docs/observability.md``, and every full metric name
@@ -438,6 +442,43 @@ def check_span_names(package_root: Path) -> list:
                     f"{METRICS_DOC}: span name {name!r} from the "
                     "TRACE_SPAN_NAMES enum is not documented"
                 )
+    return problems
+
+
+# the decode engine's arrow points one way: serving/engine.py (host:
+# queue, admission, dispatcher, harvester, recovery, stats) calls
+# serving/programs.py (everything that is traced), never the reverse
+PROGRAMS_MODULE = "unionml_tpu/serving/programs.py"
+ENGINE_MODULE = "unionml_tpu/serving/engine.py"
+_HOST_SIDE = {"engine", "scheduler", "kv_pool", "telemetry", "perf", "threading"}
+
+
+def check_engine_layering(root: Path) -> list:
+    """``serving/programs.py`` imports nothing of the host side and no
+    ``threading``; ``serving/engine.py`` jits nothing itself."""
+    problems = []
+    tree = ast.parse((root / PROGRAMS_MODULE).read_text())
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            names = [a.name for a in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            names = [f"{node.module or ''}.{a.name}" for a in node.names]
+        else:
+            continue
+        for name in names:
+            if set(name.split(".")) & _HOST_SIDE:
+                problems.append(
+                    f"{PROGRAMS_MODULE}:{node.lineno}: imports {name} — the "
+                    "device programs know nothing of the engine's host side"
+                )
+    for lineno, line in enumerate(
+        (root / ENGINE_MODULE).read_text().splitlines(), 1
+    ):
+        if "jax.jit(" in line:
+            problems.append(
+                f"{ENGINE_MODULE}:{lineno}: jax.jit( — traced code lives in "
+                f"{PROGRAMS_MODULE}"
+            )
     return problems
 
 
@@ -848,6 +889,7 @@ def main(argv) -> int:
         problems.extend(check_metrics_doc(ROOT))
         problems.extend(check_label_cardinality(ROOT / "unionml_tpu"))
         problems.extend(check_span_names(ROOT / "unionml_tpu"))
+        problems.extend(check_engine_layering(ROOT))
         problems.extend(check_rollout_reasons(ROOT))
         problems.extend(check_perf_reasons(ROOT))
         problems.extend(check_flight_event_kinds(ROOT))

@@ -442,16 +442,12 @@ def test_compiled_programs_keep_the_names_the_trace_readers_match(tiny_llama):
             keys = jnp.stack([key, key])
             prefill = getattr(engine._prefill, "__wrapped__", engine._prefill)
             chunk = getattr(engine._decode_chunk, "__wrapped__", engine._decode_chunk)
-            if paged:
-                ids = jnp.zeros((16 // 8,), jnp.int32)  # bucket / block
-                table = jnp.asarray(engine._table)
-                lowered_prefill = prefill.lower(
-                    params, state, jnp.int32(0), ids, tokens, jnp.int32(3), key)
-                lowered_chunk = chunk.lower(params, state, mask, table, keys)
-            else:
-                lowered_prefill = prefill.lower(
-                    params, state, jnp.int32(0), tokens, jnp.int32(3), key)
-                lowered_chunk = chunk.lower(params, state, mask, keys)
+            # place: the pool's block ids (bucket / block) and table
+            ids = jnp.zeros((16 // 8,), jnp.int32) if paged else None
+            table = jnp.asarray(engine._table) if paged else None
+            lowered_prefill = prefill.lower(
+                params, state, jnp.int32(0), ids, tokens, jnp.int32(3), key)
+            lowered_chunk = chunk.lower(params, state, mask, table, keys)
         finally:
             engine.close()
         assert module_name(lowered_prefill) == "jit_prefill"

@@ -24,7 +24,7 @@ from unionml_tpu.models import olmo_hybrid as hybrid_mod
 from unionml_tpu.models import olmo_hybrid_reference as reference
 from unionml_tpu.models.olmo_hybrid import OlmoHybrid, OlmoHybridConfig
 from unionml_tpu.ops import gated_delta as gd
-from unionml_tpu.serving import engine as engine_mod
+from unionml_tpu.serving import programs as programs_mod
 from unionml_tpu.serving.engine import DecodeEngine
 from unionml_tpu.serving.scheduler import SchedulerConfig
 
@@ -303,7 +303,7 @@ def test_a_padded_position_that_touches_the_state_is_caught(monkeypatch, served)
 
 
 def test_a_state_left_stale_on_slot_reuse_is_caught(monkeypatch, served):
-    splice = engine_mod._splice_rows
+    splice = programs_mod._splice_rows
 
     def skip_states(dst, src, b_start, r_start):
         # the three linear layers' (S, tail) pairs: leave them as the slot's
@@ -311,7 +311,7 @@ def test_a_state_left_stale_on_slot_reuse_is_caught(monkeypatch, served):
         states = len(dst) == 3 and all(len(layer) == 2 and layer[1].ndim == 2 for layer in dst)
         return dst if states else splice(dst, src, b_start, r_start)
 
-    monkeypatch.setattr(engine_mod, "_splice_rows", skip_states)
+    monkeypatch.setattr(programs_mod, "_splice_rows", skip_states)
     assert _broken_gap(monkeypatch, *served, prompts=_prompts(90, 23, seed=5), slots=1) > 3 * LOGIT_TOL
 
 
@@ -329,6 +329,16 @@ def test_a_state_left_stale_on_slot_reuse_is_caught(monkeypatch, served):
 def test_what_restores_from_kv_blocks_alone_is_refused(served, kwargs):
     with pytest.raises(ValueError, match="recurrent state that a block prefix does not restore"):
         DecodeEngine(served[0], slots=2, prompt_buckets=(32,), paged=True, **kwargs)
+
+
+def test_a_draft_with_recurrent_layers_is_refused():
+    """A rejected proposal cannot be rolled back out of a recurrent state:
+    the refusal reads the draft's own ``cache_layout()``, not the target's."""
+    from unionml_tpu.models.llama import Llama, LlamaConfig
+
+    target = Llama(LlamaConfig.tiny(vocab_size=VOCAB))
+    with pytest.raises(ValueError, match="recurrent state that a block prefix does not restore"):
+        DecodeEngine(target, slots=2, prompt_buckets=(32,), draft_module=OlmoHybrid(_tiny()))
 
 
 @pytest.mark.parametrize("call", ["prefill_export", "kv_export", "kv_import"])

@@ -309,7 +309,7 @@ class AsyncCheckpointManager:
     - ``save`` stalls the caller for the device→host snapshot only;
       the disk write overlaps the following training steps
       (``async_commit=False`` commits inline — the overlap-off
-      baseline the ``train_overlap`` bench preset compares against);
+      baseline);
     - ``latest_step``/``restore`` see only COMMITTED checkpoints, so a
       kill mid-commit resumes from the previous step instead of a torn
       dir (uncommitted ``*.tmp-*`` leftovers are swept at construction);
@@ -454,8 +454,7 @@ def make_checkpoint_manager(
     existing run never silently restarts from step 0. ``"async"`` /
     ``"orbax"`` force a side; ``"sync"`` (or ``async_commit=False``)
     is the async manager with INLINE commits — the caller pays
-    serialize+write+rename, the overlap-off baseline the
-    ``train_overlap`` bench preset measures against.
+    serialize+write+rename: the overlap-off baseline.
     """
     if backend not in ("auto", "async", "orbax", "sync"):
         raise ValueError(

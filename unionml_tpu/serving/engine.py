@@ -7,7 +7,15 @@ queue, runs one ``generate()`` to completion, and only then admits the
 next batch — a request arriving one step after a batch launches waits
 the entire in-flight decode plus its own.
 
-This engine holds a **fixed-slot decode batch** resident on device:
+This module is the engine's HOST side: the queue and waiting room,
+admission, the dispatcher and harvester threads, the pool allocator and
+block tables, preemption, recovery and stats. Everything that is traced
+— the prefill family, the decode chunk, the residencies that say where a
+slot's cache lives — is in :mod:`unionml_tpu.serving.programs`, which
+this module calls and which imports nothing from here; nothing is
+jitted in this file (``scripts/lint_basics.py`` holds that).
+
+The engine holds a **fixed-slot decode batch** resident on device:
 
 - the KV cache is ``[slots, L, kv_heads, head_dim]`` per layer with a
   per-slot fill index (vector ``cache_index`` — see
@@ -22,8 +30,8 @@ This engine holds a **fixed-slot decode batch** resident on device:
   head-of-line-blocking behind its whole prefill (the long-context
   serving path; only ``ceil(true_len / chunk)`` chunk programs run, so
   a short prompt in a long bucket pays for its own length);
-- decode runs in **chunks of ``chunk_steps`` inside one
-  ``lax.scan``**, and up to ``pipeline_depth`` chunks are **dispatched
+- decode runs in **chunks of ``chunk_steps`` inside one scan**, and
+  up to ``pipeline_depth`` chunks are **dispatched
   asynchronously** — the dispatcher thread never blocks on a chunk's
   tokens before enqueueing the next; a separate HARVESTER thread blocks
   on the oldest in-flight readback and accounts its tokens.
@@ -91,6 +99,8 @@ from collections import OrderedDict, deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, List, Optional, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from unionml_tpu import telemetry
@@ -103,6 +113,7 @@ from unionml_tpu.serving.faults import (
     current_deadline_ms,
 )
 from unionml_tpu.serving.kv_pool import KVBlockPool, PoolExhausted
+from unionml_tpu.serving.programs import build_programs, cache_layout
 from unionml_tpu.serving.scheduler import (
     DEFAULT_PRIORITY,
     PRIORITIES,
@@ -132,39 +143,12 @@ def _start_host_copy(arr) -> None:
         pass
 
 
-def _cache_layout(module):
-    """What each layer of ``module`` caches (``models/layers.py``: a
-    ``KVRows`` or a ``SlotState`` per layer): the module says, the engine
-    does not assume."""
-    layout = getattr(module, "cache_layout", None)
-    if layout is None:
-        raise TypeError(
-            f"{type(module).__name__} has no cache_layout(): a decoder the "
-            "engine can serve says what each of its layers caches "
-            "(unionml_tpu.models.layers.KVRows / SlotState)"
-        )
-    return tuple(layout())
-
-
-def _splice_rows(dst_tree, src_tree, b_start, r_start):
-    """Write ``src_tree``'s rows into ``dst_tree`` at (batch, row) offset
-    ``(b_start, r_start)`` — per layer, per buffer, rank-generic (covers
-    the bf16 [B, L, H, D] KV buffers and the int8-cache [B, L, H] scale
-    planes alike). The single home for the engine's three cache splices
-    (prefix seed broadcast, per-request fresh-cache seed, suffix
-    placement)."""
-    import jax
-
-    return tuple(
-        tuple(
-            jax.lax.dynamic_update_slice(
-                dst, src.astype(dst.dtype),
-                (b_start, r_start) + (0,) * (dst.ndim - 2),
-            )
-            for dst, src in zip(dst_layer, src_layer)
-        )
-        for dst_layer, src_layer in zip(dst_tree, src_tree)
-    )
+def _place(ids):
+    """A program's ``place`` argument (serving/programs.py): the pool
+    blocks a prefill scatters into, or the block table a decode chunk
+    reads through, on the device; ``None`` for slot rows, which need no
+    placing."""
+    return None if ids is None else jnp.asarray(ids)
 
 
 def _host_blocks(full, j0: int, j1: int):
@@ -433,8 +417,7 @@ class DecodeEngine:
             ``stats()["programs"]`` reports per-program hardware truth
             — and request lifecycle events stream into the flight
             recorder. Steady-state overhead is a cache-size read plus
-            counter increments per *chunk* dispatch (measured by the
-            ``serve_introspection`` bench preset); ``False`` disables
+            counter increments per *chunk* dispatch; ``False`` disables
             both for an instrumentation-free engine.
         flight: explicit :class:`~unionml_tpu.telemetry.FlightRecorder`
             for lifecycle events; defaults to the process-global one
@@ -453,7 +436,7 @@ class DecodeEngine:
             aggregates export as bounded-cardinality
             ``unionml_tenant_*`` series; ``None`` (default) disables
             metering entirely — every record site is one attr-is-None
-            check (the ``serve_usage`` bench measures the delta).
+            check.
         perf: the serving goodput plane (docs/observability.md
             "Serving goodput & tail attribution"): every dispatcher
             pass is classified into a bounded ring (full-batch /
@@ -468,8 +451,7 @@ class DecodeEngine:
             regressions (``perf_regression`` flight events). ``None``
             (default) enables the plane iff ``introspect`` is on;
             ``False`` disables it (every hook is one attr-is-None
-            check — the ``serve_perf`` bench holds the on/off p99
-            delta under 1%); an explicit
+            check); an explicit
             :class:`~unionml_tpu.serving.perf.ServingPerfPlane`
             injects one.
         paged/kv_pool_bytes/kv_pool_blocks/kv_block_size: BLOCK-PAGED
@@ -483,8 +465,7 @@ class DecodeEngine:
             grown one block at a time as decode proceeds — a short
             prompt in a long bucket charges HBM for its own tokens,
             not the bucket's, so the effective batch at a fixed byte
-            budget rises with the traffic's long-tail (the
-            ``serve_paged`` bench preset measures it). Admission
+            budget rises with the traffic's long-tail. Admission
             RESERVES a request's worst-case blocks up front (prompt +
             ``max_new_tokens``), so growth can never fail mid-decode:
             a transiently full pool parks the admission until blocks
@@ -560,8 +541,6 @@ class DecodeEngine:
         scheduler: Optional[SchedulerConfig] = None,
         phase: Optional[str] = None,
     ):
-        import jax
-
         from unionml_tpu.models.generate import make_sampler
 
         if slots < 1:
@@ -571,7 +550,7 @@ class DecodeEngine:
         # what each layer of the module caches (models/layers.py): rows of
         # keys and values, which a paged engine keeps in its block pool,
         # or a state of fixed size, which it keeps per slot
-        self._layout = _cache_layout(module)
+        self._layout = cache_layout(module)
         self._owns_rows = tuple(isinstance(l, KVRows) for l in self._layout)
         self._state_layers = len(self._layout) - sum(self._owns_rows)
         # bytes of recurrent state one slot keeps on the device
@@ -583,7 +562,6 @@ class DecodeEngine:
                 "no layer of this module caches keys and values: the engine's "
                 "buckets and pool are sized from those layers"
             )
-        self._first_rows = self._owns_rows.index(True)
         for given, what in (
             (prefix_cache not in (None, False), "prefix_cache="),
             (system_prefix is not None, "system_prefix="),
@@ -592,6 +570,10 @@ class DecodeEngine:
         ):
             if given:
                 self._refuse_recurrent(what)
+        if draft_module is not None:
+            # nor can a rejected proposal be rolled back out of a draft's
+            # recurrent state (ROADMAP.md, Queue 2 M)
+            self._refuse_recurrent("draft_module=", cache_layout(draft_module))
         # serving phase (docs/serving.md "Disaggregated serving"):
         # which half of a generative request this engine's pool owns.
         # The engine itself serves any request either way — the label
@@ -647,7 +629,6 @@ class DecodeEngine:
         # rows a dispatched chunk can advance a slot: 1 per decode step,
         # or k+1 per speculative round
         self._round_stride = 1 if self.draft is None else self.speculate_k + 1
-        self._jax = jax
         self.module = module
         self.cfg = module.config
         self.slots = slots
@@ -686,15 +667,14 @@ class DecodeEngine:
         self._tracer = tracer if tracer is not None else telemetry.get_tracer()
         self.instance = telemetry.instance_label("engine")
         # introspection sinks (None when introspect=False: every record
-        # site is a single attr-is-None check — the bench-measured
-        # instrumentation-off path)
+        # site is a single attr-is-None check)
         self.introspect = bool(introspect)
         self._flight = (
             (flight if flight is not None else telemetry.get_flight_recorder())
             if self.introspect else None
         )
         # usage metering (off-switch: None leaves every record site a
-        # single attr check, measured by the serve_usage bench)
+        # single attr check)
         if usage is True:
             from unionml_tpu.serving.usage import UsageLedger
 
@@ -706,8 +686,7 @@ class DecodeEngine:
         # the perf-regression watchdog. Defaults on with introspection
         # (perf=None); ``False`` disables it, an explicit
         # ServingPerfPlane injects one. Every hook below is a single
-        # attr-is-None check — the serve_perf bench holds the on/off
-        # p99 delta under 1%.
+        # attr-is-None check.
         if perf is None:
             perf = self.introspect
         if perf is True:
@@ -1105,27 +1084,14 @@ class DecodeEngine:
         tr = ProgramTracker(registry=self._registry, component=self.instance)
         self._programs = tr
         self._init_state = tr.wrap("engine.init_state", self._init_state)
-        if self.paged:
-            # paged programs carry the block-id vector before the
-            # tokens, and extraction is table-addressed
-            self._prefill = tr.wrap(
-                "engine.prefill", self._prefill,
-                sig_fn=lambda p, st, slot, ids, toks, *a, **k: toks.shape,
-            )
-            self._prefill_final = tr.wrap(
-                "engine.prefill_final", self._prefill_final,
-                sig_fn=lambda p, st, fresh, slot, ids, toks, *a, **k:
-                    toks.shape,
-            )
-        else:
-            self._prefill = tr.wrap(
-                "engine.prefill", self._prefill,
-                sig_fn=lambda p, st, slot, toks, *a, **k: toks.shape,
-            )
-            self._prefill_final = tr.wrap(
-                "engine.prefill_final", self._prefill_final,
-                sig_fn=lambda p, st, fresh, slot, toks, *a, **k: toks.shape,
-            )
+        self._prefill = tr.wrap(
+            "engine.prefill", self._prefill,
+            sig_fn=lambda p, st, slot, place, toks, *a, **k: toks.shape,
+        )
+        self._prefill_final = tr.wrap(
+            "engine.prefill_final", self._prefill_final,
+            sig_fn=lambda p, st, fresh, slot, place, toks, *a, **k: toks.shape,
+        )
         self._prefill_step = tr.wrap(
             "engine.prefill_chunk", self._prefill_step,
             sig_fn=lambda p, fresh, toks, start: toks.shape,
@@ -1140,16 +1106,12 @@ class DecodeEngine:
                 "engine.splice_block", self._splice_block,
                 sig_fn=lambda fresh, rows, start: rows[0][0].shape,
             )
-            if self.paged:
-                self._extract_blocks = tr.wrap(
-                    "engine.extract_blocks", self._extract_blocks,
-                    sig_fn=lambda pool, ids: ids.shape,
-                )
-            else:
-                self._extract_rows = tr.wrap(
-                    "engine.extract_rows", self._extract_rows,
-                    sig_fn=lambda cache, slot, **k: k.get("n"),
-                )
+            # engine.extract_rows or engine.extract_blocks: the
+            # residency's own name for it
+            self._extract = tr.wrap(
+                f"engine.{self._extract.__name__}", self._extract,
+                sig_fn=lambda st, slot, place, **k: k.get("n"),
+            )
 
     def _flight_rec(self, kind: str, **fields) -> None:
         """O(1) flight-recorder append (no-op when introspect=False).
@@ -1186,10 +1148,10 @@ class DecodeEngine:
     def usage(self, ledger) -> None:
         """Swap the metering seam on a live engine — ONLY while idle
         (no request in flight), or a request's vector straddles two
-        ledgers. The ``serve_usage`` bench toggles this between its
-        overhead legs so both run on the SAME engine instance (two
-        separately-constructed engines differ by several percent from
-        thread/allocator placement alone, swamping a 2% bar); the
+        ledgers. An on/off comparison toggles this so both legs run on
+        the SAME engine instance (two separately-constructed engines
+        differ by several percent from thread/allocator placement
+        alone); the
         attribution window is clamped at each chunk's dispatch time,
         so the off-leg's idle gap never inflates the first on-leg
         window."""
@@ -1205,11 +1167,8 @@ class DecodeEngine:
     @perf.setter
     def perf(self, plane) -> None:
         """Swap the goodput plane on a live engine — ONLY while idle,
-        like the ``usage`` seam above. The ``serve_perf`` bench
-        toggles this between its paired overhead legs so both run on
-        the SAME engine instance (two separately-constructed engines
-        differ by several percent from thread/allocator placement
-        alone, swamping a 1% bar)."""
+        like the ``usage`` seam above, and for the same reason: an
+        on/off comparison runs both legs on the SAME engine instance."""
         self._perf = plane or None
         # the waiting room's fair-share weighting follows the swap
         self._room._usage = self._usage
@@ -1444,653 +1403,44 @@ class DecodeEngine:
             l.row_nbytes() for l, kv in zip(self._layout, self._owns_rows) if kv
         )
 
-    def _refuse_recurrent(self, what: str) -> None:
+    def _refuse_recurrent(self, what: str, layout=None) -> None:
         """``what`` restores a sequence from its KV blocks alone; refuse
-        it for a module with recurrent layers."""
-        if self._state_layers:
+        it for a module (the served one, or the one with this cache
+        ``layout``) with recurrent layers."""
+        layout = self._layout if layout is None else layout
+        states = sum(not isinstance(l, KVRows) for l in layout)
+        if states:
             raise ValueError(
                 f"{what} rebuilds a sequence from its KV blocks, and "
-                f"{self._state_layers} of this module's {len(self._layout)} "
+                f"{states} of this module's {len(layout)} "
                 "layers keep a recurrent state that a block prefix does not "
                 "restore. State snapshots at block boundaries are not built "
                 "(ROADMAP.md, Queue 2): serve this module without it"
             )
 
-    def _init_layers(self, batch: int, rows: int, owns_rows=None):
-        """Zeroed caches of the layers (all, or those that own pool rows
-        or do not), ``batch`` sequences of ``rows`` positions."""
-        return tuple(
-            l.init(batch, rows) for l, kv in zip(self._layout, self._owns_rows)
-            if owns_rows is None or kv == owns_rows
-        )
-
-    def _join_layers(self, rows, states):
-        """The module's per-layer cache from the pool layers' entries and
-        the state layers', in layer order."""
-        rows, states = iter(rows), iter(states)
-        return tuple(next(rows) if kv else next(states) for kv in self._owns_rows)
-
-    def _split_layers(self, cache):
-        """``(pool layers' entries, state layers')`` of a per-layer cache."""
-        return (
-            tuple(c for c, kv in zip(cache, self._owns_rows) if kv),
-            tuple(c for c, kv in zip(cache, self._owns_rows) if not kv),
-        )
-
     # ------------------------------------------------------------------ #
-    # device programs (compiled once per shape)
+    # device programs (serving/programs.py; compiled once per shape)
     # ------------------------------------------------------------------ #
 
     def _build_programs(self):
-        import jax
-        import jax.numpy as jnp
-
-        if self.draft is not None:
-            self._build_spec_programs()
-            return
-        if self.paged:
-            self._build_paged_programs()
-            return
-
-        cfg, L, B = self.cfg, self.cache_len, self.slots
-        module, sample = self.module, self._sample
-        eos_id, pad_id = self.eos_id, self.pad_id
-        init_layers, first_rows = self._init_layers, self._first_rows
-
-        def init_state():
-            return {
-                "cache": init_layers(B, L),
-                "kv_mask": jnp.zeros((B, L), bool),
-                # empty slots idle at row 0: dead slots still run the
-                # decode apply and write garbage k/v at their fill row —
-                # row 0 stays masked False and is overwritten by the
-                # next admission's full-bucket splice
-                "fill": jnp.zeros((B,), jnp.int32),
-                "last_tok": jnp.zeros((B,), jnp.int32),
-                "done": jnp.ones((B,), bool),
-            }
-
-        self._init_state = jax.jit(init_state)
-
-        import functools
-
-        def finish_prefill(params, state, fresh, slot, toks, start, true_len,
-                           key, **apply_kwargs):
-            """The SINGLE home for the prefill tail (monolithic, chunked,
-            and prefix-cached admissions all trace it — a desynced
-            invariant here would corrupt one path silently): run ``toks``
-            (the whole right-padded bucket at ``start=0``, or the final
-            chunk at its offset) against ``fresh``, sample the first
-            token at the last REAL position, splice the whole fresh
-            cache into ``slot`` — cached-prefix rows spliced before the
-            chunks ran are carried along; garbage rows above ``true_len``
-            stay masked False in the resident kv_mask."""
-            bucket = fresh[first_rows][0].shape[1]
-            c = toks.shape[1]
-            kv_mask = (jnp.arange(bucket) < true_len)[None, :]
-            logits, filled = module.apply(
-                {"params": params}, toks,
-                positions=start + jnp.arange(c)[None, :],
-                cache=fresh, cache_index=start, kv_mask=kv_mask,
-                # head on the last REAL position only — the full-bucket
-                # head would materialize [1, bucket, vocab] fp32
-                logit_index=jnp.reshape(true_len - 1 - start, (1,)),
-                **apply_kwargs,
-            )
-            first = sample(logits[:, 0], key)[0]
-            cache = _splice_rows(state["cache"], filled, slot, 0)
-            row_mask = jnp.arange(L) < true_len
-            return {
-                "cache": cache,
-                "kv_mask": state["kv_mask"].at[slot].set(row_mask),
-                "fill": state["fill"].at[slot].set(true_len),
-                "last_tok": state["last_tok"].at[slot].set(first),
-                "done": state["done"].at[slot].set(False),
-            }, first
-
-        # a monolithic admission covers the whole visible history, so
-        # cfg.prefill_impl == "flash" may run it through the flash
-        # kernel (right-padded buckets need no pad mask: causal alone
-        # hides the trailing garbage). Chunked and prefix-cached
-        # admissions keep the cached path.
-        _full_kwargs = (
-            {"full_prefill": True} if cfg.prefill_impl == "flash" else {}
+        """Bind the device programs of :mod:`~unionml_tpu.serving.programs`:
+        one family whatever the residency, one call signature (``place``
+        is the pool's block ids or table, ``None`` for slot rows)."""
+        progs = build_programs(
+            self.module, draft=self.draft, speculate_k=self.speculate_k,
+            slots=self.slots, rows=self.cache_len,
+            pool_blocks=None if self.kv_pool is None else self.kv_pool.num_blocks,
+            block=self._kv_block_size, chunk_steps=self.chunk_steps,
+            sample=self._sample, eos_id=self.eos_id, pad_id=self.pad_id,
         )
-
-        def prefill(params, state, slot, tokens, true_len, key):
-            """Monolithic admission: fresh build + full-bucket finish in
-            ONE program (short buckets; one dispatch per admission)."""
-            fresh = init_layers(1, tokens.shape[0])
-            return finish_prefill(
-                params, state, fresh, slot, tokens[None], jnp.int32(0),
-                true_len, key, **_full_kwargs,
-            )
-
-        self._prefill = jax.jit(prefill, donate_argnums=(1,))
-
-        # ---- chunked prefill (long buckets): lead chunks fill a fresh
-        # [1, bucket] cache WITHOUT touching the resident state, so
-        # decode chunks interleave between them; only the final chunk
-        # (finish_prefill) splices into the slot and samples token 0.
-        # Prefix-cached admissions ride the same machinery with
-        # chunk = the cache block size and the leading chunks replaced
-        # by host-row splices. ----
-
-        @functools.partial(jax.jit, static_argnames=("bucket",))
-        def init_fresh(*, bucket):
-            return init_layers(1, bucket)
-
-        self._init_fresh = init_fresh
-
-        def prefill_step(params, fresh, toks, start):
-            """One lead chunk: tokens are fully real (the host only runs
-            chunks covering the true length; the final, possibly padded,
-            chunk goes through ``finish_prefill``)."""
-            lf = fresh[first_rows][0].shape[1]  # bucket (static)
-            c = toks.shape[1]
-            kv_mask = (jnp.arange(lf) < start + c)[None, :]
-            _, fresh = module.apply(
-                {"params": params}, toks,
-                positions=start + jnp.arange(c)[None, :],
-                cache=fresh, cache_index=start, kv_mask=kv_mask,
-                # head output unused → DCE'd; the chunk only fills cache
-                logit_index=jnp.zeros((1,), jnp.int32),
-            )
-            return fresh
-
-        self._prefill_step = jax.jit(prefill_step, donate_argnums=(1,))
-        # donate the resident state only: no output matches the fresh
-        # cache's [1, bucket] shape, so donating it would just warn
-        self._prefill_final = jax.jit(finish_prefill, donate_argnums=(1,))
-        self._build_cache_programs()
-
-        def decode_chunk(params, state, active, keys):
-            """``chunk_steps`` decode steps for every slot in one scan."""
-
-            def step(state, key):
-                live = active & ~state["done"]
-                fill = state["fill"]
-                # this step writes its k/v at row `fill`; the new token
-                # must see ITSELF, so expose the row before the apply —
-                # for live slots only (dead slots' writes land on
-                # masked-False rows and stay invisible)
-                kv_mask = state["kv_mask"] | (
-                    (jnp.arange(L)[None, :] == fill[:, None]) & live[:, None]
-                )
-                logits, cache = module.apply(
-                    {"params": params}, state["last_tok"][:, None],
-                    cache=state["cache"], cache_index=fill,
-                    kv_mask=kv_mask, live=live,
-                )
-                nxt = sample(logits[:, -1], key)
-                nxt = jnp.where(live, nxt, pad_id)
-                done = state["done"]
-                if eos_id is not None:
-                    done = done | (live & (nxt == eos_id))
-                advance = live & (fill + 1 < L)
-                # belt: a live slot at the cache end freezes its fill on a
-                # masked-True row — mark done so it stops writing there
-                done = done | (live & ~advance)
-                return {
-                    "cache": cache,
-                    "kv_mask": kv_mask,
-                    "fill": fill + advance.astype(jnp.int32),
-                    "last_tok": jnp.where(live, nxt, state["last_tok"]),
-                    "done": done,
-                }, nxt
-
-            state, toks = jax.lax.scan(step, state, keys)
-            return state, toks  # toks: [chunk_steps, slots]
-
-        self._decode_chunk = jax.jit(decode_chunk, donate_argnums=(1,))
-
-    def _build_paged_programs(self):
-        """Paged-mode device programs (``self.paged``).
-
-        Same attribute names and dispatcher contract as the contiguous
-        builders, but the resident KV is a global block pool
-        (``[num_blocks, block, kv_heads, head_dim]`` per layer) plus the
-        host-owned block table passed into every decode chunk:
-
-        - prefill still computes against a transient contiguous
-          ``[1, bucket]`` fresh cache (chunked prefill and prefix-cache
-          splices ride it unchanged — one admission's workspace, not
-          per-slot residency), but ``finish_prefill`` ends in a
-          TABLE-DIRECTED per-block scatter into the pool instead of a
-          contiguous row splice: only ``ceil(true_len / block)`` real
-          blocks are written, padding blocks land on the trash block;
-        - the decode chunk reads/writes through
-          :mod:`~unionml_tpu.ops.paged_attention` (``block_table=``
-          path in the model), with retired slots' table rows masked to
-          the trash block PER STEP so an in-flight chunk can never
-          corrupt a recycled block;
-        - harvest extract gathers a slot's blocks by table entry
-          (``jnp.take``), feeding the prefix cache per-block host
-          copies directly.
-
-        There is no resident ``kv_mask``: visibility is ``fill + 1``
-        (bit-identical to the contiguous mask for live slots — tested).
-        """
-        import functools
-
-        import jax
-        import jax.numpy as jnp
-
-        cfg, L, B = self.cfg, self.cache_len, self.slots
-        blk = self._kv_block_size
-        n_pool = self.kv_pool.num_blocks
-        module, sample = self.module, self._sample
-        eos_id, pad_id = self.eos_id, self.pad_id
-        init_layers, first_rows = self._init_layers, self._first_rows
-        join, split = self._join_layers, self._split_layers
-
-        def init_state():
-            return {
-                "pool": init_layers(n_pool, blk, owns_rows=True),
-                # the state layers' per-slot states (none for a module
-                # whose every layer caches keys and values): written
-                # whole when a prefill ends, updated in place by decode
-                "rec": init_layers(B, 0, owns_rows=False),
-                # empty slots idle at row 0 with all-trash table rows:
-                # dead slots still run the decode apply, but their
-                # writes land in the trash block (step_table masking)
-                "fill": jnp.zeros((B,), jnp.int32),
-                "last_tok": jnp.zeros((B,), jnp.int32),
-                "done": jnp.ones((B,), bool),
-            }
-
-        self._init_state = jax.jit(init_state)
-
-        def scatter_blocks(pool, fresh, ids):
-            """Table-directed block scatter: fresh ``[1, bucket]`` rows
-            into pool blocks ``ids`` ([bucket/block] int32; padding
-            entries point at the trash block — duplicate trash writes
-            race benignly, it is garbage by definition)."""
-            nb = ids.shape[0]
-            return tuple(
-                tuple(
-                    pbuf.at[ids].set(
-                        fbuf.reshape((nb, blk) + fbuf.shape[2:])
-                        .astype(pbuf.dtype)
-                    )
-                    for pbuf, fbuf in zip(p_layer, f_layer)
-                )
-                for p_layer, f_layer in zip(pool, fresh)
-            )
-
-        def finish_prefill(params, state, fresh, slot, ids, toks, start,
-                           true_len, key, **apply_kwargs):
-            """The paged prefill tail: same fresh-cache compute and
-            first-token sampling as the contiguous path (logits are
-            bit-identical), then the per-block pool scatter in place of
-            the contiguous row splice."""
-            bucket = fresh[first_rows][0].shape[1]
-            c = toks.shape[1]
-            kv_mask = (jnp.arange(bucket) < true_len)[None, :]
-            logits, filled = module.apply(
-                {"params": params}, toks,
-                positions=start + jnp.arange(c)[None, :],
-                cache=fresh, cache_index=start, kv_mask=kv_mask,
-                logit_index=jnp.reshape(true_len - 1 - start, (1,)),
-                **apply_kwargs,
-            )
-            first = sample(logits[:, 0], key)[0]
-            rows, states = split(filled)
-            pool = scatter_blocks(state["pool"], rows, ids)
-            return {
-                "pool": pool,
-                # the slot's states, whole: whatever its last occupant left
-                # is overwritten (dst [slots, ...] <- src [1, ...])
-                "rec": _splice_rows(state["rec"], states, slot, 0),
-                "fill": state["fill"].at[slot].set(true_len),
-                "last_tok": state["last_tok"].at[slot].set(first),
-                "done": state["done"].at[slot].set(False),
-            }, first
-
-        _full_kwargs = (
-            {"full_prefill": True} if cfg.prefill_impl == "flash" else {}
-        )
-
-        def prefill(params, state, slot, ids, tokens, true_len, key):
-            fresh = init_layers(1, tokens.shape[0])
-            return finish_prefill(
-                params, state, fresh, slot, ids, tokens[None],
-                jnp.int32(0), true_len, key, **_full_kwargs,
-            )
-
-        self._prefill = jax.jit(prefill, donate_argnums=(1,))
-
-        @functools.partial(jax.jit, static_argnames=("bucket",))
-        def init_fresh(*, bucket):
-            return init_layers(1, bucket)
-
-        self._init_fresh = init_fresh
-
-        def prefill_step(params, fresh, toks, start):
-            """One lead chunk against the contiguous fresh cache —
-            verbatim the contiguous engine's program (the workspace
-            layout did not change, only residency did)."""
-            lf = fresh[first_rows][0].shape[1]
-            c = toks.shape[1]
-            kv_mask = (jnp.arange(lf) < start + c)[None, :]
-            _, fresh = module.apply(
-                {"params": params}, toks,
-                positions=start + jnp.arange(c)[None, :],
-                cache=fresh, cache_index=start, kv_mask=kv_mask,
-                logit_index=jnp.zeros((1,), jnp.int32),
-            )
-            return fresh
-
-        self._prefill_step = jax.jit(prefill_step, donate_argnums=(1,))
-        self._prefill_final = jax.jit(finish_prefill, donate_argnums=(1,))
-        self._build_cache_programs()
-
-        def extract_blocks(pool, ids):
-            """Gather a slot's pool blocks ([n_blocks, block, ...] per
-            buffer) for the async device→host prefix-cache insert —
-            per-block copies addressed by table entries (the contiguous
-            path's row-window slice has no paged equivalent)."""
-            return tuple(
-                tuple(jnp.take(buf, ids, axis=0) for buf in layer)
-                for layer in pool
-            )
-
-        self._extract_blocks = jax.jit(extract_blocks)
-
-        def decode_chunk(params, state, active, table, keys):
-            """``chunk_steps`` paged decode steps in one scan. The
-            block table is a per-chunk INPUT (the host grows it between
-            chunks), with retired/dead slots' rows re-masked to the
-            trash block every step so their writes can never land in a
-            block the allocator has recycled."""
-
-            def step(state, key):
-                live = active & ~state["done"]
-                fill = state["fill"]
-                step_table = jnp.where(live[:, None], table, 0)
-                logits, cache = module.apply(
-                    {"params": params}, state["last_tok"][:, None],
-                    cache=join(state["pool"], state["rec"]), cache_index=fill,
-                    block_table=step_table, live=live,
-                )
-                pool, rec = split(cache)
-                nxt = sample(logits[:, -1], key)
-                nxt = jnp.where(live, nxt, pad_id)
-                done = state["done"]
-                if eos_id is not None:
-                    done = done | (live & (nxt == eos_id))
-                advance = live & (fill + 1 < L)
-                done = done | (live & ~advance)
-                return {
-                    "pool": pool,
-                    "rec": rec,
-                    "fill": fill + advance.astype(jnp.int32),
-                    "last_tok": jnp.where(live, nxt, state["last_tok"]),
-                    "done": done,
-                }, nxt
-
-            state, toks = jax.lax.scan(step, state, keys)
-            return state, toks  # toks: [chunk_steps, slots]
-
-        self._decode_chunk = jax.jit(decode_chunk, donate_argnums=(1,))
-
-    def _build_cache_programs(self):
-        """Prefix-cache device programs (cache-enabled engines only):
-
-        - ``_splice_block``: write one cached splice unit's host rows
-          into a fresh ``[1, bucket]`` cache at a dynamic row offset
-          (compiled once per (bucket, unit) shape; the host→device copy
-          happens once per unit via the ``_dev_splice`` memo).
-        - ``_extract_rows``: slice a slot's leading ``n`` resident rows
-          in ONE dispatch (compiled once per bucket), feeding the async
-          device→host insert path — the harvester splits the contiguous
-          copy into blocks host-side.
-
-        Both are rank-generic over the cache tree like
-        :func:`_splice_rows`, so int8-KV scale planes ride along."""
-        if self.prefix_cache is None:
-            return
-        import functools
-
-        import jax
-
-        def splice_block(fresh, rows, start):
-            return _splice_rows(fresh, rows, 0, start)
-
-        self._splice_block = jax.jit(splice_block, donate_argnums=(0,))
-
-        @functools.partial(jax.jit, static_argnames=("n",))
-        def extract_rows(cache, slot, *, n):
-            return tuple(
-                tuple(
-                    jax.lax.dynamic_slice(
-                        buf, (slot, 0) + (0,) * (buf.ndim - 2),
-                        (1, n) + buf.shape[2:],
-                    )
-                    for buf in layer
-                )
-                for layer in cache
-            )
-
-        self._extract_rows = extract_rows
-
-    def _build_spec_programs(self):
-        """Speculative-mode device programs (``draft_module`` set).
-
-        Same attribute names and call signatures as the plain builders so
-        the dispatcher/admission machinery is shared verbatim; ``params``
-        is the bound ``{"target", "draft"}`` mapping, fresh caches are
-        ``(target, draft)`` pairs, and the decode chunk is a scan of
-        ``chunk_steps`` SPECULATIVE ROUNDS: per-slot draft proposals
-        (vector ``cache_index``), ONE shared [slots, k+1] verify forward,
-        greedy acceptance advancing per-slot fills — the
-        ``make_speculative_generator`` round body (same acceptance/
-        emission/eos invariants; a desync there breaks token identity)
-        restructured for the resident slot batch. A ``system_prefix``
-        arrives PREPENDED to every prompt (the shim), so both prefills
-        cover it like any other tokens; no prefix cache in this mode
-        (refused at construction).
-        """
-        import functools
-
-        import jax
-        import jax.numpy as jnp
-
-        from unionml_tpu.models.llama import init_cache
-
-        cfg, dcfg = self.cfg, self.draft.config
-        L, B, k = self.cache_len, self.slots, self.speculate_k
-        module, draft, sample = self.module, self.draft, self._sample
-        eos_id, pad_id = self.eos_id, self.pad_id
-        R = self.chunk_steps
-
-        def init_state():
-            return {
-                "cache": init_cache(cfg, B, L),
-                "d_cache": init_cache(dcfg, B, L),
-                "kv_mask": jnp.zeros((B, L), bool),
-                "fill": jnp.zeros((B,), jnp.int32),
-                "last_tok": jnp.zeros((B,), jnp.int32),
-                "done": jnp.ones((B,), bool),
-            }
-
-        self._init_state = jax.jit(init_state)
-
-        def finish_prefill(params, state, fresh, slot, toks, start, true_len,
-                           key, *, target_kwargs=None, draft_kwargs=None):
-            """Prefill tail for BOTH caches: run the (right-padded)
-            bucket/final-chunk through target and draft, sample the first
-            token from the target's last real position, splice both
-            filled caches into ``slot``."""
-            fresh_t, fresh_d = fresh
-            bucket = fresh_t[0][0].shape[1]
-            c = toks.shape[1]
-            kv_mask = (jnp.arange(bucket) < true_len)[None, :]
-            pos = start + jnp.arange(c)[None, :]
-            logits, filled_t = module.apply(
-                {"params": params["target"]}, toks, positions=pos,
-                cache=fresh_t, cache_index=start, kv_mask=kv_mask,
-                logit_index=jnp.reshape(true_len - 1 - start, (1,)),
-                **(target_kwargs or {}),
-            )
-            # draft prefill logits are never read: DCE'd stub head
-            _, filled_d = draft.apply(
-                {"params": params["draft"]}, toks, positions=pos,
-                cache=fresh_d, cache_index=start, kv_mask=kv_mask,
-                logit_index=jnp.zeros((1,), jnp.int32),
-                **(draft_kwargs or {}),
-            )
-            first = sample(logits[:, 0], key)[0]
-            cache = _splice_rows(state["cache"], filled_t, slot, 0)
-            d_cache = _splice_rows(state["d_cache"], filled_d, slot, 0)
-            row_mask = jnp.arange(L) < true_len
-            return {
-                "cache": cache,
-                "d_cache": d_cache,
-                "kv_mask": state["kv_mask"].at[slot].set(row_mask),
-                "fill": state["fill"].at[slot].set(true_len),
-                "last_tok": state["last_tok"].at[slot].set(first),
-                "done": state["done"].at[slot].set(False),
-            }, first
-
-        # every monolithic admission is a full prefill (any system
-        # prefix is part of the prompt) — each model honors its OWN
-        # prefill_impl (target and draft configs may differ)
-        _t_full = {"full_prefill": True} if cfg.prefill_impl == "flash" else {}
-        _d_full = {"full_prefill": True} if dcfg.prefill_impl == "flash" else {}
-
-        def prefill(params, state, slot, tokens, true_len, key):
-            fresh = (
-                init_cache(cfg, 1, tokens.shape[0]),
-                init_cache(dcfg, 1, tokens.shape[0]),
-            )
-            return finish_prefill(
-                params, state, fresh, slot, tokens[None], jnp.int32(0),
-                true_len, key, target_kwargs=_t_full, draft_kwargs=_d_full,
-            )
-
-        self._prefill = jax.jit(prefill, donate_argnums=(1,))
-
-        @functools.partial(jax.jit, static_argnames=("bucket",))
-        def init_fresh(*, bucket):
-            return (init_cache(cfg, 1, bucket), init_cache(dcfg, 1, bucket))
-
-        self._init_fresh = init_fresh
-
-        def prefill_step(params, fresh, toks, start):
-            fresh_t, fresh_d = fresh
-            lf = fresh_t[0][0].shape[1]
-            c = toks.shape[1]
-            kv_mask = (jnp.arange(lf) < start + c)[None, :]
-            pos = start + jnp.arange(c)[None, :]
-            _, fresh_t = module.apply(
-                {"params": params["target"]}, toks, positions=pos,
-                cache=fresh_t, cache_index=start, kv_mask=kv_mask,
-                logit_index=jnp.zeros((1,), jnp.int32),
-            )
-            _, fresh_d = draft.apply(
-                {"params": params["draft"]}, toks, positions=pos,
-                cache=fresh_d, cache_index=start, kv_mask=kv_mask,
-                logit_index=jnp.zeros((1,), jnp.int32),
-            )
-            return fresh_t, fresh_d
-
-        self._prefill_step = jax.jit(prefill_step, donate_argnums=(1,))
-        self._prefill_final = jax.jit(finish_prefill, donate_argnums=(1,))
-
-        def spec_chunk(params, state, active, keys):
-            """``chunk_steps`` speculative rounds in one scan. Returns
-            per-round ``(emit [R, B, k+1], n_emit [R, B], accepted
-            [R, B])`` — the host credits each slot ``n_emit`` tokens per
-            round (eos-truncated device-side, budget-truncated host-side
-            like the plain path)."""
-            arange_l = jnp.arange(L)[None, :]
-            rows = jnp.arange(B)
-
-            def round_body(state, _):
-                live = active & ~state["done"]
-                fill0 = state["fill"]
-
-                # draft proposes k tokens over k+1 steps (the extra step
-                # consumes proposal k so a fully-accepted round leaves no
-                # draft-cache hole — the make_speculative_generator rule)
-                def dstep(c, _):
-                    d_cache, tok, f = c
-                    vis = state["kv_mask"] | (
-                        (arange_l >= fill0[:, None])
-                        & (arange_l <= f[:, None])
-                        & live[:, None]
-                    )
-                    logits, d_cache = draft.apply(
-                        {"params": params["draft"]}, tok[:, None],
-                        cache=d_cache, cache_index=f, kv_mask=vis,
-                    )
-                    nxt = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
-                    return (d_cache, nxt, f + 1), nxt
-
-                (d_cache, _, _), props = jax.lax.scan(
-                    dstep, (state["d_cache"], state["last_tok"], fill0),
-                    None, length=k + 1,
-                )
-                props = props.transpose(1, 0)[:, :k]          # [B, k]
-
-                # ONE shared multi-token verify forward for every slot
-                verify_in = jnp.concatenate(
-                    [state["last_tok"][:, None], props], axis=1
-                )
-                vis_v = state["kv_mask"] | (
-                    (arange_l >= fill0[:, None])
-                    & (arange_l <= (fill0 + k)[:, None])
-                    & live[:, None]
-                )
-                v_logits, cache = module.apply(
-                    {"params": params["target"]}, verify_in,
-                    cache=state["cache"], cache_index=fill0, kv_mask=vis_v,
-                )
-                from unionml_tpu.models.speculative import greedy_acceptance
-
-                greedy = jnp.argmax(v_logits, -1).astype(jnp.int32)
-                accepted, correction, emit = greedy_acceptance(props, greedy)
-                n_emit = jnp.where(live, accepted + 1, 0)
-                done = state["done"]
-                if eos_id is not None:
-                    pos_idx = jnp.arange(k + 1)[None, :]
-                    eos_hit = (emit == eos_id) & (pos_idx < n_emit[:, None])
-                    any_eos = eos_hit.any(axis=1)
-                    first_eos = jnp.argmax(eos_hit, axis=1)
-                    n_emit = jnp.where(
-                        any_eos, jnp.minimum(n_emit, first_eos + 1), n_emit
-                    )
-                    done = done | (live & any_eos)
-                # rows consumed = accepted + 1 (eos shrinks EMISSION, not
-                # the cache rows written — done stops later rounds)
-                advance = jnp.where(live, accepted + 1, 0)
-                new_fill = fill0 + advance
-                # freeze before the end: the next round writes k+1 rows
-                done = done | (live & (new_fill + k + 1 >= L))
-                new_kv = state["kv_mask"] | (
-                    (arange_l >= fill0[:, None])
-                    & (arange_l < new_fill[:, None])
-                )
-                new_last = jnp.where(live, correction, state["last_tok"])
-                out = (
-                    jnp.where(live[:, None], emit, pad_id),
-                    n_emit.astype(jnp.int32),
-                    jnp.where(live, accepted, 0).astype(jnp.int32),
-                )
-                return {
-                    "cache": cache,
-                    "d_cache": d_cache,
-                    "kv_mask": new_kv,
-                    "fill": new_fill,
-                    "last_tok": new_last,
-                    "done": done,
-                }, out
-
-            state, outs = jax.lax.scan(round_body, state, None, length=R)
-            return state, outs
-
-        self._decode_chunk = jax.jit(spec_chunk, donate_argnums=(1,))
+        self._init_state = progs.init_state
+        self._init_fresh = progs.init_fresh
+        self._prefill = progs.prefill
+        self._prefill_step = progs.prefill_step
+        self._prefill_final = progs.prefill_final
+        self._decode_chunk = progs.decode_chunk
+        self._splice_block = progs.splice_block
+        self._extract = progs.extract
 
     # ------------------------------------------------------------------ #
     # public API
@@ -2713,7 +2063,7 @@ class DecodeEngine:
         return self.buckets[-1]
 
     def _next_key(self, num: int = 1):
-        self._key, *subs = self._jax.random.split(self._key, num + 1)
+        self._key, *subs = jax.random.split(self._key, num + 1)
         return subs
 
     def _admission_preamble(self, req: _Request):
@@ -2744,8 +2094,6 @@ class DecodeEngine:
         """Dispatch ``req``'s prefill into a free slot WITHOUT blocking on
         the first token (its readback is harvested later, in dispatch
         order). Dispatcher thread only; occupancy mutates under the lock."""
-        import jax.numpy as jnp
-
         slot, _bucket, padded = self._admission_preamble(req)
         (key,) = self._next_key()
         with self._lock:
@@ -2761,16 +2109,10 @@ class DecodeEngine:
             req.rid, "admit.enqueue", annotation="engine.admit.enqueue",
             program="prefill",
         ) as sp:
-            if self.paged:
-                new_state, first = self._prefill(
-                    self._params, st, jnp.int32(slot), jnp.asarray(ids),
-                    jnp.asarray(padded), jnp.int32(len(req.prompt)), key,
-                )
-            else:
-                new_state, first = self._prefill(
-                    self._params, st, jnp.int32(slot), jnp.asarray(padded),
-                    jnp.int32(len(req.prompt)), key,
-                )
+            new_state, first = self._prefill(
+                self._params, st, jnp.int32(slot), _place(ids),
+                jnp.asarray(padded), jnp.int32(len(req.prompt)), key,
+            )
         self._it_enqueue_s += sp.end_s - sp.start_s
         _start_host_copy(first)
         if self._usage is not None:
@@ -2821,15 +2163,13 @@ class DecodeEngine:
         resident copy. Each entry keeps the host tuples alive, so an
         ``id()`` key can never be recycled while its entry lives.
         Dispatcher thread only."""
-        import jax.numpy as jnp
-
         key = tuple(id(b) for b in blocks)
         hit = self._dev_splice.get(key)
         if hit is not None:
             self._dev_splice.move_to_end(key)
             return hit[1]
         host = blocks[0] if len(blocks) == 1 else _concat_rows(blocks)
-        dev = self._jax.tree_util.tree_map(jnp.asarray, host)
+        dev = jax.tree_util.tree_map(jnp.asarray, host)
         self._dev_splice[key] = (blocks, dev)
         while len(self._dev_splice) > self._dev_splice_cap:
             self._dev_splice.popitem(last=False)
@@ -2844,8 +2184,6 @@ class DecodeEngine:
         thread blocks on the transfer. Fully-matched prompts skip the
         extraction; the entry always carries the request so its lease is
         released only after the insert could build on live ancestors."""
-        import jax.numpy as jnp
-
         cache = self.prefix_cache
         if cache is None:
             return
@@ -2854,25 +2192,17 @@ class DecodeEngine:
         st = self._state  # one read: _recover may null it concurrently
         if first_new >= nb or st is None:
             rows = None  # nothing new to store — release-only entry
-        elif self.paged:
-            # gather the slot's blocks BY TABLE ENTRY (one compiled
-            # dispatch per bucket; uncovered tail entries gather the
-            # trash block and are never inserted) — the paged form of
-            # the contiguous row-window extract
-            blk = self._kv_block_size
-            with self._lock:
-                ids = self._table[
-                    slot, : self._bucket_for(len(req.prompt)) // blk
-                ].copy()
-            rows = self._extract_blocks(st["pool"], jnp.asarray(ids))
-            for layer in rows:
-                for buf in layer:
-                    _start_host_copy(buf)
         else:
-            rows = self._extract_rows(
-                st["cache"], jnp.int32(slot),
-                n=self._bucket_for(len(req.prompt)),
-            )
+            # the bucket's leading rows of the slot, one compiled
+            # dispatch per bucket: a row window, or the slot's blocks BY
+            # TABLE ENTRY (uncovered tail entries gather the trash block
+            # and are never inserted)
+            n = self._bucket_for(len(req.prompt))
+            ids = None
+            if self.paged:
+                with self._lock:
+                    ids = self._table[slot, : n // self._kv_block_size].copy()
+            rows = self._extract(st, jnp.int32(slot), _place(ids), n=n)
             for layer in rows:
                 for buf in layer:
                     _start_host_copy(buf)
@@ -3417,7 +2747,7 @@ class DecodeEngine:
                         # were actually SERVED (inside the gens check and
                         # before the budget break) — stale-generation and
                         # post-retirement overshoot rounds would skew the
-                        # /stats acceptance_rate the benches report
+                        # /stats acceptance_rate
                         self._m_spec_rounds.inc()
                         self._m_spec_accepted.inc(int(accepted[r, slot]))
                     for i in range(int(n_emit[r, slot])):
@@ -3468,8 +2798,6 @@ class DecodeEngine:
     def _dispatch_chunk(self) -> bool:
         """Dispatch one decode chunk if the pipeline has a credit and any
         occupant still needs tokens beyond already-dispatched work."""
-        import jax.numpy as jnp
-
         if not self._chunk_credits.acquire(blocking=False):
             # pipeline_depth chunks already awaiting harvest: the poll
             # that follows is the dispatcher waiting for the chip
@@ -3513,8 +2841,6 @@ class DecodeEngine:
         """Enqueue the decode chunk that :meth:`_dispatch_chunk` decided
         on (it holds a pipeline credit) and hand its readback to the
         harvester."""
-        import jax.numpy as jnp
-
         t_dispatch = time.perf_counter()
         try:
             self._fire("engine.dispatch")
@@ -3522,15 +2848,10 @@ class DecodeEngine:
                 None, "engine.dispatch_chunk.enqueue", seq=seq,
             ) as sp:
                 keys = jnp.stack(self._next_key(self.chunk_steps))
-                if self.paged:
-                    new_state, toks = self._decode_chunk(
-                        self._params, st, jnp.asarray(mask),
-                        jnp.asarray(table_np), keys,
-                    )
-                else:
-                    new_state, toks = self._decode_chunk(
-                        self._params, st, jnp.asarray(mask), keys
-                    )
+                new_state, toks = self._decode_chunk(
+                    self._params, st, jnp.asarray(mask), _place(table_np),
+                    keys,
+                )
             self._it_enqueue_s += sp.end_s - sp.start_s
             for leaf in toks if isinstance(toks, tuple) else (toks,):
                 _start_host_copy(leaf)
@@ -3731,8 +3052,6 @@ class DecodeEngine:
         queue. The resume admission splices the SAME bytes back, so
         the resumed stream's tokens are exactly its solo run's
         (chaos-tested in tests/unit/test_scheduler.py)."""
-        import jax.numpy as jnp
-
         t0 = time.perf_counter()
         blk = self._kv_block_size
         with self._lock:
@@ -3761,7 +3080,7 @@ class DecodeEngine:
             # dispatched on the dispatcher thread BEFORE any later
             # decode chunk, so donation order guarantees it reads the
             # pre-eviction pool (the _schedule_insert precedent)
-            rows = self._extract_blocks(st["pool"], jnp.asarray(ids))
+            rows = self._extract(st, jnp.int32(slot), _place(ids), n=nb * blk)
             for layer in rows:
                 for buf in layer:
                     _start_host_copy(buf)
@@ -3993,8 +3312,6 @@ class DecodeEngine:
         ``_recover``/``close`` may concurrently null ``_admission`` —
         every transition re-checks identity under the lock so the
         admission is completed or dropped exactly once."""
-        import jax.numpy as jnp
-
         req = adm.req
         try:
             if req.abandoned:
@@ -4066,18 +3383,10 @@ class DecodeEngine:
                 req.rid, "admit.enqueue", annotation="engine.admit.enqueue",
                 program="prefill_final",
             ) as sp:
-                if self.paged:
-                    new_state, first = self._prefill_final(
-                        self._params, st, adm.fresh, jnp.int32(adm.slot),
-                        jnp.asarray(adm.pool_ids), toks, jnp.int32(start),
-                        jnp.int32(len(req.prompt)), key,
-                    )
-                else:
-                    new_state, first = self._prefill_final(
-                        self._params, st, adm.fresh, jnp.int32(adm.slot),
-                        toks, jnp.int32(start), jnp.int32(len(req.prompt)),
-                        key,
-                    )
+                new_state, first = self._prefill_final(
+                    self._params, st, adm.fresh, jnp.int32(adm.slot),
+                    _place(adm.pool_ids), toks, jnp.int32(start), jnp.int32(len(req.prompt)), key,
+                )
             self._it_enqueue_s += sp.end_s - sp.start_s
             _start_host_copy(first)
             if self._usage is not None:

@@ -119,10 +119,10 @@ def http_fault_response(exc: BaseException):
 
 
 def xla_oom_error(nbytes: int = 8 << 30) -> RuntimeError:
-    """An OOM-shaped device error for chaos tests: the message matches
-    what benchmarks/serve_latency.py's OOM detection looks for in real
-    XLA ``RESOURCE_EXHAUSTED`` failures, so harness-injected OOMs walk
-    the same string-matching paths production errors do."""
+    """An OOM-shaped device error for chaos tests: the message has the
+    shape of a real XLA ``RESOURCE_EXHAUSTED`` failure, so
+    harness-injected OOMs walk the same string-matching paths production
+    errors do."""
     return RuntimeError(
         f"RESOURCE_EXHAUSTED: Out of memory allocating {nbytes} bytes "
         "(injected by unionml_tpu.serving.faults.FaultInjector)"

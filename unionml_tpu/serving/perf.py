@@ -253,9 +253,8 @@ class ServingPerfPlane:
         self._lock = threading.Lock()
         # ring entries: (kind, occupied_slot_steps, total_slot_steps),
         # with the slot-step sums carried incrementally (evictions
-        # subtract, appends add) so the per-pass ratio math is O(1) —
-        # walking a 2048-entry ring per 2 ms dispatcher pass is what
-        # the serve_perf bench exists to catch
+        # subtract, appends add) so the per-pass ratio math is O(1),
+        # not a walk of a 2048-entry ring per 2 ms dispatcher pass
         self._ring: deque = deque(maxlen=max(16, int(ring)))
         self._occ_steps = 0
         self._disp_steps = 0
@@ -295,8 +294,8 @@ class ServingPerfPlane:
         )
         # lazy gauges, sampled at scrape/read time: the dispatcher
         # calls note_pass/note_idle every ~2 ms, and three eager
-        # Gauge.set calls per pass are measurable against the
-        # serve_perf bench's 1% p99 bar — the scrape path pays instead
+        # Gauge.set calls per pass would be paid there — the scrape
+        # path pays instead
         self._g_goodput.set_function(lambda: self._sample_ratios()[0])
         self._g_occupancy.set_function(lambda: self._sample_ratios()[1])
         self._g_kv_pressure.set_function(lambda: self._sample_ratios()[2])

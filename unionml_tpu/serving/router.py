@@ -164,8 +164,7 @@ class ReplicaHandle:
         """All tokens for one prompt, blocking — the non-streaming
         dispatch primitive. Default collects :meth:`generate_stream`;
         in-process replicas override with the engine's native blocking
-        call (one event wait instead of per-chunk queue hops — the
-        passthrough-overhead bench leg rides on this)."""
+        call (one event wait instead of per-chunk queue hops)."""
         out: List[int] = []
         for chunk in self.generate_stream(
             prompt, max_new_tokens=max_new_tokens

@@ -252,7 +252,7 @@ class UsageLedger:
         # per-label resolved (decode, device_s, flops) counter children:
         # attribute() runs on the harvester thread once per dispatched
         # chunk, so the family .labels() tuple-hash + lock is cached
-        # away (the serve_usage bench holds the overhead bar at <= 2%)
+        # away
         self._attr_children: Dict[str, tuple] = {}
         self._build_instruments()
 
