@@ -110,10 +110,14 @@ def test_positions_past_valid_len_leave_the_state(valid_len):
     "live", [[True] * 5, [False, True, False, True, False], [False, False, False, False, True], [False] * 5],
     ids=["all", "alternate", "last", "none"],
 )
-@pytest.mark.parametrize("heads,dk,dv", [(4, 16, 64), (6, 8, 128)], ids=["two-a-row", "one-a-row"])
+@pytest.mark.parametrize(
+    "heads,dk,dv", [(4, 16, 64), (6, 8, 128), (6, 96, 192)], ids=["two-a-row", "one-a-row", "cell-heads"],
+)
 def test_pallas_step_matches_xla_step(live, heads, dk, dv):
     """The ``gated_delta_step`` kernel in interpret mode against the XLA
-    step: live rows agree, dead rows keep their state bit for bit."""
+    step: live rows agree, dead rows keep their state bit for bit.
+    ``cell-heads`` is the served model's head (``d_k`` 96, no multiple of
+    the 128 lanes the keys arrive on; two heads a row), fewer of them."""
     x = _rule_inputs(batch=5, seq=1, heads=heads, dk=dk, dv=dv, seed=3)
     args = [x[n][:, 0] for n in ("q", "k", "v", "g", "beta")]
     state = gd.pack_state(jnp.asarray(x["state"]))
@@ -124,6 +128,38 @@ def test_pallas_step_matches_xla_step(live, heads, dk, dv):
     assert np.abs(np.asarray(got_o) - np.asarray(want_o))[mask].max(initial=0.0) < RULE_TOL
     assert np.abs(np.asarray(got_s) - np.asarray(want_s))[mask].max(initial=0.0) < RULE_TOL
     assert np.array_equal(np.asarray(got_s)[~mask], np.asarray(state)[~mask])
+
+
+@pytest.mark.parametrize("impl", ["reference", "pallas"])
+def test_tokens_one_at_a_time_match_one_cached_call(monkeypatch, impl):
+    """A ``GatedDeltaNet`` fed T tokens through its decode step (``seq == 1``,
+    vector ``cache_index``) and through one multi-token cached call, from the
+    same state and convolution tail: the step's convolution over the flat
+    tail's slices against the prefill's over stacked rows, the step kernel
+    against the chunked rule."""
+    import functools
+
+    cfg = _tiny(linear_num_key_heads=6, linear_num_value_heads=6, linear_key_head_dim=16,
+                linear_value_head_dim=64)
+    monkeypatch.setattr(hybrid_mod, "gated_delta_step", functools.partial(gd.gated_delta_step, impl=impl))
+    layer = hybrid_mod.GatedDeltaNet(cfg)
+    batch, seq = 3, 6
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.normal(size=(batch, seq, cfg.hidden_size)), jnp.float32)
+    cache = (
+        jnp.asarray(rng.normal(size=(batch,) + gd.state_shape(6, 16, 64)), jnp.float32),
+        jnp.asarray(rng.normal(size=(batch, 3 * cfg.conv_channels)), jnp.float32),
+    )
+    params = layer.init(jax.random.PRNGKey(2), x, cache=cache, cache_index=0)
+    want, (want_state, want_tail) = layer.apply(params, x, cache=cache, cache_index=0)
+    got, live = [], jnp.ones((batch,), bool)
+    for t in range(seq):
+        index = jnp.full((batch,), t)
+        out, cache = layer.apply(params, x[:, t:t + 1], cache=cache, cache_index=index, live=live)
+        got.append(out)
+    assert np.abs(np.asarray(jnp.concatenate(got, axis=1)) - np.asarray(want)).max() < RULE_TOL
+    assert np.abs(np.asarray(cache[0]) - np.asarray(want_state)).max() < RULE_TOL
+    assert np.array_equal(np.asarray(cache[1]), np.asarray(want_tail))
 
 
 def test_state_packing_round_trips():
@@ -370,7 +406,12 @@ def test_the_state_is_counted_where_the_pool_is(served):
         engine.generate(params, _prompts(9))
         per_slot = 3 * (4 * 8 * 64 * 4 + 3 * 320 * 4)  # S float32 and the tail, three layers
         stats = engine.stats()
-        assert stats["state"] == {"layers": 3, "bytes_per_slot": per_slot, "bytes_resident": 2 * per_slot}
+        assert stats["state"] == {
+            "layers": 3, "bytes_per_slot": per_slot, "bytes_resident": 2 * per_slot,
+            # keys and queries, values, the output: a tile a slot each; alpha and beta: a tile each
+            "step_operand_bytes": gd.step_operand_bytes(2, 4, 8, 64),
+        }
+        assert stats["state"]["step_operand_bytes"] == 6 * 8 * 128 * 4 + 2 * 8 * 128 * 4
         assert stats["goodput"]["state_bytes_resident"] == 2 * per_slot
         assert stats["goodput"]["admissions_parked_on_pool"] == 0
         # only the full-attention layer owns pool rows (its 4 heads are 16

@@ -8,8 +8,11 @@ program holds a ``tpu_custom_call``. A compile that passes is not a chip
 run: ``chip_smoke.py`` is what runs them.
 """
 
+import importlib.util
+import json
 import os
 import re
+from pathlib import Path
 
 import pytest
 
@@ -113,9 +116,52 @@ def test_paged_attention_compiles(
     assert not re.search(rf"\[{n_blocks},{block * kv_heads},{hd}\]\S* copy\(", text)
 
 
-def test_gated_delta_step_compiles(chip):
+REPO = Path(__file__).resolve().parents[2]
+
+
+@pytest.fixture(scope="module")
+def hlo():
+    """``scripts/compiled_chunk.py``: its reading of an array's type and
+    layout in a compiled module's text."""
+    spec = importlib.util.spec_from_file_location("compiled_chunk", REPO / "scripts" / "compiled_chunk.py")
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.mark.parametrize(
+    "array,want",
+    [
+        # the minor axis pads to 128 lanes, the next one to 8 sublanes
+        ("f32[32,15,96,4]{3,2,1,0:T(8,128)S(1)}", 32 * 15 * 96 * 128 * 4),
+        ("f32[32,15,2,96]{2,3,1,0:T(8,128)}", 32 * 15 * 96 * 128 * 4),
+        ("f32[32,3,20,96]{3,2,1,0:T(8,128)}", 32 * 3 * 24 * 128 * 4),
+        # two bfloat16 rows, four int8 rows to a word
+        ("bf16[32,34560]{1,0:T(8,128)(2,1)}", 32 * 34560 * 2),
+        ("bf16[5,100]{1,0:T(8,128)(2,1)}", 16 * 128 * 2),
+        ("s8[960,11008]{1,0:T(8,128)(4,1)S(1)}", 960 * 11008),
+        ("s32[32]{0:T(128)}", 128 * 4),
+        ("f32[32,30]", 32 * 30 * 4),
+    ],
+)
+def test_an_arrays_bytes_in_tiles(hlo, array, want):
+    assert hlo.result_bytes(array) == want
+
+
+def _step_kernel_call(text):
+    """The ``gated_delta_step`` custom call's result and operand types."""
+    line = next(l for l in text.splitlines() if re.search(r"%gated_delta_step(\.\d+)* = ", l))
+    result = line.split(" = ", 1)[1].split(" custom-call(")[0]
+    operands = re.search(r"operand_layout_constraints=\{(.*?\})\}", line).group(1)
+    return result, operands
+
+
+def test_gated_delta_step_compiles(chip, hlo):
     """The decode kernel of the gated delta rule at the hybrid cell's shape:
-    32 slots, 30 heads of 96 x 192, two heads a state row."""
+    32 slots, 30 heads of 96 x 192, two heads a state row. What it takes
+    besides the state is lane-dense: a few MB in the chip's tiles, as
+    ``step_operand_bytes`` counts them (26 MB when keys and queries came as
+    columns, 4 of 128 lanes used)."""
     batch, heads, dk, dv = 32, 30, 96, 192
     state = ((batch,) + gated_delta.state_shape(heads, dk, dv), jnp.float32)
     assert state[0] == (32, 15, 96, 384)
@@ -128,6 +174,63 @@ def test_gated_delta_step_compiles(chip):
     )
     # chipbench's gdn_state_ms_per_step finds the kernel by this name
     assert re.search(r"%gated_delta_step(\.\d+)* = ", text)
+    result, operands = _step_kernel_call(text)
+
+    def tiles(types):
+        """float32 arrays in (8, 128) tiles: the state, and the two [batch]
+        int32 vectors of the grid's bookkeeping, aside."""
+        return [
+            hlo.tiled_bytes(dtype, dims, order, "T(8,128)")
+            for dtype, dims, order, _ in hlo._ARRAY.findall(types)
+            if dtype == "f32" and dims != "32,15,96,384"
+        ]
+
+    taken, given = tiles(operands), tiles(result)
+    assert len(taken) == 4 and sum(taken) <= 4e6
+    assert sum(taken) + sum(given) == gated_delta.step_operand_bytes(batch, heads, dk, dv) <= 4e6
+
+
+def test_gated_delta_layer_step_compiles_lane_dense(chip, hlo):
+    """One ``GatedDeltaNet`` decode step at the hybrid cell's widths (32 x
+    3840 in, int8 weights): XLA hands the kernel and the convolution their
+    operands without turning them. A layer used to take four ``copy`` and
+    three 23.6 MB arrays ([32,15,96,4], [32,15,96,2], [32,15,2,96]: 2 or 4
+    of 128 lanes used) to pass 0.7 MB of keys and queries."""
+    from unionml_tpu.models.olmo_hybrid import GatedDeltaNet, OlmoHybridConfig
+
+    config = json.loads((REPO / "chipbench" / "configs" / "olmo-hybrid-7b-int8.json").read_text())
+    cfg = OlmoHybridConfig.from_hf(config, quantized=True)
+    layer = GatedDeltaNet(cfg, name="gdn")
+    batch = 32
+    x = jax.ShapeDtypeStruct((batch, 1, cfg.hidden_size), jnp.bfloat16, sharding=chip)
+    cache = (
+        jax.ShapeDtypeStruct((batch,) + gated_delta.state_shape(30, 96, 192), jnp.float32, sharding=chip),
+        jax.ShapeDtypeStruct((batch, 3 * cfg.conv_channels), jnp.bfloat16, sharding=chip),
+    )
+    index = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=chip)
+    live = jax.ShapeDtypeStruct((batch,), jnp.bool_, sharding=chip)
+
+    def step(params, x, cache, index, live):
+        return layer.apply(params, x, cache=cache, cache_index=index, live=live)
+
+    params = jax.eval_shape(
+        lambda x, cache, index, live: layer.init(
+            jax.random.PRNGKey(0), x, cache=cache, cache_index=index, live=live,
+        ),
+        x, cache, index, live,
+    )
+    params = jax.tree_util.tree_map(lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=chip), params)
+    text = jax.jit(step, donate_argnums=(2,)).lower(params, x, cache, index, live).compile().as_text()
+    assert re.search(r"%gated_delta_step(\.\d+)* = ", text)
+    copies = [l for l in text.splitlines() if " copy(" in l and re.search(r'op_name="[^"]*gdn', l)]
+    assert len(copies) <= 1, copies
+    thin = set()  # a minor axis of under 8 on the 128 lanes, 1 MB or more of it
+    for m in hlo._ARRAY.finditer(text):
+        _, dims, order, _ = m.groups()
+        minor = order and int(dims.split(",")[int(order.split(",")[0])])
+        if minor and minor < 8 and hlo.tiled_bytes(*m.groups()) >= 1e6:
+            thin.add(m.group(0))
+    assert not thin, sorted(thin)
 
 
 QKV = ((1, 2048, 32, 128), jnp.bfloat16)

@@ -44,7 +44,7 @@ from unionml_tpu.models.layers import (
     Attention, KVRows, MlpBlock, RMSNorm, SlotState, make_dense,
 )
 from unionml_tpu.ops.gated_delta import (
-    gated_delta_chunked, gated_delta_step, state_shape,
+    gated_delta_chunked, gated_delta_step, state_shape, step_operand_bytes,
 )
 
 LINEAR, FULL = "linear_attention", "full_attention"
@@ -197,12 +197,14 @@ class GatedDeltaNet(nn.Module):
             tail = jnp.zeros((batch, (width - 1) * channels), qkv.dtype)
         else:
             state, tail = cache
-        history = tail.reshape(batch, width - 1, channels)
         if step:
-            rows = jnp.concatenate([history, qkv], axis=1).astype(f32)     # [B, width, C]
-            mixed = jnp.einsum("bwc,wc->bc", rows, conv)[:, None]
-            new_tail = jnp.concatenate([tail[:, channels:], qkv[:, 0].astype(tail.dtype)], axis=1)
+            # the flat tail's rows are lane-aligned slices of it: the taps
+            # add in the prefill branch's order, and nothing is stacked
+            taps = [tail[:, j * channels:(j + 1) * channels] for j in range(width - 1)] + [qkv[:, 0]]
+            mixed = sum(conv[j] * taps[j].astype(f32) for j in range(width))[:, None]
+            new_tail = jnp.concatenate([tail[:, channels:], taps[-1].astype(tail.dtype)], axis=1)
         else:
+            history = tail.reshape(batch, width - 1, channels)
             rows = jnp.concatenate([history.astype(qkv.dtype), qkv], axis=1)  # [B, width-1+T, C]
             mixed = sum(conv[j] * rows[:, j:j + seq].astype(f32) for j in range(width))
             valid_len = None
@@ -294,6 +296,14 @@ class OlmoHybrid(nn.Module):
         )
         rows = KVRows(cfg.kv_cache_heads, cfg.head_dim)
         return tuple(state if kind == LINEAR else rows for kind in cfg.layer_types)
+
+    def step_operand_bytes(self, batch: int) -> int:
+        """Bytes a linear layer's state kernel moves besides the states in
+        one decode step of ``batch`` rows, as the chip tiles them."""
+        cfg = self.config
+        return step_operand_bytes(
+            batch, cfg.linear_num_value_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim,
+        )
 
     @nn.compact
     def __call__(

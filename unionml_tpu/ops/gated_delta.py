@@ -21,8 +21,14 @@ Two forms of the same function:
 - :func:`gated_delta_step` for one token a sequence (decode). On a TPU it
   is the Pallas kernel named ``gated_delta_step``: the resident state is
   aliased in and out, read and written once, and rows whose ``live`` flag
-  is false are neither read nor written. The XLA version of the same
-  function is the CPU path and the parity anchor (``impl="reference"``).
+  is false are neither read nor written. Its other operands arrive with
+  ``d_k`` or ``d_v`` on the lanes, as the projections make them: keys and
+  queries a row a head (turned into the state's columns inside the kernel,
+  a few KB in VMEM), values a row a state row, alpha and beta as scalars
+  (:func:`step_operand_bytes` counts them: 3.6 MB a call at 32 x 30 heads
+  of 96 x 192, where columns of 2 or 4 lanes took 26 MB). The XLA version
+  of the same function is the CPU path and the parity anchor
+  (``impl="reference"``).
 
 **State layout.** A state row is stored with ``pack`` heads side by side
 along the value axis, ``[heads / pack, d_k, pack * d_v]``, ``pack`` the
@@ -46,7 +52,7 @@ from jax.experimental import pallas as pl
 
 __all__ = [
     "gated_delta_chunked", "gated_delta_step", "gated_delta_step_reference",
-    "heads_per_row", "state_shape", "pack_state", "unpack_state",
+    "heads_per_row", "state_shape", "pack_state", "unpack_state", "step_operand_bytes",
 ]
 
 CHUNK = 64
@@ -184,54 +190,74 @@ def gated_delta_step_reference(q, k, v, g, beta, state, live=None):
     return o, new
 
 
-def _step_kernel(src_ref, live_ref, kq_ref, row_ref, s_ref, o_ref, s_out_ref, *,
+def _step_kernel(src_ref, live_ref, alpha_ref, beta_ref, kq_ref, v_ref, s_ref, o_ref, s_out_ref, *,
                  rows, pack, dv):
     """One grid step: ``rows`` state rows ([d_k, pack * d_v] each) of one
-    sequence. ``kq_ref`` [1, rows, d_k, 2 * pack] holds the keys then the
-    queries as columns; ``row_ref`` [1, 1, 3 * rows, pack * d_v] holds, a
-    row each, the value, alpha and beta (spread over their head's lanes)
-    of every state row."""
+    sequence. ``kq_ref`` [1, 1, 2 * rows * pack, d_k] holds the keys of the
+    step's ``rows * pack`` heads, a row each, then their queries; ``v_ref``
+    [1, 1, rows, pack * d_v] the values, a state row's heads side by side;
+    ``alpha_ref`` and ``beta_ref`` [B, H] are scalars in SMEM."""
     del src_ref
-    b = pl.program_id(1)
+    j, b = pl.program_id(0), pl.program_id(1)
 
     @pl.when(live_ref[b] == 0)
     def _dead():
-        # the state block of a dead row is another row's (see the index
-        # map): nothing is written to it; its output is never used
+        # the blocks of a dead row are another row's (see the index map):
+        # nothing is written to its state; its output is never used
         o_ref[...] = jnp.zeros_like(o_ref)
 
     @pl.when(live_ref[b] != 0)
     def _live():
         lane = jax.lax.broadcasted_iota(jnp.int32, (1, pack * dv), 1)
+        # the state wants a key down its d_k sublanes. The step's keys and
+        # queries arrive d_k on the lanes, as the projections make them (a
+        # few KB), and are turned here, in VMEM, once
+        cols = kq_ref[0, 0].T                                   # [d_k, 2 * rows * pack]
 
-        def spread(cols):
-            """[d_k, pack] columns -> [d_k, pack * d_v]: head p's column
-            over head p's lanes."""
-            out = cols[:, pack - 1:pack]
+        def spread(per_head):
+            """``pack`` columns [d_k, 1] or scalars [1, 1], one a head ->
+            [d_k or 1, pack * d_v]: head p's over head p's lanes."""
+            out = per_head[-1]
             for p in range(pack - 2, -1, -1):
-                out = jnp.where(lane < (p + 1) * dv, cols[:, p:p + 1], out)
+                out = jnp.where(lane < (p + 1) * dv, per_head[p], out)
             return out
 
+        def columns(first):
+            return spread([cols[:, first + p:first + p + 1] for p in range(pack)])
+
+        def scalars(ref, first):
+            return spread([jnp.full((1, 1), ref[b, first + p]) for p in range(pack)])
+
         for r in range(rows):
-            cols = kq_ref[0, r]
-            key, query = spread(cols[:, :pack]), spread(cols[:, pack:])
-            value = row_ref[0, 0, r:r + 1]
-            alpha = row_ref[0, 0, rows + r:rows + r + 1]
-            beta = row_ref[0, 0, 2 * rows + r:2 * rows + r + 1]
+            key, query = columns(r * pack), columns((rows + r) * pack)
+            head = (j * rows + r) * pack                        # the state row's first head
+            alpha, beta = scalars(alpha_ref, head), scalars(beta_ref, head)
             s = s_ref[0, r] * alpha
-            u = beta * (value - jnp.sum(s * key, axis=0, keepdims=True))
+            u = beta * (v_ref[0, 0, r:r + 1] - jnp.sum(s * key, axis=0, keepdims=True))
             s = s + key * u
             s_out_ref[0, r] = s
             o_ref[0, 0, r:r + 1] = jnp.sum(s * query, axis=0, keepdims=True)
 
 
-def _rows_per_step(n_rows: int, row_bytes: int) -> int:
-    """State rows a grid step handles: the fewest that move
-    ``_STEP_BYTES``, among the divisors of the row count."""
-    for rows in range(1, n_rows + 1):
-        if n_rows % rows == 0 and rows * row_bytes >= _STEP_BYTES:
-            return rows
-    return n_rows
+def _step_geometry(heads: int, d_k: int, d_v: int) -> Tuple[int, int, int, int]:
+    """``(pack, rows, groups, width)``: heads a state row, state rows a grid
+    step (the fewest that move ``_STEP_BYTES``, among the divisors of the
+    row count), grid steps a sequence, and a state row's lanes."""
+    n_rows, _, width = state_shape(heads, d_k, d_v)
+    rows = next(
+        (r for r in range(1, n_rows) if n_rows % r == 0 and r * d_k * width * 4 >= _STEP_BYTES), n_rows,
+    )
+    return heads // n_rows, rows, n_rows // rows, width
+
+
+def step_operand_bytes(batch: int, heads: int, d_k: int, d_v: int) -> int:
+    """Bytes one call of the decode kernel moves besides the state, as the
+    chip holds them (float32, the two minor axes padded to (8, 128) tiles):
+    keys and queries, values, alpha and beta in, the output out."""
+    pack, rows, groups, width = _step_geometry(heads, d_k, d_v)
+    shapes = [(batch, groups, 2 * rows * pack, d_k), (batch, heads), (batch, heads)]
+    shapes += [(batch, groups, rows, width)] * 2
+    return sum(math.prod(lead) * -(-r // 8) * 8 * -(-c // 128) * 128 * 4 for *lead, r, c in shapes)
 
 
 def _step_pallas(q, k, v, g, beta, state, live, *, interpret):
@@ -239,45 +265,34 @@ def _step_pallas(q, k, v, g, beta, state, live, *, interpret):
 
     batch, h, dk = q.shape
     dv = v.shape[-1]
-    n_rows, _, width = state.shape[1:]
-    pack = h // n_rows
-    rows = _rows_per_step(n_rows, dk * width * 4)
-    groups = n_rows // rows
+    pack, rows, groups, width = _step_geometry(h, dk, dv)
     f32 = jnp.float32
 
-    def columns(x):  # [B, H, d_k] -> [B, n_rows, d_k, pack]
-        return jnp.swapaxes(x.astype(f32).reshape(batch, n_rows, pack, dk), 2, 3)
+    def heads(x):  # [B, H, d_k] -> [B, groups, rows * pack, d_k]: a reshape, d_k stays minor
+        return x.astype(f32).reshape(batch, groups, rows * pack, dk)
 
-    def lanes(x):  # [B, H] -> [B, groups, rows, pack * d_v]
-        x = jnp.repeat(x.astype(f32)[..., None], dv, axis=-1)
-        return x.reshape(batch, groups, rows, width)
-
-    kq = jnp.concatenate([columns(k), columns(q * dk ** -0.5)], axis=-1)
-    per_row = jnp.concatenate([
-        v.astype(f32).reshape(batch, groups, rows, width), lanes(jnp.exp(g.astype(f32))),
-        lanes(beta),
-    ], axis=2)
-    # a dead row names the state block of the nearest live row before it
-    # (the first live row, for the leading dead ones): with the batch as
-    # the inner grid axis its block index then equals its neighbour's, and
-    # the pipeline neither fetches nor writes back a block for it
+    kq = jnp.concatenate([heads(k), heads(q * dk ** -0.5)], axis=2)
+    # a dead row names the blocks of the nearest live row before it (the
+    # first live row, for the leading dead ones): with the batch as the
+    # inner grid axis its block index then equals its neighbour's, and the
+    # pipeline neither fetches nor writes back a block for it
     index = jnp.arange(batch, dtype=jnp.int32)
     last_live = jax.lax.cummax(jnp.where(live, index, -1))
     src = jnp.where(last_live >= 0, last_live, jnp.argmax(live).astype(jnp.int32))
 
-    def own(j, b, src, live):
+    def own(j, b, *prefetched):
         return (b, j, 0, 0)
 
-    def shared(j, b, src, live):
+    def shared(j, b, src, *prefetched):
         return (src[b], j, 0, 0)
 
     state_block = pl.BlockSpec((1, rows, dk, width), shared)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=4,
         grid=(groups, batch),
         in_specs=[
-            pl.BlockSpec((1, rows, dk, 2 * pack), own),
-            pl.BlockSpec((1, 1, 3 * rows, width), own),
+            pl.BlockSpec((1, 1, 2 * rows * pack, dk), shared),
+            pl.BlockSpec((1, 1, rows, width), shared),
             state_block,
         ],
         out_specs=[pl.BlockSpec((1, 1, rows, width), own), state_block],
@@ -289,13 +304,16 @@ def _step_pallas(q, k, v, g, beta, state, live, *, interpret):
             jax.ShapeDtypeStruct((batch, groups, rows, width), f32),
             jax.ShapeDtypeStruct(state.shape, state.dtype),
         ],
-        # operands count the two prefetched vectors: the state is the fifth
-        input_output_aliases={4: 1},
+        # operands count the four prefetched arrays: the state is the seventh
+        input_output_aliases={6: 1},
         # a dead row's block index must equal its neighbour's: in order
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="gated_delta_step",
-    )(src, live.astype(jnp.int32), kq, per_row, state)
+    )(
+        src, live.astype(jnp.int32), jnp.exp(g.astype(f32)), beta.astype(f32),
+        kq, v.astype(f32).reshape(batch, groups, rows, width), state,
+    )
     return o.reshape(batch, h, dv), new
 
 

@@ -1893,6 +1893,11 @@ class DecodeEngine:
                 "bytes_per_slot": self._state_bytes_per_slot,
                 "bytes_resident": self._state_bytes_per_slot * self.slots,
             }
+            # what a state layer's decode kernel moves besides the states,
+            # where the module counts it from its shapes
+            operand_bytes = getattr(self.module, "step_operand_bytes", None)
+            if operand_bytes is not None:
+                out["state"]["step_operand_bytes"] = operand_bytes(self.slots)
         if self._usage is not None:
             # the compact per-tenant view (GET /debug/usage has the
             # full per-tenant resource vectors)
