@@ -1,0 +1,141 @@
+"""One Mixtral expert layer alone: the dense dispatch beside the grouped one.
+
+    chiprun -- env PYTHONPATH=. python3 benchmarks/moe_dispatch.py    # the tree's code
+    chiprun -- env PYTHONPATH=. python3 benchmarks/moe_dispatch.py --tokens 256 --chunk 64 --tiles 1024,1024
+
+One JSON line a case: microseconds a layer, from a jitted loop of ``--reps``
+passes over ``--layers`` layers, timed on the host's clock around
+``block_until_ready``. The shape is ``chipbench``'s ``mixtral_chat_decode``:
+hidden 4096, 8 experts of 14336, top-2, int8 weights, bfloat16 rows. Every
+layer has its own weights (1.41 GB; eight of them, as the cell holds): one
+layer's weights carried through a loop are not what a model reads. A layer
+is the router, the routing, and the experts' SwiGLU, its input made from the
+last layer's output so that nothing is hoisted. ``T`` rows are 32 (the decode
+chunk's slot rows) and the four prefill buckets' 128 to 1024; beside each
+time stand how far one layer's output lies from the dense dispatch's
+(``max_off_dense_in_sd``: bfloat16 rounding, a few hundredths) and the
+layer's least times: its weights at the HBM rate (every
+expert is touched from 32 rows on) and the routed rows' FLOPs at the MXU's
+peak.
+
+``--chunk`` and ``--tiles`` (k,n[,accumulator MiB]) override
+the kernel's row tile and ``_grouped_matmul_pallas``'s defaults, to re-derive
+them and ``ops.moe._row_chunk``; ``--ragged-dot`` adds ``jax.lax.ragged_dot`` (it
+keeps a bfloat16 copy of the weights: 2.8 GB a layer beside the int8 ones).
+Fails without a TPU unless ``--rehearse`` (tiny shapes, interpret mode: the
+numbers then mean nothing). Not run by any cell or test.
+"""
+
+import argparse
+import functools
+import json
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from unionml_tpu.ops import moe
+
+HBM_BYTES_PER_S, MXU_FLOPS = 819e9, 197e12  # TPU v5e (chipbench/peaks.json)
+
+
+def _layer_weights(rng, d, h, experts):
+    def q(shape):
+        return jnp.asarray(rng.integers(-127, 128, shape, dtype=np.int8))
+
+    def scale(k, n):  # the lecun standard deviation, as the cell's adapter makes it
+        return jnp.full((experts, n), 1.0 / (73.0 * np.sqrt(k)), jnp.float32)
+
+    return {
+        "router": jnp.asarray(rng.standard_normal((d, experts)) / np.sqrt(d), jnp.bfloat16),
+        "w": (q((experts, d, h)), q((experts, d, h)), q((experts, h, d))),
+        "scales": (scale(d, h), scale(d, h), scale(h, d)),
+    }
+
+
+def _layer(mlp, k, x, p):
+    weights, indices, _ = moe.top_k_routing(x @ p["router"], k)
+    out = mlp(x, weights, indices, *p["w"], scales=p["scales"]).astype(jnp.float32)
+    # the next layer's rows: unit scale again, and a function of this output
+    return (out * jax.lax.rsqrt(jnp.mean(out * out, axis=-1, keepdims=True) + 1e-6)).astype(x.dtype)
+
+
+def _time(loop, x, params, calls):
+    jax.block_until_ready(loop(x, params))
+    times = []
+    for _ in range(5):
+        t0 = time.perf_counter()
+        jax.block_until_ready(loop(x, params))
+        times.append((time.perf_counter() - t0) / calls)
+    return round(1e6 * float(np.median(times)), 1), round(1e6 * min(times), 1)
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--tokens", type=int, nargs="*", default=[32, 128, 256, 512, 1024])
+    ap.add_argument("--reps", type=int, default=10)
+    ap.add_argument("--layers", type=int, default=8)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--chunk", type=int, nargs="*", default=[], help="row tiles to try, not the op's own")
+    ap.add_argument("--tiles", nargs="*", default=[], help="weight tiles k,n to try, not the op's own")
+    ap.add_argument("--ragged-dot", action="store_true")
+    ap.add_argument("--skip-dense", action="store_true")
+    ap.add_argument("--rehearse", action="store_true")
+    args = ap.parse_args()
+    device = jax.devices()[0]
+    if device.platform != "tpu" and not args.rehearse:
+        raise SystemExit(f"needs a TPU, found {device.platform}")
+    d, h, experts, k = (256, 512, 8, 2) if args.rehearse else (4096, 14336, 8, 2)
+    reps, layers = (1, 2) if args.rehearse else (args.reps, args.layers)
+    rng = np.random.default_rng(args.seed)
+    params = [_layer_weights(rng, d, h, experts) for _ in range(layers)]
+    layer_bytes = 3 * experts * d * h
+
+    cases = [] if args.skip_dense else [("dense", moe.dense_expert_mlp, None, None)]
+    grouped = functools.partial(moe.grouped_expert_mlp, impl="pallas")
+    tiles = [tuple(int(n) for n in t.split(",")) for t in args.tiles] or [None]
+    cases += [("grouped", grouped, c, t) for c in (args.chunk or [None]) for t in tiles]
+    if args.ragged_dot:
+        cases.append(("ragged_dot", functools.partial(moe.grouped_expert_mlp, impl="ragged_dot"), None, None))
+    row_chunk, pallas = moe._row_chunk, moe._grouped_matmul_pallas
+    for tokens in args.tokens:
+        x = jnp.asarray(rng.standard_normal((tokens, d)), jnp.bfloat16)
+        for what, mlp, chunk, tile in cases:
+            moe._row_chunk = row_chunk if chunk is None else (lambda rows, e, chunk=chunk: chunk)
+            moe._grouped_matmul_pallas = pallas if tile is None else functools.partial(pallas, tiles=tile)
+
+            @jax.jit
+            def loop(x, params, mlp=mlp):
+                def body(_, x):
+                    for p in params:
+                        x = _layer(mlp, k, x, p)
+                    return x
+                return jax.lax.fori_loop(0, reps, body, x)
+
+            try:
+                us, us_min = _time(loop, x, params, reps * layers)
+                # one layer's rows against the dense dispatch's, in units of their spread
+                got, want = (
+                    jax.jit(functools.partial(_layer, m, k))(x, params[0]).astype(jnp.float32)
+                    for m in (mlp, moe.dense_expert_mlp)
+                )
+                off = round(float(jnp.max(jnp.abs(got - want)) / jnp.std(want)), 4)
+            except Exception as exc:  # a tile the compiler refuses: say so and go on
+                us, us_min, off = None, str(exc)[:300], None
+            chunk = chunk or (moe._row_chunk(tokens * k, experts) if what == "grouped" else None)
+            print(json.dumps({
+                "what": what, "tokens": tokens, "us_per_layer": us, "us_min": us_min,
+                "chunk": chunk, "tiles": tile, "max_off_dense_in_sd": off,
+                "rows_computed_over_routed": {
+                    "dense": experts / k, "ragged_dot": 1.0,
+                }.get(what) or round(moe._padded_rows(tokens * k, experts, chunk) / (tokens * k), 3),
+                "weights_us_at_hbm_rate": round(1e6 * layer_bytes / HBM_BYTES_PER_S, 1),
+                "routed_flops_us_at_peak": round(1e6 * 2 * tokens * k * 3 * d * h / MXU_FLOPS, 1),
+                "layers": layers, "device": device.device_kind, "platform": device.platform,
+            }), flush=True)
+    moe._row_chunk, moe._grouped_matmul_pallas = row_chunk, pallas
+
+
+if __name__ == "__main__":
+    main()

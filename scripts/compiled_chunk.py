@@ -2,6 +2,8 @@
 
     python scripts/compiled_chunk.py chipbench/configs/olmo-hybrid-7b-int8.json \\
         [--match gdn] [--top 3] [--text chunk.hlo]
+    python scripts/compiled_chunk.py chipbench/configs/mixtral-8x7b-int8.json \\
+        --program prefill --bucket 256 [--match moe]
 
 Builds the ``DecodeEngine`` a ``chipbench`` cell serves with (the
 configuration's adapter and its ``serving`` block, as
@@ -15,6 +17,8 @@ made them. A ``copy`` or a ``fusion`` of a device trace is one of these.
 ``--text`` keeps the compiled module's text, and reads it back instead of
 compiling if the file is there. A 32-layer model compiles in a quarter of a
 minute. Where no TPU compiler is installed it says so and compiles nothing.
+``--program prefill --bucket N`` lists the prefill program of one prompt
+bucket the same way: it has no loop, so the whole entry computation is listed.
 """
 
 from __future__ import annotations
@@ -64,16 +68,20 @@ def result_bytes(result: str) -> int:
     return sum(tiled_bytes(*m.groups()) for m in _ARRAY.finditer(result))
 
 
-def loop_body(text: str) -> list:
-    """The lines of the largest ``while`` body of a compiled module."""
-    bodies = set(re.findall(r"\bwhile\(.*?body=%?([\w.-]+)", text))
+def computation(text: str, entry: bool = False) -> list:
+    """The lines of the largest ``while`` body of a compiled module, or of
+    its entry computation (a prefill program's work is not in a loop)."""
+    if entry:
+        names = set(re.findall(r"^ENTRY %?([\w.-]+)", text, re.M))
+    else:
+        names = set(re.findall(r"\bwhile\(.*?body=%?([\w.-]+)", text))
     best, name, lines = [], None, []
     for line in text.splitlines():
-        start = re.match(r"^%?([\w.-]+) \(.*\{\s*$", line)
+        start = re.match(r"^(?:ENTRY )?%?([\w.-]+) \(.*\{\s*$", line)
         if start:
             name, lines = start.group(1), []
         elif line.startswith("}"):
-            if name in bodies and len(lines) > len(best):
+            if name in names and len(lines) > len(best):
                 best = lines
             name = None
         elif name is not None:
@@ -81,13 +89,13 @@ def loop_body(text: str) -> list:
     return best
 
 
-def compiled_chunk_text(config_path: str) -> str:
+def compiled_chunk_text(config_path: str, program: str = "decode", bucket: int | None = None) -> str:
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from unionml_tpu.ops import gated_delta, paged_attention
+    from unionml_tpu.ops import gated_delta, moe, paged_attention
     from unionml_tpu.serving.engine import DecodeEngine
 
     try:
@@ -96,7 +104,7 @@ def compiled_chunk_text(config_path: str) -> str:
         raise SystemExit(f"compiled_chunk: no TPU compiler here, nothing compiled ({exc!r})") from None
     chip = SingleDeviceSharding(topo.devices[0])
     jax.config.update("jax_enable_compilation_cache", False)  # unreadable without the chip
-    for module in (gated_delta, paged_attention):  # off their CPU branch
+    for module in (gated_delta, moe, paged_attention):  # off their CPU branch
         module._interpret = lambda: False
 
     cfg = json.loads(Path(config_path).read_text())
@@ -113,21 +121,32 @@ def compiled_chunk_text(config_path: str) -> str:
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree,
             )
 
-        args = on_chip((
-            built["abstract_serve_params"](), jax.eval_shape(engine._init_state),
-            jax.ShapeDtypeStruct((engine.slots,), jnp.bool_),
-            jax.ShapeDtypeStruct(engine._table.shape, jnp.int32),
-            jax.ShapeDtypeStruct((engine.chunk_steps, 2), jnp.uint32),
-        ))
-        chunk = getattr(engine._decode_chunk, "__wrapped__", engine._decode_chunk)  # the tracker's wrapper
-        return chunk.lower(*args).compile().as_text()
+        params, state = built["abstract_serve_params"](), jax.eval_shape(engine._init_state)
+        if program == "prefill":
+            bucket = bucket or engine.buckets[0]
+            if bucket not in engine.buckets:
+                raise SystemExit(f"compiled_chunk: no bucket {bucket} among {engine.buckets}")
+            compiled, args = engine._prefill, (
+                params, state, jax.ShapeDtypeStruct((), jnp.int32),
+                jax.ShapeDtypeStruct((bucket // engine._kv_block_size,), jnp.int32),
+                jax.ShapeDtypeStruct((bucket,), jnp.int32), jax.ShapeDtypeStruct((), jnp.int32),
+                jax.eval_shape(lambda: jax.random.PRNGKey(0)),
+            )
+        else:
+            compiled, args = engine._decode_chunk, (
+                params, state, jax.ShapeDtypeStruct((engine.slots,), jnp.bool_),
+                jax.ShapeDtypeStruct(engine._table.shape, jnp.int32),
+                jax.ShapeDtypeStruct((engine.chunk_steps, 2), jnp.uint32),
+            )
+        compiled = getattr(compiled, "__wrapped__", compiled)  # the tracker's wrapper
+        return compiled.lower(*on_chip(args)).compile().as_text()
     finally:
         engine.close()
 
 
-def report(text: str, match: str = "", top: int = 3) -> None:
+def report(text: str, match: str = "", top: int = 3, entry: bool = False) -> None:
     kinds = collections.defaultdict(list)
-    for line in loop_body(text):
+    for line in computation(text, entry):
         m = _INSTRUCTION.match(line)
         opcode = m and re.search(r" ([a-z][\w-]*)\(", m.group(2))
         op_name = re.search(r'op_name="([^"]*)"', line)
@@ -156,14 +175,16 @@ def main():
     ap.add_argument("--match", default="", help="only operations whose op_name holds this")
     ap.add_argument("--top", type=int, default=3, help="largest results listed per kind")
     ap.add_argument("--text", help="the compiled module's text: read if the file is there, else written")
+    ap.add_argument("--program", choices=("decode", "prefill"), default="decode")
+    ap.add_argument("--bucket", type=int, help="the prefill program's prompt bucket (default: the smallest)")
     args = ap.parse_args()
     if args.text and Path(args.text).exists():
         text = Path(args.text).read_text()
     else:
-        text = compiled_chunk_text(args.config)
+        text = compiled_chunk_text(args.config, args.program, args.bucket)
         if args.text:
             Path(args.text).write_text(text)
-    report(text, args.match, args.top)
+    report(text, args.match, args.top, entry=args.program == "prefill")
 
 
 if __name__ == "__main__":

@@ -267,6 +267,39 @@ def test_engine_with_moe_llama():
         engine.close()
 
 
+def test_stats_say_what_each_programs_expert_layers_do(tiny_llama):
+    """`stats()["moe"]`: for each compiled program the dispatch its expert
+    layers take and the rows they compute over the rows the router sent
+    (`ops.moe.dispatch_plan`, a count from shapes); absent for a dense model."""
+    from unionml_tpu.models import LLAMA_QUANT_PATTERNS, quantize_params
+
+    module, _ = tiny_llama
+    dense = DecodeEngine(module, slots=2, max_new_tokens=4, prompt_buckets=(8,), chunk_steps=2)
+    try:
+        assert "moe" not in dense.stats()
+    finally:
+        dense.close()
+
+    kw = dict(vocab_size=97, num_experts=4, num_selected=2)
+    params = Llama(LlamaConfig.tiny(**kw)).init(jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32))["params"]
+    for quantized, dispatch, ratio in ((False, "dense", 2.0), (True, "grouped:ragged_dot", 1.0)):
+        module = Llama(LlamaConfig.tiny(**kw, quantized=quantized))
+        engine = DecodeEngine(module, slots=2, max_new_tokens=4, prompt_buckets=(8, 16), chunk_steps=2)
+        try:
+            moe = engine.stats()["moe"]
+            assert set(moe) == {"decode_chunk", "prefill_8", "prefill_16"}
+            assert moe["prefill_16"] == {
+                "dispatch": dispatch, "expert_rows_routed": 32,
+                "expert_rows_computed": int(32 * ratio), "computed_over_routed": ratio,
+            }
+            assert moe["decode_chunk"]["expert_rows_routed"] == 2 * 2  # slots x top-k
+            if quantized:  # and the grouped programs serve
+                qparams = quantize_params(params, LLAMA_QUANT_PATTERNS)
+                assert [len(o) for o in engine.generate(qparams, [[1, 2, 3], [4, 5, 6, 7, 8, 9]])] == [4, 4]
+        finally:
+            engine.close()
+
+
 def test_engine_under_tensor_parallel_sharding(tiny_llama):
     """Continuous batching with TP-sharded weights: GSPMD propagates the
     `tensor`-axis sharding through prefill and decode chunks, and slot

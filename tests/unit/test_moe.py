@@ -290,3 +290,190 @@ def test_migrate_moe_router_params_old_layout_restores():
     )
     out, aux = module.apply({"params": new_moe}, x)
     assert out.shape == x.shape and np.isfinite(float(aux))
+
+
+# ------------------------------------------------ grouped dispatch (PR 34)
+#
+# The dense one-hot dispatch is the plain reference: every expert on
+# every token. The grouped dispatch must give the same block output by
+# both of its matmuls: `jax.lax.ragged_dot` (the CPU's path) and the
+# Pallas kernel `moe_grouped_matmul` in interpret mode.
+
+
+def _expert_weights(experts, d, hidden, quantized, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    shapes = ((experts, d, hidden), (experts, d, hidden), (experts, hidden, d))
+    if not quantized:
+        return tuple(jax.random.normal(k, s) * s[1] ** -0.5 for k, s in zip(ks, shapes)), None
+    ws = tuple(jax.random.randint(k, s, -127, 128, jnp.int8) for k, s in zip(ks, shapes))
+    scales = tuple(
+        jax.random.uniform(k, (experts, s[2]), minval=0.5, maxval=1.5) / (73.0 * s[1] ** 0.5)
+        for k, s in zip(ks, shapes)
+    )
+    return ws, scales
+
+
+def _assert_grouped_matches_dense(x, weights, indices, ws, scales, impl):
+    from unionml_tpu.ops.moe import dense_expert_mlp, grouped_expert_mlp
+
+    want = dense_expert_mlp(x, weights, indices, *ws, scales=scales)
+    got = grouped_expert_mlp(x, weights, indices, *ws, scales=scales, impl=impl)
+    assert got.shape == want.shape == x.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("impl", ["ragged_dot", "pallas"])
+@pytest.mark.parametrize("experts,selected", [(8, 2), (4, 1)])
+@pytest.mark.parametrize("tokens", [1, 7, 32, 256])
+@pytest.mark.parametrize("quantized", [False, True], ids=["float", "int8"])
+def test_grouped_dispatch_matches_dense(quantized, tokens, experts, selected, impl):
+    d, hidden = 64, 128
+    ws, scales = _expert_weights(experts, d, hidden, quantized, seed=tokens)
+    kx, kr = jax.random.split(jax.random.PRNGKey(tokens + experts))
+    x = jax.random.normal(kx, (tokens, d))
+    weights, indices, _ = top_k_routing(jax.random.normal(kr, (tokens, experts)), selected)
+    _assert_grouped_matches_dense(x, weights, indices, ws, scales, impl)
+
+
+def _special_routing(kind):
+    """(indices [T, k], experts): routings a random router rarely deals."""
+    if kind == "empty_experts":  # experts 0, 3 and 7 get no row
+        pairs = [(1, 2), (4, 5), (6, 1), (2, 4), (5, 6)] * 8
+        return jnp.asarray(pairs, jnp.int32), 8
+    if kind == "one_expert":  # every token to expert 2, alone
+        return jnp.full((40, 1), 2, jnp.int32), 4
+    # split_tile: 21 + 43 rows, so expert 1's rows start inside what would
+    # be expert 0's second 16-row tile and end inside a tile of their own
+    assert kind == "split_tile"
+    return jnp.asarray([[0]] * 21 + [[1]] * 43, jnp.int32), 4
+
+
+@pytest.mark.parametrize("impl", ["ragged_dot", "pallas"])
+@pytest.mark.parametrize("kind", ["empty_experts", "one_expert", "split_tile"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["float", "int8"])
+def test_grouped_dispatch_special_routings(quantized, kind, impl):
+    indices, experts = _special_routing(kind)
+    tokens, selected = indices.shape
+    d, hidden = 32, 64
+    ws, scales = _expert_weights(experts, d, hidden, quantized, seed=3)
+    x = jax.random.normal(jax.random.PRNGKey(5), (tokens, d))
+    weights = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(6), (tokens, selected)), -1)
+    _assert_grouped_matches_dense(x, weights, indices, ws, scales, impl)
+
+
+@pytest.mark.parametrize("chunk", [1, 16, 128])
+def test_group_rows_layout(chunk):
+    from unionml_tpu.ops.moe import _padded_rows, group_rows
+
+    experts, selected = 8, 2
+    _, indices, _ = top_k_routing(jax.random.normal(jax.random.PRNGKey(0), (50, experts)), selected)
+    indices = jnp.where(indices == 5, 6, indices)  # expert 5 stays empty
+    source, slot, sizes, tile_expert, tile_rows = map(np.asarray, group_rows(indices, experts, chunk))
+    flat = np.asarray(indices).reshape(-1)
+    assert source.shape == (_padded_rows(flat.size, experts, chunk),)
+    np.testing.assert_array_equal(sizes, np.bincount(flat, minlength=experts))
+    assert sizes[5] == 0 and len(set(slot)) == flat.size  # one row a pair
+    np.testing.assert_array_equal(source[slot], np.arange(flat.size) // selected)
+    starts = np.cumsum(-(-sizes // chunk) * chunk) - (-(-sizes // chunk) * chunk)
+    assert (starts % chunk == 0).all() and (tile_rows > 0).sum() == (-(-sizes // chunk)).sum()
+    assert tile_rows.sum() == flat.size and (np.diff((tile_rows > 0).astype(int)) <= 0).all()
+    for e in range(experts):
+        rows = np.sort(slot[flat == e])
+        # an expert's rows are contiguous from its start, in token order
+        np.testing.assert_array_equal(rows, starts[e] + np.arange(sizes[e]))
+        np.testing.assert_array_equal(slot[flat == e], rows)
+        assert (tile_expert[rows // chunk] == e).all()
+        used = -(-sizes[e] // chunk)  # the expert's tiles, and the routed rows each holds
+        held = np.bincount(rows // chunk, minlength=tile_rows.size)[tile_expert == e]
+        np.testing.assert_array_equal(held[:used], tile_rows[tile_expert == e][:used])
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["float", "int8"])
+@pytest.mark.parametrize("gated", [False, True], ids=["down", "gate_up"])
+def test_grouped_matmul_kernel_matches_ragged_dot(gated, quantized):
+    """The kernel alone in interpret mode, its k and n axes tiled (three k
+    tiles: the accumulator is written, added to twice, scaled and stored),
+    against `ragged_dot` on the same rows packed without padding."""
+    from unionml_tpu.ops import moe
+
+    experts, depth, width, chunk = 4, 384, 256, 16
+    sizes = np.array([19, 0, 37, 8])
+    indices = jnp.asarray(np.repeat(np.arange(experts), sizes)[:, None], jnp.int32)
+    ws, scales = _expert_weights(experts, depth, width, quantized, seed=9)
+    rhs, scales = (ws[:2], scales and scales[:2]) if gated else (ws[:1], scales and scales[:1])
+    x = jax.random.normal(jax.random.PRNGKey(2), (int(sizes.sum()), depth))
+
+    want = moe.grouped_matmul(x, rhs, jnp.asarray(sizes, jnp.int32), scales=scales, impl="ragged_dot")
+    source, slot, _, tile_expert, tile_rows = moe.group_rows(indices, experts, chunk)
+    got = moe._grouped_matmul_pallas(
+        x[source], rhs, scales, tile_expert, tile_rows,
+        chunk=chunk, gated=gated, interpret=True, tiles=(128, 128),
+    )
+    assert got.shape == (moe._padded_rows(x.shape[0], experts, chunk), width)
+    np.testing.assert_allclose(np.asarray(got[slot]), np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+def test_dispatch_plan_counts_rows(monkeypatch):
+    from unionml_tpu.ops import moe
+
+    # float experts may be differentiated or sharded: the dense dispatch,
+    # every expert on every token
+    plan = moe.dispatch_plan(256, 8, 2, quantized=False)
+    assert plan["dispatch"] == "dense" and plan["computed_over_routed"] == 4.0
+    plan = moe.dispatch_plan(256, 8, 2, quantized=True)  # off the chip
+    assert plan == {
+        "dispatch": "grouped:ragged_dot", "expert_rows_routed": 512,
+        "expert_rows_computed": 512, "computed_over_routed": 1.0,
+    }
+    # under a mesh the compiler partitions, int8 experts too: it cannot
+    # partition a Pallas call (inside shard_map the axes are manual)
+    mesh = make_mesh({"expert": 2, "tensor": 2}, devices=jax.devices()[:4])
+    with jax.set_mesh(mesh):
+        assert moe.dispatch_plan(256, 8, 2, quantized=True)["dispatch"] == "dense"
+    seen = []
+    jax.shard_map(
+        lambda x: seen.append(moe.dispatch_plan(256, 8, 2, quantized=True)["dispatch"]) or x,
+        mesh=mesh, in_specs=jax.sharding.PartitionSpec(), out_specs=jax.sharding.PartitionSpec(),
+    )(jnp.zeros(4))
+    assert seen == ["grouped:ragged_dot"]
+    monkeypatch.setattr(moe, "_interpret", lambda: False)  # as on a TPU
+    # up to the MXU's side in tokens (a decode chunk's slot rows) the dense
+    # einsums are at the weight read's pace already
+    for tokens in (32, 128):
+        plan = moe.dispatch_plan(tokens, 8, 2, quantized=True)
+        assert plan["dispatch"] == "dense" and plan["computed_over_routed"] == 4.0
+    for tokens in (256, 512, 1024):
+        plan = moe.dispatch_plan(tokens, 8, 2, quantized=True)
+        chunk = moe._row_chunk(2 * tokens, 8)
+        assert plan["dispatch"] == "grouped:moe_grouped_matmul"
+        assert plan["expert_rows_routed"] == 2 * tokens
+        assert plan["expert_rows_computed"] == moe._padded_rows(2 * tokens, 8, chunk)
+        assert 1.0 <= plan["computed_over_routed"] < 4.0  # under the dense dispatch's
+
+
+def test_quantized_moe_module_takes_the_grouped_dispatch(monkeypatch):
+    """`MoEMlp(quantized=True)` routes through `grouped_expert_mlp`, float
+    experts through the dense dispatch, and both agree with each other on
+    the same (dequantized) weights."""
+    from unionml_tpu.models import LLAMA_QUANT_PATTERNS, quantize_params
+    from unionml_tpu.ops import moe
+
+    calls = []
+    for name in ("grouped_expert_mlp", "dense_expert_mlp"):
+        fn = getattr(moe, name)
+        monkeypatch.setattr(
+            moe, name, lambda *a, _fn=fn, _name=name, **kw: calls.append(_name) or _fn(*a, **kw)
+        )
+    kw = dict(num_experts=4, num_selected=2, hidden_dim=32, model_dim=16, dtype=jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 16))
+    params = MoEMlp(**kw).init(jax.random.PRNGKey(1), x)["params"]
+    qparams = quantize_params({"moe": params}, LLAMA_QUANT_PATTERNS)["moe"]
+    dequantized = {"router_kernel": params["router_kernel"], **{
+        name: qparams[f"{name}_q"] * qparams[f"{name}_scale"][:, None, :]
+        for name in ("w_gate", "w_up", "w_down")
+    }}
+    calls.clear()
+    want, _ = MoEMlp(**kw).apply({"params": dequantized}, x)
+    got, _ = MoEMlp(**kw, quantized=True).apply({"params": qparams}, x)
+    assert calls == ["dense_expert_mlp", "grouped_expert_mlp"]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)

@@ -32,6 +32,7 @@ from unionml_tpu.ops import (
     fused_norm,
     gated_delta,
     int4_matmul,
+    moe,
     paged_attention,
 )
 
@@ -54,7 +55,7 @@ def _compile_for_the_chip(monkeypatch):
     written there but cannot be read back without one."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
-    for module in (flash_attention, fused_attention, fused_norm, gated_delta, paged_attention):
+    for module in (flash_attention, fused_attention, fused_norm, gated_delta, moe, paged_attention):
         monkeypatch.setattr(module, "_interpret", lambda: False)
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -317,3 +318,59 @@ def test_int4_matmul_compiles(chip, group_size):
             )
 
     _assert_mosaic(chip, fn, *shapes)
+
+
+# mixtral_chat_decode's expert layer (hidden 4096, 8 experts of 14336, top-2,
+# int8 weights, bfloat16 rows) at a 256-token prefill bucket (512 routed rows:
+# [512, 4096] x [8, 4096, 14336] for gate and up, then the down projection),
+# the largest bucket, and the decode chunk's 32 slot rows (64 routed rows: the
+# engine's decode chunk takes the dense dispatch, the kernel must still compile)
+_MIXTRAL_LAYER = dict(d=4096, hidden=14336, experts=8, selected=2)
+
+
+def _no_array_of_every_expert(text, tokens, d, hidden, experts, **_):
+    # the weights stay int8 in HBM, and no array holds every expert's
+    # products for every token
+    assert f"s8[{experts},{d},{hidden}]" in text
+    assert not re.search(rf"(?:bf16|f32)\[{experts},(?:{d},{hidden}|{hidden},{d})\]", text)
+    assert not re.search(rf"\[{experts},{tokens},(?:{hidden}|{d})\]", text)
+
+
+@pytest.mark.parametrize("tokens", [32, 256, 1024], ids=["decode_32_slots", "prefill_256", "prefill_1024"])
+def test_moe_grouped_matmul_compiles(chip, tokens):
+    d, hidden, experts, selected = _MIXTRAL_LAYER.values()
+
+    def mlp(x, weights, indices, w_gate, w_up, w_down, *scales):
+        return moe.grouped_expert_mlp(x, weights, indices, w_gate, w_up, w_down, scales=scales, impl="pallas")
+
+    text = _assert_mosaic(
+        chip, mlp,
+        ((tokens, d), jnp.bfloat16), ((tokens, selected), jnp.bfloat16), ((tokens, selected), jnp.int32),
+        ((experts, d, hidden), jnp.int8), ((experts, d, hidden), jnp.int8), ((experts, hidden, d), jnp.int8),
+        ((experts, hidden), jnp.float32), ((experts, hidden), jnp.float32), ((experts, d), jnp.float32),
+    )
+    # gate + up with the SwiGLU in one call, the down projection in another,
+    # under the name a trace shows
+    assert len(re.findall(r"%moe_grouped_matmul(?:\.\d+)* = ", text)) == 2
+    _no_array_of_every_expert(text, tokens, **_MIXTRAL_LAYER)
+
+
+def test_moe_layer_of_a_prefill_holds_no_array_of_every_expert(chip):
+    """`MoEMlp` as the 256-token prefill program traces it: the grouped
+    dispatch, and nothing shaped `[8, 256, 14336]` or `bf16[8, 4096, 14336]`."""
+    tokens = 256
+    d, hidden, experts, selected = _MIXTRAL_LAYER.values()
+    layer = moe.MoEMlp(
+        num_experts=experts, num_selected=selected, hidden_dim=hidden, model_dim=d,
+        dtype=jnp.bfloat16, quantized=True,
+    )
+    x = jax.ShapeDtypeStruct((1, tokens, d), jnp.bfloat16, sharding=chip)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0), x),
+    )
+    plan = moe.dispatch_plan(tokens, experts, selected, quantized=True)
+    assert plan["dispatch"] == "grouped:moe_grouped_matmul" and plan["computed_over_routed"] < 4.0
+    text = jax.jit(layer.apply).lower(params, x).compile().as_text()
+    assert len(re.findall(r"%moe_grouped_matmul(?:\.\d+)* = ", text)) == 2
+    _no_array_of_every_expert(text, tokens, **_MIXTRAL_LAYER)

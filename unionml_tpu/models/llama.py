@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from unionml_tpu.models.layers import Attention, KVRows, MlpBlock, RMSNorm, make_dense
-from unionml_tpu.ops.moe import MoEMlp
+from unionml_tpu.ops.moe import MoEMlp, dispatch_plan
 from unionml_tpu.parallel.sharding import PartitionRule
 
 Cache = Tuple[Tuple[jnp.ndarray, jnp.ndarray], ...]  # per-layer (k, v)
@@ -207,6 +207,14 @@ class Llama(nn.Module):
         """Every layer caches keys and values (see ``layers.KVRows``)."""
         cfg = self.config
         return (KVRows(cfg.num_kv_heads, cfg.head_dim, cfg.kv_quant),) * cfg.num_layers
+
+    def moe_dispatch(self, tokens: int) -> Optional[dict]:
+        """What a mixture layer does with a program of ``tokens`` rows
+        (``ops.moe.dispatch_plan``); ``None`` for a dense model."""
+        cfg = self.config
+        if not cfg.num_experts:
+            return None
+        return dispatch_plan(tokens, cfg.num_experts, cfg.num_selected, quantized=cfg.quantized)
 
     @nn.compact
     def __call__(

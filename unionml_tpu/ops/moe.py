@@ -1,13 +1,22 @@
-"""Mixture-of-experts: top-k routing + expert-parallel dispatch.
+"""Mixture-of-experts: top-k routing + expert dispatch.
 
 Expert parallelism (SURVEY.md §2.4 TPU additions, §7.8 "EP: expert-sharded
-MoE with all_to_all dispatch"). Two dispatch paths, one routing math:
+MoE with all_to_all dispatch"). Three dispatch paths, one routing math:
 
-- **Dense einsum dispatch** (:class:`MoEMlp`): every tensor is
-  static-shaped; with the expert dim of the weights sharded over the
-  mesh's ``expert`` axis, GSPMD inserts the all_to_all-style collectives.
-  No capacity limit — every routed token is processed. The default for
-  pjit training via partition rules.
+- **Grouped dispatch** (:func:`grouped_expert_mlp`, what :class:`MoEMlp`
+  serves int8 experts with): the routed (token, choice) pairs are ordered
+  by expert and each row meets only its own expert's weights, through a
+  grouped matmul (:func:`grouped_matmul`: the Pallas kernel
+  ``moe_grouped_matmul`` on a TPU, ``jax.lax.ragged_dot`` elsewhere). No
+  capacity limit — every routed token is processed — and top-k experts'
+  FLOPs a token, not every expert's.
+- **Dense einsum dispatch** (:func:`dense_expert_mlp`, what
+  :class:`MoEMlp` runs float experts with): every expert on every token,
+  unrouted products masked. Every tensor is static-shaped and
+  differentiable; with the expert dim of the weights sharded over the
+  mesh's ``expert`` axis, GSPMD inserts the all_to_all-style collectives
+  (it cannot partition a Pallas call). The default for pjit training via
+  partition rules, and the tests' plain reference for the grouped one.
 - **Explicit all_to_all dispatch**
   (:func:`expert_parallel_moe_sharded` / :func:`expert_parallel_moe`):
   the GShard/Switch algorithm inside ``shard_map`` — tokens are bucketed
@@ -23,14 +32,16 @@ x mean routing fraction per expert).
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from flax import linen as nn
 from jax import lax
+from jax.experimental import pallas as pl
 
 
 def load_balance_stats(
@@ -114,11 +125,327 @@ def make_dispatch(
     return dispatch, combine, aux_loss
 
 
+# ------------------------------------------------------------ grouped dispatch
+#
+# Every (token, choice) pair becomes one row, rows of one expert lie
+# together, and a row meets only its own expert's weights. The rows of an
+# expert start on a multiple of ``chunk`` (the kernel's row tile), so a
+# tile belongs to one expert: no tile is masked or visited twice, and what
+# is computed beyond the routed rows is each expert's last tile's padding.
+
+
+def _interpret() -> bool:
+    return jax.devices()[0].platform != "tpu"
+
+
+def _mesh_in_sight() -> bool:
+    """Whether the trace runs under a mesh with an axis of several devices
+    that the compiler partitions (``jax.set_mesh``; not ``shard_map``'s
+    manual axes): it does not partition a Pallas call."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return any(size > 1 for axis, size in mesh.shape.items() if axis not in mesh.manual_axes)
+
+
+_MXU_ROWS = 128  # the side of the chip's matrix unit
+
+
+def _row_chunk(rows: int, num_experts: int) -> int:
+    """Rows of the kernel's row tile for ``rows`` routed pairs. The MXU
+    loads a weight tile in the time ``_MXU_ROWS`` rows take to stream, so a
+    shorter tile costs what that one does and two of them twice as much:
+    the tile is the MXU's side, or half of it where an expert's rows fit
+    that (even routing deals it a quarter), which halves the padding."""
+    return _MXU_ROWS if 4 * rows > num_experts * _MXU_ROWS else _MXU_ROWS // 2
+
+
+def dispatch_plan(tokens: int, num_experts: int, num_selected: int, *, quantized: bool) -> dict:
+    """What :class:`MoEMlp` does with ``tokens`` rows, from static shapes:
+    the dispatch, the rows the experts' matmuls compute and the rows the
+    router sent (``tokens x num_selected``). The dense dispatch runs every
+    expert on every token; the grouped one pads each expert's rows to the
+    kernel's row tile, counted here at its worst (every expert's last tile
+    holding one row).
+
+    On a TPU the grouped kernel serves programs of more than ``_MXU_ROWS``
+    tokens. Up to there every expert's matmul streams no more rows than a
+    weight tile takes to load, so the dense einsums are at the weight
+    read's pace already and nothing of the sort, the gathers and the grid
+    is paid (a decode chunk's slot rows; measured in PERF.md section 6).
+    """
+    routed, on_chip = tokens * num_selected, not _interpret()
+    if not quantized or _mesh_in_sight() or (on_chip and tokens <= _MXU_ROWS):
+        dispatch, computed = "dense", tokens * num_experts
+    elif on_chip:
+        chunk = _row_chunk(routed, num_experts)
+        dispatch, computed = "grouped:moe_grouped_matmul", _padded_rows(routed, num_experts, chunk)
+    else:
+        dispatch, computed = "grouped:ragged_dot", routed
+    return {
+        "dispatch": dispatch,
+        "expert_rows_routed": routed,
+        "expert_rows_computed": computed,
+        "computed_over_routed": round(computed / routed, 3),
+    }
+
+
+def _padded_rows(rows: int, num_experts: int, chunk: int) -> int:
+    """Rows of the layout in which every expert's rows start on a multiple
+    of ``chunk``: the most that ``sum(ceil(size / chunk) * chunk)`` can be."""
+    return (rows + num_experts * (chunk - 1)) // chunk * chunk
+
+
+def group_rows(indices: jnp.ndarray, num_experts: int, chunk: int = 1):
+    """Order the ``[T, k]`` routed pairs by expert (stable: by token within
+    an expert), each expert's rows starting on a multiple of ``chunk``.
+
+    Returns ``(source [R], slot [T * k], group_sizes [E], tile_expert
+    [R // chunk], tile_rows [R // chunk])``: ``source[r]`` is the token whose
+    row sits at ``r`` (token 0 in padding, never read back), ``slot[j]`` the
+    row of pair ``j``, ``tile_expert`` the expert of each row tile and
+    ``tile_rows`` how many of its rows hold a routed pair (the tiles that
+    hold any come first). ``R`` is static.
+    """
+    num_selected = indices.shape[-1]
+    flat = indices.reshape(-1).astype(jnp.int32)
+    pairs = flat.shape[0]
+    group_sizes = jnp.bincount(flat, length=num_experts).astype(jnp.int32)
+    padded = (group_sizes + chunk - 1) // chunk * chunk
+    ends, padded_ends = jnp.cumsum(group_sizes), jnp.cumsum(padded)
+    order = jnp.argsort(flat, stable=True)
+    expert = flat[order]
+    row = (padded_ends - padded)[expert] + jnp.arange(pairs, dtype=jnp.int32) - (ends - group_sizes)[expert]
+    rows = _padded_rows(pairs, num_experts, chunk)
+    source = jnp.zeros((rows,), jnp.int32).at[row].set((order // num_selected).astype(jnp.int32))
+    slot = jnp.zeros((pairs,), jnp.int32).at[order].set(row)
+    tile_start = jnp.arange(rows // chunk, dtype=jnp.int32) * chunk
+    # the expert whose padded rows hold the tile's first row (the last
+    # expert for tiles past every row: they are never computed)
+    tile_expert = jnp.minimum(
+        jnp.sum(tile_start[:, None] >= padded_ends[None, :], axis=1), num_experts - 1
+    ).astype(jnp.int32)
+    tile_rows = jnp.clip((padded_ends - padded + group_sizes)[tile_expert] - tile_start, 0, chunk)
+    tile_rows = jnp.where(tile_start < padded_ends[-1], tile_rows, 0).astype(jnp.int32)
+    return source, slot, group_sizes, tile_expert, tile_rows
+
+
+def _largest_tile(size: int, most: int) -> int:
+    """The largest power-of-two multiple of 128 up to ``most`` that divides
+    ``size``, or ``size`` itself (a block may span a whole axis)."""
+    tile = most
+    while tile >= 128:
+        if size % tile == 0:
+            return tile
+        tile //= 2
+    return size
+
+
+_VMEM_LIMIT = 96 * 2**20  # of the chip's 128 MiB; the default scope is 16
+
+
+def _gmm_kernel(tile_expert, tile_rows, tiles_used, lhs_ref, *refs, chunk, k_tiles, n_rhs, scaled, gated):
+    del tile_expert, tiles_used
+    rhs_refs, refs = refs[:n_rhs], refs[n_rhs:]
+    scale_refs, refs = (refs[:n_rhs], refs[n_rhs:]) if scaled else ((None,) * n_rhs, refs)
+    out_ref, acc_refs = refs[0], refs[1:]
+    k, tile = pl.program_id(1), pl.program_id(2)
+    rows = pl.ds(pl.multiple_of(tile * chunk, chunk), chunk)
+
+    @pl.when(tile_rows[tile] > 0)  # a tile past the last one used holds no routed row
+    def _tile():
+        x = lhs_ref[...]
+        for acc, w in zip(acc_refs, rhs_refs):
+            # int8 tiles were read from HBM as int8; the MXU sees bf16
+            part = jnp.dot(x, w[...].astype(x.dtype), preferred_element_type=jnp.float32)
+
+            @pl.when(k == 0)
+            def _first():
+                acc[rows, :] = part
+
+            @pl.when(k > 0)
+            def _rest():
+                acc[rows, :] += part
+
+        @pl.when(k == k_tiles - 1)
+        def _store():
+            # the float32 scale before the single cast down, as qmm did
+            ys = [
+                (acc[rows, :] if s is None else acc[rows, :] * s[...]).astype(out_ref.dtype)
+                for acc, s in zip(acc_refs, scale_refs)
+            ]
+            if gated:  # the chip's vector unit has no bfloat16: float32 and one more cast
+                gate, up = (y.astype(jnp.float32) for y in ys)
+                ys = [(gate * jax.nn.sigmoid(gate) * up).astype(out_ref.dtype)]
+            out_ref[rows, :] = ys[0]
+
+
+# jitted: a model's layers trace and lower one kernel between them, and not
+# one each (an engine's warm set-up pays tracing and lowering every process)
+@functools.partial(jax.jit, static_argnames=("chunk", "gated", "interpret", "tiles"))
+def _grouped_matmul_pallas(lhs, rhs, scales, tile_expert, tile_rows, *, chunk, gated, interpret, tiles=None):
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, depth = lhs.shape
+    num_experts, _, width = rhs[0].shape
+    n_rhs, scaled = len(rhs), scales is not None
+    # largest weight tile (k, n) and accumulator MiB (float32 sums of every
+    # row x one column tile): measured on the chip (PERF.md section 6, PR
+    # 34); the benchmark's --tiles re-derives them
+    tk, tn, acc_mb = tuple(tiles or ()) + (2048, 2048, 12)[len(tiles or ()):]
+    tk, tn = _largest_tile(depth, tk), _largest_tile(width, tn)
+    while n_rhs * rows * tn * 4 > acc_mb * 2**20 and tn % 256 == 0:
+        tn //= 2
+    k_tiles = depth // tk
+    tiles_used = jnp.sum(tile_rows > 0).astype(jnp.int32).reshape(1)
+
+    # a tile past the last one used repeats the block indices of the last
+    # one used at the last k: nothing is fetched for it
+    def used(tile, k, tiles_used):
+        last = tiles_used[0] - 1
+        return jnp.minimum(tile, last), jnp.where(tile > last, k_tiles - 1, k)
+
+    def lhs_map(n, k, tile, tile_expert, tile_rows, tiles_used):
+        tile, k = used(tile, k, tiles_used)
+        return tile, k
+
+    def rhs_map(n, k, tile, tile_expert, tile_rows, tiles_used):
+        tile, k = used(tile, k, tiles_used)
+        return tile_expert[tile], k, n
+
+    def scale_map(n, k, tile, tile_expert, tile_rows, tiles_used):
+        return tile_expert[jnp.minimum(tile, tiles_used[0] - 1)], 0, n
+
+    in_specs = [pl.BlockSpec((chunk, tk), lhs_map)]
+    in_specs += [pl.BlockSpec((None, tk, tn), rhs_map)] * n_rhs
+    operands = [lhs, *rhs]
+    if scaled:
+        in_specs += [pl.BlockSpec((None, 1, tn), scale_map)] * n_rhs
+        operands += [s.reshape(num_experts, 1, width) for s in scales]
+    kernel = functools.partial(
+        _gmm_kernel, chunk=chunk, k_tiles=k_tiles, n_rhs=n_rhs, scaled=scaled, gated=gated,
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            # a column tile of every row stays in VMEM while the k tiles
+            # and, innermost, the row tiles pass: an expert's weight tile
+            # is fetched once however many row tiles it has
+            grid=(width // tn, k_tiles, rows // chunk),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((rows, tn), lambda n, k, tile, *_: (0, n)),
+            scratch_shapes=[pltpu.VMEM((rows, tn), jnp.float32)] * n_rhs,
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows, width), lhs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        interpret=interpret,
+        name="moe_grouped_matmul",
+    )(tile_expert, tile_rows, tiles_used, *operands)
+
+
+def grouped_matmul(
+    lhs: jnp.ndarray,
+    rhs: Sequence[jnp.ndarray],
+    group_sizes: jnp.ndarray,
+    *,
+    scales: Optional[Sequence[jnp.ndarray]] = None,
+    tile_expert: Optional[jnp.ndarray] = None,
+    tile_rows: Optional[jnp.ndarray] = None,
+    chunk: int = 1,
+    impl: str = "auto",
+) -> jnp.ndarray:
+    """``lhs`` rows times their own expert's matrix: ``[R, K] x [E, K, N]
+    -> [R, N]`` in ``lhs.dtype``, the rows of expert ``e`` contiguous and
+    ``group_sizes[e]`` many, float32 accumulation. ``rhs`` holds one matrix
+    per expert, or two (gate and up): then the result is ``silu(lhs @
+    gate) * (lhs @ up)``. Int8 matrices come with ``scales`` (``[E, N]``
+    float32 each), applied to the float32 sums before the single cast down.
+
+    ``impl``: ``"pallas"`` (the kernel ``moe_grouped_matmul``; it wants the
+    layout of :func:`group_rows` at ``chunk`` >= 16 with its ``tile_expert``
+    and ``tile_rows``; interpreter mode off-TPU), ``"ragged_dot"``
+    (``jax.lax.ragged_dot`` on rows packed without padding, ``chunk`` 1:
+    differentiable, and the CPU's path), or ``"auto"``.
+    """
+    gated = len(rhs) == 2
+    if impl == "auto":
+        impl = "ragged_dot" if _interpret() else "pallas"
+    if impl == "pallas":
+        return _grouped_matmul_pallas(
+            lhs, tuple(rhs), None if scales is None else tuple(scales), tile_expert, tile_rows,
+            chunk=chunk, gated=gated, interpret=_interpret(),
+        )
+    if impl != "ragged_dot":
+        raise ValueError(f"unknown grouped matmul impl {impl!r}")
+    if chunk != 1:
+        raise ValueError("ragged_dot takes rows packed without padding (chunk 1)")
+    row_expert = jnp.repeat(
+        jnp.arange(group_sizes.shape[0]), group_sizes, total_repeat_length=lhs.shape[0]
+    )
+    ys = []
+    for i, w in enumerate(rhs):
+        y = lax.ragged_dot(lhs, w.astype(lhs.dtype), group_sizes, preferred_element_type=jnp.float32)
+        if scales is not None:
+            y = y * scales[i][row_expert]
+        ys.append(y.astype(lhs.dtype))
+    return jax.nn.silu(ys[0]) * ys[1] if gated else ys[0]
+
+
+def grouped_expert_mlp(tokens, weights, indices, w_gate, w_up, w_down, *, scales=None, impl="auto"):
+    """The experts' SwiGLU over ``tokens`` [T, d] for the routing ``weights``
+    / ``indices`` [T, k]: every routed pair computed by its own expert, none
+    dropped. ``w_gate`` / ``w_up`` [E, d, h], ``w_down`` [E, h, d]; int8
+    with ``scales = (gate, up, down)``, each [E, n] float32."""
+    num_experts = w_gate.shape[0]
+    t, k = indices.shape
+    if impl == "auto":
+        impl = "ragged_dot" if _interpret() else "pallas"
+    chunk = _row_chunk(t * k, num_experts) if impl == "pallas" else 1
+    source, slot, group_sizes, tile_expert, tile_rows = group_rows(indices, num_experts, chunk)
+    layout = dict(tile_expert=tile_expert, tile_rows=tile_rows, chunk=chunk, impl=impl)
+    gate_up, down = (None, None) if scales is None else (scales[:2], scales[2:])
+    hidden = grouped_matmul(tokens[source], (w_gate, w_up), group_sizes, scales=gate_up, **layout)
+    out = grouped_matmul(hidden, (w_down,), group_sizes, scales=down, **layout)
+    # a token's k rows, weighted and summed in the order of its choices
+    return jnp.sum(out[slot].reshape(t, k, -1) * weights.astype(out.dtype)[..., None], axis=1)
+
+
 def _swiglu_experts(x, w_gate, w_up, w_down):
     """x: [E, C, d]; w_*: [E, d, h] / [E, h, d] -> [E, C, d]."""
     gated = jax.nn.silu(jnp.einsum("ecd,edh->ech", x, w_gate))
     up = jnp.einsum("ecd,edh->ech", x, w_up)
     return jnp.einsum("ech,ehd->ecd", gated * up, w_down)
+
+
+def dense_expert_mlp(tokens, weights, indices, w_gate, w_up, w_down, *, scales=None):
+    """The experts' SwiGLU by one-hot dispatch, every expert on every token
+    and the unrouted products masked to zero: static shapes, differentiable,
+    and GSPMD inserts the collectives when the expert dim is sharded. Same
+    arguments and result as :func:`grouped_expert_mlp`."""
+    dtype = tokens.dtype
+    dispatch = jax.nn.one_hot(indices, w_gate.shape[0], dtype=dtype)
+    combine = jnp.einsum("tke,tk->te", dispatch, weights.astype(dtype))  # [T, E] routing weight
+    mask = (combine > 0).astype(dtype)
+    expert_in = jnp.einsum("te,td->etd", mask, tokens)
+    if scales is None:
+        expert_out = _swiglu_experts(expert_in, w_gate, w_up, w_down)
+    else:
+        # int8->compute-dtype converts fuse into the einsums (HBM reads
+        # stay int8); accumulate fp32 and apply the fp32 scale BEFORE
+        # the single cast down — same recipe as QuantizedDenseGeneral
+        def qmm(x, w_q, w_s):
+            y = jnp.einsum(
+                "etd,edh->eth", x, w_q.astype(dtype),
+                preferred_element_type=jnp.float32,
+            )
+            return (y * w_s[:, None, :]).astype(dtype)
+
+        gated = jax.nn.silu(qmm(expert_in, w_gate, scales[0]))
+        up = qmm(expert_in, w_up, scales[1])
+        expert_out = qmm(gated * up, w_down, scales[2])
+    return jnp.einsum("etd,te->td", expert_out, combine)
 
 
 def expert_parallel_moe_sharded(
@@ -212,13 +539,18 @@ def expert_parallel_moe(
 
 
 class MoEMlp(nn.Module):
-    """Expert-parallel SwiGLU MLP block.
+    """Mixture-of-experts SwiGLU MLP block; every routed token is processed
+    (no capacity drops).
 
-    Weight shapes carry a leading expert dim — shard it with a
-    ``PartitionRule(r"moe/.*", ("expert", ...))`` to get expert parallelism
-    on the mesh (GSPMD inserts the dispatch collectives; every routed
-    token is processed — no capacity drops). For explicit capacity-bucketed
-    all_to_all dispatch use the functional
+    Weight shapes carry a leading expert dim. Float experts take the dense
+    dispatch: shard the expert dim with a ``PartitionRule(r"moe/.*",
+    ("expert", ...))`` to get expert parallelism on the mesh (GSPMD inserts
+    the dispatch collectives), and differentiate it as it is. Int8 experts
+    (``quantized``, the serving path) take the grouped dispatch where
+    :func:`dispatch_plan` says so from the static row count; on a TPU that
+    is a Pallas kernel, which JAX refuses to partition: under a mesh the
+    trace can see they fall back to the dense dispatch. For explicit
+    capacity-bucketed all_to_all dispatch use the functional
     :func:`expert_parallel_moe` / :func:`expert_parallel_moe_sharded` ops:
     their expert-sharded weight shapes cannot be created by module init
     outside ``shard_map``, so they are not a module knob.
@@ -253,22 +585,18 @@ class MoEMlp(nn.Module):
                                (self.num_experts, n), jnp.float32)
                 return q, s
 
-            gate_q, gate_s = qparam("w_gate", d, self.hidden_dim)
-            up_q, up_s = qparam("w_up", d, self.hidden_dim)
-            down_q, down_s = qparam("w_down", self.hidden_dim, d)
+            pairs = [
+                qparam("w_gate", d, self.hidden_dim), qparam("w_up", d, self.hidden_dim),
+                qparam("w_down", self.hidden_dim, d),
+            ]
+            experts, scales = zip(*pairs)
         else:
-            w_gate = self.param(
-                "w_gate", nn.initializers.lecun_normal(),
-                (self.num_experts, d, self.hidden_dim), self.dtype,
-            )
-            w_up = self.param(
-                "w_up", nn.initializers.lecun_normal(),
-                (self.num_experts, d, self.hidden_dim), self.dtype,
-            )
-            w_down = self.param(
-                "w_down", nn.initializers.lecun_normal(),
-                (self.num_experts, self.hidden_dim, d), self.dtype,
-            )
+            h = self.hidden_dim
+            shapes = {"w_gate": (d, h), "w_up": (d, h), "w_down": (h, d)}
+            experts, scales = [
+                self.param(name, nn.initializers.lecun_normal(), (self.num_experts, *shape), self.dtype)
+                for name, shape in shapes.items()
+            ], None
 
         gate_logits = tokens @ router_kernel.astype(tokens.dtype)
         weights, indices, aux_loss, (routing_frac, gate_frac) = top_k_routing(
@@ -282,31 +610,9 @@ class MoEMlp(nn.Module):
         # (flax drops the sow) unless "moe_stats" is made mutable.
         self.sow("moe_stats", "fractions", jnp.stack([routing_frac, gate_frac]))
 
-        # dense one-hot dispatch: static shapes, collectives inserted by
-        # GSPMD when the expert dim is sharded
-        dispatch = jax.nn.one_hot(indices, self.num_experts, dtype=self.dtype)
-        # [T, k, E] x [T, d] -> per-expert token batches [E, T, d] weighted later
-        combine = jnp.einsum("tke,tk->te", dispatch, weights.astype(self.dtype))
-
-        mask = (combine > 0).astype(self.dtype)
-        expert_in = jnp.einsum("te,td->etd", mask, tokens.astype(self.dtype))
-        if self.quantized:
-            # int8->compute-dtype converts fuse into the einsums (HBM reads
-            # stay int8); accumulate fp32 and apply the fp32 scale BEFORE
-            # the single cast down — same recipe as QuantizedDenseGeneral
-            def qmm(x, w_q, w_s):
-                y = jnp.einsum(
-                    "etd,edh->eth", x, w_q.astype(self.dtype),
-                    preferred_element_type=jnp.float32,
-                )
-                return (y * w_s[:, None, :]).astype(self.dtype)
-
-            gated = jax.nn.silu(qmm(expert_in, gate_q, gate_s))
-            up = qmm(expert_in, up_q, up_s)
-            expert_out = qmm(gated * up, down_q, down_s)
-        else:
-            expert_out = _swiglu_experts(expert_in, w_gate, w_up, w_down)
-        out = jnp.einsum("etd,te->td", expert_out, combine)
+        plan = dispatch_plan(b * s, self.num_experts, self.num_selected, quantized=self.quantized)
+        mlp = dense_expert_mlp if plan["dispatch"] == "dense" else grouped_expert_mlp
+        out = mlp(tokens.astype(self.dtype), weights, indices, *experts, scales=scales)
         return out.reshape(b, s, d).astype(self.dtype), aux_loss
 
 

@@ -1898,6 +1898,15 @@ class DecodeEngine:
             operand_bytes = getattr(self.module, "step_operand_bytes", None)
             if operand_bytes is not None:
                 out["state"]["step_operand_bytes"] = operand_bytes(self.slots)
+        moe_dispatch = getattr(self.module, "moe_dispatch", None)
+        if moe_dispatch is not None and moe_dispatch(self.slots) is not None:
+            # what each compiled program's expert layers do with its rows:
+            # the dispatch, and the rows computed over the rows routed
+            chunk = self.prefill_chunk or self.buckets[-1]
+            out["moe"] = {
+                "decode_chunk": moe_dispatch(self.slots * self._round_stride),
+                **{f"prefill_{b}": moe_dispatch(min(b, chunk)) for b in self.buckets},
+            }
         if self._usage is not None:
             # the compact per-tenant view (GET /debug/usage has the
             # full per-tenant resource vectors)
