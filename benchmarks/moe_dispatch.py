@@ -1,12 +1,19 @@
-"""One Mixtral expert layer alone: the dense dispatch beside the grouped one.
+"""One expert layer alone: the dense dispatch beside the grouped one.
 
-    chiprun -- env PYTHONPATH=. python3 benchmarks/moe_dispatch.py    # the tree's code
+    chiprun -- env PYTHONPATH=. python3 benchmarks/moe_dispatch.py    # the tree's code, Mixtral's layer
     chiprun -- env PYTHONPATH=. python3 benchmarks/moe_dispatch.py --tokens 256 --chunk 64 --tiles 1024,1024
+    chiprun -- env PYTHONPATH=. python3 benchmarks/moe_dispatch.py --experts 64 --top-k 4 --hidden 2048 \\
+        --width 1536 --router sigmoid --layers 12 --tokens 32 512 4096 --chunk 0 16 32 --live 14
 
 One JSON line a case: microseconds a layer, from a jitted loop of ``--reps``
 passes over ``--layers`` layers, timed on the host's clock around
 ``block_until_ready``. The shape is ``chipbench``'s ``mixtral_chat_decode``:
-hidden 4096, 8 experts of 14336, top-2, int8 weights, bfloat16 rows. Every
+hidden 4096, 8 experts of 14336, top-2, int8 weights, bfloat16 rows, unless
+``--experts`` / ``--top-k`` / ``--hidden`` / ``--width`` / ``--router`` give
+another layer (``glm_flash_code_context_decode``'s: 64 experts of 1536 on
+2048, top-4, the sigmoid router). ``--live N``: only the first N rows are
+distinct and the rest repeat one row, as a decode chunk's dead slots all hold
+the pad token and route alike (the experts touched are then the live rows'). Every
 layer has its own weights (1.41 GB; eight of them, as the cell holds): one
 layer's weights carried through a loop are not what a model reads. A layer
 is the router, the routing, and the experts' SwiGLU, its input made from the
@@ -54,8 +61,12 @@ def _layer_weights(rng, d, h, experts):
     }
 
 
-def _layer(mlp, k, x, p):
-    weights, indices, _ = moe.top_k_routing(x @ p["router"], k)
+def _layer(mlp, k, x, p, router="softmax"):
+    if router == "sigmoid":
+        logits = jnp.matmul(x.astype(jnp.float32), p["router"].astype(jnp.float32))
+        weights, indices = moe.sigmoid_top_k_routing(logits, jnp.zeros((logits.shape[-1],)), k, scaling=1.8)
+    else:
+        weights, indices, _ = moe.top_k_routing(x @ p["router"], k)
     out = mlp(x, weights, indices, *p["w"], scales=p["scales"]).astype(jnp.float32)
     # the next layer's rows: unit scale again, and a function of this output
     return (out * jax.lax.rsqrt(jnp.mean(out * out, axis=-1, keepdims=True) + 1e-6)).astype(x.dtype)
@@ -77,7 +88,13 @@ def main():
     ap.add_argument("--reps", type=int, default=10)
     ap.add_argument("--layers", type=int, default=8)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--chunk", type=int, nargs="*", default=[], help="row tiles to try, not the op's own")
+    ap.add_argument("--experts", type=int, default=8)
+    ap.add_argument("--top-k", type=int, default=2)
+    ap.add_argument("--hidden", type=int, default=4096, help="the model's width")
+    ap.add_argument("--width", type=int, default=14336, help="an expert's width")
+    ap.add_argument("--router", choices=("softmax", "sigmoid"), default="softmax")
+    ap.add_argument("--live", type=int, default=0, help="distinct rows; the rest repeat one (0: all)")
+    ap.add_argument("--chunk", type=int, nargs="*", default=[], help="row tiles to try (0: the op's own)")
     ap.add_argument("--tiles", nargs="*", default=[], help="weight tiles k,n to try, not the op's own")
     ap.add_argument("--ragged-dot", action="store_true")
     ap.add_argument("--skip-dense", action="store_true")
@@ -86,7 +103,9 @@ def main():
     device = jax.devices()[0]
     if device.platform != "tpu" and not args.rehearse:
         raise SystemExit(f"needs a TPU, found {device.platform}")
-    d, h, experts, k = (256, 512, 8, 2) if args.rehearse else (4096, 14336, 8, 2)
+    d, h, experts, k = (args.hidden, args.width, args.experts, args.top_k)
+    if args.rehearse:
+        d, h, experts, k = 256, 512, 8, 2
     reps, layers = (1, 2) if args.rehearse else (args.reps, args.layers)
     rng = np.random.default_rng(args.seed)
     params = [_layer_weights(rng, d, h, experts) for _ in range(layers)]
@@ -95,12 +114,14 @@ def main():
     cases = [] if args.skip_dense else [("dense", moe.dense_expert_mlp, None, None)]
     grouped = functools.partial(moe.grouped_expert_mlp, impl="pallas")
     tiles = [tuple(int(n) for n in t.split(",")) for t in args.tiles] or [None]
-    cases += [("grouped", grouped, c, t) for c in (args.chunk or [None]) for t in tiles]
+    cases += [("grouped", grouped, c or None, t) for c in (args.chunk or [None]) for t in tiles]
     if args.ragged_dot:
         cases.append(("ragged_dot", functools.partial(moe.grouped_expert_mlp, impl="ragged_dot"), None, None))
     row_chunk, pallas = moe._row_chunk, moe._grouped_matmul_pallas
     for tokens in args.tokens:
         x = jnp.asarray(rng.standard_normal((tokens, d)), jnp.bfloat16)
+        if args.live:
+            x = jnp.where(jnp.arange(tokens)[:, None] < args.live, x, x[-1:])
         for what, mlp, chunk, tile in cases:
             moe._row_chunk = row_chunk if chunk is None else (lambda rows, e, chunk=chunk: chunk)
             moe._grouped_matmul_pallas = pallas if tile is None else functools.partial(pallas, tiles=tile)
@@ -109,7 +130,7 @@ def main():
             def loop(x, params, mlp=mlp):
                 def body(_, x):
                     for p in params:
-                        x = _layer(mlp, k, x, p)
+                        x = _layer(mlp, k, x, p, args.router)
                     return x
                 return jax.lax.fori_loop(0, reps, body, x)
 
@@ -117,7 +138,8 @@ def main():
                 us, us_min = _time(loop, x, params, reps * layers)
                 # one layer's rows against the dense dispatch's, in units of their spread
                 got, want = (
-                    jax.jit(functools.partial(_layer, m, k))(x, params[0]).astype(jnp.float32)
+                    jax.jit(functools.partial(_layer, m, k, router=args.router))(x, params[0])
+                    .astype(jnp.float32)
                     for m in (mlp, moe.dense_expert_mlp)
                 )
                 off = round(float(jnp.max(jnp.abs(got - want)) / jnp.std(want)), 4)
@@ -131,6 +153,11 @@ def main():
                     "dense": experts / k, "ragged_dot": 1.0,
                 }.get(what) or round(moe._padded_rows(tokens * k, experts, chunk) / (tokens * k), 3),
                 "weights_us_at_hbm_rate": round(1e6 * layer_bytes / HBM_BYTES_PER_S, 1),
+                "experts_touched_expected": round(
+                    moe.expected_experts_touched(args.live or tokens, experts, k), 1
+                ),
+                "experts": experts, "top_k": k, "hidden": d, "width": h, "router": args.router,
+                "live": args.live,
                 "routed_flops_us_at_peak": round(1e6 * 2 * tokens * k * 3 * d * h / MXU_FLOPS, 1),
                 "layers": layers, "device": device.device_kind, "platform": device.platform,
             }), flush=True)

@@ -4,6 +4,7 @@
         [--match gdn] [--top 3] [--text chunk.hlo]
     python scripts/compiled_chunk.py chipbench/configs/mixtral-8x7b-int8.json \\
         --program prefill --bucket 256 [--match moe]
+    python scripts/compiled_chunk.py chipbench/configs/glm-4.7-flash-int8.json [--match attn]
 
 Builds the ``DecodeEngine`` a ``chipbench`` cell serves with (the
 configuration's adapter and its ``serving`` block, as
@@ -95,7 +96,7 @@ def compiled_chunk_text(config_path: str, program: str = "decode", bucket: int |
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from unionml_tpu.ops import gated_delta, moe, paged_attention
+    from unionml_tpu.ops import flash_attention, gated_delta, moe, paged_attention
     from unionml_tpu.serving.engine import DecodeEngine
 
     try:
@@ -104,7 +105,7 @@ def compiled_chunk_text(config_path: str, program: str = "decode", bucket: int |
         raise SystemExit(f"compiled_chunk: no TPU compiler here, nothing compiled ({exc!r})") from None
     chip = SingleDeviceSharding(topo.devices[0])
     jax.config.update("jax_enable_compilation_cache", False)  # unreadable without the chip
-    for module in (gated_delta, moe, paged_attention):  # off their CPU branch
+    for module in (flash_attention, gated_delta, moe, paged_attention):  # off their CPU branch
         module._interpret = lambda: False
 
     cfg = json.loads(Path(config_path).read_text())

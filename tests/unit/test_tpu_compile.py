@@ -374,3 +374,75 @@ def test_moe_layer_of_a_prefill_holds_no_array_of_every_expert(chip):
     text = jax.jit(layer.apply).lower(params, x).compile().as_text()
     assert len(re.findall(r"%moe_grouped_matmul(?:\.\d+)* = ", text)) == 2
     _no_array_of_every_expert(text, tokens, **_MIXTRAL_LAYER)
+
+
+# ---- the glm_flash_code_context_decode cell's kernels (PR 36): absorbed
+# latent attention at 32 rows x 20 heads over latent rows of 576 values held
+# in 640 lanes, a 4.0 GB pool of 16-row blocks and a table 293 wide (4,096 +
+# 512 + the in-flight chunks' spare rows); whole prompts' expanded attention
+# at head width 256; the sigmoid-routed experts (64, top-4, 2048 x 1536) at
+# the decode chunk's rows and the largest bucket
+@pytest.mark.parametrize("blocks,width", [(15_024, 293), (512, 11)], ids=["cell-32x293", "short-table"])
+def test_paged_latent_attention_compiles(chip, blocks, width):
+    from unionml_tpu.models.layers import LatentRows
+
+    row = LatentRows(512, 64)
+    assert row.stored_width == 640
+
+    def fn(q, pool, table, lengths):
+        return paged_attention.paged_latent_attention(
+            q, pool, table, lengths, value_dim=512, scale=256 ** -0.5, impl="pallas",
+        )
+
+    text = _assert_mosaic(
+        chip, fn, ((32, 20, 640), jnp.bfloat16), ((blocks, 16, 640), jnp.bfloat16),
+        ((32, width), jnp.int32), ((32,), jnp.int32),
+    )
+    # chipbench's latent_attn_ms_per_step finds the kernel by this name
+    assert re.search(r"%paged_latent_attention(\.\d+)* = ", text)
+    # the pool goes to the kernel as it lies: no copy, no relayout of it
+    assert not re.search(rf"bf16\[{blocks},16,640\]\S* (?:copy|transpose)\(", text)
+
+
+def test_a_latent_pool_row_of_576_lanes_is_refused_by_the_chip(chip):
+    """Why ``LatentRows`` stores 640: the chip lays 576 out in 640 lanes and
+    its kernels copy whole lane tiles only."""
+    with pytest.raises(Exception, match="aligned to tiling"):
+        jax.jit(
+            lambda q, pool, t, n: paged_attention.paged_latent_attention(
+                q, pool, t, n, value_dim=512, scale=0.0625, impl="pallas")
+        ).lower(*[
+            jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in (
+                ((32, 20, 576), jnp.bfloat16), ((512, 16, 576), jnp.bfloat16),
+                ((32, 11), jnp.int32), ((32,), jnp.int32))
+        ]).compile()
+
+
+def test_flash_attention_at_the_latent_models_expanded_width_compiles(chip):
+    qkv = ((1, 4096, 20, 256), jnp.bfloat16)
+    _assert_mosaic(
+        chip,
+        lambda q, k, v, pads: flash_attention.flash_attention(
+            q, k, v, causal=True, scale=256 ** -0.5, kv_valid_start=pads),
+        qkv, qkv, qkv, ((1,), jnp.int32),
+    )
+
+
+_GLM_LAYER = dict(d=2048, hidden=1536, experts=64, selected=4)
+
+
+@pytest.mark.parametrize("tokens", [32, 512, 4096], ids=["decode_32_slots", "prefill_512", "prefill_4096"])
+def test_moe_grouped_matmul_compiles_at_64_experts_top_4(chip, tokens):
+    d, hidden, experts, selected = _GLM_LAYER.values()
+
+    def mlp(x, weights, indices, w_gate, w_up, w_down, *scales):
+        return moe.grouped_expert_mlp(x, weights, indices, w_gate, w_up, w_down, scales=scales, impl="pallas")
+
+    text = _assert_mosaic(
+        chip, mlp,
+        ((tokens, d), jnp.bfloat16), ((tokens, selected), jnp.float32), ((tokens, selected), jnp.int32),
+        ((experts, d, hidden), jnp.int8), ((experts, d, hidden), jnp.int8), ((experts, hidden, d), jnp.int8),
+        ((experts, hidden), jnp.float32), ((experts, hidden), jnp.float32), ((experts, d), jnp.float32),
+    )
+    assert len(re.findall(r"%moe_grouped_matmul(?:\.\d+)* = ", text)) == 2
+    _no_array_of_every_expert(text, tokens, **_GLM_LAYER)

@@ -27,6 +27,11 @@ from unionml_tpu.models.llama import (
     init_cache,
 )
 from unionml_tpu.models.olmo_hybrid import OlmoHybrid, OlmoHybridConfig
+from unionml_tpu.models.glm_moe_lite import (
+    GLM_MOE_LITE_QUANT_PATTERNS,
+    GlmMoeLite,
+    GlmMoeLiteConfig,
+)
 from unionml_tpu.models.encdec import (
     ENCDEC_PARTITION_RULES,
     EncDecConfig,
@@ -101,6 +106,7 @@ __all__ = [
     "BERT_PARTITION_RULES", "make_mlm_batch", "mlm_step",
     "Llama", "LlamaConfig", "init_cache", "LLAMA_PARTITION_RULES",
     "OlmoHybrid", "OlmoHybridConfig",
+    "GlmMoeLite", "GlmMoeLiteConfig", "GLM_MOE_LITE_QUANT_PATTERNS",
     "EncoderDecoder", "EncDecConfig", "ENCDEC_PARTITION_RULES",
     "init_decoder_cache", "make_seq2seq_generator", "make_seq2seq_predictor", "seq2seq_step",
     "LLAMA_QUANT_PARTITION_RULES", "LLAMA_MOE_PARTITION_RULES",

@@ -40,6 +40,8 @@ Dtype = Any
 # A cache-capable decoder has a ``cache_layout()`` method that returns one
 # of these per layer, in layer order; its ``__call__`` takes and returns a
 # per-layer ``cache`` tuple whose entries are what ``init`` builds.
+# ``owns_rows`` says which residency a paged engine gives the layer: rows
+# of its block pool (``KVRows``, ``LatentRows``) or a state per slot.
 
 
 @dataclass(frozen=True)
@@ -51,6 +53,8 @@ class KVRows:
     kv_heads: int
     head_dim: int
     quantized: bool = False
+    owns_rows = True  # a row a token: a paged engine's pool holds them
+    kind = "kv"
 
     def init(self, batch: int, rows: int, dtype: Dtype = jnp.bfloat16):
         shape = (batch, rows, self.kv_heads, self.head_dim)
@@ -65,6 +69,63 @@ class KVRows:
         per_head = (self.head_dim + 4) if self.quantized else 2 * self.head_dim
         return 2 * self.kv_heads * per_head
 
+    def pool_row_nbytes(self) -> int:
+        """What a paged engine budgets a position at (the served models'
+        head widths are whole 128-lane tiles: the chip pads nothing)."""
+        return self.row_nbytes()
+
+    @property
+    def pool_row(self) -> Tuple[int, int, int]:
+        """(heads, width, bytes a value) of a pool buffer's row: what the
+        paged kernel's group size follows from."""
+        return self.kv_heads, self.head_dim, 1 if self.quantized else 2
+
+
+@dataclass(frozen=True)
+class LatentRows:
+    """A layer whose cache grows by one row a token, and the row is the
+    *latent* that all heads' keys and values are projections of (latent
+    attention, DeepSeek-V2's MLA): ``latent_dim`` compressed values
+    followed by ``rope_dim`` rotated key values that every head shares,
+    bf16, in one buffer whose rows are ``stored_width`` wide: whole tiles
+    of 128 lanes, the last columns zero. (The chip lays a 576-wide minor
+    axis out in 640 lanes anyway, as it would a 512 and a 64 buffer, and
+    its kernels copy whole tiles only: the padding is made explicit, at
+    no cost in bytes.) The same lifetime as :class:`KVRows`: a paged
+    engine gives it rows of its block pool, commits a prefill by block
+    scatter, and a block prefix restores a sequence."""
+
+    latent_dim: int
+    rope_dim: int
+    dtype: str = "bfloat16"  # one row feeds every head's keys and values
+    owns_rows = True
+    kind = "latent"
+
+    @property
+    def width(self) -> int:
+        return self.latent_dim + self.rope_dim
+
+    @property
+    def stored_width(self) -> int:
+        return -(-self.width // 128) * 128
+
+    def init(self, batch: int, rows: int, dtype: Optional[Dtype] = None):
+        return (jnp.zeros((batch, rows, self.stored_width), jnp.dtype(dtype or self.dtype)),)
+
+    def row_nbytes(self) -> int:
+        """Bytes of the values one cached position holds in this layer."""
+        return jnp.dtype(self.dtype).itemsize * self.width
+
+    def pool_row_nbytes(self) -> int:
+        """Bytes a position takes as stored, which is what a paged engine
+        budgets (576 bfloat16 values in 640 lanes: 1,280)."""
+        return jnp.dtype(self.dtype).itemsize * self.stored_width
+
+    @property
+    def pool_row(self) -> Tuple[int, int, int]:
+        """As ``KVRows.pool_row``: one head, the row as stored."""
+        return 1, self.stored_width, jnp.dtype(self.dtype).itemsize
+
 
 @dataclass(frozen=True)
 class SlotState:
@@ -76,6 +137,8 @@ class SlotState:
 
     shapes: Tuple[Tuple[int, ...], ...]
     dtypes: Tuple[str, ...]
+    owns_rows = False  # one state per slot, whatever the sequence's length
+    kind = "state"
 
     def init(self, batch: int, rows: int = 0):
         """``rows`` is taken for ``KVRows.init``'s sake: the state has none."""

@@ -89,6 +89,27 @@ def top_k_routing(
     return weights, indices, aux_loss
 
 
+def sigmoid_top_k_routing(
+    gate_logits: jnp.ndarray,
+    selection_bias: jnp.ndarray,
+    num_selected: int,
+    *,
+    scaling: float = 1.0,
+):
+    """Sigmoid routing with a selection-only bias (DeepSeek-V3's
+    ``noaux_tc``, one expert group): every expert scores ``sigmoid(logit)``
+    on its own, the ``num_selected`` experts are the top of ``score +
+    selection_bias``, and their weights are the *scores* (the bias picks
+    and does not weigh) divided by their sum and multiplied by ``scaling``.
+    All of it float32. gate_logits: [tokens, experts]; selection_bias:
+    [experts]. Returns (weights [T, k] float32, indices [T, k])."""
+    scores = jax.nn.sigmoid(gate_logits.astype(jnp.float32))
+    _, indices = jax.lax.top_k(scores + selection_bias.astype(jnp.float32).reshape(-1), num_selected)
+    weights = jnp.take_along_axis(scores, indices, axis=-1)
+    weights = weights / (weights.sum(-1, keepdims=True) + 1e-20) * scaling
+    return weights, indices
+
+
 def expert_capacity(
     tokens: int, num_experts: int, num_selected: int, capacity_factor: float
 ) -> int:
@@ -149,43 +170,96 @@ def _mesh_in_sight() -> bool:
 _MXU_ROWS = 128  # the side of the chip's matrix unit
 
 
+_MIN_ROW_TILE = 16  # a bfloat16 tile's sublanes
+
+
 def _row_chunk(rows: int, num_experts: int) -> int:
     """Rows of the kernel's row tile for ``rows`` routed pairs. The MXU
     loads a weight tile in the time ``_MXU_ROWS`` rows take to stream, so a
     shorter tile costs what that one does and two of them twice as much:
-    the tile is the MXU's side, or half of it where an expert's rows fit
-    that (even routing deals it a quarter), which halves the padding."""
-    return _MXU_ROWS if 4 * rows > num_experts * _MXU_ROWS else _MXU_ROWS // 2
+    the tile is the MXU's side, or the smallest power of two under it that
+    holds twice what even routing deals an expert (``rows / num_experts``),
+    which cuts the padding and the rows held in VMEM (many small experts:
+    64 experts' 128 pairs of a decode chunk lie in 1,088 rows of 16-row
+    tiles, in 4,160 of 64-row ones)."""
+    tile = _MXU_ROWS
+    while tile > _MIN_ROW_TILE and 4 * rows <= num_experts * tile:
+        tile //= 2
+    return tile
 
 
-def dispatch_plan(tokens: int, num_experts: int, num_selected: int, *, quantized: bool) -> dict:
-    """What :class:`MoEMlp` does with ``tokens`` rows, from static shapes:
-    the dispatch, the rows the experts' matmuls compute and the rows the
-    router sent (``tokens x num_selected``). The dense dispatch runs every
-    expert on every token; the grouped one pads each expert's rows to the
-    kernel's row tile, counted here at its worst (every expert's last tile
-    holding one row).
+def expected_experts_touched(tokens: int, num_experts: int, num_selected: int) -> float:
+    """Experts that hold a routed row, expected under even routing: each
+    of ``tokens`` rows draws ``num_selected`` distinct experts."""
+    return num_experts * (1.0 - (1.0 - num_selected / num_experts) ** tokens)
+
+
+# Up to ``_MXU_ROWS`` tokens the dense einsums run at the weight read's
+# pace, so the grouped dispatch can only win by the experts no row routes
+# to, whose weights it does not read; it pays a sort, two gathers and a
+# grid for that (~50 us a layer). Measured at 64 experts top-4 of 2048 x
+# 1536 (PERF.md section 6, PR 36; us a layer, dense / grouped at 16-row
+# tiles): 32 rows, 87 % of the experts touched, 815 / 759; the same chunk
+# with 14 rows live, 60 % touched, 815 / 597; 128 rows, every expert
+# touched, 968 / 976. So the grouped dispatch serves such a program where
+# fewer than this share of the experts is expected to hold a row ...
+_DENSE_FROM_TOUCHED_SHARE = 0.9
+# ... among this many experts or more: with fewer (8 experts top-2, where
+# 8 rows already touch 90 %) the few-row programs are no cell's, nothing
+# was measured, and the plan is left as PR 34 measured it.
+_SPARSE_CHUNK_MIN_EXPERTS = 16
+
+
+def dispatch_plan(
+    tokens: int, num_experts: int, num_selected: int, *, quantized: bool,
+    model_dim: Optional[int] = None, hidden_dim: Optional[int] = None,
+) -> dict:
+    """What :class:`MoEMlp` does with ``tokens`` rows, from static shapes
+    (experts, top-k, rows, and the experts' widths where given): the
+    dispatch, the rows the experts' matmuls compute and the rows the router
+    sent (``tokens x num_selected``) and, with the widths, the experts
+    expected to hold a routed row and the bytes of expert weights the
+    layer reads (one byte a weight when ``quantized``, else two).
+    The dense dispatch runs every expert on every token; the grouped one
+    pads each expert's rows to the kernel's row tile, counted here at its
+    worst (every expert's last tile holding one row), and reads the
+    weights of the experts that hold a row.
 
     On a TPU the grouped kernel serves programs of more than ``_MXU_ROWS``
     tokens. Up to there every expert's matmul streams no more rows than a
     weight tile takes to load, so the dense einsums are at the weight
     read's pace already and nothing of the sort, the gathers and the grid
-    is paid (a decode chunk's slot rows; measured in PERF.md section 6).
+    is paid (a decode chunk's slot rows; measured in PERF.md section 6),
+    unless the rows meet so many experts that a tenth or more of them hold
+    no row and need not be read (``_DENSE_FROM_TOUCHED_SHARE``: a 32-row
+    chunk at 64 experts top-4).
     """
     routed, on_chip = tokens * num_selected, not _interpret()
-    if not quantized or _mesh_in_sight() or (on_chip and tokens <= _MXU_ROWS):
-        dispatch, computed = "dense", tokens * num_experts
+    touched = expected_experts_touched(tokens, num_experts, num_selected)
+    weight_read = on_chip and tokens <= _MXU_ROWS and (
+        num_experts < _SPARSE_CHUNK_MIN_EXPERTS
+        or touched >= _DENSE_FROM_TOUCHED_SHARE * num_experts
+    )
+    if not quantized or _mesh_in_sight() or weight_read:
+        dispatch, computed, read = "dense", tokens * num_experts, float(num_experts)
     elif on_chip:
         chunk = _row_chunk(routed, num_experts)
-        dispatch, computed = "grouped:moe_grouped_matmul", _padded_rows(routed, num_experts, chunk)
+        dispatch, computed, read = (
+            "grouped:moe_grouped_matmul", _padded_rows(routed, num_experts, chunk), touched,
+        )
     else:
-        dispatch, computed = "grouped:ragged_dot", routed
-    return {
+        dispatch, computed, read = "grouped:ragged_dot", routed, touched
+    plan = {
         "dispatch": dispatch,
         "expert_rows_routed": routed,
         "expert_rows_computed": computed,
         "computed_over_routed": round(computed / routed, 3),
     }
+    if model_dim and hidden_dim:
+        per_expert = 3 * model_dim * hidden_dim * (1 if quantized else 2)
+        plan["experts_touched"] = round(touched, 2)
+        plan["expert_bytes_read"] = int(read * per_expert)
+    return plan
 
 
 def _padded_rows(rows: int, num_experts: int, chunk: int) -> int:
@@ -562,6 +636,12 @@ class MoEMlp(nn.Module):
     model_dim: int
     dtype: jnp.dtype = jnp.bfloat16
     quantized: bool = False  # int8 weight-only experts (serving path)
+    # the published router: "softmax" (:func:`top_k_routing`, Mixtral) or
+    # "sigmoid" (:func:`sigmoid_top_k_routing`: a float32 router with the
+    # selection-only ``e_score_correction_bias`` and ``routed_scaling``;
+    # no auxiliary loss)
+    router: str = "softmax"
+    routed_scaling: float = 1.0
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -598,17 +678,33 @@ class MoEMlp(nn.Module):
                 for name, shape in shapes.items()
             ], None
 
-        gate_logits = tokens @ router_kernel.astype(tokens.dtype)
-        weights, indices, aux_loss, (routing_frac, gate_frac) = top_k_routing(
-            gate_logits, self.num_selected, return_stats=True
-        )
+        if self.router == "sigmoid":
+            # [experts, 1]: a column, so that seeded fills give it the
+            # deviation of a kernel's column and not a vector's
+            bias = self.param(
+                "e_score_correction_bias", nn.initializers.zeros, (self.num_experts, 1), jnp.float32,
+            )
+            gate_logits = jnp.matmul(
+                tokens.astype(jnp.float32), router_kernel, precision=lax.Precision.HIGHEST,
+            )
+            weights, indices = sigmoid_top_k_routing(
+                gate_logits, bias, self.num_selected, scaling=self.routed_scaling,
+            )
+            aux_loss = jnp.zeros((), jnp.float32)
+        elif self.router == "softmax":
+            gate_logits = tokens @ router_kernel.astype(tokens.dtype)
+            weights, indices, aux_loss, (routing_frac, gate_frac) = top_k_routing(
+                gate_logits, self.num_selected, return_stats=True
+            )
 
-        # the load-balance loss is a product of token-MEAN stats, so it is
-        # not additive across sequence shards — sow the raw fractions into
-        # a separate collection so sharded consumers (sequence_parallel)
-        # can pmean them globally before re-forming E*sum(rf*gf). A no-op
-        # (flax drops the sow) unless "moe_stats" is made mutable.
-        self.sow("moe_stats", "fractions", jnp.stack([routing_frac, gate_frac]))
+            # the load-balance loss is a product of token-MEAN stats, so it is
+            # not additive across sequence shards — sow the raw fractions into
+            # a separate collection so sharded consumers (sequence_parallel)
+            # can pmean them globally before re-forming E*sum(rf*gf). A no-op
+            # (flax drops the sow) unless "moe_stats" is made mutable.
+            self.sow("moe_stats", "fractions", jnp.stack([routing_frac, gate_frac]))
+        else:
+            raise ValueError(f"unknown router {self.router!r}")
 
         plan = dispatch_plan(b * s, self.num_experts, self.num_selected, quantized=self.quantized)
         mlp = dense_expert_mlp if plan["dispatch"] == "dense" else grouped_expert_mlp

@@ -82,7 +82,10 @@ from jax.experimental import pallas as pl
 
 NEG_INF = -1e30
 
-__all__ = ["paged_attention", "paged_attention_reference"]
+__all__ = [
+    "latent_attention", "paged_attention", "paged_attention_reference",
+    "paged_latent_attention", "paged_latent_attention_reference",
+]
 
 
 def _interpret() -> bool:
@@ -453,4 +456,234 @@ def paged_attention(
         q, k, v, block_table, lengths,
         k_scale=k_scale, v_scale=v_scale, scale=scale,
         interpret=_interpret(),
+    )
+
+
+# --------------------------------------------------------- latent attention
+#
+# Latent attention (DeepSeek-V2's MLA) caches one row a position that all
+# heads share: ``value_dim`` compressed values ``c_kv`` followed by the
+# rotated key values ``k_rope``. In the *absorbed* form a query head is
+# carried into the latent space (``q_lat = q_nope W_uk^T``, followed by its
+# rotated part), scores are ``q . row`` over the whole row, and the
+# weighted sum is taken over the row's first ``value_dim`` columns: the
+# pool is the keys and the values at once, every head reads the same rows,
+# and no per-head key or value exists for a cached position.
+
+
+def latent_attention(q, rows, bias, *, value_dim: int, scale: float):
+    """Absorbed latent attention in plain JAX: ``q`` [B, S, H, W] queries
+    in the latent space, ``rows`` [B, L, W] cached latents, ``bias``
+    broadcastable to [B, H, S, L] (``NEG_INF`` hides a position). Returns
+    [B, S, H, value_dim] in ``q.dtype``; float32 scores and softmax. Off
+    the TPU the operands are float32 too (the CPU's runtime refuses some
+    bfloat16 products with a float32 sum)."""
+    dtype = jnp.float32 if _interpret() else q.dtype
+    s = jnp.einsum("bshw,blw->bhsl", q.astype(dtype), rows.astype(dtype), preferred_element_type=jnp.float32)
+    p = jax.nn.softmax(s * scale + bias, axis=-1)
+    out = jnp.einsum(
+        "bhsl,blv->bshv", p.astype(dtype), rows[..., :value_dim].astype(dtype),
+        preferred_element_type=jnp.float32,
+    )
+    return out.astype(q.dtype)
+
+
+def _check_latent_shapes(q, pool, block_table, lengths, value_dim):
+    if q.ndim != 3 or pool.ndim != 3 or q.shape[-1] != pool.shape[-1]:
+        raise ValueError(
+            "q must be [batch, heads, width] and the pool [num_blocks, "
+            f"block_size, width], got {q.shape} / {pool.shape}"
+        )
+    if block_table.ndim != 2 or block_table.shape[0] != q.shape[0]:
+        raise ValueError(
+            f"block_table must be [batch, table_width], got {block_table.shape} for batch {q.shape[0]}"
+        )
+    if lengths.shape != (q.shape[0],):
+        raise ValueError(f"lengths must be [batch], got {lengths.shape}")
+    if not 0 < value_dim <= pool.shape[-1]:
+        raise ValueError(f"value_dim {value_dim} is not within the row's width {pool.shape[-1]}")
+
+
+def paged_latent_attention_reference(q, pool, block_table, lengths, *, value_dim: int, scale: float):
+    """The plain gather: the table's blocks taken into a contiguous
+    ``[B, W * block, width]`` view, then :func:`latent_attention` with the
+    columns past a row's length hidden. The CPU's path and the kernel's
+    parity anchor."""
+    _check_latent_shapes(q, pool, block_table, lengths, value_dim)
+    batch, w = block_table.shape
+    block = pool.shape[1]
+    rows = jnp.take(pool, block_table.reshape(-1), axis=0).reshape(batch, w * block, -1)
+    visible = jnp.arange(w * block)[None, :] < lengths.astype(jnp.int32)[:, None]
+    bias = jnp.where(visible, 0.0, NEG_INF)[:, None, None, :]
+    return latent_attention(q[:, None], rows, bias, value_dim=value_dim, scale=scale)[:, 0]
+
+
+_LATENT_ROWS_PER_STEP = 512  # positions a group gathers and scores
+
+
+def _latent_kernel(table_ref, len_ref, q_ref, pool, o_ref, buf, sem, state,
+                   acc_ref, m_ref, l_ref, *, scale, block, width, pages, value_dim):
+    """:func:`_paged_kernel`'s walk (a grid step a row, its groups a loop,
+    the next group's copies in flight while this one is scored) over one
+    pool whose rows are the keys and, in their first ``value_dim``
+    columns, the values, shared by every head: a group is scored by one
+    ``[heads, W] x [W, rows]`` matmul and no mask tells heads apart."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    batch = pl.num_programs(0)
+    rows = pages * block
+
+    def visible(row):
+        return jnp.clip(len_ref[row], 0, width * block)
+
+    def copies(row, grp, slot, fn):
+        def page(j, carry):
+            src = table_ref[row, grp * pages + j]
+            fn(pltpu.make_async_copy(pool.at[src], buf.at[slot, j], sem.at[slot]))
+            return carry
+        live_pages = jnp.minimum(pl.cdiv(visible(row), block) - grp * pages, pages)
+        jax.lax.fori_loop(0, live_pages, page, 0)
+
+    @pl.when(b == 0)
+    def _reset():
+        state[0] = 0
+        state[1] = 0
+
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    length = visible(b)
+    groups = pl.cdiv(length, rows)
+
+    def score(g, slot):
+        q = q_ref[0]                                       # [H, W]
+        k = buf[slot].reshape(rows, -1).astype(q.dtype)    # [rows, W]
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
+        seen = g * rows + col < length                     # [1, rows]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        ) * scale                                          # [H, rows]
+        s = jnp.where(seen, s, NEG_INF)
+        m_prev = m_ref[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
+        p = jnp.where(seen, jnp.exp(s - m_safe), 0.0)
+        corr = jnp.exp(jnp.where(m_prev == NEG_INF, NEG_INF, m_prev - m_safe))
+        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        # pages past the row's last were not copied: 0 x garbage must stay 0
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        v = jnp.where(g * rows + row < length, k[:, :value_dim], 0)
+        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
+            p.astype(q.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        )
+        m_ref[:] = m_new
+
+    @pl.when(groups > 0)
+    def _walk():
+        first = state[0]
+
+        @pl.when(state[1] == 0)
+        def _start_own():
+            copies(b, 0, first, lambda c: c.start())
+
+        nxt_b = jax.lax.while_loop(
+            lambda r: (r < batch) & (len_ref[jnp.minimum(r, batch - 1)] <= 0),
+            lambda r: r + 1,
+            b + 1,
+        )
+        has_next = nxt_b < batch
+
+        def one_group(g, slot):
+            last = g + 1 == groups
+
+            @pl.when(jnp.logical_not(last) | has_next)
+            def _start_next():
+                copies(
+                    jnp.where(last, nxt_b, b), jnp.where(last, 0, g + 1),
+                    1 - slot, lambda c: c.start(),
+                )
+
+            copies(b, g, slot, lambda c: c.wait())
+            score(g, slot)
+            return 1 - slot
+
+        state[0] = jax.lax.fori_loop(0, groups, one_group, first)
+        state[1] = has_next.astype(jnp.int32)
+
+    o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
+
+
+def _latent_pallas(q, pool, block_table, lengths, *, value_dim, scale, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+
+    batch, heads, row_width = q.shape
+    _, block, _ = pool.shape
+    w = block_table.shape[1]
+    pages = max(1, min(_LATENT_ROWS_PER_STEP // block, w))
+    # the heads are the matmuls' rows: whole bfloat16 sublane tiles of them
+    padded = -(-heads // 16) * 16
+    if padded != heads:
+        q = jnp.pad(q, ((0, 0), (0, padded - heads), (0, 0)))
+
+    def q_map(b, table, lens):
+        return (b, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(batch,),
+        in_specs=[pl.BlockSpec((1, padded, row_width), q_map), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, padded, value_dim), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((2, pages, block, row_width), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((2,), jnp.int32),
+            pltpu.VMEM((padded, value_dim), jnp.float32),
+            pltpu.VMEM((padded, 1), jnp.float32),
+            pltpu.VMEM((padded, 1), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _latent_kernel, scale=scale, block=block, width=w, pages=pages, value_dim=value_dim,
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((batch, padded, value_dim), q.dtype),
+        # a row starts the gather of the next row's first group: in order
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_latent_attention",
+    )(block_table.astype(jnp.int32), lengths.astype(jnp.int32), q, pool)
+    return out[:, :heads]
+
+
+def paged_latent_attention(q, pool, block_table, lengths, *, value_dim: int,
+                           scale: float, impl: str = "auto"):
+    """Single-step absorbed latent attention over a block-paged latent pool.
+
+    Shapes: ``q`` [B, H, W] (one query per row and head, in the latent
+    space: ``q_nope W_uk^T`` followed by the rotated part); ``pool``
+    [num_blocks, block, W] latent rows (``W = value_dim + rope_dim``: the
+    compressed values, then the shared rotated key); ``block_table``
+    [B, table_width] int32; ``lengths`` [B] int32 visible rows (0 for a row
+    whose output nobody reads: the kernel gathers nothing for it and writes
+    zeros). ``scale`` is the published softmax scale (the *expanded* head
+    width's, not ``W``'s). Returns [B, H, value_dim] in ``q.dtype``: the
+    weighted latents, which the caller carries through ``W_uv``.
+
+    ``impl``: ``"reference"`` (the plain gather), ``"pallas"`` (the kernel
+    ``paged_latent_attention``; interpreter mode off-TPU) or ``"auto"``
+    (pallas on TPU, reference elsewhere)."""
+    _check_latent_shapes(q, pool, block_table, lengths, value_dim)
+    if impl == "auto":
+        impl = "reference" if _interpret() else "pallas"
+    if impl == "reference":
+        return paged_latent_attention_reference(
+            q, pool, block_table, lengths, value_dim=value_dim, scale=scale,
+        )
+    if impl != "pallas":
+        raise ValueError(f"unknown paged latent attention impl {impl!r}")
+    return _latent_pallas(
+        q, pool, block_table, lengths, value_dim=value_dim, scale=scale, interpret=_interpret(),
     )

@@ -105,7 +105,6 @@ import numpy as np
 
 from unionml_tpu import telemetry
 from unionml_tpu._logging import logger
-from unionml_tpu.models.layers import KVRows
 from unionml_tpu.serving.faults import (
     DeadlineExceeded,
     EngineUnavailable,
@@ -551,8 +550,9 @@ class DecodeEngine:
         # keys and values, which a paged engine keeps in its block pool,
         # or a state of fixed size, which it keeps per slot
         self._layout = cache_layout(module)
-        self._owns_rows = tuple(isinstance(l, KVRows) for l in self._layout)
+        self._owns_rows = tuple(l.owns_rows for l in self._layout)
         self._state_layers = len(self._layout) - sum(self._owns_rows)
+        self._latent_layers = sum(l.kind == "latent" for l in self._layout)
         # bytes of recurrent state one slot keeps on the device
         self._state_bytes_per_slot = sum(
             l.nbytes() for l, kv in zip(self._layout, self._owns_rows) if not kv
@@ -1397,10 +1397,11 @@ class DecodeEngine:
 
     def _kv_block_nbytes(self, blk: int) -> int:
         """Device bytes of one pool block across every layer that owns
-        pool rows (``KVRows.row_nbytes``: bf16 k/v, or int8 k/v + fp32
-        per-(row, head) scales under ``kv_quant``)."""
+        pool rows (``pool_row_nbytes``: bf16 k/v, or int8 k/v + fp32
+        per-(row, head) scales under ``kv_quant``; a latent row as the
+        chip tiles it)."""
         return blk * sum(
-            l.row_nbytes() for l, kv in zip(self._layout, self._owns_rows) if kv
+            l.pool_row_nbytes() for l, kv in zip(self._layout, self._owns_rows) if kv
         )
 
     def _refuse_recurrent(self, what: str, layout=None) -> None:
@@ -1408,7 +1409,7 @@ class DecodeEngine:
         it for a module (the served one, or the one with this cache
         ``layout``) with recurrent layers."""
         layout = self._layout if layout is None else layout
-        states = sum(not isinstance(l, KVRows) for l in layout)
+        states = sum(not l.owns_rows for l in layout)
         if states:
             raise ValueError(
                 f"{what} rebuilds a sequence from its KV blocks, and "
@@ -1876,15 +1877,18 @@ class DecodeEngine:
         if self.kv_pool is not None:
             from unionml_tpu.ops.paged_attention import _pages_per_step
 
-            rows = next(l for l in self._layout if isinstance(l, KVRows))
+            rows = next(l for l in self._layout if l.owns_rows)
             out["kv_pool"] = {
                 **self.kv_pool.stats(),
+                # what a cached position costs over every layer that
+                # owns rows, as the chip tiles it, and what the row is
+                "bytes_per_token": self._kv_block_nbytes(1),
+                "row_layout": rows.kind,
                 # what the decode kernel walks: a live row's visible
                 # blocks (at most the table's width), this many a group
                 "table_width": self._table_width,
                 "kernel_blocks_per_group": _pages_per_step(
-                    self._kv_block_size, rows.kv_heads, rows.head_dim,
-                    1 if rows.quantized else 2, self._table_width,
+                    self._kv_block_size, *rows.pool_row, self._table_width,
                 ),
             }
         if self._state_layers:
@@ -2924,6 +2928,10 @@ class DecodeEngine:
                         self.kv_pool.capacity
                         if self.kv_pool is not None else 0
                     ),
+                    kv_tokens=(
+                        self.kv_pool.used_rows
+                        if self.kv_pool is not None else 0
+                    ),
                 )
         self._inflight.put(("chunk", ep0, mask, gens, toks, t_dispatch, seq))
 
@@ -3617,6 +3625,7 @@ class DecodeEngine:
             req.rid, "admit", annotation="engine.admit",
             bucket=self._bucket_for(len(req.prompt)),
             prompt_tokens=len(req.prompt), state_layers=self._state_layers,
+            latent_layers=self._latent_layers,
         ) as sp:
             step(arg)
             sp.note(cached_tokens=req._saved_tokens)

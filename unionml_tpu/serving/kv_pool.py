@@ -291,6 +291,12 @@ class KVBlockPool:
         if n > 0:
             self._m_preempted.inc(n)
 
+    @property
+    def used_rows(self) -> int:
+        """Rows holding a cached position across the blocks in use (the
+        engine's host-side estimate, as :meth:`note_used_rows` last set it)."""
+        return self._used_rows
+
     def note_used_rows(self, rows: int) -> None:
         """Update the fragmentation gauge's numerator: total rows
         actually holding KV across every in-use block (the engine's

@@ -263,6 +263,7 @@ class ServingPerfPlane:
         self._tokens = 0
         self._t0 = clock()
         self._kv_pressure = 0.0
+        self._kv_tokens = 0  # cached positions resident at the last dispatch
         self._zero_window_locked()
         self.watchdog = (
             watchdog
@@ -309,6 +310,7 @@ class ServingPerfPlane:
         prefill_mix: bool = False,
         kv_in_use: int = 0,
         kv_capacity: int = 0,
+        kv_tokens: int = 0,
         waiting: int = 0,
         admitted: int = 0,
         prefill_tokens: int = 0,
@@ -318,7 +320,8 @@ class ServingPerfPlane:
         chunk that ran while chunked admission was interleaving.
         ``waiting`` is the waiting room's depth at the dispatch — the
         chunk's empty slot-steps are *starved* when it is above 0 —
-        and ``admitted`` / ``prefill_tokens`` are the admissions
+        ``kv_tokens`` the cached positions the pool's blocks hold, and
+        ``admitted`` / ``prefill_tokens`` are the admissions
         completed and prompt tokens prefilled since the previous
         chunk."""
         occupied = min(self._slots, max(0, int(occupied)))
@@ -344,6 +347,7 @@ class ServingPerfPlane:
                 self._kv_pressure = min(
                     1.0, max(0.0, kv_in_use / kv_capacity)
                 )
+                self._kv_tokens = int(kv_tokens)
             if self._passes % _GOODPUT_FEED_EVERY == 0:
                 goodput = self._ratios_locked()[0]
         if goodput is not None:
@@ -498,6 +502,7 @@ class ServingPerfPlane:
             "occupancy_ratio": round(occupancy, 6),
             "kv_pressure_ratio": round(pressure, 6),
             "state_bytes_resident": self._state_bytes,
+            "kv_tokens_resident": self._kv_tokens,
             "tokens": tokens,
             "tokens_per_s": round(tokens / elapsed, 3),
             **window,

@@ -18,12 +18,14 @@ the prefix cache. There are two:
   each served model and a row of the visibility mask ``kv_mask``;
   ``place`` is ``None``. A speculative engine keeps two caches this way
   (``cache`` for the target, ``d_cache`` for the draft) under one mask.
-- :class:`BlockPool`: the layers that cache keys and values share a
-  pool of blocks, ``place`` is the host's block ids (a prefill's) or
-  block table (a decode chunk's), and the layers with a state of fixed
-  size keep one per slot (``rec``). Visibility is ``fill + 1``.
+- :class:`BlockPool`: the layers that cache a row a token (keys and
+  values, or the latent they are projections of: the layout entries
+  that say ``owns_rows``) share a pool of blocks, ``place`` is the
+  host's block ids (a prefill's) or block table (a decode chunk's), and
+  the layers with a state of fixed size keep one per slot (``rec``).
+  Visibility is ``fill + 1``.
 
-A new kind of cache (window layers, a latent cache) is a third residency;
+A cache with another lifetime (window layers' ring) is a third residency;
 a change to how prompts are prefilled is a change to the one prefill
 family below (``init_fresh``, ``prefill_step``, ``finish_prefill``,
 ``prefill``), which runs over every served model and knows no residency.
@@ -40,7 +42,6 @@ from types import SimpleNamespace
 import jax
 import jax.numpy as jnp
 
-from unionml_tpu.models.layers import KVRows
 from unionml_tpu.models.speculative import greedy_acceptance
 
 __all__ = ["BlockPool", "SlotRows", "build_programs", "cache_layout"]
@@ -48,14 +49,14 @@ __all__ = ["BlockPool", "SlotRows", "build_programs", "cache_layout"]
 
 def cache_layout(module):
     """What each layer of ``module`` caches (``models/layers.py``: a
-    ``KVRows`` or a ``SlotState`` per layer): the module says, the engine
-    does not assume."""
+    ``KVRows``, a ``LatentRows`` or a ``SlotState`` per layer): the module
+    says, the engine does not assume."""
     layout = getattr(module, "cache_layout", None)
     if layout is None:
         raise TypeError(
             f"{type(module).__name__} has no cache_layout(): a decoder the "
             "engine can serve says what each of its layers caches "
-            "(unionml_tpu.models.layers.KVRows / SlotState)"
+            "(unionml_tpu.models.layers.KVRows / LatentRows / SlotState)"
         )
     return tuple(layout())
 
@@ -84,7 +85,7 @@ def _init_layers(layout, batch: int, rows: int, owns_rows=None):
     do not), ``batch`` sequences of ``rows`` positions."""
     return tuple(
         l.init(batch, rows) for l in layout
-        if owns_rows is None or isinstance(l, KVRows) == owns_rows
+        if owns_rows is None or l.owns_rows == owns_rows
     )
 
 
@@ -93,15 +94,15 @@ def _join_layers(layout, rows, states):
     state layers', in layer order."""
     rows, states = iter(rows), iter(states)
     return tuple(
-        next(rows) if isinstance(l, KVRows) else next(states) for l in layout
+        next(rows) if l.owns_rows else next(states) for l in layout
     )
 
 
 def _split_layers(layout, cache):
     """``(row layers' entries, state layers')`` of a per-layer cache."""
     return (
-        tuple(c for c, l in zip(cache, layout) if isinstance(l, KVRows)),
-        tuple(c for c, l in zip(cache, layout) if not isinstance(l, KVRows)),
+        tuple(c for c, l in zip(cache, layout) if l.owns_rows),
+        tuple(c for c, l in zip(cache, layout) if not l.owns_rows),
     )
 
 
@@ -168,8 +169,10 @@ class SlotRows:
 
 
 class BlockPool:
-    """Residency: the layers that cache keys and values share ``pool``,
-    ``[num_blocks, block, kv_heads, head_dim]`` per buffer, addressed
+    """Residency: the layers that own rows share ``pool``, per buffer
+    ``[num_blocks, block, ...]`` with the row's shape behind (``kv_heads,
+    head_dim`` for keys and values, the latent's width for a latent
+    layer: the copies below are rank-generic), addressed
     through the host-owned block table; the state layers keep one state
     per slot in ``rec`` (empty for a module without such layers),
     written whole when a prefill ends and updated in place by decode.
@@ -256,7 +259,7 @@ def build_programs(
         residency = SlotRows(dict(zip(("cache", "d_cache"), layouts)), slots, rows)
     L, B = rows, slots
     first_rows = next(
-        i for i, l in enumerate(layouts[0]) if isinstance(l, KVRows)
+        i for i, l in enumerate(layouts[0]) if l.owns_rows
     )
 
     def init_state():
