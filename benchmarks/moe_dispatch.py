@@ -1,7 +1,7 @@
 """One expert layer alone: the dense dispatch beside the grouped one.
 
     chiprun -- env PYTHONPATH=. python3 benchmarks/moe_dispatch.py    # the tree's code, Mixtral's layer
-    chiprun -- env PYTHONPATH=. python3 benchmarks/moe_dispatch.py --tokens 256 --chunk 64 --tiles 1024,1024
+    chiprun -- env PYTHONPATH=. python3 benchmarks/moe_dispatch.py --tokens 256 --chunk 64 --tiles 2048,1024,0
     chiprun -- env PYTHONPATH=. python3 benchmarks/moe_dispatch.py --experts 64 --top-k 4 --hidden 2048 \\
         --width 1536 --router sigmoid --layers 12 --tokens 32 512 4096 --chunk 0 16 32 --live 14
 
@@ -25,9 +25,12 @@ layer's least times: its weights at the HBM rate (every
 expert is touched from 32 rows on) and the routed rows' FLOPs at the MXU's
 peak.
 
-``--chunk`` and ``--tiles`` (k,n[,accumulator MiB]) override
-the kernel's row tile and ``_grouped_matmul_pallas``'s defaults, to re-derive
-them and ``ops.moe._row_chunk``; ``--ragged-dot`` adds ``jax.lax.ragged_dot`` (it
+``--chunk`` and ``--tiles`` override the kernel's row tile
+(``ops.moe._row_chunk``) and the tiles ``ops.moe.matmul_tiles`` gives it, to
+re-derive both: ``k,n,b`` is the weight tile and the row tiles of a row block
+(0: every row in one block) for both products, ``k,n,b/k,n,b`` for gate + up
+and for down, ``rule`` the op's own beside them; each line says the tiles and
+grid steps it ran. ``--ragged-dot`` adds ``jax.lax.ragged_dot`` (it
 keeps a bfloat16 copy of the weights: 2.8 GB a layer beside the int8 ones).
 Fails without a TPU unless ``--rehearse`` (tiny shapes, interpret mode: the
 numbers then mean nothing). Not run by any cell or test.
@@ -72,6 +75,33 @@ def _layer(mlp, k, x, p, router="softmax"):
     return (out * jax.lax.rsqrt(jnp.mean(out * out, axis=-1, keepdims=True) + 1e-6)).astype(x.dtype)
 
 
+def _tiles_arg(text):
+    """``k,n,b`` or ``k,n,b/k,n,b`` -> ((tk, tn, block) of gate + up, of down);
+    ``rule`` -> None, the op's own."""
+    if text == "rule":
+        return None
+    products = [tuple(int(n) for n in p.split(",")) for p in text.split("/")]
+    return tuple(products * 2)[:2]
+
+
+def _with_tiles(pallas, tiles, lhs, *args, chunk, gated, **kw):
+    tk, tn, block = tiles[0 if gated else 1]
+    return pallas(lhs, *args, chunk=chunk, gated=gated, tiles=(tk, tn, block or lhs.shape[0] // chunk), **kw)
+
+
+def _kernel_plan(tokens, k, experts, d, h, chunk, tiles):
+    """The tiles and grid steps the two calls of a layer run."""
+    rows = moe._padded_rows(tokens * k, experts, chunk)
+    plan = {}
+    products = {"gate_up": (d, h, 2), "down": (h, d, 1)}
+    for (name, (depth, width, n_rhs)), given in zip(products.items(), tiles or (None, None)):
+        if given is None:
+            plan[name] = moe.matmul_tiles(rows, depth, width, n_rhs, chunk)
+        else:
+            plan[name] = moe._tile_plan(rows, depth, width, chunk, *given[:2], given[2] or rows // chunk)
+    return plan
+
+
 def _time(loop, x, params, calls):
     jax.block_until_ready(loop(x, params))
     times = []
@@ -95,7 +125,7 @@ def main():
     ap.add_argument("--router", choices=("softmax", "sigmoid"), default="softmax")
     ap.add_argument("--live", type=int, default=0, help="distinct rows; the rest repeat one (0: all)")
     ap.add_argument("--chunk", type=int, nargs="*", default=[], help="row tiles to try (0: the op's own)")
-    ap.add_argument("--tiles", nargs="*", default=[], help="weight tiles k,n to try, not the op's own")
+    ap.add_argument("--tiles", nargs="*", default=[], help="k,n,b[/k,n,b] to try, not the op's own")
     ap.add_argument("--ragged-dot", action="store_true")
     ap.add_argument("--skip-dense", action="store_true")
     ap.add_argument("--rehearse", action="store_true")
@@ -113,7 +143,7 @@ def main():
 
     cases = [] if args.skip_dense else [("dense", moe.dense_expert_mlp, None, None)]
     grouped = functools.partial(moe.grouped_expert_mlp, impl="pallas")
-    tiles = [tuple(int(n) for n in t.split(",")) for t in args.tiles] or [None]
+    tiles = [_tiles_arg(t) for t in args.tiles] or [None]
     cases += [("grouped", grouped, c or None, t) for c in (args.chunk or [None]) for t in tiles]
     if args.ragged_dot:
         cases.append(("ragged_dot", functools.partial(moe.grouped_expert_mlp, impl="ragged_dot"), None, None))
@@ -124,7 +154,9 @@ def main():
             x = jnp.where(jnp.arange(tokens)[:, None] < args.live, x, x[-1:])
         for what, mlp, chunk, tile in cases:
             moe._row_chunk = row_chunk if chunk is None else (lambda rows, e, chunk=chunk: chunk)
-            moe._grouped_matmul_pallas = pallas if tile is None else functools.partial(pallas, tiles=tile)
+            moe._grouped_matmul_pallas = (
+                pallas if tile is None else functools.partial(_with_tiles, pallas, tile)
+            )
 
             @jax.jit
             def loop(x, params, mlp=mlp):
@@ -148,7 +180,8 @@ def main():
             chunk = chunk or (moe._row_chunk(tokens * k, experts) if what == "grouped" else None)
             print(json.dumps({
                 "what": what, "tokens": tokens, "us_per_layer": us, "us_min": us_min,
-                "chunk": chunk, "tiles": tile, "max_off_dense_in_sd": off,
+                "chunk": chunk, "max_off_dense_in_sd": off,
+                "kernel": _kernel_plan(tokens, k, experts, d, h, chunk, tile) if what == "grouped" else None,
                 "rows_computed_over_routed": {
                     "dense": experts / k, "ragged_dot": 1.0,
                 }.get(what) or round(moe._padded_rows(tokens * k, experts, chunk) / (tokens * k), 3),

@@ -267,7 +267,7 @@ def test_engine_with_moe_llama():
         engine.close()
 
 
-def test_stats_say_what_each_programs_expert_layers_do(tiny_llama):
+def test_stats_say_what_each_programs_expert_layers_do(tiny_llama, monkeypatch):
     """`stats()["moe"]`: for each compiled program the dispatch its expert
     layers take and the rows they compute over the rows the router sent
     (`ops.moe.dispatch_plan`, a count from shapes); absent for a dense model."""
@@ -284,16 +284,35 @@ def test_stats_say_what_each_programs_expert_layers_do(tiny_llama):
     params = Llama(LlamaConfig.tiny(**kw)).init(jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32))["params"]
     for quantized, dispatch, ratio in ((False, "dense", 2.0), (True, "grouped:ragged_dot", 1.0)):
         module = Llama(LlamaConfig.tiny(**kw, quantized=quantized))
-        engine = DecodeEngine(module, slots=2, max_new_tokens=4, prompt_buckets=(8, 16), chunk_steps=2)
+        engine = DecodeEngine(module, slots=2, max_new_tokens=4, prompt_buckets=(8, 16, 192), chunk_steps=2)
         try:
             moe = engine.stats()["moe"]
-            assert set(moe) == {"decode_chunk", "prefill_8", "prefill_16"}
+            assert set(moe) == {"decode_chunk", "prefill_8", "prefill_16", "prefill_192"}
+            touched = round(4 * (1 - 0.5 ** 16), 2)  # 16 rows each draw 2 of 4 experts
+            read = 4 if dispatch == "dense" else 4 * (1 - 0.5 ** 16)  # experts whose weights are read
+            bytes_read = int(read * 3 * 64 * 128 * (1 if quantized else 2))
             assert moe["prefill_16"] == {
                 "dispatch": dispatch, "expert_rows_routed": 32,
                 "expert_rows_computed": int(32 * ratio), "computed_over_routed": ratio,
+                "experts_touched": touched, "expert_bytes_read": bytes_read,
             }
             assert moe["decode_chunk"]["expert_rows_routed"] == 2 * 2  # slots x top-k
-            if quantized:  # and the grouped programs serve
+            if quantized:
+                # on a TPU the kernel serves programs of more than 128 rows,
+                # and the stats say the grid it runs, from the same shapes
+                from unionml_tpu.ops import moe as moe_ops
+
+                with monkeypatch.context() as on_chip:
+                    on_chip.setattr(moe_ops, "_interpret", lambda: False)
+                    wide = engine.stats()["moe"]["prefill_192"]
+                assert wide["dispatch"] == "grouped:moe_grouped_matmul" and wide["row_tile"] == 128
+                assert wide["expert_rows_computed"] == 6 * 128  # 384 pairs and four experts' last tiles
+                for product, tk, tn in (("gate_up", 64, 128), ("down", 128, 64)):
+                    assert wide[product] == {
+                        "tk": tk, "tn": tn, "row_block_tiles": 6, "row_blocks": 1, "grid_steps": 6,
+                    }
+                assert "row_tile" not in moe["prefill_192"]  # here ragged_dot serves
+                # and the grouped programs serve
                 qparams = quantize_params(params, LLAMA_QUANT_PATTERNS)
                 assert [len(o) for o in engine.generate(qparams, [[1, 2, 3], [4, 5, 6, 7, 8, 9]])] == [4, 4]
         finally:

@@ -407,10 +407,159 @@ def test_grouped_matmul_kernel_matches_ragged_dot(gated, quantized):
     source, slot, _, tile_expert, tile_rows = moe.group_rows(indices, experts, chunk)
     got = moe._grouped_matmul_pallas(
         x[source], rhs, scales, tile_expert, tile_rows,
-        chunk=chunk, gated=gated, interpret=True, tiles=(128, 128),
+        chunk=chunk, gated=gated, interpret=True, tiles=(128, 128, 7),
     )
     assert got.shape == (moe._padded_rows(x.shape[0], experts, chunk), width)
     np.testing.assert_allclose(np.asarray(got[slot]), np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+# --- several row blocks (PR 37): the kernel's sums cover a block of row
+# tiles, and the grid passes the blocks outermost. Tiny shapes, the tiles
+# given (`tiles=`: tk, tn, row tiles a block) so that the rows fall in
+# several blocks; 16-row tiles.
+
+
+def _block_routing(kind):
+    """(rows of each expert, row tiles a block) for 16-row tiles."""
+    if kind == "straddles_an_edge":
+        # expert 1 holds tiles 1-4: blocks of three tiles cut it after its
+        # second tile, and expert 3 (tiles 6-8) starts a block of its own
+        return [16, 60, 5, 40], 3
+    if kind == "last_blocks_empty":
+        # 40 rows in 3 tiles used of the layout's 6: blocks 1 and 2 of two
+        # tiles hold no routed row (and block 1 starts on the last tile used)
+        return [33, 0, 7, 0], 2
+    if kind == "one_pair":  # one routed pair in all: one tile of 4 used, two blocks
+        return [0, 0, 1, 0], 2
+    assert kind == "ragged_last_block"  # 9 tiles in blocks of four: the last holds one
+    return [30, 31, 29, 30], 4
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["float", "int8"])
+@pytest.mark.parametrize("gated", [False, True], ids=["down", "gate_up"])
+@pytest.mark.parametrize("kind", ["straddles_an_edge", "last_blocks_empty", "one_pair", "ragged_last_block"])
+def test_grouped_matmul_kernel_in_row_blocks_matches_ragged_dot(kind, gated, quantized):
+    """The kernel in interpret mode over several row blocks, three k tiles
+    and two column tiles, against `ragged_dot` on the rows packed without
+    padding."""
+    from unionml_tpu.ops import moe
+
+    sizes, block_tiles = _block_routing(kind)
+    experts, depth, width, chunk = len(sizes), 384, 256, 16
+    sizes = np.asarray(sizes)
+    indices = jnp.asarray(np.repeat(np.arange(experts), sizes)[:, None], jnp.int32)
+    ws, scales = _expert_weights(experts, depth, width, quantized, seed=11)
+    rhs, scales = (ws[:2], scales and scales[:2]) if gated else (ws[:1], scales and scales[:1])
+    x = jax.random.normal(jax.random.PRNGKey(4), (int(sizes.sum()), depth))
+
+    want = moe.grouped_matmul(x, rhs, jnp.asarray(sizes, jnp.int32), scales=scales, impl="ragged_dot")
+    source, slot, _, tile_expert, tile_rows = moe.group_rows(indices, experts, chunk)
+    rows = moe._padded_rows(x.shape[0], experts, chunk)
+    plan = moe._tile_plan(rows, depth, width, chunk, 128, 128, block_tiles)
+    assert plan["row_blocks"] > 1 and plan["grid_steps"] == plan["row_blocks"] * 2 * 3 * block_tiles
+    got = moe._grouped_matmul_pallas(
+        x[source], rhs, scales, tile_expert, tile_rows,
+        chunk=chunk, gated=gated, interpret=True, tiles=(128, 128, block_tiles),
+    )
+    assert got.shape == (rows, width)
+    np.testing.assert_allclose(np.asarray(got[slot]), np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["float", "int8"])
+@pytest.mark.parametrize(
+    "depth,weight_tile", [(384, 128), (192, 256)], ids=["three_k_tiles", "depth_192_in_one_pass"],
+)
+def test_grouped_dispatch_in_row_blocks_matches_dense(monkeypatch, depth, weight_tile, quantized):
+    """Both products of the experts' SwiGLU through the kernel as
+    `matmul_tiles` lays it out when the rows' sums outgrow the VMEM it may
+    take (shrunk here, so that 8 experts' 11 row tiles go in blocks of one
+    to four), against the dense dispatch: a depth in three k tiles, and
+    one that no power of two divides, in one pass."""
+    from unionml_tpu.ops import moe
+
+    experts, selected, tokens, hidden = 8, 2, 120, 256
+    monkeypatch.setattr(moe, "_WEIGHT_TILE", weight_tile)
+    chunk = moe._row_chunk(tokens * selected, experts)
+    monkeypatch.setattr(moe, "_ACC_BYTES", 4 * chunk * 128 * 4)  # four row tiles x 128 columns
+    moe._grouped_matmul_pallas.clear_cache()
+    try:
+        rows = moe._padded_rows(tokens * selected, experts, chunk)
+        gate_up = moe.matmul_tiles(rows, depth, hidden, 2, chunk)
+        down = moe.matmul_tiles(rows, hidden, depth, 1, chunk)
+        assert gate_up["tk"] == min(depth, weight_tile) and down["tk"] == weight_tile
+        for plan in (gate_up, down):
+            assert 1 <= plan["row_block_tiles"] <= 4 and plan["row_blocks"] >= 3
+            assert plan["row_blocks"] * plan["row_block_tiles"] >= rows // chunk
+        ws, scales = _expert_weights(experts, depth, hidden, quantized, seed=13)
+        kx, kr = jax.random.split(jax.random.PRNGKey(17))
+        x = jax.random.normal(kx, (tokens, depth))
+        weights, indices, _ = top_k_routing(jax.random.normal(kr, (tokens, experts)), selected)
+        indices = jnp.where(indices == 5, 6, indices)  # expert 5 stays empty
+        _assert_grouped_matches_dense(x, weights, indices, ws, scales, "pallas")
+    finally:
+        moe._grouped_matmul_pallas.clear_cache()
+
+
+_MIXTRAL_PRODUCTS = dict(experts=8, selected=2, d=4096, hidden=14336)
+_GLM_PRODUCTS = dict(experts=64, selected=4, d=2048, hidden=1536)
+
+
+def _layer_tiles(tokens, experts, selected, d, hidden):
+    from unionml_tpu.ops import moe
+
+    chunk = moe._row_chunk(tokens * selected, experts)
+    rows = moe._padded_rows(tokens * selected, experts, chunk)
+    gate_up, down = moe.matmul_tiles(rows, d, hidden, 2, chunk), moe.matmul_tiles(rows, hidden, d, 1, chunk)
+    return chunk, rows, gate_up, down
+
+
+@pytest.mark.parametrize("layer,tokens,gate_up,down", [
+    # (tk, tn, row tiles a block, row blocks) of gate + up and of down, each
+    # from a measured pair of `benchmarks/moe_dispatch.py` runs (PERF.md
+    # section 6, PR 37) against the one block of narrower tiles before it
+    (_MIXTRAL_PRODUCTS, 256, (2048, 2048, 6, 2), (2048, 2048, 11, 1)),  # was 1024 / 2048 wide: -2 %
+    (_MIXTRAL_PRODUCTS, 512, (2048, 2048, 5, 3), (2048, 2048, 8, 2)),  # was 512 / 1024 wide: -10 %
+    (_MIXTRAL_PRODUCTS, 1024, (2048, 2048, 6, 4), (2048, 2048, 12, 2)),  # was 512 / 1024 wide: -12 %
+    # the decode chunk's 1,088 rows of 16: was 512 wide and 512 deep (-1 %; -3 % at 14 live rows)
+    (_GLM_PRODUCTS, 32, (2048, 1536, 34, 2), (1536, 2048, 68, 1)),
+    (_GLM_PRODUCTS, 512, (2048, 1536, 16, 6), (1536, 2048, 24, 4)),  # 64-row tiles
+], ids=["mixtral_256", "mixtral_512", "mixtral_1024", "glm_chunk_32", "glm_512"])
+def test_matmul_tiles_at_the_cells_shapes(layer, tokens, gate_up, down):
+    chunk, rows, *plans = _layer_tiles(tokens, **layer)
+    for plan, (tk, tn, block_tiles, blocks), (depth, width) in zip(
+        plans, (gate_up, down), ((layer["d"], layer["hidden"]), (layer["hidden"], layer["d"]))
+    ):
+        assert plan == {
+            "tk": tk, "tn": tn, "row_block_tiles": block_tiles, "row_blocks": blocks,
+            "grid_steps": blocks * (width // tn) * (depth // tk) * block_tiles,
+        }
+        assert (blocks - 1) * block_tiles < rows // chunk <= blocks * block_tiles
+
+
+@pytest.mark.parametrize("tokens", [1024, 2048, 4096])
+def test_matmul_tiles_at_64_experts_top_4_go_in_row_blocks(tokens):
+    """The buckets whose rows outgrew one block's sums: wide column tiles,
+    the 1,536 depth in one pass, a few hundred grid steps a layer, and
+    what a grid step holds in VMEM under the kernel's limit."""
+    from unionml_tpu.ops import moe
+
+    d, hidden = _GLM_PRODUCTS["d"], _GLM_PRODUCTS["hidden"]
+    chunk, rows, gate_up, down = _layer_tiles(tokens, **_GLM_PRODUCTS)
+    assert chunk == 128 and rows == {1024: 12160, 2048: 16256, 4096: 24448}[tokens]
+    assert gate_up["tk"] == d and down["tk"] == hidden  # one k pass each
+    assert gate_up["grid_steps"] + down["grid_steps"] <= 400  # 3,420 / 7,620 / 11,460 before
+    for plan, n_rhs, depth in ((gate_up, 2, d), (down, 1, hidden)):
+        assert plan["tn"] >= 512 and plan["row_blocks"] > 1
+        assert plan["row_blocks"] * plan["row_block_tiles"] >= rows // chunk
+        assert (plan["row_blocks"] - 1) * plan["row_block_tiles"] < rows // chunk  # no empty block
+        block_rows = plan["row_block_tiles"] * chunk
+        sums = n_rhs * block_rows * plan["tn"] * 4
+        assert sums <= moe._ACC_BYTES
+        # the sums, the output block and the operands in two buffers each,
+        # and the weight tiles' bfloat16 copies the MXU sees
+        held = sums + 2 * block_rows * plan["tn"] * 2 + 2 * chunk * plan["tk"] * 2
+        held += n_rhs * plan["tk"] * plan["tn"] * (2 * 1 + 2)
+        assert held <= moe._VMEM_LIMIT // 2
 
 
 def test_dispatch_plan_counts_rows(monkeypatch):

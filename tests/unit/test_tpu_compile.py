@@ -333,7 +333,8 @@ def _no_array_of_every_expert(text, tokens, d, hidden, experts, **_):
     # products for every token
     assert f"s8[{experts},{d},{hidden}]" in text
     assert not re.search(rf"(?:bf16|f32)\[{experts},(?:{d},{hidden}|{hidden},{d})\]", text)
-    assert not re.search(rf"\[{experts},{tokens},(?:{hidden}|{d})\]", text)
+    # (float: at 2,048 tokens the int8 weights themselves are [64, 2048, 1536])
+    assert not re.search(rf"(?:bf16|f32)\[{experts},{tokens},(?:{hidden}|{d})\]", text)
 
 
 @pytest.mark.parametrize("tokens", [32, 256, 1024], ids=["decode_32_slots", "prefill_256", "prefill_1024"])
@@ -431,9 +432,17 @@ def test_flash_attention_at_the_latent_models_expanded_width_compiles(chip):
 _GLM_LAYER = dict(d=2048, hidden=1536, experts=64, selected=4)
 
 
-@pytest.mark.parametrize("tokens", [32, 512, 4096], ids=["decode_32_slots", "prefill_512", "prefill_4096"])
+@pytest.mark.parametrize(
+    "tokens", [32, 512, 2048, 4096], ids=["decode_32_slots", "prefill_512", "prefill_2048", "prefill_4096"],
+)
 def test_moe_grouped_matmul_compiles_at_64_experts_top_4(chip, tokens):
+    """The buckets' rows go in several row blocks (PR 37: the grid's
+    outermost axis), the last of them ragged: 127 row tiles in blocks of 8
+    and 12 at 2,048 tokens."""
     d, hidden, experts, selected = _GLM_LAYER.values()
+    plan = moe.dispatch_plan(tokens, experts, selected, quantized=True, model_dim=d, hidden_dim=hidden)
+    blocks = [plan[product]["row_blocks"] for product in ("gate_up", "down")]
+    assert blocks == {32: [2, 1], 512: [6, 4], 2048: [16, 11], 4096: [24, 16]}[tokens]
 
     def mlp(x, weights, indices, w_gate, w_up, w_down, *scales):
         return moe.grouped_expert_mlp(x, weights, indices, w_gate, w_up, w_down, scales=scales, impl="pallas")

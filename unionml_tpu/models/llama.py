@@ -214,7 +214,10 @@ class Llama(nn.Module):
         cfg = self.config
         if not cfg.num_experts:
             return None
-        return dispatch_plan(tokens, cfg.num_experts, cfg.num_selected, quantized=cfg.quantized)
+        return dispatch_plan(
+            tokens, cfg.num_experts, cfg.num_selected, quantized=cfg.quantized,
+            model_dim=cfg.hidden_dim, hidden_dim=cfg.mlp_dim,
+        )
 
     @nn.compact
     def __call__(

@@ -218,8 +218,11 @@ def dispatch_plan(
     (experts, top-k, rows, and the experts' widths where given): the
     dispatch, the rows the experts' matmuls compute and the rows the router
     sent (``tokens x num_selected``) and, with the widths, the experts
-    expected to hold a routed row and the bytes of expert weights the
-    layer reads (one byte a weight when ``quantized``, else two).
+    expected to hold a routed row, the bytes of expert weights the layer
+    reads (one byte a weight when ``quantized``, else two) and, where the
+    kernel serves, its ``row_tile`` and for each of its two calls
+    (``gate_up``, ``down``) the weight tile ``tk`` x ``tn``, the row tiles
+    of a row block, the row blocks and the grid steps.
     The dense dispatch runs every expert on every token; the grouped one
     pads each expert's rows to the kernel's row tile, counted here at its
     worst (every expert's last tile holding one row), and reads the
@@ -259,6 +262,11 @@ def dispatch_plan(
         per_expert = 3 * model_dim * hidden_dim * (1 if quantized else 2)
         plan["experts_touched"] = round(touched, 2)
         plan["expert_bytes_read"] = int(read * per_expert)
+        if dispatch == "grouped:moe_grouped_matmul":
+            # the kernel's grid, by the rule it runs (:func:`matmul_tiles`)
+            plan["row_tile"] = chunk
+            plan["gate_up"] = matmul_tiles(computed, model_dim, hidden_dim, 2, chunk)
+            plan["down"] = matmul_tiles(computed, hidden_dim, model_dim, 1, chunk)
     return plan
 
 
@@ -302,29 +310,71 @@ def group_rows(indices: jnp.ndarray, num_experts: int, chunk: int = 1):
     return source, slot, group_sizes, tile_expert, tile_rows
 
 
-def _largest_tile(size: int, most: int) -> int:
-    """The largest power-of-two multiple of 128 up to ``most`` that divides
-    ``size``, or ``size`` itself (a block may span a whole axis)."""
-    tile = most
-    while tile >= 128:
+_WEIGHT_TILE = 2048  # the most of a weight tile's depth and of its width
+_ACC_BYTES = 12 * 2**20  # the float32 sums a row block keeps in VMEM
+_VMEM_LIMIT = 96 * 2**20  # of the chip's 128 MiB; the default scope is 16
+
+
+def _weight_tile(size: int) -> int:
+    """A weight tile's extent along an axis of ``size``: the whole axis
+    where ``_WEIGHT_TILE`` holds it (1,536: one tile, not three of 512),
+    else the largest power-of-two multiple of 128 up to ``_WEIGHT_TILE``
+    that divides it, or the axis itself if none does."""
+    tile = _WEIGHT_TILE
+    while size > tile >= 128:
         if size % tile == 0:
             return tile
         tile //= 2
     return size
 
 
-_VMEM_LIMIT = 96 * 2**20  # of the chip's 128 MiB; the default scope is 16
+def _tile_plan(rows: int, depth: int, width: int, chunk: int, tk: int, tn: int, block_tiles: int) -> dict:
+    """The grid of one product from its tiles: the row tiles go in
+    ``row_blocks`` blocks of ``block_tiles`` (the last may hold fewer), and
+    every block passes its column tiles, k tiles and row tiles."""
+    blocks = -(-(rows // chunk) // block_tiles)
+    return {
+        "tk": tk, "tn": tn, "row_block_tiles": block_tiles, "row_blocks": blocks,
+        "grid_steps": blocks * (width // tn) * (depth // tk) * block_tiles,
+    }
 
 
-def _gmm_kernel(tile_expert, tile_rows, tiles_used, lhs_ref, *refs, chunk, k_tiles, n_rhs, scaled, gated):
+def matmul_tiles(rows: int, depth: int, width: int, n_rhs: int, chunk: int) -> dict:
+    """The tiles of ``moe_grouped_matmul`` for ``rows`` rows in row tiles
+    of ``chunk`` times ``n_rhs`` matrices of ``[depth, width]`` an expert,
+    from shapes alone: the weight tile ``tk`` x ``tn``, the row tiles of a
+    row block, the row blocks and the grid steps (:func:`_tile_plan`).
+
+    The weight tile is as large as ``_WEIGHT_TILE`` lets it be, whatever
+    the rows. The kernel keeps the float32 sums of one row block x one
+    column tile in VMEM while the k tiles and, innermost, the block's row
+    tiles pass, so the rows go in as many blocks of equal size as keep
+    those sums within ``_ACC_BYTES``. Rows lie by expert, so a block holds
+    a run of experts, and one whose rows straddle two blocks is read twice.
+    Measured (PERF.md section 6, PR 37): that costs 64 small experts
+    nothing and 8 large ones a few percent, where a column tile narrowed to
+    keep every row's sums in VMEM cost 10 % at 2k-3k rows and half the time
+    at 24k (128 columns left, 11,460 grid steps a layer of mostly fixed
+    cost)."""
+    tiles = rows // chunk
+    tk, tn = _weight_tile(depth), _weight_tile(width)
+    fit = max(_ACC_BYTES // (n_rhs * chunk * tn * 4), 1)  # the row tiles whose sums fit
+    blocks = -(-tiles // fit)
+    return _tile_plan(rows, depth, width, chunk, tk, tn, -(-tiles // blocks))
+
+
+def _gmm_kernel(
+    tile_expert, tile_rows, tiles_used, lhs_ref, *refs, chunk, block_tiles, k_tiles, n_rhs, scaled, gated,
+):
     del tile_expert, tiles_used
     rhs_refs, refs = refs[:n_rhs], refs[n_rhs:]
     scale_refs, refs = (refs[:n_rhs], refs[n_rhs:]) if scaled else ((None,) * n_rhs, refs)
     out_ref, acc_refs = refs[0], refs[1:]
-    k, tile = pl.program_id(1), pl.program_id(2)
+    k, tile = pl.program_id(2), pl.program_id(3)  # the row tile within its block
     rows = pl.ds(pl.multiple_of(tile * chunk, chunk), chunk)
 
-    @pl.when(tile_rows[tile] > 0)  # a tile past the last one used holds no routed row
+    # a tile past the last one used holds no routed row
+    @pl.when(tile_rows[pl.program_id(0) * block_tiles + tile] > 0)
     def _tile():
         x = lhs_ref[...]
         for acc, w in zip(acc_refs, rhs_refs):
@@ -361,32 +411,40 @@ def _grouped_matmul_pallas(lhs, rhs, scales, tile_expert, tile_rows, *, chunk, g
     rows, depth = lhs.shape
     num_experts, _, width = rhs[0].shape
     n_rhs, scaled = len(rhs), scales is not None
-    # largest weight tile (k, n) and accumulator MiB (float32 sums of every
-    # row x one column tile): measured on the chip (PERF.md section 6, PR
-    # 34); the benchmark's --tiles re-derives them
-    tk, tn, acc_mb = tuple(tiles or ()) + (2048, 2048, 12)[len(tiles or ()):]
-    tk, tn = _largest_tile(depth, tk), _largest_tile(width, tn)
-    while n_rhs * rows * tn * 4 > acc_mb * 2**20 and tn % 256 == 0:
-        tn //= 2
-    k_tiles = depth // tk
+    # ``tiles``: (tk, tn, row tiles a block) in place of the rule's, for
+    # the benchmark (benchmarks/moe_dispatch.py --tiles) and the tests
+    plan = (
+        matmul_tiles(rows, depth, width, n_rhs, chunk) if tiles is None
+        else _tile_plan(rows, depth, width, chunk, *tiles)
+    )
+    tk, tn, block_tiles, row_blocks = (plan[key] for key in ("tk", "tn", "row_block_tiles", "row_blocks"))
+    k_tiles, n_tiles = depth // tk, width // tn
     tiles_used = jnp.sum(tile_rows > 0).astype(jnp.int32).reshape(1)
+    # the last block's tiles past the last row: none holds a routed row
+    tile_rows = jnp.pad(tile_rows, (0, row_blocks * block_tiles - rows // chunk))
 
     # a tile past the last one used repeats the block indices of the last
-    # one used at the last k: nothing is fetched for it
-    def used(tile, k, tiles_used):
+    # one used, and a whole block of them the last block's last indices:
+    # nothing is fetched for either
+    def used(block, n, k, tile, tiles_used):
         last = tiles_used[0] - 1
-        return jnp.minimum(tile, last), jnp.where(tile > last, k_tiles - 1, k)
+        past = block * block_tiles > last
+        return (
+            jnp.minimum(block * block_tiles + tile, last),
+            jnp.where(past, k_tiles - 1, k), jnp.where(past, n_tiles - 1, n),
+        )
 
-    def lhs_map(n, k, tile, tile_expert, tile_rows, tiles_used):
-        tile, k = used(tile, k, tiles_used)
+    def lhs_map(block, n, k, tile, tile_expert, tile_rows, tiles_used):
+        tile, k, _ = used(block, n, k, tile, tiles_used)
         return tile, k
 
-    def rhs_map(n, k, tile, tile_expert, tile_rows, tiles_used):
-        tile, k = used(tile, k, tiles_used)
+    def rhs_map(block, n, k, tile, tile_expert, tile_rows, tiles_used):
+        tile, k, n = used(block, n, k, tile, tiles_used)
         return tile_expert[tile], k, n
 
-    def scale_map(n, k, tile, tile_expert, tile_rows, tiles_used):
-        return tile_expert[jnp.minimum(tile, tiles_used[0] - 1)], 0, n
+    def scale_map(block, n, k, tile, tile_expert, tile_rows, tiles_used):
+        tile, _, n = used(block, n, k, tile, tiles_used)
+        return tile_expert[tile], 0, n
 
     in_specs = [pl.BlockSpec((chunk, tk), lhs_map)]
     in_specs += [pl.BlockSpec((None, tk, tn), rhs_map)] * n_rhs
@@ -395,23 +453,26 @@ def _grouped_matmul_pallas(lhs, rhs, scales, tile_expert, tile_rows, *, chunk, g
         in_specs += [pl.BlockSpec((None, 1, tn), scale_map)] * n_rhs
         operands += [s.reshape(num_experts, 1, width) for s in scales]
     kernel = functools.partial(
-        _gmm_kernel, chunk=chunk, k_tiles=k_tiles, n_rhs=n_rhs, scaled=scaled, gated=gated,
+        _gmm_kernel, chunk=chunk, block_tiles=block_tiles, k_tiles=k_tiles, n_rhs=n_rhs, scaled=scaled,
+        gated=gated,
     )
+    block_rows = block_tiles * chunk
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            # a column tile of every row stays in VMEM while the k tiles
-            # and, innermost, the row tiles pass: an expert's weight tile
-            # is fetched once however many row tiles it has
-            grid=(width // tn, k_tiles, rows // chunk),
+            # a column tile of a row block stays in VMEM while the k tiles
+            # and, innermost, the block's row tiles pass: rows lie by
+            # expert, so an expert's weight tile is fetched once however
+            # many row tiles it has (once more where it straddles blocks)
+            grid=(row_blocks, n_tiles, k_tiles, block_tiles),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((rows, tn), lambda n, k, tile, *_: (0, n)),
-            scratch_shapes=[pltpu.VMEM((rows, tn), jnp.float32)] * n_rhs,
+            out_specs=pl.BlockSpec((block_rows, tn), lambda block, n, k, tile, *_: (block, n)),
+            scratch_shapes=[pltpu.VMEM((block_rows, tn), jnp.float32)] * n_rhs,
         ),
         out_shape=jax.ShapeDtypeStruct((rows, width), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=interpret,
