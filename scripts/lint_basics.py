@@ -445,6 +445,130 @@ def check_span_names(package_root: Path) -> list:
     return problems
 
 
+# the CLOSED device-scope vocabulary: every ``jax.named_scope("...")``
+# literal in unionml_tpu/ names work that no Flax module owns, and a
+# device trace carries it as each operation's ``tf_op`` — what
+# chipbench/opscopes.py sums device time by (``part_of``). A name the
+# readers do not know lands in their ``unscoped`` part, so the set is
+# closed here and documented in docs/observability.md "Device time by
+# model part".
+DEVICE_SCOPE_NAMES = (
+    # serving/programs.py: a decode step, a prefill, a speculative round
+    "sample", "commit", "step_io", "draft", "verify", "accept",
+    # models/train.py
+    "loss", "optimizer", "grad_accumulate",
+    # ops/moe.py, under a block's ``moe``
+    "router", "group_rows", "gather", "experts", "combine",
+    # models/olmo_hybrid.py GatedDeltaNet, under ``gdn``
+    "conv", "gates", "state_update",
+    # models/glm_moe_lite.py LatentAttention, under ``attn``
+    "absorb", "expand",
+)
+_DEVICE_SCOPE_DOC_BEGIN = "<!-- DEVICE_SCOPE_NAMES:begin -->"
+_DEVICE_SCOPE_DOC_END = "<!-- DEVICE_SCOPE_NAMES:end -->"
+PART_TABLE_MODULE = "chipbench/opscopes.py"
+
+
+def part_table_names(root: Path) -> dict:
+    """{name: part} of the string literals in ``PART_TABLE`` of
+    chipbench/opscopes.py, read from its source; empty where the file or
+    the table is not there."""
+    path = root / PART_TABLE_MODULE
+    if not path.exists():
+        return {}
+    out = {}
+    for node in ast.walk(ast.parse(path.read_text(encoding="utf-8"))):
+        if not (
+            isinstance(node, ast.Assign)
+            and any(isinstance(t, ast.Name) and t.id == "PART_TABLE" for t in node.targets)
+            and isinstance(node.value, ast.Tuple)
+        ):
+            continue
+        for row in node.value.elts:
+            if not (isinstance(row, ast.Tuple) and len(row.elts) == 2):
+                continue
+            for leaf in ast.walk(row.elts[1]):
+                if isinstance(leaf, ast.Constant) and isinstance(leaf.value, str):
+                    out.setdefault(leaf.value, row.elts[0].value)
+    return out
+
+
+def documented_scope_parts(table_text: str) -> dict:
+    """{scope: part} of the doc's table: the names of a row's first cell
+    beside those of its fourth, one part for all or one each."""
+    out = {}
+    for line in table_text.splitlines():
+        cells = [c.strip() for c in line.strip().strip("|").split("|")]
+        if len(cells) < 4:
+            continue
+        scopes = _BACKTICK_TOKEN_RE.findall(cells[0])
+        parts = _BACKTICK_TOKEN_RE.findall(cells[3])
+        if len(parts) == 1:
+            parts = parts * len(scopes)
+        out.update(zip(scopes, parts))
+    return out
+
+
+def check_device_scope_names(root: Path) -> list:
+    """Every ``named_scope`` call in ``unionml_tpu/`` takes a string
+    literal of :data:`DEVICE_SCOPE_NAMES`, every name of the set is
+    documented between the markers of docs/observability.md, and the
+    trace readers' ``PART_TABLE`` knows each name under the part the doc
+    gives it: three lists that cannot drift."""
+    problems = []
+    for path in sorted((root / "unionml_tpu").rglob("*.py")):
+        if "__pycache__" in path.parts:
+            continue
+        try:
+            tree = ast.parse(path.read_text(encoding="utf-8"))
+        except SyntaxError:
+            continue  # reported by the per-file checker
+        for node in ast.walk(tree):
+            if not (
+                isinstance(node, ast.Call)
+                and isinstance(node.func, ast.Attribute)
+                and node.func.attr == "named_scope"
+            ):
+                continue
+            arg = node.args[0] if node.args else None
+            name = arg.value if isinstance(arg, ast.Constant) else None
+            if name not in DEVICE_SCOPE_NAMES:
+                problems.append(
+                    f"{path}:{node.lineno}: jax.named_scope({name!r}) is "
+                    "outside the closed DEVICE_SCOPE_NAMES set "
+                    "(scripts/lint_basics.py): the trace readers sum "
+                    "device time by these names; add it there, to "
+                    f"chipbench/opscopes.py and to {METRICS_DOC}, or "
+                    "reuse an existing name (a literal, not a variable)"
+                )
+    doc_path = root / METRICS_DOC
+    if doc_path.exists():
+        doc_text = doc_path.read_text(encoding="utf-8")
+        begin = doc_text.find(_DEVICE_SCOPE_DOC_BEGIN)
+        end = doc_text.find(_DEVICE_SCOPE_DOC_END)
+        if begin < 0 or end < begin:
+            problems.append(
+                f"{METRICS_DOC}: the DEVICE_SCOPE_NAMES markers "
+                "(\"Device time by model part\") are missing"
+            )
+        else:
+            documented = documented_scope_parts(doc_text[begin:end])
+            table = part_table_names(root)
+            for name in DEVICE_SCOPE_NAMES:
+                if name not in documented:
+                    problems.append(
+                        f"{METRICS_DOC}: device scope {name!r} from "
+                        "DEVICE_SCOPE_NAMES is not documented"
+                    )
+                elif table and table.get(name) != documented[name]:
+                    problems.append(
+                        f"{PART_TABLE_MODULE}: PART_TABLE puts device scope "
+                        f"{name!r} under {table.get(name)!r}, {METRICS_DOC} "
+                        f"under {documented[name]!r}"
+                    )
+    return problems
+
+
 # the decode engine's arrow points one way: serving/engine.py (host:
 # queue, admission, dispatcher, harvester, recovery, stats) calls
 # serving/programs.py (everything that is traced), never the reverse
@@ -889,6 +1013,7 @@ def main(argv) -> int:
         problems.extend(check_metrics_doc(ROOT))
         problems.extend(check_label_cardinality(ROOT / "unionml_tpu"))
         problems.extend(check_span_names(ROOT / "unionml_tpu"))
+        problems.extend(check_device_scope_names(ROOT))
         problems.extend(check_engine_layering(ROOT))
         problems.extend(check_rollout_reasons(ROOT))
         problems.extend(check_perf_reasons(ROOT))

@@ -199,23 +199,25 @@ class LatentAttention(nn.Module):
 
         def expanded():
             """Every head's keys and values from this call's own latents."""
-            up = jnp.einsum("bsc,chd->bshd", c_kv, w_up, preferred_element_type=f32)
-            up = (up if w_scale is None else up * w_scale).astype(dtype)
-            k = jnp.concatenate(
-                [up[..., :nope], jnp.broadcast_to(k_rope[:, :, None, :], (batch, seq, heads, rope))], axis=-1,
-            )
-            return jnp.concatenate([q_nope, q_rope], axis=-1), k, up[..., nope:]
+            with jax.named_scope("expand"):
+                up = jnp.einsum("bsc,chd->bshd", c_kv, w_up, preferred_element_type=f32)
+                up = (up if w_scale is None else up * w_scale).astype(dtype)
+                k_rope_heads = jnp.broadcast_to(k_rope[:, :, None, :], (batch, seq, heads, rope))
+                k = jnp.concatenate([up[..., :nope], k_rope_heads], axis=-1)
+                return jnp.concatenate([q_nope, q_rope], axis=-1), k, up[..., nope:]
 
         def absorbed_query():
             """``[q_nope W_uk^T ; q_rope ; 0]``: the query in the row's space."""
-            qn = q_nope if w_scale is None else (q_nope.astype(f32) * w_scale[:, :nope]).astype(dtype)
-            q_lat = jnp.einsum("bshd,chd->bshc", qn, w_up[..., :nope], preferred_element_type=f32)
-            return jnp.concatenate([q_lat.astype(dtype), q_rope], axis=-1)
+            with jax.named_scope("absorb"):
+                qn = q_nope if w_scale is None else (q_nope.astype(f32) * w_scale[:, :nope]).astype(dtype)
+                q_lat = jnp.einsum("bshd,chd->bshc", qn, w_up[..., :nope], preferred_element_type=f32)
+                return jnp.concatenate([q_lat.astype(dtype), q_rope], axis=-1)
 
         def from_latent(o_lat):
             """``o_lat W_uv``: the weighted latents to every head's values."""
-            o = jnp.einsum("bshc,chd->bshd", o_lat, w_up[..., nope:], preferred_element_type=f32)
-            return (o if w_scale is None else o * w_scale[:, nope:]).astype(dtype)
+            with jax.named_scope("absorb"):
+                o = jnp.einsum("bshc,chd->bshd", o_lat, w_up[..., nope:], preferred_element_type=f32)
+                return (o if w_scale is None else o * w_scale[:, nope:]).astype(dtype)
 
         new_cache = None
         if cache is None:

@@ -188,8 +188,9 @@ class GatedDeltaNet(nn.Module):
         conv = self.param("conv_kernel", nn.initializers.lecun_normal(), (width, channels), f32)
         a_log = self.param("A_log", _a_log_init, (heads,), f32)
         dt_bias = self.param("dt_bias", nn.initializers.ones, (heads,), f32)
-        beta = jax.nn.sigmoid(gate("b")) * (2.0 if cfg.linear_allow_neg_eigval else 1.0)
-        g = -jnp.exp(a_log) * jax.nn.softplus(gate("a") + dt_bias)
+        with jax.named_scope("gates"):
+            beta = jax.nn.sigmoid(gate("b")) * (2.0 if cfg.linear_allow_neg_eigval else 1.0)
+            g = -jnp.exp(a_log) * jax.nn.softplus(gate("a") + dt_bias)
 
         step = cache is not None and seq == 1 and jnp.ndim(cache_index) == 1
         if cache is None:
@@ -197,39 +198,42 @@ class GatedDeltaNet(nn.Module):
             tail = jnp.zeros((batch, (width - 1) * channels), qkv.dtype)
         else:
             state, tail = cache
-        if step:
-            # the flat tail's rows are lane-aligned slices of it: the taps
-            # add in the prefill branch's order, and nothing is stacked
-            taps = [tail[:, j * channels:(j + 1) * channels] for j in range(width - 1)] + [qkv[:, 0]]
-            mixed = sum(conv[j] * taps[j].astype(f32) for j in range(width))[:, None]
-            new_tail = jnp.concatenate([tail[:, channels:], taps[-1].astype(tail.dtype)], axis=1)
-        else:
-            history = tail.reshape(batch, width - 1, channels)
-            rows = jnp.concatenate([history.astype(qkv.dtype), qkv], axis=1)  # [B, width-1+T, C]
-            mixed = sum(conv[j] * rows[:, j:j + seq].astype(f32) for j in range(width))
-            valid_len = None
-            if cache is not None:
-                valid_len = jnp.full((batch,), seq, jnp.int32)
-                if kv_mask is not None:
-                    base = jnp.asarray(cache_index if cache_index is not None else 0)
-                    pos = base.reshape(-1, 1) + jnp.arange(seq)[None, :]
-                    pos = jnp.broadcast_to(pos, (batch, seq))
-                    valid_len = jnp.sum(jnp.take_along_axis(kv_mask, pos, axis=1), axis=1).astype(jnp.int32)
-                # the rows of the last width-1 real tokens (the old tail's,
-                # where the chunk holds fewer)
-                new_tail = jax.vmap(
-                    lambda r, n: jax.lax.dynamic_slice_in_dim(r, n, width - 1, axis=0)
-                )(rows, valid_len).reshape(batch, -1).astype(tail.dtype)
-        mixed = jax.nn.silu(mixed)
-        q, k, v = jnp.split(mixed, [heads * dk, 2 * heads * dk], axis=-1)
-        q = _l2norm(q.reshape(batch, seq, heads, dk))
-        k = _l2norm(k.reshape(batch, seq, heads, dk))
-        v = v.reshape(batch, seq, heads, dv)
-        if step:
-            o, new_state = gated_delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state, live)
-            o = o[:, None]
-        else:
-            o, new_state = gated_delta_chunked(q, k, v, g, beta, state, valid_len)
+        with jax.named_scope("conv"):
+            if step:
+                # the flat tail's rows are lane-aligned slices of it: the taps
+                # add in the prefill branch's order, and nothing is stacked
+                taps = [tail[:, j * channels:(j + 1) * channels] for j in range(width - 1)] + [qkv[:, 0]]
+                mixed = sum(conv[j] * taps[j].astype(f32) for j in range(width))[:, None]
+                new_tail = jnp.concatenate([tail[:, channels:], taps[-1].astype(tail.dtype)], axis=1)
+            else:
+                history = tail.reshape(batch, width - 1, channels)
+                rows = jnp.concatenate([history.astype(qkv.dtype), qkv], axis=1)  # [B, width-1+T, C]
+                mixed = sum(conv[j] * rows[:, j:j + seq].astype(f32) for j in range(width))
+                valid_len = None
+                if cache is not None:
+                    valid_len = jnp.full((batch,), seq, jnp.int32)
+                    if kv_mask is not None:
+                        base = jnp.asarray(cache_index if cache_index is not None else 0)
+                        pos = base.reshape(-1, 1) + jnp.arange(seq)[None, :]
+                        pos = jnp.broadcast_to(pos, (batch, seq))
+                        seen = jnp.take_along_axis(kv_mask, pos, axis=1)
+                        valid_len = jnp.sum(seen, axis=1).astype(jnp.int32)
+                    # the rows of the last width-1 real tokens (the old tail's,
+                    # where the chunk holds fewer)
+                    new_tail = jax.vmap(
+                        lambda r, n: jax.lax.dynamic_slice_in_dim(r, n, width - 1, axis=0)
+                    )(rows, valid_len).reshape(batch, -1).astype(tail.dtype)
+            mixed = jax.nn.silu(mixed)
+            q, k, v = jnp.split(mixed, [heads * dk, 2 * heads * dk], axis=-1)
+            q = _l2norm(q.reshape(batch, seq, heads, dk))
+            k = _l2norm(k.reshape(batch, seq, heads, dk))
+            v = v.reshape(batch, seq, heads, dv)
+        with jax.named_scope("state_update"):
+            if step:
+                o, new_state = gated_delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state, live)
+                o = o[:, None]
+            else:
+                o, new_state = gated_delta_chunked(q, k, v, g, beta, state, valid_len)
         o = RMSNorm(eps=cfg.rms_norm_eps, dtype=f32, name="o_norm")(o)
         o = o * jax.nn.silu(dense(heads * dv, "g")(x).astype(f32).reshape(o.shape))
         out = dense(cfg.hidden_size, "o")(o.reshape(batch, seq, heads * dv).astype(dtype))

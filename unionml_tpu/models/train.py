@@ -220,47 +220,51 @@ def accumulated_value_and_grad(
         # an exact leading +0): bitwise-identical trajectories.
         def body(carry, microbatch):
             loss_acc, aux_acc, grad_acc, pending = carry
-            grad_acc = jax.tree_util.tree_map(
-                lambda a, g: a + g.astype(jnp.float32), grad_acc, pending
-            )
+            with jax.named_scope("grad_accumulate"):
+                grad_acc = jax.tree_util.tree_map(
+                    lambda a, g: a + g.astype(jnp.float32), grad_acc, pending
+                )
             (loss, aux), grads = vg(params, microbatch)
-            loss_acc = loss_acc + loss.astype(jnp.float32)
-            aux_acc = jax.tree_util.tree_map(
-                lambda a, b: a + jnp.asarray(b, jnp.float32), aux_acc, aux
-            )
+            with jax.named_scope("grad_accumulate"):
+                loss_acc = loss_acc + loss.astype(jnp.float32)
+                aux_acc = jax.tree_util.tree_map(
+                    lambda a, b: a + jnp.asarray(b, jnp.float32), aux_acc, aux
+                )
             return (loss_acc, aux_acc, grad_acc, grads), None
 
-        pending0 = jax.tree_util.tree_map(
-            lambda s: jnp.zeros(s.shape, s.dtype), grad_s
-        )
-        (loss, aux, grads, pending), _ = jax.lax.scan(
-            body, (zeros(loss_s), zeros(aux_s), zeros(grad_s), pending0),
-            batch,
-        )
-        grads = jax.tree_util.tree_map(
-            lambda a, g: a + g.astype(jnp.float32), grads, pending
-        )
+        with jax.named_scope("grad_accumulate"):
+            pending0 = jax.tree_util.tree_map(
+                lambda s: jnp.zeros(s.shape, s.dtype), grad_s
+            )
+            init = (zeros(loss_s), zeros(aux_s), zeros(grad_s), pending0)
+        (loss, aux, grads, pending), _ = jax.lax.scan(body, init, batch)
+        with jax.named_scope("grad_accumulate"):
+            grads = jax.tree_util.tree_map(
+                lambda a, g: a + g.astype(jnp.float32), grads, pending
+            )
     else:
         def body(carry, microbatch):
             loss_acc, aux_acc, grad_acc = carry
             (loss, aux), grads = vg(params, microbatch)
-            loss_acc = loss_acc + loss.astype(jnp.float32)
-            aux_acc = jax.tree_util.tree_map(
-                lambda a, b: a + jnp.asarray(b, jnp.float32), aux_acc, aux
-            )
-            grad_acc = jax.tree_util.tree_map(
-                lambda a, g: a + g.astype(jnp.float32), grad_acc, grads
-            )
+            with jax.named_scope("grad_accumulate"):
+                loss_acc = loss_acc + loss.astype(jnp.float32)
+                aux_acc = jax.tree_util.tree_map(
+                    lambda a, b: a + jnp.asarray(b, jnp.float32), aux_acc, aux
+                )
+                grad_acc = jax.tree_util.tree_map(
+                    lambda a, g: a + g.astype(jnp.float32), grad_acc, grads
+                )
             return (loss_acc, aux_acc, grad_acc), None
 
-        (loss, aux, grads), _ = jax.lax.scan(
-            body, (zeros(loss_s), zeros(aux_s), zeros(grad_s)), batch
-        )
+        with jax.named_scope("grad_accumulate"):
+            init = (zeros(loss_s), zeros(aux_s), zeros(grad_s))
+        (loss, aux, grads), _ = jax.lax.scan(body, init, batch)
     mean = lambda t: jax.tree_util.tree_map(lambda x: x / n, t)  # noqa: E731
-    grads = jax.tree_util.tree_map(
-        lambda g, p: (g / n).astype(p.dtype), grads, params
-    )
-    return (loss / n, mean(aux)), grads
+    with jax.named_scope("grad_accumulate"):
+        grads = jax.tree_util.tree_map(
+            lambda g, p: (g / n).astype(p.dtype), grads, params
+        )
+        return (loss / n, mean(aux)), grads
 
 
 def _shard_map_accumulated(
@@ -301,50 +305,51 @@ def _shard_map_accumulated(
         def body(carry, microbatch):
             loss_acc, aux_acc, grad_acc, pending = carry
             # consume the PREVIOUS microbatch's reduced grads first …
-            grad_acc = jax.tree_util.tree_map(
-                lambda a, g: a + g, grad_acc, pending
-            )
+            with jax.named_scope("grad_accumulate"):
+                grad_acc = jax.tree_util.tree_map(
+                    lambda a, g: a + g, grad_acc, pending
+                )
             (loss, aux), grads = vg(params, microbatch)
             # … and issue this one's all-reduce, bucketed so the chunks
             # pipeline; its result is not needed until the next
             # iteration's carry-add
-            bucket_kw = (
-                {} if overlap.bucket_bytes is None
-                else {"bucket_bytes": overlap.bucket_bytes}
-            )
-            reduced = bucketed_psum(
-                jax.tree_util.tree_map(
-                    lambda g: g.astype(jnp.float32), grads
-                ),
-                axis_arg, **bucket_kw,
-            )
-            loss_acc = loss_acc + lax.pmean(
-                loss.astype(jnp.float32), axis_arg
-            )
-            aux_acc = jax.tree_util.tree_map(
-                lambda a, b: a + lax.pmean(
-                    jnp.asarray(b, jnp.float32), axis_arg
-                ),
-                aux_acc, aux,
-            )
+            with jax.named_scope("grad_accumulate"):
+                bucket_kw = (
+                    {} if overlap.bucket_bytes is None
+                    else {"bucket_bytes": overlap.bucket_bytes}
+                )
+                reduced = bucketed_psum(
+                    jax.tree_util.tree_map(
+                        lambda g: g.astype(jnp.float32), grads
+                    ),
+                    axis_arg, **bucket_kw,
+                )
+                loss_acc = loss_acc + lax.pmean(
+                    loss.astype(jnp.float32), axis_arg
+                )
+                aux_acc = jax.tree_util.tree_map(
+                    lambda a, b: a + lax.pmean(
+                        jnp.asarray(b, jnp.float32), axis_arg
+                    ),
+                    aux_acc, aux,
+                )
             return (loss_acc, aux_acc, grad_acc, reduced), None
 
-        (loss, aux, grads, pending), _ = jax.lax.scan(
-            body,
-            (zeros(loss_s), zeros(aux_s), zeros(grad_s), zeros(grad_s)),
-            batch,
-        )
-        grads = jax.tree_util.tree_map(lambda a, g: a + g, grads, pending)
-        ndev = lax.psum(1, axis_arg)
-        mean = lambda t: jax.tree_util.tree_map(  # noqa: E731
-            lambda x: x / n, t
-        )
-        # /(n*ndev) in ONE division: ndev is a power of two on real
-        # meshes, so the extra scale vs the serial path's /n is exact
-        grads = jax.tree_util.tree_map(
-            lambda g, p: (g / (n * ndev)).astype(p.dtype), grads, params
-        )
-        return (loss / n, mean(aux)), grads
+        with jax.named_scope("grad_accumulate"):
+            init = (zeros(loss_s), zeros(aux_s), zeros(grad_s), zeros(grad_s))
+        (loss, aux, grads, pending), _ = jax.lax.scan(body, init, batch)
+        with jax.named_scope("grad_accumulate"):
+            grads = jax.tree_util.tree_map(lambda a, g: a + g, grads, pending)
+            ndev = lax.psum(1, axis_arg)
+            mean = lambda t: jax.tree_util.tree_map(  # noqa: E731
+                lambda x: x / n, t
+            )
+            # /(n*ndev) in ONE division: ndev is a power of two on real
+            # meshes, so the extra scale vs the serial path's /n is exact
+            grads = jax.tree_util.tree_map(
+                lambda g, p: (g / (n * ndev)).astype(p.dtype), grads, params
+            )
+            return (loss / n, mean(aux)), grads
 
     fn = shard_map(
         local, mesh=overlap.mesh,
@@ -382,10 +387,11 @@ def classification_step(module: nn.Module, *, accumulate_steps: int = 1) -> Call
     def loss_fn(params, microbatch):
         features, labels = microbatch
         logits = module.apply({"params": params}, features)
-        loss = optax.softmax_cross_entropy_with_integer_labels(
-            logits.astype(jnp.float32), labels
-        ).mean()
-        return loss, {"accuracy": _accuracy(logits, labels)}
+        with jax.named_scope("loss"):
+            loss = optax.softmax_cross_entropy_with_integer_labels(
+                logits.astype(jnp.float32), labels
+            ).mean()
+            return loss, {"accuracy": _accuracy(logits, labels)}
 
     def step(state: TrainState, batch: Tuple[Any, Any]):
         bound = _bind_frozen(loss_fn, state)
@@ -397,7 +403,8 @@ def classification_step(module: nn.Module, *, accumulate_steps: int = 1) -> Call
             (loss, aux), grads = jax.value_and_grad(bound, has_aux=True)(
                 state.params, batch
             )
-        state = state.apply_gradients(grads=grads)
+        with jax.named_scope("optimizer"):
+            state = state.apply_gradients(grads=grads)
         return state, {"loss": loss, "accuracy": aux["accuracy"]}
 
     return step
@@ -432,18 +439,20 @@ def lm_step(
         if isinstance(microbatch, tuple):
             inputs, targets = microbatch
         else:
-            inputs, targets = microbatch[:, :-1], microbatch[:, 1:]
+            with jax.named_scope("loss"):
+                inputs, targets = microbatch[:, :-1], microbatch[:, 1:]
         logits, mods = module.apply(
             {"params": params}, inputs, mutable=["aux_losses"]
         )
-        ce_loss = masked_cross_entropy(logits, targets, ignore_id=ignore_id)
-        sown = jax.tree_util.tree_leaves(mods.get("aux_losses", {}))
-        aux = (
-            sum(v.astype(jnp.float32) for v in sown) / len(sown)
-            if sown
-            else jnp.float32(0.0)
-        )
-        return ce_loss + aux_loss_weight * aux, {"ce": ce_loss, "aux": aux}
+        with jax.named_scope("loss"):
+            ce_loss = masked_cross_entropy(logits, targets, ignore_id=ignore_id)
+            sown = jax.tree_util.tree_leaves(mods.get("aux_losses", {}))
+            aux = (
+                sum(v.astype(jnp.float32) for v in sown) / len(sown)
+                if sown
+                else jnp.float32(0.0)
+            )
+            return ce_loss + aux_loss_weight * aux, {"ce": ce_loss, "aux": aux}
 
     def step(state: TrainState, batch):
         bound = _bind_frozen(loss_fn, state)
@@ -455,9 +464,12 @@ def lm_step(
             (_, aux), grads = jax.value_and_grad(bound, has_aux=True)(
                 state.params, batch
             )
-        state = state.apply_gradients(grads=grads)
+        with jax.named_scope("optimizer"):
+            state = state.apply_gradients(grads=grads)
         loss, aux_loss = aux["ce"], aux["aux"]
-        return state, {"loss": loss, "perplexity": jnp.exp(loss), "aux_loss": aux_loss}
+        with jax.named_scope("loss"):
+            perplexity = jnp.exp(loss)
+        return state, {"loss": loss, "perplexity": perplexity, "aux_loss": aux_loss}
 
     return step
 

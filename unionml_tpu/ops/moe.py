@@ -538,13 +538,18 @@ def grouped_expert_mlp(tokens, weights, indices, w_gate, w_up, w_down, *, scales
     if impl == "auto":
         impl = "ragged_dot" if _interpret() else "pallas"
     chunk = _row_chunk(t * k, num_experts) if impl == "pallas" else 1
-    source, slot, group_sizes, tile_expert, tile_rows = group_rows(indices, num_experts, chunk)
+    with jax.named_scope("group_rows"):
+        source, slot, group_sizes, tile_expert, tile_rows = group_rows(indices, num_experts, chunk)
     layout = dict(tile_expert=tile_expert, tile_rows=tile_rows, chunk=chunk, impl=impl)
     gate_up, down = (None, None) if scales is None else (scales[:2], scales[2:])
-    hidden = grouped_matmul(tokens[source], (w_gate, w_up), group_sizes, scales=gate_up, **layout)
-    out = grouped_matmul(hidden, (w_down,), group_sizes, scales=down, **layout)
+    with jax.named_scope("gather"):
+        rows = tokens[source]
+    with jax.named_scope("experts"):
+        hidden = grouped_matmul(rows, (w_gate, w_up), group_sizes, scales=gate_up, **layout)
+        out = grouped_matmul(hidden, (w_down,), group_sizes, scales=down, **layout)
     # a token's k rows, weighted and summed in the order of its choices
-    return jnp.sum(out[slot].reshape(t, k, -1) * weights.astype(out.dtype)[..., None], axis=1)
+    with jax.named_scope("combine"):
+        return jnp.sum(out[slot].reshape(t, k, -1) * weights.astype(out.dtype)[..., None], axis=1)
 
 
 def _swiglu_experts(x, w_gate, w_up, w_down):
@@ -560,12 +565,15 @@ def dense_expert_mlp(tokens, weights, indices, w_gate, w_up, w_down, *, scales=N
     and GSPMD inserts the collectives when the expert dim is sharded. Same
     arguments and result as :func:`grouped_expert_mlp`."""
     dtype = tokens.dtype
-    dispatch = jax.nn.one_hot(indices, w_gate.shape[0], dtype=dtype)
-    combine = jnp.einsum("tke,tk->te", dispatch, weights.astype(dtype))  # [T, E] routing weight
-    mask = (combine > 0).astype(dtype)
-    expert_in = jnp.einsum("te,td->etd", mask, tokens)
+    with jax.named_scope("group_rows"):
+        dispatch = jax.nn.one_hot(indices, w_gate.shape[0], dtype=dtype)
+        combine = jnp.einsum("tke,tk->te", dispatch, weights.astype(dtype))  # [T, E] routing weight
+        mask = (combine > 0).astype(dtype)
+    with jax.named_scope("gather"):
+        expert_in = jnp.einsum("te,td->etd", mask, tokens)
     if scales is None:
-        expert_out = _swiglu_experts(expert_in, w_gate, w_up, w_down)
+        with jax.named_scope("experts"):
+            expert_out = _swiglu_experts(expert_in, w_gate, w_up, w_down)
     else:
         # int8->compute-dtype converts fuse into the einsums (HBM reads
         # stay int8); accumulate fp32 and apply the fp32 scale BEFORE
@@ -577,10 +585,12 @@ def dense_expert_mlp(tokens, weights, indices, w_gate, w_up, w_down, *, scales=N
             )
             return (y * w_s[:, None, :]).astype(dtype)
 
-        gated = jax.nn.silu(qmm(expert_in, w_gate, scales[0]))
-        up = qmm(expert_in, w_up, scales[1])
-        expert_out = qmm(gated * up, w_down, scales[2])
-    return jnp.einsum("etd,te->td", expert_out, combine)
+        with jax.named_scope("experts"):
+            gated = jax.nn.silu(qmm(expert_in, w_gate, scales[0]))
+            up = qmm(expert_in, w_up, scales[1])
+            expert_out = qmm(gated * up, w_down, scales[2])
+    with jax.named_scope("combine"):
+        return jnp.einsum("etd,te->td", expert_out, combine)
 
 
 def expert_parallel_moe_sharded(
@@ -745,18 +755,20 @@ class MoEMlp(nn.Module):
             bias = self.param(
                 "e_score_correction_bias", nn.initializers.zeros, (self.num_experts, 1), jnp.float32,
             )
-            gate_logits = jnp.matmul(
-                tokens.astype(jnp.float32), router_kernel, precision=lax.Precision.HIGHEST,
-            )
-            weights, indices = sigmoid_top_k_routing(
-                gate_logits, bias, self.num_selected, scaling=self.routed_scaling,
-            )
+            with jax.named_scope("router"):
+                gate_logits = jnp.matmul(
+                    tokens.astype(jnp.float32), router_kernel, precision=lax.Precision.HIGHEST,
+                )
+                weights, indices = sigmoid_top_k_routing(
+                    gate_logits, bias, self.num_selected, scaling=self.routed_scaling,
+                )
             aux_loss = jnp.zeros((), jnp.float32)
         elif self.router == "softmax":
-            gate_logits = tokens @ router_kernel.astype(tokens.dtype)
-            weights, indices, aux_loss, (routing_frac, gate_frac) = top_k_routing(
-                gate_logits, self.num_selected, return_stats=True
-            )
+            with jax.named_scope("router"):
+                gate_logits = tokens @ router_kernel.astype(tokens.dtype)
+                weights, indices, aux_loss, (routing_frac, gate_frac) = top_k_routing(
+                    gate_logits, self.num_selected, return_stats=True
+                )
 
             # the load-balance loss is a product of token-MEAN stats, so it is
             # not additive across sequence shards — sow the raw fractions into

@@ -31,7 +31,11 @@ family below (``init_fresh``, ``prefill_step``, ``finish_prefill``,
 ``prefill``), which runs over every served model and knows no residency.
 Every program has static shapes: XLA compiles one executable per bucket
 and program. The names of the traced functions are what the trace
-readers match (``jit_prefill``, ``jit_decode_chunk``): keep them.
+readers match (``jit_prefill``, ``jit_decode_chunk``): keep them. So are
+the ``jax.named_scope`` names round the work that no module of the model
+owns (``sample``, ``commit``, ``step_io``; ``draft`` / ``verify`` /
+``accept`` in a speculative round): a device trace carries them as each
+operation's ``tf_op`` (docs/observability.md "Device time by model part").
 """
 
 from __future__ import annotations
@@ -280,7 +284,8 @@ def build_programs(
     # (`finish_prefill`) commits into the slot and samples token 0. ----
 
     def fresh_caches(bucket):
-        return tuple(_init_layers(layout, 1, bucket) for layout in layouts)
+        with jax.named_scope("step_io"):
+            return tuple(_init_layers(layout, 1, bucket) for layout in layouts)
 
     @functools.partial(jax.jit, static_argnames=("bucket",))
     def init_fresh(*, bucket):
@@ -292,8 +297,9 @@ def build_programs(
         chunk goes through ``finish_prefill``)."""
         lf = fresh[0][first_rows][0].shape[1]  # bucket (static)
         c = toks.shape[1]
-        kv_mask = (jnp.arange(lf) < start + c)[None, :]
-        positions = start + jnp.arange(c)[None, :]
+        with jax.named_scope("step_io"):
+            kv_mask = (jnp.arange(lf) < start + c)[None, :]
+            positions = start + jnp.arange(c)[None, :]
         return tuple(
             model.apply(
                 {"params": pick(params)}, toks, positions=positions,
@@ -318,11 +324,12 @@ def build_programs(
         causal alone hides the trailing garbage)."""
         bucket = fresh[0][first_rows][0].shape[1]
         c = toks.shape[1]
-        kv_mask = (jnp.arange(bucket) < true_len)[None, :]
-        positions = start + jnp.arange(c)[None, :]
-        # head on the last REAL position only — the full-bucket head
-        # would materialize [1, bucket, vocab] fp32
-        last = jnp.reshape(true_len - 1 - start, (1,))
+        with jax.named_scope("step_io"):
+            kv_mask = (jnp.arange(bucket) < true_len)[None, :]
+            positions = start + jnp.arange(c)[None, :]
+            # head on the last REAL position only — the full-bucket head
+            # would materialize [1, bucket, vocab] fp32
+            last = jnp.reshape(true_len - 1 - start, (1,))
         outs = [
             model.apply(
                 {"params": pick(params)}, toks, positions=positions,
@@ -335,16 +342,18 @@ def build_programs(
             )
             for (model, pick), cache in zip(models, fresh)
         ]
-        first = sample(outs[0][0][:, 0], key)[0]
-        resident = residency.commit(
-            state, tuple(filled for _, filled in outs), slot, place, true_len
-        )
-        return {
-            **resident,
-            "fill": state["fill"].at[slot].set(true_len),
-            "last_tok": state["last_tok"].at[slot].set(first),
-            "done": state["done"].at[slot].set(False),
-        }, first
+        with jax.named_scope("sample"):
+            first = sample(outs[0][0][:, 0], key)[0]
+        with jax.named_scope("commit"):
+            resident = residency.commit(
+                state, tuple(filled for _, filled in outs), slot, place, true_len
+            )
+            return {
+                **resident,
+                "fill": state["fill"].at[slot].set(true_len),
+                "last_tok": state["last_tok"].at[slot].set(first),
+                "done": state["done"].at[slot].set(False),
+            }, first
 
     def prefill(params, state, slot, place, tokens, true_len, key):
         """Monolithic admission: fresh build + full-bucket finish in
@@ -358,7 +367,8 @@ def build_programs(
         """One cached splice unit's host rows into a fresh cache at a
         dynamic row offset (compiled once per (bucket, unit) shape)."""
         (cache,) = fresh
-        return (_splice_rows(cache, rows, 0, start),)
+        with jax.named_scope("commit"):
+            return (_splice_rows(cache, rows, 0, start),)
 
     # ---- the decode chunk ----
 
@@ -367,29 +377,32 @@ def build_programs(
         ((model, pick),) = models
 
         def step(state, key):
-            live = active & ~state["done"]
-            fill = state["fill"]
-            args = residency.step_args(state, live, place)
+            with jax.named_scope("step_io"):
+                live = active & ~state["done"]
+                fill = state["fill"]
+                args = residency.step_args(state, live, place)
             logits, cache = model.apply(
                 {"params": pick(params)}, state["last_tok"][:, None],
                 cache_index=fill, live=live, **args,
             )
-            resident = residency.step_result(args, cache)
-            nxt = sample(logits[:, -1], key)
-            nxt = jnp.where(live, nxt, pad_id)
-            done = state["done"]
-            if eos_id is not None:
-                done = done | (live & (nxt == eos_id))
-            advance = live & (fill + 1 < L)
-            # belt: a live slot at the cache end freezes its fill on a
-            # visible row — mark done so it stops writing there
-            done = done | (live & ~advance)
-            return {
-                **resident,
-                "fill": fill + advance.astype(jnp.int32),
-                "last_tok": jnp.where(live, nxt, state["last_tok"]),
-                "done": done,
-            }, nxt
+            with jax.named_scope("sample"):
+                nxt = sample(logits[:, -1], key)
+            with jax.named_scope("step_io"):
+                resident = residency.step_result(args, cache)
+                nxt = jnp.where(live, nxt, pad_id)
+                done = state["done"]
+                if eos_id is not None:
+                    done = done | (live & (nxt == eos_id))
+                advance = live & (fill + 1 < L)
+                # belt: a live slot at the cache end freezes its fill on a
+                # visible row — mark done so it stops writing there
+                done = done | (live & ~advance)
+                return {
+                    **resident,
+                    "fill": fill + advance.astype(jnp.int32),
+                    "last_tok": jnp.where(live, nxt, state["last_tok"]),
+                    "done": done,
+                }, nxt
 
         state, toks = jax.lax.scan(step, state, keys)
         return state, toks  # toks: [chunk_steps, slots]
@@ -410,8 +423,9 @@ def build_programs(
         arange_l = jnp.arange(L)[None, :]
 
         def round_body(state, _):
-            live = active & ~state["done"]
-            fill0 = state["fill"]
+            with jax.named_scope("step_io"):
+                live = active & ~state["done"]
+                fill0 = state["fill"]
 
             # draft proposes k tokens over k+1 steps (the extra step
             # consumes proposal k so a fully-accepted round leaves no
@@ -430,62 +444,65 @@ def build_programs(
                 nxt = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
                 return (d_cache, nxt, f + 1), nxt
 
-            (d_cache, _, _), props = jax.lax.scan(
-                dstep, (state["d_cache"], state["last_tok"], fill0),
-                None, length=k + 1,
-            )
-            props = props.transpose(1, 0)[:, :k]          # [B, k]
+            with jax.named_scope("draft"):
+                (d_cache, _, _), props = jax.lax.scan(
+                    dstep, (state["d_cache"], state["last_tok"], fill0),
+                    None, length=k + 1,
+                )
+                props = props.transpose(1, 0)[:, :k]          # [B, k]
 
             # ONE shared multi-token verify forward for every slot
-            verify_in = jnp.concatenate(
-                [state["last_tok"][:, None], props], axis=1
-            )
-            vis_v = state["kv_mask"] | (
-                (arange_l >= fill0[:, None])
-                & (arange_l <= (fill0 + k)[:, None])
-                & live[:, None]
-            )
-            v_logits, cache = module.apply(
-                {"params": params["target"]}, verify_in,
-                cache=state["cache"], cache_index=fill0, kv_mask=vis_v,
-            )
-            greedy = jnp.argmax(v_logits, -1).astype(jnp.int32)
-            accepted, correction, emit = greedy_acceptance(props, greedy)
-            n_emit = jnp.where(live, accepted + 1, 0)
-            done = state["done"]
-            if eos_id is not None:
-                pos_idx = jnp.arange(k + 1)[None, :]
-                eos_hit = (emit == eos_id) & (pos_idx < n_emit[:, None])
-                any_eos = eos_hit.any(axis=1)
-                first_eos = jnp.argmax(eos_hit, axis=1)
-                n_emit = jnp.where(
-                    any_eos, jnp.minimum(n_emit, first_eos + 1), n_emit
+            with jax.named_scope("verify"):
+                verify_in = jnp.concatenate(
+                    [state["last_tok"][:, None], props], axis=1
                 )
-                done = done | (live & any_eos)
-            # rows consumed = accepted + 1 (eos shrinks EMISSION, not
-            # the cache rows written — done stops later rounds)
-            advance = jnp.where(live, accepted + 1, 0)
-            new_fill = fill0 + advance
-            # freeze before the end: the next round writes k+1 rows
-            done = done | (live & (new_fill + k + 1 >= L))
-            new_kv = state["kv_mask"] | (
-                (arange_l >= fill0[:, None])
-                & (arange_l < new_fill[:, None])
-            )
-            new_last = jnp.where(live, correction, state["last_tok"])
-            out = (
-                jnp.where(live[:, None], emit, pad_id),
-                n_emit.astype(jnp.int32),
-                jnp.where(live, accepted, 0).astype(jnp.int32),
-            )
-            return {
-                "cache": cache,
-                "d_cache": d_cache,
-                "kv_mask": new_kv,
-                "fill": new_fill,
-                "last_tok": new_last,
-                "done": done,
-            }, out
+                vis_v = state["kv_mask"] | (
+                    (arange_l >= fill0[:, None])
+                    & (arange_l <= (fill0 + k)[:, None])
+                    & live[:, None]
+                )
+                v_logits, cache = module.apply(
+                    {"params": params["target"]}, verify_in,
+                    cache=state["cache"], cache_index=fill0, kv_mask=vis_v,
+                )
+            with jax.named_scope("accept"):
+                greedy = jnp.argmax(v_logits, -1).astype(jnp.int32)
+                accepted, correction, emit = greedy_acceptance(props, greedy)
+                n_emit = jnp.where(live, accepted + 1, 0)
+                done = state["done"]
+                if eos_id is not None:
+                    pos_idx = jnp.arange(k + 1)[None, :]
+                    eos_hit = (emit == eos_id) & (pos_idx < n_emit[:, None])
+                    any_eos = eos_hit.any(axis=1)
+                    first_eos = jnp.argmax(eos_hit, axis=1)
+                    n_emit = jnp.where(
+                        any_eos, jnp.minimum(n_emit, first_eos + 1), n_emit
+                    )
+                    done = done | (live & any_eos)
+                # rows consumed = accepted + 1 (eos shrinks EMISSION, not
+                # the cache rows written — done stops later rounds)
+                advance = jnp.where(live, accepted + 1, 0)
+                new_fill = fill0 + advance
+                # freeze before the end: the next round writes k+1 rows
+                done = done | (live & (new_fill + k + 1 >= L))
+                new_kv = state["kv_mask"] | (
+                    (arange_l >= fill0[:, None])
+                    & (arange_l < new_fill[:, None])
+                )
+                new_last = jnp.where(live, correction, state["last_tok"])
+                out = (
+                    jnp.where(live[:, None], emit, pad_id),
+                    n_emit.astype(jnp.int32),
+                    jnp.where(live, accepted, 0).astype(jnp.int32),
+                )
+                return {
+                    "cache": cache,
+                    "d_cache": d_cache,
+                    "kv_mask": new_kv,
+                    "fill": new_fill,
+                    "last_tok": new_last,
+                    "done": done,
+                }, out
 
         state, outs = jax.lax.scan(
             round_body, state, None, length=chunk_steps
