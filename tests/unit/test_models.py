@@ -246,3 +246,31 @@ def test_mlm_step_masks_padding_and_trains():
     for _ in range(10):
         state, metrics = step(state, batch)
     assert float(metrics["loss"]) < float(first["loss"])
+
+
+@pytest.mark.parametrize(
+    "lhs,rhs,dims",
+    [
+        # q / k / v: [B, S, d] x [d, H, D]; o: [B, S, H, D] x [H, D, d]
+        ((2, 5, 24), (24, 3, 8), (((2,), (0,)), ((), ()))),
+        ((2, 5, 3, 8), (3, 8, 24), (((2, 3), (0, 1)), ((), ()))),
+        # what the merge does not cover goes to lax.dot_general as it came
+        ((2, 5, 24), (24, 16), (((2,), (0,)), ((), ()))),
+        ((2, 24, 5), (24, 3, 8), (((1,), (0,)), ((), ()))),
+        ((2, 5, 24), (2, 24, 8), (((2,), (1,)), ((0,), (0,)))),
+    ],
+    ids=["to-heads", "from-heads", "flat-kernel", "inner-axis", "batched"],
+)
+def test_merged_dot_general_is_dot_general(lhs, rhs, dims):
+    from unionml_tpu.models.layers import merged_dot_general
+
+    a = jax.random.normal(jax.random.PRNGKey(0), lhs)
+    b = jax.random.normal(jax.random.PRNGKey(1), rhs)
+    want = jax.lax.dot_general(a, b, dims)
+    got = merged_dot_general(a, b, dims)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+    grads = jax.grad(lambda a, b: jnp.sum(merged_dot_general(a, b, dims) ** 2), argnums=(0, 1))(a, b)
+    wants = jax.grad(lambda a, b: jnp.sum(jax.lax.dot_general(a, b, dims) ** 2), argnums=(0, 1))(a, b)
+    for g, w in zip(grads, wants):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4, atol=1e-4)

@@ -127,6 +127,78 @@ def test_fused_gradients_match_reference_gqa():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-4)
 
 
+# (seq, q_heads, kv_heads, head_dim, causal) -> the blocks the rule takes:
+# two 64-wide heads a lane tile, a 128- or 256-wide head over whole tiles,
+# four 32-wide heads a tile; a width that fits no tile (80) or an odd head
+# count at 64 keeps a head a block row
+FUSED_LAYOUT_CASES = {
+    "d64-s197": ((197, 4, 4, 64, False), ("rows", 2)),
+    "d64-s64-causal": ((64, 4, 4, 64, True), ("rows", 2)),
+    "d64-s197-causal-gqa": ((197, 4, 2, 64, True), ("rows", 2)),
+    "d128-s64": ((64, 2, 2, 128, False), ("rows", 1)),
+    "d128-s48-causal-gqa": ((48, 4, 2, 128, True), ("rows", 1)),
+    "d256-s32-causal": ((32, 2, 2, 256, True), ("rows", 1)),
+    "d32-s80-causal": ((80, 8, 8, 32, True), ("rows", 4)),
+    "d80-s64-causal": ((64, 2, 2, 80, True), ("heads", 1)),
+    "d64-odd-heads-s48": ((48, 3, 3, 64, False), ("heads", 1)),
+}
+
+
+@pytest.mark.parametrize("what", ["values", "gradients"])
+@pytest.mark.parametrize("case", FUSED_LAYOUT_CASES)
+def test_fused_layouts_match_reference(case, what):
+    from unionml_tpu.ops.fused_attention import attention_layout, fused_attention
+
+    (seq, q_heads, kv_heads, dim, causal), expected = FUSED_LAYOUT_CASES[case]
+    q, k, v = make_qkv(batch=2, seq=seq, q_heads=q_heads, kv_heads=kv_heads, dim=dim)
+    assert attention_layout(seq, q_heads, dim, q.dtype)[:2] == expected
+    if what == "values":
+        np.testing.assert_allclose(
+            np.asarray(fused_attention(q, k, v, causal=causal)),
+            np.asarray(mha_reference(q, k, v, causal=causal)), atol=2e-5,
+        )
+        return
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v, causal=causal) ** 2)
+
+    g_ref = jax.grad(loss(mha_reference), argnums=(0, 1, 2))(q, k, v)
+    g_fused = jax.grad(loss(fused_attention), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g_ref, g_fused):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-4)
+
+
+@pytest.mark.parametrize(
+    "shape,layout,heads_per_tile,stored,values",
+    [
+        # the vit_b16_train cell: 197 rows lie in 208, nothing else is padding
+        ((197, 12, 64, jnp.bfloat16), "rows", 2, 208 * 768 * 2, 197 * 768 * 2),
+        ((128, 12, 64, jnp.bfloat16), "rows", 2, 128 * 768 * 2, 128 * 768 * 2),
+        ((512, 32, 128, jnp.bfloat16), "rows", 1, 512 * 4096 * 2, 512 * 4096 * 2),
+        ((197, 12, 64, jnp.float32), "rows", 2, 200 * 768 * 4, 197 * 768 * 4),
+        ((256, 8, 80, jnp.bfloat16), "heads", 1, 8 * 256 * 128 * 2, 256 * 640 * 2),
+        ((197, 3, 64, jnp.bfloat16), "heads", 1, 3 * 208 * 128 * 2, 197 * 192 * 2),
+    ],
+    ids=["vit-cell", "bert-base", "d128", "float32", "d80", "odd-heads"],
+)
+def test_fused_attention_layout_rule(shape, layout, heads_per_tile, stored, values):
+    from unionml_tpu.ops.fused_attention import _heads_layout, attention_layout
+
+    assert attention_layout(*shape) == (layout, heads_per_tile, stored, values)
+    if layout == "heads":
+        assert attention_layout(*shape) == _heads_layout(*shape)
+
+
+def test_fused_attention_layout_at_the_cell_stores_what_it_holds():
+    from unionml_tpu.ops.fused_attention import _heads_layout, attention_layout
+
+    cell = (197, 12, 64, jnp.bfloat16)
+    new, old = attention_layout(*cell), _heads_layout(*cell)
+    assert round(new.stored_bytes / new.value_bytes, 3) == 1.056
+    assert round(old.stored_bytes / old.value_bytes, 3) == 2.112
+
+
 def test_fused_rejects_long_sequences():
     from unionml_tpu.ops.fused_attention import fused_attention
 

@@ -264,17 +264,71 @@ def test_flash_attention_padded_gqa_forward_compiles(chip):
 
 
 VIT_QKV = ((64, 197, 12, 64), jnp.bfloat16)  # ViT-B/16 at batch 64
+# a decoder's short-sequence training under attn_impl="auto": 128-wide heads
+DECODER_QKV = ((4, 512, 8, 128), jnp.bfloat16)
+
+
+def _fused_grads(causal):
+    def loss(q, k, v):
+        return fused_attention.fused_attention(q, k, v, causal=causal).astype(jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2))
 
 
 def test_fused_attention_forward_compiles(chip):
-    _assert_mosaic(chip, fused_attention.fused_attention, VIT_QKV, VIT_QKV, VIT_QKV)
+    text = _assert_mosaic(chip, fused_attention.fused_attention, VIT_QKV, VIT_QKV, VIT_QKV)
+    # two 64-wide heads a lane tile: the kernel takes the projections' own
+    # [B, S, H*D], not [B, H, S, D] blocks whose every tile is half padding
+    assert re.search(r"bf16\[64,197,768\]\S* custom-call\(.*tpu_custom_call", text)
 
 
 def test_fused_attention_backward_compiles(chip):
-    def loss(q, k, v):
-        return fused_attention.fused_attention(q, k, v).astype(jnp.float32).sum()
+    text = _assert_mosaic(chip, _fused_grads(False), VIT_QKV, VIT_QKV, VIT_QKV)
+    assert "bf16[64,12,197,64]" not in "".join(
+        line for line in text.splitlines() if "tpu_custom_call" in line
+    )
 
-    _assert_mosaic(chip, jax.grad(loss, argnums=(0, 1, 2)), VIT_QKV, VIT_QKV, VIT_QKV)
+
+def test_fused_attention_wide_heads_causal_compile(chip):
+    text = _assert_mosaic(chip, _fused_grads(True), DECODER_QKV, DECODER_QKV, DECODER_QKV)
+    assert re.search(r"bf16\[4,512,1024\]\S* custom-call\(.*tpu_custom_call", text)
+
+
+def test_fused_attention_heads_major_width_compiles(chip):
+    # 80 lanes fit no tile: a head a block row, as every width was before
+    qkv = ((8, 256, 8, 80), jnp.bfloat16)
+    text = _assert_mosaic(chip, _fused_grads(False), qkv, qkv, qkv)
+    assert re.search(r"bf16\[8,8,256,80\]\S* custom-call\(.*tpu_custom_call", text)
+
+
+def test_attention_layer_keeps_lane_dense_rows_through_the_kernel(chip):
+    """ViT-B's attention layer, forward and backward: the projections make
+    and take ``[B, S, 768]`` (``layers.merged_dot_general``), so the reshape
+    round the kernel cancels and nothing is laid out with a 64-wide minor
+    axis, nor copied into what the kernel takes."""
+    from unionml_tpu.models.layers import Attention
+
+    layer = Attention(num_heads=12, attn_impl="fused")
+    x = jax.ShapeDtypeStruct((64, 197, 768), jnp.bfloat16, sharding=chip)
+    params = jax.tree_util.tree_map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=chip),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0), x),
+    )
+    assert params["params"]["q"]["kernel"].shape == (768, 12, 64)  # the tree is as it was
+    assert params["params"]["o"]["kernel"].shape == (12, 64, 768)
+
+    def loss(params, x):
+        return layer.apply(params, x).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile().as_text()
+    calls = [line for line in text.splitlines() if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 2
+    for line in calls:
+        shapes = line.split("custom_call_target")[0]
+        assert "bf16[64,197,768]{2,1,0" in shapes and ",64]{" not in shapes
+    assert not re.search(r"bf16\[64,(197,12|12,197),64\]", text)
+    # a prefetch (copy-done) may feed the kernel; a layout copy may not
+    assert not any(re.search(r"custom-call\([^)]*%copy(\.\d+)?[,)]", line) for line in calls)
 
 
 def test_fused_layer_norm_forward_and_grad_compile(chip):

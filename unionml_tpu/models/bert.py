@@ -57,13 +57,17 @@ class BertBlock(nn.Module):
         cfg = self.config
         dtype = jnp.dtype(cfg.dtype)
         head_dim = cfg.hidden_dim // cfg.num_heads
+        from unionml_tpu.models.layers import ATTN_IMPLS, merged_dot_general
+
+        # products of width heads * head_dim, as in layers.Attention: what
+        # lies between the projections and a fused kernel stays lane-dense
         dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
-            features=feats, axis=-1, dtype=dtype, name=name
+            features=feats, axis=-1, dtype=dtype, name=name,
+            dot_general=merged_dot_general,
         )
         q = dense((cfg.num_heads, head_dim), "attn_q")(x)
         k = dense((cfg.num_heads, head_dim), "attn_k")(x)
         v = dense((cfg.num_heads, head_dim), "attn_v")(x)
-        from unionml_tpu.models.layers import ATTN_IMPLS
 
         # BERT has no sequence mesh axis: the sequence-parallel impls can
         # never work here
@@ -85,7 +89,8 @@ class BertBlock(nn.Module):
                 q, k, v, impl=cfg.attn_impl, causal=False, sequence_axis=None
             )
         attn = nn.DenseGeneral(
-            features=cfg.hidden_dim, axis=(-2, -1), dtype=dtype, name="attn_o"
+            features=cfg.hidden_dim, axis=(-2, -1), dtype=dtype, name="attn_o",
+            dot_general=merged_dot_general,
         )(attn)
         x = nn.LayerNorm(dtype=dtype, name="ln1")(x + attn)
         h = MlpBlock(

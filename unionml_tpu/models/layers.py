@@ -22,6 +22,7 @@ Design notes (TPU):
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Any, Optional, Tuple
 
@@ -155,6 +156,37 @@ class SlotState:
         )
 
 
+def merged_dot_general(lhs, rhs, dimension_numbers, precision=None, preferred_element_type=None):
+    """``lax.dot_general`` as ``DenseGeneral`` calls it, over the kernel's
+    axes merged to ``[K, N]``: a projection to ``(heads, head_dim)`` is one
+    product of width ``heads * head_dim`` followed by a reshape, and one
+    from it is a reshape followed by a product of that depth. The same sums;
+    what differs is what XLA may lay out: a ``[B, S, H, D]`` result is tiled
+    over its 64-wide minor axis (half of every tile padding), a
+    ``[B, S, H*D]`` one is not, and the reshape back cancels against the
+    fused attention kernel's own (ops/fused_attention.py)."""
+    (lhs_contract, rhs_contract), (lhs_batch, _) = dimension_numbers
+    n_contract = len(rhs_contract)
+    trailing = tuple(range(lhs.ndim - n_contract, lhs.ndim))
+    if (
+        lhs_batch or rhs.ndim == 2 or tuple(lhs_contract) != trailing
+        or tuple(rhs_contract) != tuple(range(n_contract))
+    ):
+        return jax.lax.dot_general(
+            lhs, rhs, dimension_numbers, precision=precision,
+            preferred_element_type=preferred_element_type,
+        )
+    features = rhs.shape[n_contract:]
+    depth = math.prod(rhs.shape[:n_contract])
+    flat = lhs.reshape(lhs.shape[: lhs.ndim - n_contract] + (depth,))
+    out = jax.lax.dot_general(
+        flat, rhs.reshape(depth, math.prod(features)),
+        (((flat.ndim - 1,), (0,)), ((), ())), precision=precision,
+        preferred_element_type=preferred_element_type,
+    )
+    return out.reshape(out.shape[:-1] + features)
+
+
 def make_dense(
     *,
     quantized: bool,
@@ -203,7 +235,7 @@ def make_dense(
         return QuantizedDenseGeneral(features=features, axis=axis, dtype=dtype, name=name)
     return nn.DenseGeneral(
         features=features, axis=axis, use_bias=use_bias, dtype=dtype,
-        param_dtype=param_dtype, name=name,
+        param_dtype=param_dtype, name=name, dot_general=merged_dot_general,
     )
 
 

@@ -41,8 +41,10 @@ class ViTConfig:
 
     @staticmethod
     def base16(num_classes: int = 1000, attn_impl: str = "fused") -> "ViTConfig":
-        # "fused" = Pallas one-program-per-batch attention: at S=197 it
-        # beats XLA attention ~1.6x fwd+bwd on v5e (see ops/fused_attention)
+        # "fused" = Pallas one-program-per-batch attention over the
+        # projections' own [B, S, 768] (two 64-wide heads a lane tile, so
+        # nothing between q/k/v/o and the kernel is half padding): at S=197
+        # it beats XLA attention fwd+bwd on v5e (see ops/fused_attention)
         return ViTConfig(num_classes=num_classes, attn_impl=attn_impl)
 
     @staticmethod
