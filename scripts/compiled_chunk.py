@@ -96,7 +96,7 @@ def compiled_chunk_text(config_path: str, program: str = "decode", bucket: int |
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from unionml_tpu.ops import flash_attention, gated_delta, moe, paged_attention
+    from unionml_tpu.ops import flash_attention, gated_delta, moe, paged_attention, sparse_attention
     from unionml_tpu.serving.engine import DecodeEngine
 
     try:
@@ -105,7 +105,8 @@ def compiled_chunk_text(config_path: str, program: str = "decode", bucket: int |
         raise SystemExit(f"compiled_chunk: no TPU compiler here, nothing compiled ({exc!r})") from None
     chip = SingleDeviceSharding(topo.devices[0])
     jax.config.update("jax_enable_compilation_cache", False)  # unreadable without the chip
-    for module in (flash_attention, gated_delta, moe, paged_attention):  # off their CPU branch
+    # off their CPU branch
+    for module in (flash_attention, gated_delta, moe, paged_attention, sparse_attention):
         module._interpret = lambda: False
 
     cfg = json.loads(Path(config_path).read_text())

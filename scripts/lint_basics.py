@@ -463,7 +463,16 @@ DEVICE_SCOPE_NAMES = (
     "conv", "gates", "state_update",
     # models/glm_moe_lite.py LatentAttention, under ``attn``
     "absorb", "expand",
+    # models/keye_vl_moe.py IndexedSparseAttention and ops/sparse_attention.py,
+    # under ``attn``; ops/paged_attention.py's plain form of the decode read
+    "indexer", "select", "paged_sparse_attention",
 )
+# scopes that stand in no row of ``PART_TABLE``: they are only ever opened
+# under the module named here, whose row places them (``part_of`` reads the
+# whole path), so the benchmark's table needs no edit for them
+DEVICE_SCOPES_PLACED_BY_OWNER = {
+    "indexer": "attn", "select": "attn", "paged_sparse_attention": "attn",
+}
 _DEVICE_SCOPE_DOC_BEGIN = "<!-- DEVICE_SCOPE_NAMES:begin -->"
 _DEVICE_SCOPE_DOC_END = "<!-- DEVICE_SCOPE_NAMES:end -->"
 PART_TABLE_MODULE = "chipbench/opscopes.py"
@@ -560,6 +569,14 @@ def check_device_scope_names(root: Path) -> list:
                         f"{METRICS_DOC}: device scope {name!r} from "
                         "DEVICE_SCOPE_NAMES is not documented"
                     )
+                elif table and name not in table and name in DEVICE_SCOPES_PLACED_BY_OWNER:
+                    owner = DEVICE_SCOPES_PLACED_BY_OWNER[name]
+                    if table.get(owner) != documented[name]:
+                        problems.append(
+                            f"{PART_TABLE_MODULE}: PART_TABLE puts {owner!r}, the owner of device "
+                            f"scope {name!r}, under {table.get(owner)!r}, {METRICS_DOC} puts the "
+                            f"scope under {documented[name]!r}"
+                        )
                 elif table and table.get(name) != documented[name]:
                     problems.append(
                         f"{PART_TABLE_MODULE}: PART_TABLE puts device scope "

@@ -23,6 +23,7 @@ import pytest
 from unionml_tpu.models import ViT, ViTConfig, classification_step, lm_step
 from unionml_tpu.models.generate import make_sampler
 from unionml_tpu.models.glm_moe_lite import GlmMoeLite, GlmMoeLiteConfig
+from unionml_tpu.models.keye_vl_moe import KeyeVLMoe, KeyeVLMoeConfig
 from unionml_tpu.models.llama import Llama, LlamaConfig
 from unionml_tpu.models.olmo_hybrid import OlmoHybrid, OlmoHybridConfig
 from unionml_tpu.models.train import TrainState, adamw
@@ -126,6 +127,7 @@ FAMILIES = {
     "float_moe_llama": lambda: Llama(LlamaConfig.tiny(vocab_size=97, num_experts=4, num_selected=2)),
     "olmo_hybrid": lambda: OlmoHybrid(OlmoHybridConfig.tiny(vocab_size=97)),
     "glm_moe_lite": lambda: GlmMoeLite(GlmMoeLiteConfig.tiny(vocab_size=97)),
+    "keye_vl_moe": lambda: KeyeVLMoe(KeyeVLMoeConfig.tiny(vocab_size=97, quantized=True)),
     "vit": lambda: ViT(ViTConfig.tiny()),
 }
 MOE_SCOPES = {"router", "group_rows", "gather", "experts", "combine"}
@@ -177,7 +179,7 @@ def _train_text(module, accumulate_steps: int = 1) -> str:
 
 SERVED = [
     (family, program)
-    for family in ("dense_llama", "int8_moe_llama", "olmo_hybrid", "glm_moe_lite")
+    for family in ("dense_llama", "int8_moe_llama", "olmo_hybrid", "glm_moe_lite", "keye_vl_moe")
     for program in ("decode_chunk", "prefill")
 ]
 
@@ -200,6 +202,12 @@ def test_served_program_names_every_operation(family, program):
         assert "absorb" in scopes_under(names, "attn")
     if family == "olmo_hybrid":
         assert scopes_under(names, "gdn") == {"conv", "gates", "state_update"}
+    if family == "keye_vl_moe":
+        assert scopes_under(names, "moe") == MOE_SCOPES
+        # topk 8 of a 64-row table and of a 32-token bucket: the selection runs
+        # in both programs; only the decode step gathers picked rows
+        picked = {"paged_sparse_attention"} if program == "decode_chunk" else set()
+        assert scopes_under(names, "attn") == {"indexer", "select"} | picked
 
 
 def test_a_slot_rows_chunk_commits_nothing_but_a_prefill_does():

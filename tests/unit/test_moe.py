@@ -388,6 +388,46 @@ def test_group_rows_layout(chunk):
         np.testing.assert_array_equal(held[:used], tile_rows[tile_expert == e][:used])
 
 
+@pytest.mark.parametrize("chunk", [1, 16])
+def test_group_rows_gives_a_token_left_out_no_row(chunk):
+    """``valid`` takes a right-padded prompt's padding out of the layout:
+    the real tokens' pairs lie as they would alone, fewer tiles hold rows,
+    and the others' slots read row 0."""
+    from unionml_tpu.ops.moe import group_rows
+
+    experts, selected, tokens, real = 8, 2, 50, 31
+    _, indices, _ = top_k_routing(jax.random.normal(jax.random.PRNGKey(0), (tokens, experts)), selected)
+    valid = jnp.arange(tokens) < real
+    source, slot, sizes, tile_expert, tile_rows = map(np.asarray, group_rows(indices, experts, chunk, valid))
+    alone = [np.asarray(a) for a in group_rows(indices[:real], experts, chunk)]
+    np.testing.assert_array_equal(sizes, alone[2])
+    np.testing.assert_array_equal(slot[: real * selected], alone[1])
+    assert (slot[real * selected:] == 0).all()
+    np.testing.assert_array_equal(source[slot[: real * selected]], np.arange(real * selected) // selected)
+    assert tile_rows.sum() == real * selected and (tile_rows > 0).sum() == (alone[4] > 0).sum()
+    used = (tile_rows > 0).sum()
+    np.testing.assert_array_equal(tile_expert[:used], alone[3][:used])
+
+
+@pytest.mark.parametrize("impl", ["ragged_dot", "pallas"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["float", "int8"])
+def test_grouped_experts_send_a_token_left_out_nowhere(quantized, impl):
+    """The real tokens' outputs are what they are without the mask, the
+    others' are zero, whatever the others hold."""
+    from unionml_tpu.ops.moe import grouped_expert_mlp
+
+    experts, selected, tokens, real, d, hidden = 8, 2, 40, 23, 32, 64
+    ws, scales = _expert_weights(experts, d, hidden, quantized, seed=3)
+    x = jax.random.normal(jax.random.PRNGKey(5), (tokens, d))
+    weights, indices, _ = top_k_routing(jax.random.normal(jax.random.PRNGKey(6), (tokens, experts)), selected)
+    valid = jnp.arange(tokens) < real
+    want = grouped_expert_mlp(x, weights, indices, *ws, scales=scales, impl=impl)
+    got = grouped_expert_mlp(
+        x.at[real:].set(jnp.nan), weights, indices, *ws, scales=scales, impl=impl, valid=valid)
+    np.testing.assert_allclose(np.asarray(got[:real]), np.asarray(want[:real]), rtol=1e-5, atol=1e-5)
+    assert not np.asarray(got[real:]).any()
+
+
 @pytest.mark.parametrize("quantized", [False, True], ids=["float", "int8"])
 @pytest.mark.parametrize("gated", [False, True], ids=["down", "gate_up"])
 def test_grouped_matmul_kernel_matches_ragged_dot(gated, quantized):

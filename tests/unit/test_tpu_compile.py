@@ -34,6 +34,7 @@ from unionml_tpu.ops import (
     int4_matmul,
     moe,
     paged_attention,
+    sparse_attention,
 )
 
 
@@ -55,7 +56,9 @@ def _compile_for_the_chip(monkeypatch):
     written there but cannot be read back without one."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
-    for module in (flash_attention, fused_attention, fused_norm, gated_delta, moe, paged_attention):
+    for module in (
+        flash_attention, fused_attention, fused_norm, gated_delta, moe, paged_attention, sparse_attention,
+    ):
         monkeypatch.setattr(module, "_interpret", lambda: False)
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -509,3 +512,74 @@ def test_moe_grouped_matmul_compiles_at_64_experts_top_4(chip, tokens):
     )
     assert len(re.findall(r"%moe_grouped_matmul(?:\.\d+)* = ", text)) == 2
     _no_array_of_every_expert(text, tokens, **_GLM_LAYER)
+
+
+# ---- the keye_vl2_long_context_decode cell's new programs (PR 40): 16 rows
+# over a table 274 blocks of 64 positions wide (16,384 + 1,024 + the in-flight
+# chunks' spare rows) in a 4.0 GB pool of 2,260 blocks a layer (a position's
+# keys and values one 8 x 128 tile, the 64-wide indexer key held in 128
+# lanes); the indexer's 16 heads of 64; the exact top-2,048; whole prompts in
+# blocks of queries. Blocks of 16 (the other served cells') compile too
+@pytest.mark.parametrize(
+    "blocks,block,width", [(2_260, 64, 274), (9_042, 16, 1_093), (512, 16, 11)],
+    ids=["cell-16x274x64", "blocks-of-16", "short-table"],
+)
+def test_paged_index_scores_compiles(chip, blocks, block, width):
+    from unionml_tpu.models.layers import IndexedKVRows
+
+    assert IndexedKVRows(4, 128, 64).index_stored == 128
+
+    def fn(iq, iw, pool, table, lengths):
+        return paged_attention.paged_index_scores(iq, iw, pool, table, lengths, impl="pallas")
+
+    text = _assert_mosaic(
+        chip, fn, ((16, 16, 128), jnp.bfloat16), ((16, 16), jnp.float32),
+        ((blocks, block, 128), jnp.bfloat16), ((16, width), jnp.int32), ((16,), jnp.int32),
+    )
+    # chipbench's index_scores_roofline finds the kernel by this name
+    assert re.search(r"%paged_index_scores(\.\d+)* = ", text)
+    # the pool goes to the kernel as it lies: no copy, no relayout of it
+    assert not re.search(rf"bf16\[{blocks},{block},128\]\S* (?:copy|transpose)\(", text)
+
+
+def test_the_sparse_decode_read_gathers_picked_rows_and_copies_no_pool(chip):
+    """The exact top-2,048 of 17,536 scores a row and the attention over the
+    picked rows, as the decode step runs them: the pool is read by one
+    gather of 16 x 2,048 tiles of 8 x 128 (a position's keys and values
+    together), never copied or re-laid out whole."""
+    def fn(q, kv, table, scores):
+        picked, valid = sparse_attention.select_top_k(scores, 2048)
+        return paged_attention.paged_sparse_attention(q, kv, table, picked, valid)
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in (
+        ((16, 32, 128), jnp.bfloat16), ((2_260, 64, 8, 128), jnp.bfloat16),
+        ((16, 274), jnp.int32), ((16, 17_536), jnp.float32))]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert re.search(r"bf16\[(?:16,2048|32768),8,128\]\S* (?:gather|fusion)\(", text)
+    assert not re.search(r"bf16\[2260,64,8,128\]\S* (?:copy|transpose|fusion)\(", text)
+    assert "paged_sparse_attention" in text
+
+
+@pytest.mark.parametrize("seq", [4096, 16384], ids=["smallest-bucket", "largest-bucket"])
+def test_a_whole_prompts_sparse_attention_compiles_as_two_kernels(chip, seq):
+    """One layer of a whole prompt at the cell's smallest and largest
+    bucket: index scores and the selection in the kernel
+    ``sparse_prefill_select`` (a tile of 128 queries' ordered scores over
+    up to 16,384 keys in 8 MB of fast memory), the softmax over the selected
+    set in ``sparse_prefill_attention``; no loop over blocks of queries and
+    no float32 ``[S, S]`` array of scores."""
+    def fn(q, k, v, iq, ik, iw, valid):
+        return sparse_attention.sparse_attention(
+            q, k, v, iq, ik, iw, jnp.arange(seq)[None, :], valid, topk=2048, scale=128 ** -0.5)
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in (
+        ((1, seq, 32, 128), jnp.bfloat16), ((1, seq, 4, 128), jnp.bfloat16), ((1, seq, 4, 128), jnp.bfloat16),
+        ((1, seq, 16, 64), jnp.bfloat16), ((1, seq, 64), jnp.bfloat16), ((1, seq, 16), jnp.float32),
+        ((1, seq), jnp.bool_))]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert re.search(r"%sparse_prefill_select(\.\d+)* = ", text)
+    assert re.search(r"%sparse_prefill_attention(\.\d+)* = ", text)
+    assert "while(" not in text
+    assert not re.search(rf"f32\[(?:1,)?(?:(?:32|4,8|16),)?{seq},{seq}\]", text)
+    # the mask goes from one kernel to the other in tiles, as int8
+    assert re.search(rf"s8\[1,{seq // 128},{seq // 512},128,512\]", text)

@@ -32,6 +32,11 @@ from unionml_tpu.models.glm_moe_lite import (
     GlmMoeLite,
     GlmMoeLiteConfig,
 )
+from unionml_tpu.models.keye_vl_moe import (
+    KEYE_VL_MOE_QUANT_PATTERNS,
+    KeyeVLMoe,
+    KeyeVLMoeConfig,
+)
 from unionml_tpu.models.encdec import (
     ENCDEC_PARTITION_RULES,
     EncDecConfig,
@@ -107,6 +112,7 @@ __all__ = [
     "Llama", "LlamaConfig", "init_cache", "LLAMA_PARTITION_RULES",
     "OlmoHybrid", "OlmoHybridConfig",
     "GlmMoeLite", "GlmMoeLiteConfig", "GLM_MOE_LITE_QUANT_PATTERNS",
+    "KeyeVLMoe", "KeyeVLMoeConfig", "KEYE_VL_MOE_QUANT_PATTERNS",
     "EncoderDecoder", "EncDecConfig", "ENCDEC_PARTITION_RULES",
     "init_decoder_cache", "make_seq2seq_generator", "make_seq2seq_predictor", "seq2seq_step",
     "LLAMA_QUANT_PARTITION_RULES", "LLAMA_MOE_PARTITION_RULES",

@@ -42,7 +42,8 @@ Dtype = Any
 # of these per layer, in layer order; its ``__call__`` takes and returns a
 # per-layer ``cache`` tuple whose entries are what ``init`` builds.
 # ``owns_rows`` says which residency a paged engine gives the layer: rows
-# of its block pool (``KVRows``, ``LatentRows``) or a state per slot.
+# of its block pool (``KVRows``, ``LatentRows``, ``IndexedKVRows``) or a
+# state per slot.
 
 
 @dataclass(frozen=True)
@@ -126,6 +127,69 @@ class LatentRows:
     def pool_row(self) -> Tuple[int, int, int]:
         """As ``KVRows.pool_row``: one head, the row as stored."""
         return 1, self.stored_width, jnp.dtype(self.dtype).itemsize
+
+
+@dataclass(frozen=True)
+class IndexedKVRows:
+    """A layer whose cache grows by one row a token and the row is keys,
+    values *and* one key of a learned indexer (sparse attention after
+    DeepSeek-V3.2's: a light scorer reads every cached position's
+    ``index_dim``-wide key, picks the positions a query attends, and only
+    their keys and values are read). Two buffers with the same blocks, ids
+    and lifetime as :class:`KVRows`' two: a paged engine gives the layer
+    rows of its block pool, commits a prefill by block scatter, and a block
+    prefix restores a sequence, indexer keys included.
+
+    The first buffer holds a position's keys *and* values as ``2 *
+    kv_heads`` rows of ``head_dim``: ``[.., 2 * kv_heads, head_dim]``, the
+    key heads first. With 4 + 4 heads of 128 that is one whole bfloat16 tile
+    of 8 x 128 a position: 2 KB that lie together, so a picked position is
+    one fetch of one tile (rows of a ``[.., 1024]`` buffer are strips of
+    many tiles, and gathering them read slower in a program whose picks
+    were constants: the sizes are not to be trusted, PERF.md, PR 40), and the
+    block scatter of a prefill keeps the pool's layout (a ``[.., 4, 128]``
+    buffer is tiled over four rows and was re-laid out round every scatter).
+    The second holds the indexer key ``index_stored`` wide: whole tiles of
+    128 lanes, the last columns zero (a 64-wide bfloat16 minor axis lies in
+    128 lanes on the chip whatever its shape says: the padding is made
+    explicit and budgeted)."""
+
+    kv_heads: int
+    head_dim: int
+    index_dim: int
+    dtype: str = "bfloat16"
+    owns_rows = True
+    kind = "kv+index"
+
+    @property
+    def kv_width(self) -> int:
+        return self.kv_heads * self.head_dim
+
+    @property
+    def index_stored(self) -> int:
+        return -(-self.index_dim // 128) * 128
+
+    def init(self, batch: int, rows: int, dtype: Optional[Dtype] = None):
+        dtype = jnp.dtype(dtype or self.dtype)
+        return (
+            jnp.zeros((batch, rows, 2 * self.kv_heads, self.head_dim), dtype),
+            jnp.zeros((batch, rows, self.index_stored), dtype),
+        )
+
+    def row_nbytes(self) -> int:
+        """Bytes of the values one cached position holds in this layer."""
+        return jnp.dtype(self.dtype).itemsize * (2 * self.kv_width + self.index_dim)
+
+    def pool_row_nbytes(self) -> int:
+        """Bytes a position takes as stored, which is what a paged engine
+        budgets (4 x 128 keys, as many values, a 64-wide indexer key in 128
+        lanes, bfloat16: 2,304)."""
+        return jnp.dtype(self.dtype).itemsize * (2 * self.kv_width + self.index_stored)
+
+    @property
+    def pool_row(self) -> Tuple[int, int, int]:
+        """As ``KVRows.pool_row``: the keys' and values' buffers."""
+        return self.kv_heads, self.head_dim, jnp.dtype(self.dtype).itemsize
 
 
 @dataclass(frozen=True)

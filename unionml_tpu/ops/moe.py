@@ -276,9 +276,12 @@ def _padded_rows(rows: int, num_experts: int, chunk: int) -> int:
     return (rows + num_experts * (chunk - 1)) // chunk * chunk
 
 
-def group_rows(indices: jnp.ndarray, num_experts: int, chunk: int = 1):
+def group_rows(indices: jnp.ndarray, num_experts: int, chunk: int = 1, valid: Optional[jnp.ndarray] = None):
     """Order the ``[T, k]`` routed pairs by expert (stable: by token within
     an expert), each expert's rows starting on a multiple of ``chunk``.
+    The pairs of a token that ``valid`` [T] leaves out (a right-padded
+    prompt's padding) take no row: their ``slot`` is row 0, for the caller
+    to leave unread.
 
     Returns ``(source [R], slot [T * k], group_sizes [E], tile_expert
     [R // chunk], tile_rows [R // chunk])``: ``source[r]`` is the token whose
@@ -290,14 +293,24 @@ def group_rows(indices: jnp.ndarray, num_experts: int, chunk: int = 1):
     num_selected = indices.shape[-1]
     flat = indices.reshape(-1).astype(jnp.int32)
     pairs = flat.shape[0]
-    group_sizes = jnp.bincount(flat, length=num_experts).astype(jnp.int32)
+    if valid is not None:
+        flat = jnp.where(jnp.repeat(valid, num_selected), flat, num_experts)   # sorted behind every expert
+        group_sizes = jnp.bincount(flat, length=num_experts + 1)[:num_experts].astype(jnp.int32)
+    else:
+        group_sizes = jnp.bincount(flat, length=num_experts).astype(jnp.int32)
     padded = (group_sizes + chunk - 1) // chunk * chunk
     ends, padded_ends = jnp.cumsum(group_sizes), jnp.cumsum(padded)
     order = jnp.argsort(flat, stable=True)
     expert = flat[order]
-    row = (padded_ends - padded)[expert] + jnp.arange(pairs, dtype=jnp.int32) - (ends - group_sizes)[expert]
     rows = _padded_rows(pairs, num_experts, chunk)
-    source = jnp.zeros((rows,), jnp.int32).at[row].set((order // num_selected).astype(jnp.int32))
+    if valid is not None:
+        taken, expert = expert < num_experts, jnp.minimum(expert, num_experts - 1)
+    row = (padded_ends - padded)[expert] + jnp.arange(pairs, dtype=jnp.int32) - (ends - group_sizes)[expert]
+    write = row
+    if valid is not None:
+        # a pair left out writes past the last row (dropped) and reads row 0
+        write, row = jnp.where(taken, row, rows), jnp.where(taken, row, 0)
+    source = jnp.zeros((rows,), jnp.int32).at[write].set((order // num_selected).astype(jnp.int32))
     slot = jnp.zeros((pairs,), jnp.int32).at[order].set(row)
     tile_start = jnp.arange(rows // chunk, dtype=jnp.int32) * chunk
     # the expert whose padded rows hold the tile's first row (the last
@@ -528,18 +541,21 @@ def grouped_matmul(
     return jax.nn.silu(ys[0]) * ys[1] if gated else ys[0]
 
 
-def grouped_expert_mlp(tokens, weights, indices, w_gate, w_up, w_down, *, scales=None, impl="auto"):
+def grouped_expert_mlp(
+    tokens, weights, indices, w_gate, w_up, w_down, *, scales=None, impl="auto", valid=None,
+):
     """The experts' SwiGLU over ``tokens`` [T, d] for the routing ``weights``
     / ``indices`` [T, k]: every routed pair computed by its own expert, none
     dropped. ``w_gate`` / ``w_up`` [E, d, h], ``w_down`` [E, h, d]; int8
-    with ``scales = (gate, up, down)``, each [E, n] float32."""
+    with ``scales = (gate, up, down)``, each [E, n] float32. A token that
+    ``valid`` [T] leaves out is sent to no expert and comes back zero."""
     num_experts = w_gate.shape[0]
     t, k = indices.shape
     if impl == "auto":
         impl = "ragged_dot" if _interpret() else "pallas"
     chunk = _row_chunk(t * k, num_experts) if impl == "pallas" else 1
     with jax.named_scope("group_rows"):
-        source, slot, group_sizes, tile_expert, tile_rows = group_rows(indices, num_experts, chunk)
+        source, slot, group_sizes, tile_expert, tile_rows = group_rows(indices, num_experts, chunk, valid)
     layout = dict(tile_expert=tile_expert, tile_rows=tile_rows, chunk=chunk, impl=impl)
     gate_up, down = (None, None) if scales is None else (scales[:2], scales[2:])
     with jax.named_scope("gather"):
@@ -549,7 +565,10 @@ def grouped_expert_mlp(tokens, weights, indices, w_gate, w_up, w_down, *, scales
         out = grouped_matmul(hidden, (w_down,), group_sizes, scales=down, **layout)
     # a token's k rows, weighted and summed in the order of its choices
     with jax.named_scope("combine"):
-        return jnp.sum(out[slot].reshape(t, k, -1) * weights.astype(out.dtype)[..., None], axis=1)
+        routed = out[slot].reshape(t, k, -1) * weights.astype(out.dtype)[..., None]
+        if valid is not None:
+            routed = jnp.where(valid[:, None, None], routed, 0)
+        return jnp.sum(routed, axis=1)
 
 
 def _swiglu_experts(x, w_gate, w_up, w_down):
@@ -715,8 +734,13 @@ class MoEMlp(nn.Module):
     routed_scaling: float = 1.0
 
     @nn.compact
-    def __call__(self, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """x: [batch, seq, model_dim] -> (out, aux_loss)."""
+    def __call__(
+        self, x: jnp.ndarray, valid: Optional[jnp.ndarray] = None,
+    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """x: [batch, seq, model_dim] -> (out, aux_loss). ``valid`` [batch,
+        seq] marks the real tokens of a right-padded prompt: the grouped
+        dispatch sends the others to no expert (their output is zero), the
+        dense dispatch computes them as it computes every token."""
         b, s, d = x.shape
         tokens = x.reshape(b * s, d)
 
@@ -780,8 +804,13 @@ class MoEMlp(nn.Module):
             raise ValueError(f"unknown router {self.router!r}")
 
         plan = dispatch_plan(b * s, self.num_experts, self.num_selected, quantized=self.quantized)
-        mlp = dense_expert_mlp if plan["dispatch"] == "dense" else grouped_expert_mlp
-        out = mlp(tokens.astype(self.dtype), weights, indices, *experts, scales=scales)
+        if plan["dispatch"] == "dense":
+            out = dense_expert_mlp(tokens.astype(self.dtype), weights, indices, *experts, scales=scales)
+        else:
+            out = grouped_expert_mlp(
+                tokens.astype(self.dtype), weights, indices, *experts, scales=scales,
+                valid=None if valid is None else valid.reshape(b * s),
+            )
         return out.reshape(b, s, d).astype(self.dtype), aux_loss
 
 

@@ -84,7 +84,9 @@ NEG_INF = -1e30
 
 __all__ = [
     "latent_attention", "paged_attention", "paged_attention_reference",
+    "paged_index_scores", "paged_index_scores_reference",
     "paged_latent_attention", "paged_latent_attention_reference",
+    "paged_sparse_attention",
 ]
 
 
@@ -687,3 +689,253 @@ def paged_latent_attention(q, pool, block_table, lengths, *, value_dim: int,
     return _latent_pallas(
         q, pool, block_table, lengths, value_dim=value_dim, scale=scale, interpret=_interpret(),
     )
+
+
+# --------------------------------------------------------- sparse attention
+#
+# Learned sparse attention (:mod:`unionml_tpu.ops.sparse_attention`) keeps,
+# beside a position's keys and values, one key of an indexer, in a pool
+# buffer of its own with the same blocks: ``[num_blocks, block, stored]``,
+# the key in the first columns of a row of whole 128-lane tiles. A decode
+# step reads every visible position's indexer key (:func:`paged_index_scores`),
+# selects (``sparse_attention.select_top_k``), and reads the keys and values
+# of the selected positions only (:func:`paged_sparse_attention`).
+
+
+def _check_index_shapes(index_q, index_w, pool, block_table, lengths):
+    if index_q.ndim != 3 or pool.ndim != 3 or index_q.shape[-1] != pool.shape[-1]:
+        raise ValueError(
+            "index_q must be [batch, heads, width] and the pool [num_blocks, "
+            f"block_size, width], got {index_q.shape} / {pool.shape}"
+        )
+    if index_w.shape != index_q.shape[:2]:
+        raise ValueError(f"index_w must be [batch, heads], got {index_w.shape} for queries {index_q.shape}")
+    if block_table.ndim != 2 or block_table.shape[0] != index_q.shape[0]:
+        raise ValueError(
+            f"block_table must be [batch, table_width], got {block_table.shape} for batch {index_q.shape[0]}"
+        )
+    if lengths.shape != (index_q.shape[0],):
+        raise ValueError(f"lengths must be [batch], got {lengths.shape}")
+
+
+def paged_index_scores_reference(index_q, index_w, pool, block_table, lengths):
+    """The plain gather: the table's blocks of indexer keys taken into a
+    contiguous ``[B, W * block, width]`` view, then
+    ``sparse_attention.index_scores``, ``-inf`` past a row's length."""
+    from unionml_tpu.ops.sparse_attention import index_scores
+
+    _check_index_shapes(index_q, index_w, pool, block_table, lengths)
+    batch, w = block_table.shape
+    keys = jnp.take(pool, block_table.reshape(-1), axis=0).reshape(batch, w * pool.shape[1], -1)
+    scores = index_scores(index_q[:, None], keys, index_w[:, None])[:, 0]
+    visible = jnp.arange(keys.shape[1])[None, :] < lengths.astype(jnp.int32)[:, None]
+    return jnp.where(visible, scores, -jnp.inf)
+
+
+_INDEX_ROWS_PER_STEP = 512  # positions a group gathers and scores
+
+
+def _index_kernel(table_ref, len_ref, q_ref, w_ref, pool, o_ref, buf, sem, state,
+                  *, block, width, pages):
+    """:func:`_latent_kernel`'s walk over the pool of indexer keys: a group
+    is scored by one ``[heads, W] x [W, rows]`` matmul, ReLU, the heads'
+    weighted sum, and written to its row of the output; groups past the
+    row's length keep the ``-inf`` the output starts with."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    batch = pl.num_programs(0)
+    rows = pages * block
+
+    def visible(row):
+        return jnp.clip(len_ref[row], 0, width * block)
+
+    def copies(row, grp, slot, fn):
+        def page(j, carry):
+            src = table_ref[row, grp * pages + j]
+            fn(pltpu.make_async_copy(pool.at[src], buf.at[slot, j], sem.at[slot]))
+            return carry
+        live_pages = jnp.minimum(pl.cdiv(visible(row), block) - grp * pages, pages)
+        jax.lax.fori_loop(0, live_pages, page, 0)
+
+    @pl.when(b == 0)
+    def _reset():
+        state[0] = 0
+        state[1] = 0
+
+    o_ref[...] = jnp.full_like(o_ref, -jnp.inf)
+    length = visible(b)
+    groups = pl.cdiv(length, rows)
+
+    def score(g, slot):
+        q = q_ref[0]                                       # [Hi, W]
+        k = buf[slot].reshape(rows, -1).astype(q.dtype)    # [rows, W]
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        )                                                  # [Hi, rows]
+        total = jnp.sum(jnp.maximum(s, 0.0) * w_ref[0], axis=0, keepdims=True)
+        total = jnp.where(total == 0.0, 0.0, total)
+        # pages past the row's last were not copied: the length hides them
+        o_ref[0, pl.ds(g, 1), :] = jnp.where(g * rows + col < length, total, -jnp.inf)
+
+    @pl.when(groups > 0)
+    def _walk():
+        first = state[0]
+
+        @pl.when(state[1] == 0)
+        def _start_own():
+            copies(b, 0, first, lambda c: c.start())
+
+        nxt_b = jax.lax.while_loop(
+            lambda r: (r < batch) & (len_ref[jnp.minimum(r, batch - 1)] <= 0),
+            lambda r: r + 1,
+            b + 1,
+        )
+        has_next = nxt_b < batch
+
+        def one_group(g, slot):
+            last = g + 1 == groups
+
+            @pl.when(jnp.logical_not(last) | has_next)
+            def _start_next():
+                copies(
+                    jnp.where(last, nxt_b, b), jnp.where(last, 0, g + 1),
+                    1 - slot, lambda c: c.start(),
+                )
+
+            copies(b, g, slot, lambda c: c.wait())
+            score(g, slot)
+            return 1 - slot
+
+        state[0] = jax.lax.fori_loop(0, groups, one_group, first)
+        state[1] = has_next.astype(jnp.int32)
+
+
+def _index_pallas(index_q, index_w, pool, block_table, lengths, *, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+
+    batch, heads, row_width = index_q.shape
+    _, block, _ = pool.shape
+    w = block_table.shape[1]
+    pages = max(1, min(_INDEX_ROWS_PER_STEP // block, w))
+    groups, rows = -(-w // pages), pages * block
+    # the heads are the matmul's rows: whole bfloat16 sublane tiles of them
+    # (a padded head has weight 0)
+    padded = -(-heads // 16) * 16
+    if padded != heads:
+        index_q = jnp.pad(index_q, ((0, 0), (0, padded - heads), (0, 0)))
+        index_w = jnp.pad(index_w, ((0, 0), (0, padded - heads)))
+
+    def row_map(b, table, lens):
+        return (b, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(batch,),
+        in_specs=[
+            pl.BlockSpec((1, padded, row_width), row_map), pl.BlockSpec((1, padded, 1), row_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, groups, rows), row_map),
+        scratch_shapes=[
+            pltpu.VMEM((2, pages, block, row_width), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((2,), jnp.int32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_index_kernel, block=block, width=w, pages=pages),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((batch, groups, rows), jnp.float32),
+        # a row starts the gather of the next row's first group: in order
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_index_scores",
+    )(
+        block_table.astype(jnp.int32), lengths.astype(jnp.int32), index_q,
+        index_w.astype(jnp.float32)[..., None], pool,
+    )
+    return out.reshape(batch, groups * rows)[:, :w * block]
+
+
+def paged_index_scores(index_q, index_w, pool, block_table, lengths, *, impl: str = "auto"):
+    """A decode step's index scores over a block-paged pool of indexer keys.
+
+    Shapes: ``index_q`` [B, Hi, W] (one rotated query a row and indexer
+    head, zero past the key's width), ``index_w`` [B, Hi] the heads'
+    weights, ``pool`` [num_blocks, block, W] the cached keys (one key head),
+    ``block_table`` [B, table_width] int32, ``lengths`` [B] int32 visible
+    rows. Returns float32 [B, table_width * block]: ``sum_j w_j relu(q_j .
+    k_s)`` for each of the row's positions, ``-inf`` past its length (a row
+    of length 0 reads nothing and is all ``-inf``).
+
+    ``impl``: ``"reference"`` (the plain gather), ``"pallas"`` (the kernel
+    ``paged_index_scores``; interpreter mode off-TPU) or ``"auto"`` (pallas
+    on TPU, reference elsewhere)."""
+    _check_index_shapes(index_q, index_w, pool, block_table, lengths)
+    if impl == "auto":
+        impl = "reference" if _interpret() else "pallas"
+    if impl == "reference":
+        return paged_index_scores_reference(index_q, index_w, pool, block_table, lengths)
+    if impl != "pallas":
+        raise ValueError(f"unknown paged index scores impl {impl!r}")
+    return _index_pallas(index_q, index_w, pool, block_table, lengths, interpret=_interpret())
+
+
+def paged_sparse_attention(q, kv, block_table, positions, valid, *, scale: Optional[float] = None):
+    """Single-step decode attention over a row's *picked* positions.
+
+    Shapes: ``q`` [B, Hq, D]; ``kv`` [num_blocks, block, 2 * Hk, D] the pool
+    whose rows hold a position's key heads and, behind them, its value
+    heads (``IndexedKVRows``); ``block_table`` [B, table_width] int32;
+    ``positions`` [B, K] int32 the picked positions of each row and
+    ``valid`` [B, K] which of them count (a row with fewer than K visible
+    positions, or a retired one, has the rest false: they read the trash
+    block's first row and weigh nothing). Returns [B, Hq, D] in
+    ``q.dtype``; zeros for a row with no valid pick.
+
+    The picked rows are fetched by (block, offset) through the table: one
+    XLA gather of ``K`` rows, keys and values together, then grouped-query
+    softmax attention over them (float32 scores and softmax). Nothing else
+    of the pool is read. The operations carry the scope
+    ``paged_sparse_attention`` in a device trace."""
+    batch, q_heads, head_dim = q.shape
+    if kv.ndim != 4 or kv.shape[-1] != head_dim or kv.shape[2] % 2 or q_heads % (kv.shape[2] // 2):
+        raise ValueError(
+            "the pool must be [num_blocks, block_size, 2 * kv_heads, head_dim] with the query heads a "
+            f"multiple of the kv heads, got {kv.shape} for q {q.shape}"
+        )
+    if positions.shape != valid.shape or positions.shape[0] != batch or block_table.shape[0] != batch:
+        raise ValueError(
+            f"positions / valid must be [batch, picks] and block_table [batch, table_width], got "
+            f"{positions.shape} / {valid.shape} / {block_table.shape} for batch {batch}"
+        )
+    block, kv_heads = kv.shape[1], kv.shape[2] // 2
+    group, picks, heads = q_heads // kv_heads, positions.shape[1], 2 * kv_heads
+    if scale is None:
+        scale = head_dim ** -0.5
+    dtype = jnp.float32 if _interpret() else q.dtype
+    with jax.named_scope("paged_sparse_attention"):
+        block_id = jnp.take_along_axis(block_table, positions // block, axis=1)
+        # a picked position's row number in the pool seen as rows (merging
+        # blocks and their rows moves nothing); the table keeps it in bounds
+        row = jnp.where(valid, block_id * block + positions % block, 0)
+        picked = kv.reshape((-1,) + kv.shape[2:]).at[row].get(mode="promise_in_bounds")   # [B, K, 2 Hk, D]
+        # the picked tiles as they lie, [K * 2 Hk, D]: row n is head n % (2 Hk)
+        # of pick n // (2 Hk). ONE matmul scores every query head against every
+        # row and a mask keeps each query head's own key head (the paged
+        # kernel's scheme): a per-head product would first turn 67 MB of tiles
+        # round, and the picked rows are the smaller part of that cost
+        rows2d = picked.reshape(batch, picks * heads, head_dim).astype(dtype)
+        s = jnp.einsum("bqd,bnd->bqn", q.astype(dtype), rows2d, preferred_element_type=jnp.float32) * scale
+        column = jnp.arange(picks * heads) % heads
+        own_key = column[None, :] == (jnp.arange(q_heads) // group)[:, None]            # [Hq, K * 2 Hk]
+        seen = own_key[None] & jnp.repeat(valid, heads, axis=-1)[:, None, :]
+        m = jnp.max(jnp.where(seen, s, NEG_INF), axis=-1, keepdims=True)
+        p = jnp.where(seen, jnp.exp(s - m), 0.0)
+        p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+        # a pick's value head lies kv_heads rows behind its key head
+        weights = jnp.roll(p, kv_heads, axis=-1).astype(dtype)
+        out = jnp.einsum("bqn,bnd->bqd", weights, rows2d, preferred_element_type=jnp.float32)
+        return out.astype(q.dtype)

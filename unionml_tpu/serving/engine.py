@@ -97,7 +97,7 @@ import threading
 import time
 from collections import OrderedDict, deque
 from dataclasses import dataclass, field
-from typing import Any, Callable, List, Optional, Sequence
+from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -553,6 +553,12 @@ class DecodeEngine:
         self._owns_rows = tuple(l.owns_rows for l in self._layout)
         self._state_layers = len(self._layout) - sum(self._owns_rows)
         self._latent_layers = sum(l.kind == "latent" for l in self._layout)
+        self._index_layers = sum(l.kind == "kv+index" for l in self._layout)
+        # a learned selection inside attention: a decode step reads at most
+        # this many of a sequence's cached rows (None: it reads them all)
+        self._index_topk = (
+            getattr(module.config, "index_topk", None) if self._index_layers else None
+        )
         # bytes of recurrent state one slot keeps on the device
         self._state_bytes_per_slot = sum(
             l.nbytes() for l, kv in zip(self._layout, self._owns_rows) if not kv
@@ -1883,13 +1889,24 @@ class DecodeEngine:
                 # what a cached position costs over every layer that
                 # owns rows, as the chip tiles it, and what the row is
                 "bytes_per_token": self._kv_block_nbytes(1),
-                "row_layout": rows.kind,
+                "row_layout": rows.kind,  # "kv", "latent" or "kv+index"
                 # what the decode kernel walks: a live row's visible
                 # blocks (at most the table's width), this many a group
                 "table_width": self._table_width,
                 "kernel_blocks_per_group": _pages_per_step(
                     self._kv_block_size, *rows.pool_row, self._table_width,
                 ),
+            }
+        if self._index_topk is not None and self._perf is not None:
+            # a learned selection: of the cached rows the dispatched steps'
+            # live sequences could see, those the selection lets their
+            # attention read (reckoned from the slots' fills)
+            report = self._perf.report()
+            out["attention"] = {
+                "index_layers": self._index_layers,
+                "index_topk": self._index_topk,
+                "selected_positions": report["selected_positions"],
+                "visible_positions": report["visible_positions"],
             }
         if self._state_layers:
             out["state"] = {
@@ -2911,6 +2928,7 @@ class DecodeEngine:
             occupied_now = int(mask.sum())
             self._m_occupied.inc(occupied_now * self.chunk_steps)
             if self._perf is not None:
+                visible, selected = self._positions_read_locked(mask)
                 # goodput ring: classify this pass (full batch /
                 # padded slots / prefill-mix) + KV pool pressure
                 admitted, prefill_tokens = self._admitted_since_chunk
@@ -2932,8 +2950,26 @@ class DecodeEngine:
                         self.kv_pool.used_rows
                         if self.kv_pool is not None else 0
                     ),
+                    visible_positions=visible, selected_positions=selected,
                 )
         self._inflight.put(("chunk", ep0, mask, gens, toks, t_dispatch, seq))
+
+    def _positions_read_locked(self, mask) -> Tuple[int, int]:
+        """(visible, selected) of a module with a learned selection inside
+        attention: the cached rows the chunk just launched lets its live
+        sequences see, summed over its steps, and the smaller of each
+        sequence's rows and the selection's ``topk``. **Reckoned, not
+        read back**: from the host's upper bound of each slot's fill, as
+        the block tables are grown from; what the device's attention
+        fetched is held by tests (rows outside the picks may hold
+        anything), not by this count. (0, 0) for every other module."""
+        if self._index_topk is None or not self.paged:
+            return 0, 0
+        live = [s for s in np.flatnonzero(mask) if self._occupant[s] is not None]
+        last = np.asarray([self._slot_rows[s] for s in live], np.int64)
+        # the rows each live slot sees at each of the chunk's steps
+        rows = np.maximum(last[:, None] - np.arange(self.chunk_steps)[None, ::-1], 0)
+        return int(rows.sum()), int(np.minimum(rows, self._index_topk).sum())
 
     def _pop_request(self) -> Optional[_Request]:
         """Atomically dequeue a request and mark it as mid-admission, so
@@ -3625,7 +3661,7 @@ class DecodeEngine:
             req.rid, "admit", annotation="engine.admit",
             bucket=self._bucket_for(len(req.prompt)),
             prompt_tokens=len(req.prompt), state_layers=self._state_layers,
-            latent_layers=self._latent_layers,
+            latent_layers=self._latent_layers, index_layers=self._index_layers,
         ) as sp:
             step(arg)
             sp.note(cached_tokens=req._saved_tokens)

@@ -311,6 +311,8 @@ class ServingPerfPlane:
         kv_in_use: int = 0,
         kv_capacity: int = 0,
         kv_tokens: int = 0,
+        selected_positions: int = 0,
+        visible_positions: int = 0,
         waiting: int = 0,
         admitted: int = 0,
         prefill_tokens: int = 0,
@@ -320,7 +322,12 @@ class ServingPerfPlane:
         chunk that ran while chunked admission was interleaving.
         ``waiting`` is the waiting room's depth at the dispatch — the
         chunk's empty slot-steps are *starved* when it is above 0 —
-        ``kv_tokens`` the cached positions the pool's blocks hold, and
+        ``kv_tokens`` the cached positions the pool's blocks hold,
+        ``visible_positions`` / ``selected_positions`` the cached rows
+        the chunk's live sequences could see and the rows a learned
+        selection lets their attention read, summed over its steps (the
+        engine reckons both from its host-side fills; 0 for a module
+        without a selection), and
         ``admitted`` / ``prefill_tokens`` are the admissions
         completed and prompt tokens prefilled since the previous
         chunk."""
@@ -343,6 +350,8 @@ class ServingPerfPlane:
                 self._win_starved_steps += total - occ
             self._win_admissions += int(admitted)
             self._win_prefill_tokens += int(prefill_tokens)
+            self._win_selected += int(selected_positions)
+            self._win_visible += int(visible_positions)
             if kv_capacity > 0:
                 self._kv_pressure = min(
                     1.0, max(0.0, kv_in_use / kv_capacity)
@@ -419,6 +428,8 @@ class ServingPerfPlane:
         self._win_admissions = 0
         self._win_parked = 0
         self._win_prefill_tokens = 0
+        self._win_selected = 0
+        self._win_visible = 0
         self._win_polls = {reason: 0 for reason in POLL_REASONS}
         self._win_dispatcher_s = {phase: 0.0 for phase in DISPATCHER_PHASES}
 
@@ -474,6 +485,8 @@ class ServingPerfPlane:
                 "admissions": self._win_admissions,
                 "admissions_parked_on_pool": self._win_parked,
                 "prefill_tokens": self._win_prefill_tokens,
+                "selected_positions": self._win_selected,
+                "visible_positions": self._win_visible,
                 "polls": dict(self._win_polls),
                 "dispatcher_s": {
                     phase: round(s, 6)
