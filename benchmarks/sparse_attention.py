@@ -4,6 +4,8 @@ cell's shapes.
     chiprun -- env PYTHONPATH=. python3 benchmarks/sparse_attention.py
     chiprun -- env PYTHONPATH=. python3 benchmarks/sparse_attention.py --live 6 16 --positions 8192 16384 \\
         --prefill 8192
+    ... --live 16 --positions 17536 --blocks 4400 --layers 6 --legs mask walk      (the table's worst case)
+    ... --live 6 --positions 8192 --legs walk --group-rows 512                     (another group size)
 
 One JSON line a case: microseconds a layer of one decode step for each
 stage, from a jitted loop of ``--reps`` passes over ``--layers`` pools,
@@ -12,28 +14,43 @@ timed on the host's clock around ``block_until_ready``. The shape is
 over 4 key / value heads of 128, an indexer of 16 heads of 64 (keys stored
 128 wide), ``topk`` 2,048, pools of 2,260 blocks of 64 positions a layer
 (a position's keys and values in one tile of 8 x 128: 296 MB; indexer keys
-37 MB), a table 274 wide (``--block`` gives other block sizes over the same
-bytes).
+37 MB; ``--blocks`` sizes another pool), a table 274 wide (``--block`` gives
+other block sizes over the same bytes).
 Twelve layers' pools are read in turn, as the cell's decode step reads them
 (one pool carried through a loop stays in fast memory and reads faster than
 a model's does). ``--live`` rows hold ``--positions`` cached positions each
-(in scattered blocks) and the others none. **Every loop takes the table, the
-lengths and the picks as arguments of its program**, as a decode chunk does:
-closed over, they are constants the compiler folds into the gather, and the
-picked rows then read three times faster than in the cell (PERF.md, PR 40).
-The stages:
+(in scattered blocks) and the others none: dead rows are part of every
+batch. **Every loop takes the table, the lengths and the selection as
+arguments of its program**, as a decode chunk does: closed over, they are
+constants the compiler folds in, and a stage then reads up to three times
+faster than in the cell (PERF.md, PR 40). The stages (``--legs`` times some):
 
 - ``scores``: ``paged_index_scores`` (the Pallas kernel);
-- ``select``: ``sparse_attention.select_top_k`` (``jax.lax.top_k``: a sort);
-- ``mask``: ``sparse_attention.top_k_mask`` over the same scores (the
-  threshold descent a prefill uses; it yields no positions);
-- ``attention``: ``paged_sparse_attention`` over picks handed in;
-- ``chain``: scores, select and attention a layer, each feeding the next, as
-  the chunk's loop runs them;
+- ``mask``: ``sparse_attention.top_k_mask`` over those scores (the exact
+  top-2,048 as a mask: the threshold descent, no sort);
+- ``walk``: ``paged_sparse_attention`` (the Pallas kernel: the row's live
+  blocks walked with the mask handed in; its output is also compared with
+  the plain form's, dead rows with zeros);
+- ``chain``: scores, mask and walk a layer, each feeding the next, as the
+  chunk's loop runs them;
 - ``dense``: ``paged_attention`` (the kernel every other decoder's step
   runs) over all of the same rows, in ``[blocks, block, 4, 128]`` pools.
 
-Beside each: the bytes the stage must read as stored and their time at the
+Read on a TPU v5e (my chip runs, PR 42), us a layer at 16 live rows x 8,192
+positions / 8 x 16,384 / 6 x 8,192 / 16 x 17,536 (the last with ``--blocks
+4400 --layers 6``: more than the cell's pool can hold):
+
+- ``walk``, groups of 1,024 positions: **368.7 / 369.6 / 146.9 / 815.2**
+  (groups of 512: 401.8 / 403.0 / 158.3 / 870.8; of 256: 505.2 / - / 196.3 / -);
+- the gather it replaced (one XLA gather of 2,048 tiles a row, live or
+  not; deleted in PR 42): 413.7 / 626.6 / 681.6 / 451.6;
+- ``mask`` 98.3 / 98.9 / 98.2 / 106.3 beside ``jax.lax.top_k`` (the sort it
+  replaced) 271.8 / 270.6 / 269.2 / 280.0;
+- ``scores`` 160.5 / 160.5 / 93.3; ``dense`` 377.3 / 379.2 / 149.9;
+- ``chain``: **571.2 / 571.8 / 283.7** (groups of 512: 604.1 / 605.0 / 294.2;
+  with the sort and the gather, PR 40: 1,124.7 / 1,317.4 / 1,307.1).
+
+Beside each stage: the bytes it must read as stored and their time at the
 HBM rate. ``--prefill N`` also times one layer of the plain form over a
 whole prompt of ``N`` tokens (``sparse_attention.sparse_attention``: index
 scores and the selection as a mask in blocks of queries, the softmax over
@@ -43,6 +60,7 @@ mean nothing). Not run by any cell or test.
 """
 
 import argparse
+import functools
 import json
 import time
 
@@ -72,6 +90,9 @@ def main():
     ap.add_argument("--positions", type=int, nargs="*", default=[8192, 16384])
     ap.add_argument("--prefill", type=int, nargs="*", default=[])
     ap.add_argument("--block", type=int, default=64, help="positions a pool block holds")
+    ap.add_argument("--blocks", type=int, default=2_260, help="blocks of 64 positions a layer's pool holds")
+    ap.add_argument("--legs", nargs="*", default=None, help="the stages to time (default: all)")
+    ap.add_argument("--group-rows", type=int, default=None, help="positions a group of the walk holds")
     ap.add_argument("--reps", type=int, default=10)
     ap.add_argument("--layers", type=int, default=12)
     ap.add_argument("--seed", type=int, default=0)
@@ -82,9 +103,12 @@ def main():
         raise SystemExit(f"needs a TPU, found {device.platform}")
     rows, q_heads, kv_heads, hd, ih, idim, topk, block, blocks, width = (
         (4, 4, 2, 16, 4, 8, 8, 8, 64, 12) if args.rehearse
-        else (16, 32, 4, 128, 16, 64, 2048, args.block, 2_260 * 64 // args.block, -(-17_536 // args.block))
+        else (16, 32, 4, 128, 16, 64, 2048, args.block, args.blocks * 64 // args.block,
+              -(-17_536 // args.block))
     )
     stored = -(-idim // 128) * 128
+    if args.group_rows:
+        pa._SPARSE_ROWS_PER_STEP = args.group_rows
     layers, reps = (2, 1) if args.rehearse else (args.layers, args.reps)
     calls = layers * reps
     key = jax.random.PRNGKey(args.seed)
@@ -95,7 +119,10 @@ def main():
 
     kv_pools = [normal(2 * i, (blocks, block, 2 * kv_heads, hd)) for i in range(layers)]
     i_pools = [normal(2 * i + 1, (blocks, block, stored)).at[..., idim:].set(0) for i in range(layers)]
-    dense_pools = [(kv[:, :, :kv_heads] + 0, kv[:, :, kv_heads:] + 0) for kv in kv_pools]
+    legs = set(args.legs or ("scores", "mask", "walk", "chain", "dense"))
+    dense_pools = (
+        [(kv[:, :, :kv_heads] + 0, kv[:, :, kv_heads:] + 0) for kv in kv_pools] if "dense" in legs else []
+    )
     q = normal(1000, (rows, q_heads, hd))
     iq = normal(1001, (rows, ih, stored)).at[..., idim:].set(0)
     iw = jax.random.normal(jax.random.fold_in(key, 1002), (rows, ih), jnp.float32)
@@ -132,17 +159,6 @@ def main():
             same_inf = bool((np.isfinite(np.asarray(scores)) == finite).all())
 
             @jax.jit
-            def select_loop(scores):
-                def body(_, carry):
-                    scores, acc = carry
-                    for _ in range(layers):
-                        picked, valid = sa.select_top_k(scores, topk)
-                        scores = scores + (picked[:, :1] % 2).astype(scores.dtype) * 1e-6
-                        acc = acc + picked[:, 0]
-                    return scores, acc
-                return jax.lax.fori_loop(0, reps, body, (scores, jnp.zeros((rows,), jnp.int32)))
-
-            @jax.jit
             def mask_loop(scores):
                 def body(_, carry):
                     scores, acc = carry
@@ -153,18 +169,19 @@ def main():
                     return scores, acc
                 return jax.lax.fori_loop(0, reps, body, (scores, jnp.zeros((rows,), jnp.int32)))
 
-            picked, valid = jax.jit(lambda s: sa.select_top_k(s, topk))(scores)
+            # the mask against the sort it replaced: the same set, ties and all
             in_mask = np.asarray(jax.jit(lambda s: sa.top_k_mask(s, topk))(scores))
+            values, picks = (np.asarray(x) for x in jax.jit(lambda s: jax.lax.top_k(s, topk))(scores))
             same_set = bool(all(
-                set(row[ok].tolist()) == set(np.flatnonzero(in_mask[r]).tolist())
-                for r, (row, ok) in enumerate(zip(np.asarray(picked), np.asarray(valid)))
+                set(row[np.isfinite(v)].tolist()) == set(np.flatnonzero(in_mask[r]).tolist())
+                for r, (row, v) in enumerate(zip(picks, values))
             ))
 
             @jax.jit
-            def attention_loop(q, kv_pools, table, picked, valid):
+            def walk_loop(q, kv_pools, table, lengths, selected):
                 def body(_, q):
                     for kv in kv_pools:
-                        o = pa.paged_sparse_attention(q, kv, table, picked, valid)
+                        o = pa.paged_sparse_attention(q, kv, table, lengths, selected, impl="pallas")
                         q = q + (o * 1e-3).astype(q.dtype)
                     return q
                 return jax.lax.fori_loop(0, reps, body, q)
@@ -174,12 +191,22 @@ def main():
                 def body(_, carry):
                     q, iq = carry
                     for pool, kv in zip(i_pools, kv_pools):
-                        picked, valid = sa.select_top_k(scores_of(iq, pool, table, lengths), topk)
-                        o = pa.paged_sparse_attention(q, kv, table, picked, valid)
+                        selected = sa.top_k_mask(scores_of(iq, pool, table, lengths), topk)
+                        o = pa.paged_sparse_attention(q, kv, table, lengths, selected, impl="pallas")
                         q = q + (o * 1e-3).astype(q.dtype)
                         iq = iq.at[:, :, 0].add((q[:, :ih, 0] * 1e-3).astype(iq.dtype))
                     return q, iq
                 return jax.lax.fori_loop(0, reps, body, (q, iq))
+
+            # the walk's output against the plain form's over the same selection
+            selected = jnp.asarray(in_mask)
+            walked, plain = (
+                jax.jit(functools.partial(pa.paged_sparse_attention, impl=impl))(
+                    q, kv_pools[0], table, lengths, selected).astype(jnp.float32)
+                for impl in ("pallas", "reference")
+            )
+            walk_off = float(jnp.abs(walked - plain).max())
+            dead_zero = bool(not np.asarray(walked)[live:].any())
 
             @jax.jit
             def dense_loop(q, pools, table, lengths):
@@ -192,27 +219,29 @@ def main():
 
             out = {"live_rows": live, "positions_each": positions, "topk": topk, "block": block}
             for name, fn, fn_args in (
-                ("scores", scores_loop, (iq, i_pools, table, lengths)), ("select", select_loop, (scores,)),
-                ("mask", mask_loop, (scores,)),
-                ("attention", attention_loop, (q, kv_pools, table, picked, valid)),
+                ("scores", scores_loop, (iq, i_pools, table, lengths)), ("mask", mask_loop, (scores,)),
+                ("walk", walk_loop, (q, kv_pools, table, lengths, selected)),
                 ("chain", chain_loop, (q, iq, i_pools, kv_pools, table, lengths)),
                 ("dense", dense_loop, (q, dense_pools, table, lengths)),
             ):
+                if name not in legs:
+                    continue
                 try:
                     out[f"{name}_us"] = _timed(fn, *fn_args, calls=calls)
                 except Exception as exc:  # a shape the compiler refuses: say so and go on
                     out[f"{name}_us"], out[f"{name}_error"] = None, str(exc)[:300]
             index_bytes = live * positions * stored * 2
-            picked_bytes = live * min(positions, topk) * 2 * kv_heads * hd * 2
+            selected_bytes = live * min(positions, topk) * 2 * kv_heads * hd * 2
             dense_bytes = live * positions * 2 * kv_heads * hd * 2
             out.update(
                 scores_bytes_as_stored=index_bytes,
                 scores_us_at_hbm_rate=round(1e6 * index_bytes / HBM_BYTES_PER_S, 1),
-                attention_bytes=picked_bytes,
-                attention_us_at_hbm_rate=round(1e6 * picked_bytes / HBM_BYTES_PER_S, 1),
+                selected_bytes=selected_bytes,
+                selected_us_at_hbm_rate=round(1e6 * selected_bytes / HBM_BYTES_PER_S, 1),
                 dense_bytes=dense_bytes, dense_us_at_hbm_rate=round(1e6 * dense_bytes / HBM_BYTES_PER_S, 1),
                 scores_max_off_gather_in_sd=round(off, 5), scores_same_visible=same_inf,
-                select_and_mask_same_set=same_set,
+                mask_is_top_k_set=same_set, walk_max_off_plain=round(walk_off, 5),
+                walk_dead_rows_zero=dead_zero, walk_group_rows=pa._SPARSE_ROWS_PER_STEP,
                 layers=layers, rows=rows, device=device.device_kind, platform=device.platform,
             )
             print(json.dumps(out), flush=True)

@@ -12,6 +12,7 @@ copies, fusion wrappers) and the bodies of reducers and comparators (an
 ``op_name`` without the program's ``jit(...)`` prefix) are not the
 program's to name."""
 
+import functools
 import importlib.util
 import re
 from pathlib import Path
@@ -184,10 +185,16 @@ SERVED = [
 ]
 
 
+@functools.lru_cache(maxsize=None)
+def _family_text(family: str, program: str) -> str:
+    """One compile a served case, whichever tests read it."""
+    return _served_text(FAMILIES[family](), program)
+
+
 @pytest.mark.parametrize("family,program", SERVED)
 def test_served_program_names_every_operation(family, program):
     module = FAMILIES[family]()
-    names = program_op_names(_served_text(module, program))
+    names = program_op_names(_family_text(family, program))
     assert len(names) > 50
     assert unowned(names, type(module).__name__) == []
     # the work no module owns, where the readers look for it
@@ -205,9 +212,22 @@ def test_served_program_names_every_operation(family, program):
     if family == "keye_vl_moe":
         assert scopes_under(names, "moe") == MOE_SCOPES
         # topk 8 of a 64-row table and of a 32-token bucket: the selection runs
-        # in both programs; only the decode step gathers picked rows
-        picked = {"paged_sparse_attention"} if program == "decode_chunk" else set()
-        assert scopes_under(names, "attn") == {"indexer", "select"} | picked
+        # in both programs; only the decode step walks a pool with it
+        walk = {"paged_sparse_attention"} if program == "decode_chunk" else set()
+        assert scopes_under(names, "attn") == {"indexer", "select"} | walk
+
+
+@pytest.mark.parametrize("family,program", [case for case in SERVED if case[0] != "keye_vl_moe"])
+def test_the_selection_and_its_read_reach_no_other_familys_program(family, program):
+    """The selection as a mask and the walk that takes it
+    (``paged_sparse_attention``) have one caller, the family with an
+    indexer: no other served program holds the kernel's name or an
+    operation under ``indexer``, ``select`` or ``paged_sparse_attention``,
+    so a change to that read cannot move their compiled programs."""
+    text = _family_text(family, program)
+    assert "paged_sparse_attention" not in text and "paged_index_scores" not in text
+    parts = {part for name in program_op_names(text) for part in name.split("/")[:-1]}
+    assert not parts & {"indexer", "select", "paged_sparse_attention", "paged_index_scores"}
 
 
 def test_a_slot_rows_chunk_commits_nothing_but_a_prefill_does():

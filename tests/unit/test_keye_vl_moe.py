@@ -11,8 +11,8 @@ loop over experts, no cache).
 
 Tolerances. Program and reference compute the same float32 numbers in
 another order (blocks of queries and a threshold mask against a dense
-``top_k``, a cache against a full pass, picked rows gathered against a
-mask, grouped against looped experts), which moves logits of size ~3 by a
+``top_k``, a cache against a full pass, a pool's blocks walked group by
+group, grouped against looped experts), which moves logits of size ~3 by a
 few 1e-6: ``LOGIT_TOL`` is 1e-4. It holds only while both select the same
 positions: with 8 picks of up to 124 a different pick moves a logit by
 0.1-3 (a path without the selection lies 2-3.4 away), so the tolerance is
@@ -215,22 +215,20 @@ def _tied_scores(seed, rows=5, n=70):
 
 @pytest.mark.parametrize("scores", ["distinct", "tied-1", "tied-2", "tied-3"])
 @pytest.mark.parametrize("k", [1, 8, 33])
-def test_the_mask_and_the_list_select_what_top_k_selects(k, scores):
+def test_the_mask_selects_what_top_k_selects(k, scores):
     """Distinct scores and ties alike: the ``k`` largest, ties towards the
     lower position, never a ``-inf``; fewer than ``k`` visible selects them
-    all."""
+    all. (``jax.lax.top_k``, a sort on the chip, is what no program runs any
+    more: the mask is the selection's one form.)"""
     if scores == "distinct":
         scores = np.random.default_rng(9).standard_normal((5, 70)).astype(np.float32)
     else:
         scores = _tied_scores(int(scores[-1]))
     _, want = jax.lax.top_k(jnp.asarray(scores), k)
     mask = np.asarray(sparse.top_k_mask(jnp.asarray(scores), k))
-    positions, valid = (np.asarray(x) for x in sparse.select_top_k(jnp.asarray(scores), k))
     for r in range(scores.shape[0]):
         expect = {int(p) for p in np.asarray(want)[r] if np.isfinite(scores[r, p])}
         assert set(np.flatnonzero(mask[r]).tolist()) == expect
-        assert set(positions[r][valid[r]].tolist()) == expect and valid[r].sum() == len(expect)
-        assert (positions[r][~valid[r]] == 0).all()
 
 
 def test_kth_largest_key_is_the_kth_largest():
@@ -289,52 +287,138 @@ def _table(rng, lengths, block=8, width=6, blocks=24):
     return jnp.asarray(table)
 
 
-def test_every_row_picked_is_paged_attention():
-    rng = np.random.default_rng(6)
-    kv, _ = _pools(rng)
-    k, v = kv[:, :, :2], kv[:, :, 2:]
-    lengths = np.asarray([40, 1, 17, 0])
-    table = _table(rng, lengths)
-    q = jnp.asarray(rng.standard_normal((4, 4, 16)), jnp.float32)
-    positions = jnp.broadcast_to(jnp.arange(48)[None, :], (4, 48))
-    valid = positions < jnp.asarray(lengths)[:, None]
-    got = paged.paged_sparse_attention(q, kv, table, positions, valid)
-    want = paged.paged_attention(q, k, v, table, jnp.asarray(lengths), impl="reference")
-    assert np.abs(np.asarray(got)[:3] - np.asarray(want)[:3]).max() < 1e-5
-    assert (np.asarray(got)[3] == 0).all()          # a retired slot picks nothing and reads nothing
-    with pytest.raises(ValueError, match="2 \\* kv_heads, head_dim"):
-        paged.paged_sparse_attention(q, kv.reshape(24, 8, 64), table, positions, valid)
+def _selected_softmax(q, kv, table, selected, scale):
+    """Grouped-query softmax attention over the selected positions of each
+    row, one position at a time in float64: what the decode read must equal."""
+    q, kv, table, selected = (np.asarray(x) for x in (q, kv, table, selected))
+    block, kv_heads = kv.shape[1], kv.shape[2] // 2
+    group = q.shape[1] // kv_heads
+    out = np.zeros(q.shape, np.float64)
+    for b in range(q.shape[0]):
+        picks = np.flatnonzero(selected[b])
+        if not picks.size:
+            continue
+        rows = kv[table[b, picks // block], picks % block].astype(np.float64)    # [K, 2 Hk, D]
+        for h in range(q.shape[1]):
+            s = rows[:, h // group] @ q[b, h].astype(np.float64) * scale
+            w = np.exp(s - s.max())
+            out[b, h] = (w / w.sum()) @ rows[:, kv_heads + h // group]
+    return out
 
 
-@pytest.mark.parametrize("lengths", [(40, 1, 17, 0), (9, 48, 0, 33)], ids=["mixed", "full-table"])
-def test_the_decode_read_fetches_the_picked_rows_and_no_others(lengths):
-    """What ``selected_rows_pct`` cannot show (the engine reckons it): every
-    pool row outside the picks may hold NaN and the output does not move, so
-    the attention read the picked rows only. (Pool row 0, the trash block's
-    first, is what an invalid pick reads: finite, weighed by nothing.)"""
-    rng = np.random.default_rng(16)
-    kv, _ = _pools(rng)
+def _decode_read_case(rng, lengths, *, blocks=24, block=8, kv_heads=2, hd=16, q_heads=4, width=6):
+    """(q, pool, table, lengths, scores): rows of ``lengths`` cached
+    positions in shuffled pool blocks, their index scores ``-inf`` past the
+    length as ``paged_index_scores`` leaves them."""
+    kv, _ = _pools(rng, blocks=blocks, block=block, kv_heads=kv_heads, hd=hd)
     lengths = np.asarray(lengths)
-    table = _table(rng, lengths)
-    q = jnp.asarray(rng.standard_normal((4, 4, 16)), jnp.float32)
-    scores = jnp.asarray(rng.standard_normal((4, 48)), jnp.float32)
-    scores = jnp.where(jnp.arange(48)[None, :] < jnp.asarray(lengths)[:, None], scores, -jnp.inf)
-    picked, valid = sparse.select_top_k(scores, 8)
-    assert np.asarray(valid).sum(-1).tolist() == np.minimum(lengths, 8).tolist()
+    table = _table(rng, lengths, block=block, width=width, blocks=blocks)
+    q = jnp.asarray(rng.standard_normal((len(lengths), q_heads, hd)), jnp.float32)
+    scores = jnp.asarray(rng.standard_normal((len(lengths), width * block)), jnp.float32)
+    scores = jnp.where(jnp.arange(width * block)[None, :] < jnp.asarray(lengths)[:, None], scores, -jnp.inf)
+    return q, kv, table, jnp.asarray(lengths), scores
+
+
+IMPLS = ["reference", "pallas"]
+
+
+@pytest.mark.parametrize("impl", IMPLS)
+def test_every_row_selected_is_paged_attention(impl, monkeypatch):
+    """Rows no longer than ``topk`` select everything they see: the walk is
+    ``paged_attention`` over the same pool, the last block partly filled, a
+    retired slot among the live ones."""
+    monkeypatch.setattr(paged, "_SPARSE_ROWS_PER_STEP", 16)      # three groups of two blocks a row
+    q, kv, table, lengths, scores = _decode_read_case(np.random.default_rng(6), (40, 1, 17, 0))
+    selected = sparse.top_k_mask(scores, 48)
+    assert np.asarray(selected).sum(-1).tolist() == [40, 1, 17, 0]
+    got = paged.paged_sparse_attention(q, kv, table, lengths, selected, impl=impl)
+    want = paged.paged_attention(q, kv[:, :, :2], kv[:, :, 2:], table, lengths, impl="reference")
+    assert np.abs(np.asarray(got)[:3] - np.asarray(want)[:3]).max() < 1e-5
+    assert (np.asarray(got)[3] == 0).all()          # a retired slot selects nothing, walks nothing
+    with pytest.raises(ValueError, match="2 \\* kv_heads, head_dim"):
+        paged.paged_sparse_attention(q, kv.reshape(24, 8, 64), table, lengths, selected, impl=impl)
+    with pytest.raises(ValueError, match="table_width \\* block_size"):
+        paged.paged_sparse_attention(q, kv, table, lengths, selected[:, :40], impl=impl)
+
+
+@pytest.mark.parametrize("impl", IMPLS)
+@pytest.mark.parametrize(
+    "lengths", [(40, 1, 17, 0), (9, 48, 0, 33), (0, 0, 48, 9)],
+    ids=["mixed", "dead-between-live", "leading-empty"])
+def test_the_decode_read_weighs_the_selected_rows_and_no_others(lengths, impl, monkeypatch):
+    """The kernel (interpret mode, three groups of two blocks a row) and the
+    plain form against the float64 softmax over the selected set, on a pool
+    with shuffled block ids. What ``selected_rows_pct`` cannot show (the
+    engine reckons it): every row outside the selection may hold anything
+    *finite* (the walk fetches it and weighs it zero; the pool never holds
+    anything else) and the output does not move."""
+    monkeypatch.setattr(paged, "_SPARSE_ROWS_PER_STEP", 16)
+    rng = np.random.default_rng(16)
+    q, kv, table, lengths, scores = _decode_read_case(rng, lengths)
+    selected = sparse.top_k_mask(scores, 8)
+    assert np.asarray(selected).sum(-1).tolist() == np.minimum(np.asarray(lengths), 8).tolist()
+    want = _selected_softmax(q, kv, table, selected, 0.25)
+    clean = np.asarray(paged.paged_sparse_attention(q, kv, table, lengths, selected, impl=impl))
+    assert np.abs(clean - want).max() < 1e-5
+    assert not clean[np.asarray(lengths) == 0].any()
     block = kv.shape[1]
-    block_id = np.asarray(jnp.take_along_axis(table, picked // block, axis=1))
-    rows = block_id * block + np.asarray(picked) % block
-    keep = np.zeros(kv.shape[0] * block, bool)
-    keep[rows[np.asarray(valid)]] = True
-    keep[0] = True
-    assert keep.sum() == 1 + np.minimum(lengths, 8).sum()
-    poisoned = jnp.where(jnp.asarray(keep).reshape(kv.shape[:2])[..., None, None], kv, jnp.nan)
-    clean = np.asarray(paged.paged_sparse_attention(q, kv, table, picked, valid))
-    got = np.asarray(paged.paged_sparse_attention(q, poisoned, table, picked, valid))
-    assert np.isfinite(got).all() and (got == clean).all()
-    # and a picked row does count: poison one and its sequence's output goes
-    hit = jnp.asarray(poisoned).reshape((-1,) + kv.shape[2:]).at[rows[1, 0]].set(jnp.nan).reshape(kv.shape)
-    assert np.isnan(np.asarray(paged.paged_sparse_attention(q, hit, table, picked, valid))[1]).all()
+    picks = np.asarray(selected)
+    keep = np.zeros(kv.shape[:2], bool)
+    for r in range(picks.shape[0]):
+        at = np.flatnonzero(picks[r])
+        keep[np.asarray(table)[r, at // block], at % block] = True
+    assert keep.sum() == np.minimum(np.asarray(lengths), 8).sum()
+    poison = jnp.asarray(rng.choice([-1e4, 1e4], kv.shape), jnp.float32)
+    poisoned = jnp.where(jnp.asarray(keep)[..., None, None], kv, poison)
+    got = np.asarray(paged.paged_sparse_attention(q, poisoned, table, lengths, selected, impl=impl))
+    assert np.isfinite(got).all() and np.abs(got - clean).max() < 1e-6
+    # and a selected row does count: move one and its sequence's output moves
+    live = int(np.flatnonzero(np.asarray(lengths))[0])
+    at = int(np.flatnonzero(picks[live])[0])
+    hit = kv.at[np.asarray(table)[live, at // block], at % block].add(3.0)
+    moved = np.asarray(paged.paged_sparse_attention(q, hit, table, lengths, selected, impl=impl))
+    assert np.abs(moved[live] - clean[live]).max() > 1e-3
+    assert np.abs(np.delete(moved, live, 0) - np.delete(clean, live, 0)).max() < 1e-6
+
+
+@pytest.mark.parametrize("impl", IMPLS)
+def test_ties_at_the_last_selected_score_are_cut_towards_the_lower_position(impl, monkeypatch):
+    """Equal index scores across the cut: the mask keeps the lower positions
+    (what ``jax.lax.top_k`` keeps) and the read weighs exactly those."""
+    monkeypatch.setattr(paged, "_SPARSE_ROWS_PER_STEP", 16)
+    rng = np.random.default_rng(26)
+    q, kv, table, lengths, _ = _decode_read_case(rng, (48, 30, 0, 11))
+    scores = _tied_scores(2, rows=4, n=48)
+    scores = np.where(np.arange(48)[None, :] < np.asarray(lengths)[:, None], scores, -np.inf)
+    selected = sparse.top_k_mask(jnp.asarray(scores), 8)
+    _, want = jax.lax.top_k(jnp.asarray(scores), 8)
+    for r in range(4):
+        expect = {int(p) for p in np.asarray(want)[r] if np.isfinite(scores[r, p])}
+        assert set(np.flatnonzero(np.asarray(selected)[r]).tolist()) == expect
+    first = np.asarray(selected)[0]
+    assert set(scores[0, first].tolist()) & set(scores[0, ~first].tolist())       # the cut runs through a tie
+    got = np.asarray(paged.paged_sparse_attention(q, kv, table, lengths, selected, impl=impl))
+    assert np.abs(got - _selected_softmax(q, kv, table, selected, 0.25)).max() < 1e-5
+
+
+def test_the_walk_at_the_cells_tile_shapes(monkeypatch):
+    """Blocks of 64 positions of 4 + 4 heads of 128, 32 query heads, as the
+    served cell's: a position's eight heads are one tile, a lane tile of
+    score columns holds 16 positions, and the selection reaches the columns
+    by the gather within a tile (the tiny pools above take ``jnp.repeat``).
+    Two blocks a group; nine groups, so that the mask's rows span two
+    sublane tiles; a row that ends in the middle of a block."""
+    monkeypatch.setattr(paged, "_SPARSE_ROWS_PER_STEP", 128)
+    rng = np.random.default_rng(36)
+    q, kv, table, lengths, scores = _decode_read_case(
+        rng, (1100, 0, 70, 1152), blocks=40, block=64, kv_heads=4, hd=128, q_heads=32, width=18)
+    selected = sparse.top_k_mask(scores, 64)
+    scale = 128 ** -0.5
+    want = _selected_softmax(q, kv, table, selected, scale)
+    got = np.asarray(paged.paged_sparse_attention(q, kv, table, lengths, selected, impl="pallas"))
+    assert np.abs(got - want).max() < 1e-5 and not got[1].any()
+    plain = np.asarray(paged.paged_sparse_attention(q, kv, table, lengths, selected, impl="reference"))
+    assert np.abs(plain - want).max() < 1e-5
 
 
 def test_a_cache_no_longer_than_topk_is_plain_causal_attention():
@@ -502,16 +586,18 @@ def _worst_gap(params, cfg, prompt, tokens, logits):
     [((40, 5), "reference", {}), ((100, 64), "pallas", {}),
      ((100, 70), "reference", {"prefill_chunk": 64}), ((20, 100), "reference", {"paged": False}),
      ((40, 5), "reference", {"prefill_impl": "flash"})],
-    ids=["gather", "kernel", "chunked-prefill", "contiguous-cache", "prefill_impl-flash"],
+    ids=["plain", "kernel", "chunked-prefill", "contiguous-cache", "prefill_impl-flash"],
 )
 def test_engine_serves_the_references_logits(monkeypatch, served, lengths, paged_impl, kw):
     """Right-padded in its bucket, prefilled (index scores, the selection as
     a mask and the softmax in blocks of queries; or in lead chunks that read
     the rows before them), committed to the pool by block scatter, keys,
     values and indexer keys alike, then 24 tokens decoded through the pool
-    (``paged_index_scores``, the exact top-8, ``paged_sparse_attention``):
-    every sampled row of logits is the reference's row of its full pass,
-    with the selection active in both programs."""
+    (``paged_index_scores``, the exact top-8 as a mask, the walk of
+    ``paged_sparse_attention`` with it; the two kernels in interpret mode
+    under ``paged_impl="pallas"``): every sampled row of logits is the
+    reference's row of its full pass, with the selection active in both
+    programs."""
     _, params = served
     kw = dict(kw)
     # the other decoders' value for whole prompts: the engine then says
@@ -530,6 +616,7 @@ def test_engine_serves_the_references_logits(monkeypatch, served, lengths, paged
         assert stats["moe"]["decode_chunk"]["router"] == "softmax"
         attention = stats["attention"]
         assert attention["index_layers"] == 3 and attention["index_topk"] == 8
+        assert attention["decode_read"] == "walk"       # a table of 160 positions over a topk of 8
         assert 0 < attention["selected_positions"] < attention["visible_positions"]
 
 
@@ -543,8 +630,8 @@ def test_a_short_table_takes_ordinary_paged_attention(monkeypatch, served):
     real = keye_mod.paged_attention
     monkeypatch.setattr(keye_mod, "paged_attention", lambda *a, **kw: called.append(1) or real(*a, **kw))
     prompts = _prompts(20)
-    results, _ = _serve(monkeypatch, module, params, prompts)
-    assert called
+    results, stats = _serve(monkeypatch, module, params, prompts)
+    assert called and stats["attention"]["decode_read"] == "dense"
     for prompt, (tokens, logits) in zip(prompts, results):
         want = _reference_logits(params, list(prompt) + list(tokens), module.config)
         assert np.abs(logits - want[len(prompt) - 1:len(prompt) - 1 + len(tokens)]).max() < LOGIT_TOL

@@ -542,22 +542,26 @@ def test_paged_index_scores_compiles(chip, blocks, block, width):
     assert not re.search(rf"bf16\[{blocks},{block},128\]\S* (?:copy|transpose)\(", text)
 
 
-def test_the_sparse_decode_read_gathers_picked_rows_and_copies_no_pool(chip):
-    """The exact top-2,048 of 17,536 scores a row and the attention over the
-    picked rows, as the decode step runs them: the pool is read by one
-    gather of 16 x 2,048 tiles of 8 x 128 (a position's keys and values
-    together), never copied or re-laid out whole."""
-    def fn(q, kv, table, scores):
-        picked, valid = sparse_attention.select_top_k(scores, 2048)
-        return paged_attention.paged_sparse_attention(q, kv, table, picked, valid)
+def test_the_sparse_decode_read_walks_the_pool_as_it_lies_and_sorts_nothing(chip):
+    """The exact top-2,048 of 17,536 scores a row as a mask and the attention
+    over the selected rows, as the decode step runs them at the cell's
+    shapes: the threshold descent (no ``sort``), then the kernel
+    ``paged_sparse_attention``, which takes the pool whole: no gather of
+    its tiles, no copy, transpose or fusion of it."""
+    def fn(q, kv, table, lengths, scores):
+        selected = sparse_attention.top_k_mask(scores, 2048)
+        return paged_attention.paged_sparse_attention(q, kv, table, lengths, selected, impl="pallas")
 
-    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in (
-        ((16, 32, 128), jnp.bfloat16), ((2_260, 64, 8, 128), jnp.bfloat16),
-        ((16, 274), jnp.int32), ((16, 17_536), jnp.float32))]
-    text = jax.jit(fn).lower(*args).compile().as_text()
-    assert re.search(r"bf16\[(?:16,2048|32768),8,128\]\S* (?:gather|fusion)\(", text)
-    assert not re.search(r"bf16\[2260,64,8,128\]\S* (?:copy|transpose|fusion)\(", text)
-    assert "paged_sparse_attention" in text
+    text = _assert_mosaic(
+        chip, fn, ((16, 32, 128), jnp.bfloat16), ((2_260, 64, 8, 128), jnp.bfloat16),
+        ((16, 274), jnp.int32), ((16,), jnp.int32), ((16, 17_536), jnp.float32),
+    )
+    # chipbench's sparse_attn_ms_per_step finds the kernel by this name
+    assert re.search(r"%paged_sparse_attention(\.\d+)* = \S+ custom-call\(", text)
+    assert not re.search(r"\bsort\(", text)
+    assert not re.search(r"bf16\[(?:16,2048|32768),8,128\]", text)          # no picked tiles gathered
+    assert not re.search(r" gather\(", text)
+    assert not re.search(r"bf16\[2260,(?:64,8|512),128\]\S* (?:copy|transpose|fusion)\(", text)
 
 
 @pytest.mark.parametrize("seq", [4096, 16384], ids=["smallest-bucket", "largest-bucket"])

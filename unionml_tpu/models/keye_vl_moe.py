@@ -32,8 +32,10 @@ untied head.
   prompt, a chunk, a slot's rows) the scores, the selection as a mask and
   the softmax run in blocks of queries; a decode step over a block pool
   appends its row, scores the row's live blocks of indexer keys
-  (``paged_index_scores``), selects, and reads the picked rows only
-  (``paged_sparse_attention``). A cache no longer than ``topk`` selects
+  (``paged_index_scores``), selects as a mask over the row's table
+  (``top_k_mask``), and walks the row's live blocks of keys and values
+  with that mask (``paged_sparse_attention``: a position that is not
+  selected weighs zero). A cache no longer than ``topk`` selects
   everything and takes ordinary attention (``paged_attention`` over a pool).
 - **Mixture**: :class:`~unionml_tpu.ops.moe.MoEMlp` with the softmax router
   (top-k of the softmax, renormalised: ``norm_topk_prob``), no shared
@@ -59,7 +61,7 @@ from flax import linen as nn
 from unionml_tpu.models.layers import IndexedKVRows, RMSNorm, make_dense, rotary_embedding
 from unionml_tpu.ops.moe import MoEMlp, dispatch_plan
 from unionml_tpu.ops.paged_attention import paged_attention, paged_index_scores, paged_sparse_attention
-from unionml_tpu.ops.sparse_attention import select_top_k, sparse_attention
+from unionml_tpu.ops.sparse_attention import sparse_attention, top_k_mask
 
 
 @dataclass(frozen=True)
@@ -178,6 +180,20 @@ def multi_axis_rotary(x, positions, sections, *, theta: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
+# the descent is sixteen passes of a few operations each: every layer calls
+# one trace and one lowering of it
+_top_k_mask = jax.jit(top_k_mask, static_argnums=1)
+
+
+def decode_read(table_positions: int, topk: int) -> str:
+    """What a block-paged decode step compiles for a table of that many
+    positions: ``"dense"`` (no longer than ``topk``, so every visible row is
+    selected: ``paged_attention``) or ``"walk"`` (index scores, the
+    selection as a mask, ``paged_sparse_attention`` over the row's live
+    blocks)."""
+    return "dense" if table_positions <= topk else "walk"
+
+
 class IndexedSparseAttention(nn.Module):
     """The attention block. ``cache`` is a layer's entry of
     ``IndexedKVRows.init``: ``(keys and values, indexer keys)``, ``[B, L,
@@ -244,7 +260,7 @@ class IndexedSparseAttention(nn.Module):
                 rows = rows.at[pid, off].set(row[:, 0])
                 index_keys = index_keys.at[pid, off].set(ik_row[:, 0])
                 lengths = index + 1 if live is None else jnp.where(live, index + 1, 0)
-                if block_table.shape[1] * blk <= topk:
+                if decode_read(block_table.shape[1] * blk, topk) == "dense":
                     # every visible row is selected: ordinary paged attention
                     out = paged_attention(
                         q[:, 0], rows[:, :, :kv_heads], rows[:, :, kv_heads:], block_table, lengths,
@@ -257,8 +273,10 @@ class IndexedSparseAttention(nn.Module):
                             block_table, lengths, impl=cfg.paged_impl,
                         )
                     with jax.named_scope("select"):
-                        picked, valid = select_top_k(scores, topk)
-                    out = paged_sparse_attention(q[:, 0], rows, block_table, picked, valid, scale=scale)
+                        selected = _top_k_mask(scores, topk)
+                    out = paged_sparse_attention(
+                        q[:, 0], rows, block_table, lengths, selected, scale=scale, impl=cfg.paged_impl,
+                    )
                 out = out[:, None]
             else:
                 if index.ndim == 1:
@@ -338,6 +356,11 @@ class KeyeVLMoe(nn.Module):
         cfg = self.config
         row = IndexedKVRows(cfg.num_key_value_heads, cfg.head_dim, cfg.indexer_head_dim, cfg.cache_dtype)
         return (row,) * cfg.num_hidden_layers
+
+    def decode_read(self, table_positions: int) -> str:
+        """:func:`decode_read` of this model's ``topk`` (what a serving
+        engine reports as ``stats()["attention"]["decode_read"]``)."""
+        return decode_read(table_positions, self.config.index_topk)
 
     def moe_dispatch(self, tokens: int) -> Optional[dict]:
         """What a mixture layer does with a program of ``tokens`` rows

@@ -86,7 +86,7 @@ __all__ = [
     "latent_attention", "paged_attention", "paged_attention_reference",
     "paged_index_scores", "paged_index_scores_reference",
     "paged_latent_attention", "paged_latent_attention_reference",
-    "paged_sparse_attention",
+    "paged_sparse_attention", "paged_sparse_attention_reference",
 ]
 
 
@@ -698,8 +698,13 @@ def paged_latent_attention(q, pool, block_table, lengths, *, value_dim: int,
 # buffer of its own with the same blocks: ``[num_blocks, block, stored]``,
 # the key in the first columns of a row of whole 128-lane tiles. A decode
 # step reads every visible position's indexer key (:func:`paged_index_scores`),
-# selects (``sparse_attention.select_top_k``), and reads the keys and values
-# of the selected positions only (:func:`paged_sparse_attention`).
+# selects as a mask over the row's table (``sparse_attention.top_k_mask``),
+# and walks the row's live blocks of keys and values with that mask
+# (:func:`paged_sparse_attention`). The form in which the selection reaches
+# attention decides both costs: as positions it takes a sort of every row's
+# whole table to make and a gather of ``topk`` tiles a row to use, live or
+# not; as a mask it takes neither, and the read costs what the live rows
+# hold (PERF.md, section 6, PR 42).
 
 
 def _check_index_shapes(index_q, index_w, pool, block_table, lengths):
@@ -883,59 +888,277 @@ def paged_index_scores(index_q, index_w, pool, block_table, lengths, *, impl: st
     return _index_pallas(index_q, index_w, pool, block_table, lengths, interpret=_interpret())
 
 
-def paged_sparse_attention(q, kv, block_table, positions, valid, *, scale: Optional[float] = None):
-    """Single-step decode attention over a row's *picked* positions.
-
-    Shapes: ``q`` [B, Hq, D]; ``kv`` [num_blocks, block, 2 * Hk, D] the pool
-    whose rows hold a position's key heads and, behind them, its value
-    heads (``IndexedKVRows``); ``block_table`` [B, table_width] int32;
-    ``positions`` [B, K] int32 the picked positions of each row and
-    ``valid`` [B, K] which of them count (a row with fewer than K visible
-    positions, or a retired one, has the rest false: they read the trash
-    block's first row and weigh nothing). Returns [B, Hq, D] in
-    ``q.dtype``; zeros for a row with no valid pick.
-
-    The picked rows are fetched by (block, offset) through the table: one
-    XLA gather of ``K`` rows, keys and values together, then grouped-query
-    softmax attention over them (float32 scores and softmax). Nothing else
-    of the pool is read. The operations carry the scope
-    ``paged_sparse_attention`` in a device trace."""
+def _check_sparse_shapes(q, kv, block_table, lengths, selected):
     batch, q_heads, head_dim = q.shape
     if kv.ndim != 4 or kv.shape[-1] != head_dim or kv.shape[2] % 2 or q_heads % (kv.shape[2] // 2):
         raise ValueError(
             "the pool must be [num_blocks, block_size, 2 * kv_heads, head_dim] with the query heads a "
             f"multiple of the kv heads, got {kv.shape} for q {q.shape}"
         )
-    if positions.shape != valid.shape or positions.shape[0] != batch or block_table.shape[0] != batch:
+    if block_table.ndim != 2 or block_table.shape[0] != batch or lengths.shape != (batch,):
         raise ValueError(
-            f"positions / valid must be [batch, picks] and block_table [batch, table_width], got "
-            f"{positions.shape} / {valid.shape} / {block_table.shape} for batch {batch}"
+            f"block_table must be [batch, table_width] and lengths [batch], got {block_table.shape} / "
+            f"{lengths.shape} for batch {batch}"
         )
-    block, kv_heads = kv.shape[1], kv.shape[2] // 2
-    group, picks, heads = q_heads // kv_heads, positions.shape[1], 2 * kv_heads
-    if scale is None:
-        scale = head_dim ** -0.5
+    want = (batch, block_table.shape[1] * kv.shape[1])
+    if selected.shape != want:
+        raise ValueError(f"selected must be [batch, table_width * block_size] = {want}, got {selected.shape}")
+
+
+def paged_sparse_attention_reference(q, kv, block_table, lengths, selected, *, scale: float):
+    """The plain gather: the table's blocks taken into a contiguous
+    ``[B, W * block, 2 Hk, D]`` view, then grouped-query softmax attention
+    over the positions ``selected`` keeps (float32 scores and softmax;
+    float32 operands off the TPU). The CPU's path and the kernel's parity
+    anchor; ``lengths`` is not read (nothing past a length is selected)."""
+    _check_sparse_shapes(q, kv, block_table, lengths, selected)
+    batch, q_heads, head_dim = q.shape
+    kv_heads = kv.shape[2] // 2
     dtype = jnp.float32 if _interpret() else q.dtype
+    rows = jnp.take(kv, block_table.reshape(-1), axis=0).reshape((batch, -1) + kv.shape[2:]).astype(dtype)
+    grouped = q.reshape(batch, kv_heads, q_heads // kv_heads, head_dim).astype(dtype)
+    keys, values = rows[:, :, :kv_heads], rows[:, :, kv_heads:]
+    s = jnp.einsum("bkgd,blkd->bkgl", grouped, keys, preferred_element_type=jnp.float32) * scale
+    seen = selected[:, None, None, :]
+    m = jnp.max(jnp.where(seen, s, NEG_INF), axis=-1, keepdims=True)
+    p = jnp.where(seen, jnp.exp(s - m), 0.0)
+    p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    out = jnp.einsum("bkgl,blkd->bkgd", p.astype(dtype), values, preferred_element_type=jnp.float32)
+    return out.reshape(q.shape).astype(q.dtype)
+
+
+# positions a group gathers and scores (see ``_ROWS_PER_STEP``: the same
+# trade, one pool of 2 KB rows where that kernel has two of 1 KB). On a
+# v5e, us a layer at 16 live rows x 8,192 / 6 x 8,192 of the served cell's
+# pool: 505.2 / 196.3 at 256, 401.8 / 158.3 at 512, 368.7 / 146.9 at 1,024
+# (a group's fixed price is ~0.4 us; 4 MB of buffers; PERF.md, section 6,
+# PR 42)
+_SPARSE_ROWS_PER_STEP = 1024
+
+
+def _columns_of(mask_ref, g, heads):
+    """Group ``g`` of ``mask_ref`` [1, groups, positions] -> [1, positions *
+    heads]: column ``c`` holds position ``c // heads``'s entry. A lane tile
+    of the result draws on ``128 // heads`` lanes of one tile of the mask: a
+    gather within the tile, which costs a bundle or two (``jnp.repeat``
+    lowers to twenty times that)."""
+    positions = mask_ref.shape[2]
+    if 128 % heads or positions % 128:
+        return jnp.repeat(mask_ref[0, pl.ds(g, 1), :], heads, axis=1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1)
+    # the eight groups that share a sublane tile with g, and g's row of them
+    # on every sublane
+    base, own = pl.multiple_of(g // 8 * 8, 8), jnp.full((8, 128), g % 8, jnp.int32)
+    each, tiles = 128 // heads, []
+    for t in range(positions // 128):
+        source = jnp.take_along_axis(mask_ref[0, pl.ds(base, 8), pl.ds(t * 128, 128)], own, axis=0)
+        tiles += [jnp.take_along_axis(source, each * j + lane // heads, axis=1) for j in range(heads)]
+    return jnp.concatenate(tiles, axis=1)[:1]
+
+
+def _sparse_kernel(table_ref, len_ref, q_ref, mask_ref, pool, o_ref, buf, sem, state,
+                   acc_ref, m_ref, l_ref, *, scale, block, kv_heads, group, width, pages):
+    """:func:`_latent_kernel`'s walk over the pool whose rows hold a
+    position's key heads and, behind them, its value heads. A group's blocks
+    lie in the buffer as they lie in the pool, flattened ``[positions * 2 Hk,
+    D]``: row ``r`` is head ``r % (2 Hk)`` of position ``r // (2 Hk)``. ONE
+    matmul scores every query head against every row (:func:`_paged_kernel`'s
+    scheme), the scores move ``Hk`` columns up, from a position's key head to
+    its value head, and there each query head keeps its own head's columns
+    *of the selected positions*: the weights then stand over the value rows,
+    zero over everything else, and one more matmul with the same buffer sums
+    them. ``mask_ref`` holds the row's selection a position, group by group."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    batch = pl.num_programs(0)
+    heads = 2 * kv_heads
+    rows = pages * block
+    cols = rows * heads
+    q_heads = kv_heads * group
+
+    def visible(row):
+        return jnp.clip(len_ref[row], 0, width * block)
+
+    def copies(row, grp, slot, fn):
+        def page(j, carry):
+            src = table_ref[row, grp * pages + j]
+            fn(pltpu.make_async_copy(pool.at[src], buf.at[slot, j], sem.at[slot]))
+            return carry
+        live_pages = jnp.minimum(pl.cdiv(visible(row), block) - grp * pages, pages)
+        jax.lax.fori_loop(0, live_pages, page, 0)
+
+    @pl.when(b == 0)
+    def _reset():
+        state[0] = 0
+        state[1] = 0
+        # a page no copy has reached yet weighs zero, and 0 x what fast
+        # memory happened to hold may be NaN: from here on the buffers hold
+        # zeros or rows of the pool, which are finite
+        buf[...] = jnp.zeros_like(buf)
+
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    length = visible(b)
+    groups = pl.cdiv(length, rows)
+
+    def score(g, slot):
+        q = q_ref[0]                                       # [Hq, D]
+        kv = buf[slot].reshape(cols, -1).astype(q.dtype)   # [cols, D]
+        s = jax.lax.dot_general(
+            q, kv, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        ) * scale                                          # [Hq, cols]
+        # a key head's score to its value head's column (the last position's
+        # value columns wrap to the first's key columns, which nobody keeps)
+        s = pltpu.roll(s, kv_heads, 1)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+        q_head = jax.lax.broadcasted_iota(jnp.int32, (q_heads, 1), 0)
+        # nothing past the row's length is selected, so a page that was not
+        # copied (an earlier group's rows, or zeros) weighs nothing
+        picked = _columns_of(mask_ref, g, heads) > 0.0         # [1, cols]
+        valid = (col % heads == kv_heads + q_head // group) & picked
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_ref[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
+        p = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
+        corr = jnp.exp(jnp.where(m_prev == NEG_INF, NEG_INF, m_prev - m_safe))
+        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
+            p.astype(q.dtype), kv, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        )
+        m_ref[:] = m_new
+
+    @pl.when(groups > 0)
+    def _walk():
+        first = state[0]
+
+        @pl.when(state[1] == 0)
+        def _start_own():
+            copies(b, 0, first, lambda c: c.start())
+
+        nxt_b = jax.lax.while_loop(
+            lambda r: (r < batch) & (len_ref[jnp.minimum(r, batch - 1)] <= 0),
+            lambda r: r + 1,
+            b + 1,
+        )
+        has_next = nxt_b < batch
+
+        def one_group(g, slot):
+            last = g + 1 == groups
+
+            @pl.when(jnp.logical_not(last) | has_next)
+            def _start_next():
+                copies(
+                    jnp.where(last, nxt_b, b), jnp.where(last, 0, g + 1),
+                    1 - slot, lambda c: c.start(),
+                )
+
+            copies(b, g, slot, lambda c: c.wait())
+            score(g, slot)
+            return 1 - slot
+
+        state[0] = jax.lax.fori_loop(0, groups, one_group, first)
+        state[1] = has_next.astype(jnp.int32)
+
+    o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
+
+
+# one trace and one lowering for every layer of a program: a model's layers
+# call it with the same shapes, and a warm start pays the lowering (the
+# compiled program comes from the cache, its key from the lowered text)
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _sparse_pallas(q, kv, block_table, lengths, selected, *, scale, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+
+    batch, q_heads, head_dim = q.shape
+    num_pool_blocks, block, heads, _ = kv.shape
+    w = block_table.shape[1]
+    pages = max(1, min(_SPARSE_ROWS_PER_STEP // block, w))
+    groups, rows = -(-w // pages), pages * block
+    # the selection in whole groups, whole sublane tiles of them (nothing
+    # past the table is selected)
+    mask_groups = -(-groups // 8) * 8
+    mask = jnp.pad(selected.astype(jnp.float32), ((0, 0), (0, mask_groups * rows - selected.shape[1])))
+    mask = mask.reshape(batch, mask_groups, rows)
+
+    def row_map(b, table, lens):
+        return (b, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(batch,),
+        in_specs=[
+            pl.BlockSpec((1, q_heads, head_dim), row_map), pl.BlockSpec((1, mask_groups, rows), row_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, q_heads, head_dim), row_map),
+        scratch_shapes=[
+            pltpu.VMEM((2, pages, block * heads, head_dim), kv.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((2,), jnp.int32),
+            pltpu.VMEM((q_heads, head_dim), jnp.float32),
+            pltpu.VMEM((q_heads, 1), jnp.float32),
+            pltpu.VMEM((q_heads, 1), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _sparse_kernel, scale=scale, block=block, kv_heads=heads // 2, group=q_heads // (heads // 2),
+        width=w, pages=pages,
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((batch, q_heads, head_dim), q.dtype),
+        # a row starts the gather of the next row's first group: in order
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_sparse_attention",
+    )(
+        # [N, block, 2 Hk, D] -> [N, block * 2 Hk, D] merges the two middle
+        # dims under an unchanged minor dim: the pool as it lies
+        block_table.astype(jnp.int32), lengths.astype(jnp.int32), q, mask,
+        kv.reshape(num_pool_blocks, block * heads, head_dim),
+    )
+
+
+def paged_sparse_attention(q, kv, block_table, lengths, selected, *, scale: Optional[float] = None,
+                           impl: str = "auto"):
+    """Single-step decode attention over a row's *selected* positions.
+
+    Shapes: ``q`` [B, Hq, D]; ``kv`` [num_blocks, block, 2 * Hk, D] the pool
+    whose rows hold a position's key heads and, behind them, its value
+    heads (``IndexedKVRows``), handed whole: never sliced into keys and
+    values, copied or re-laid out; ``block_table`` [B, table_width] int32;
+    ``lengths`` [B] int32 visible rows (0 for a row whose output nobody
+    reads); ``selected`` [B, table_width * block] bool, the positions of
+    each row that count (``sparse_attention.top_k_mask`` of its index
+    scores: nothing past its length). Returns [B, Hq, D] in ``q.dtype``;
+    zeros for a row that selects nothing. Grouped-query softmax attention
+    over the selected positions, float32 scores, softmax and sums; a
+    position that is not selected weighs exactly zero.
+
+    ``impl``: ``"reference"`` (the plain gather of the table's blocks),
+    ``"pallas"`` (the kernel ``paged_sparse_attention``; interpreter mode
+    off-TPU) or ``"auto"`` (pallas on TPU, reference elsewhere). The kernel
+    walks a row's live blocks as :func:`paged_attention` does (a grid step a
+    row, ``cdiv(length, group)`` groups of its blocks, the next group's
+    copies in flight while this one is scored): what it *fetches* is every
+    visible row, what it *weighs* the selected ones, and a row of length 0
+    walks nothing and returns zeros. **The pool holds finite values only**
+    (zeros at the start, then real rows: the contract :func:`_paged_kernel`
+    relies on for the tail of a last block): a fetched row that is not
+    selected is multiplied by a zero weight, not skipped. Kernel and
+    operations carry the scope ``paged_sparse_attention`` in a device trace."""
+    _check_sparse_shapes(q, kv, block_table, lengths, selected)
+    if impl == "auto":
+        impl = "reference" if _interpret() else "pallas"
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     with jax.named_scope("paged_sparse_attention"):
-        block_id = jnp.take_along_axis(block_table, positions // block, axis=1)
-        # a picked position's row number in the pool seen as rows (merging
-        # blocks and their rows moves nothing); the table keeps it in bounds
-        row = jnp.where(valid, block_id * block + positions % block, 0)
-        picked = kv.reshape((-1,) + kv.shape[2:]).at[row].get(mode="promise_in_bounds")   # [B, K, 2 Hk, D]
-        # the picked tiles as they lie, [K * 2 Hk, D]: row n is head n % (2 Hk)
-        # of pick n // (2 Hk). ONE matmul scores every query head against every
-        # row and a mask keeps each query head's own key head (the paged
-        # kernel's scheme): a per-head product would first turn 67 MB of tiles
-        # round, and the picked rows are the smaller part of that cost
-        rows2d = picked.reshape(batch, picks * heads, head_dim).astype(dtype)
-        s = jnp.einsum("bqd,bnd->bqn", q.astype(dtype), rows2d, preferred_element_type=jnp.float32) * scale
-        column = jnp.arange(picks * heads) % heads
-        own_key = column[None, :] == (jnp.arange(q_heads) // group)[:, None]            # [Hq, K * 2 Hk]
-        seen = own_key[None] & jnp.repeat(valid, heads, axis=-1)[:, None, :]
-        m = jnp.max(jnp.where(seen, s, NEG_INF), axis=-1, keepdims=True)
-        p = jnp.where(seen, jnp.exp(s - m), 0.0)
-        p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
-        # a pick's value head lies kv_heads rows behind its key head
-        weights = jnp.roll(p, kv_heads, axis=-1).astype(dtype)
-        out = jnp.einsum("bqn,bnd->bqd", weights, rows2d, preferred_element_type=jnp.float32)
-        return out.astype(q.dtype)
+        if impl == "reference":
+            return paged_sparse_attention_reference(q, kv, block_table, lengths, selected, scale=scale)
+        if impl != "pallas":
+            raise ValueError(f"unknown paged sparse attention impl {impl!r}")
+        return _sparse_pallas(q, kv, block_table, lengths, selected, scale=scale, interpret=_interpret())

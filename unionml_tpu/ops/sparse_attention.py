@@ -7,16 +7,15 @@ The *index score* of query ``t`` for cached position ``s`` is ``I[t, s] =
 sum_j w[t, j] * relu(q_idx[t, j] . k_idx[s])`` over the indexer's heads
 ``j`` (one key head). The selection is **exact**: the ``topk`` visible
 positions of largest score, ties cut towards the lower position (what
-``jax.lax.top_k`` does), all of them while fewer are visible. Two forms of
-it, the same set:
-
-- :func:`select_top_k`: the positions as a list (a decode step gathers the
-  picked rows by it): ``jax.lax.top_k``.
-- :func:`top_k_mask`: the set as a mask over the positions (a prefill keeps
-  the scores' layout and hides the rest): the ``topk``-th largest score is
-  found without a sort, by a descent over the bits of the scores' ordered
-  integer image (:func:`kth_largest_key`: 16 counting passes of two bits),
-  ``score > tau`` is in, and of ``score == tau`` the first few by position.
+``jax.lax.top_k`` does), all of them while fewer are visible. It is made
+as a **mask** over the positions (:func:`top_k_mask`), for a prompt and for a
+decode step alike: the ``topk``-th largest score is found without a sort, by
+a descent over the bits of the scores' ordered integer image
+(:func:`kth_largest_key`: 16 counting passes of two bits), ``score > tau`` is
+in, and of ``score == tau`` the first few by position. As a list of positions
+(``jax.lax.top_k``: on the chip a sort of the whole axis) a decode step's
+attention had to gather the picked rows one tile a pick; the mask lets it
+walk the row's live blocks instead (PERF.md, section 6, PR 42).
 
 :func:`sparse_attention` is the form over contiguous rows (a prompt, a
 prefill chunk behind cached rows, a slot's rows). On a TPU, whole tiles:
@@ -40,7 +39,7 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 __all__ = [
-    "index_scores", "kth_largest_key", "masked_attention", "ordered_key", "select_mask_tiles", "select_top_k",
+    "index_scores", "kth_largest_key", "masked_attention", "ordered_key", "select_mask_tiles",
     "sparse_attention", "top_k_mask",
 ]
 
@@ -106,20 +105,6 @@ def top_k_mask(scores, k: int):
     room = k - jnp.sum(above, axis=-1, keepdims=True)
     first_ties = jnp.cumsum(ties, axis=-1, dtype=jnp.int32) <= room
     return (above | (ties & first_ties)) & (scores > -jnp.inf)
-
-
-def select_top_k(scores, k: int):
-    """(positions [..., k] int32, valid [..., k] bool): the ``k`` entries of
-    largest ``scores`` along the last axis, ties towards the lower index;
-    ``valid`` is false where fewer than ``k`` entries lie above ``-inf``
-    (the position is then 0). ``jax.lax.top_k``: on the chip a sort of the
-    whole axis; a threshold select that yields positions (:func:`top_k_mask`,
-    a prefix sum and a two-level search) read no faster there (PERF.md, PR
-    40) and was not kept."""
-    k = min(k, scores.shape[-1])
-    values, positions = jax.lax.top_k(scores, k)
-    valid = values > -jnp.inf
-    return jnp.where(valid, positions, 0).astype(jnp.int32), valid
 
 
 # what one block of queries may hold in float32 score arrays

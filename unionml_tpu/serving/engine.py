@@ -559,6 +559,7 @@ class DecodeEngine:
         self._index_topk = (
             getattr(module.config, "index_topk", None) if self._index_layers else None
         )
+        self._decode_read: Optional[str] = None
         # bytes of recurrent state one slot keeps on the device
         self._state_bytes_per_slot = sum(
             l.nbytes() for l, kv in zip(self._layout, self._owns_rows) if not kv
@@ -854,6 +855,9 @@ class DecodeEngine:
                 block_nbytes=block_nbytes, registry=self._registry,
             )
             self._table = np.zeros((slots, self._table_width), np.int32)
+            if self._index_topk is not None:
+                # which read of the selected rows the decode chunk compiles
+                self._decode_read = module.decode_read(self._table_width * blk)
             self._slot_covered = [0] * slots   # taken blocks per slot row
             self._slot_rows = [0] * slots      # dispatched-rows upper bound
         self._sample = make_sampler(
@@ -1905,6 +1909,10 @@ class DecodeEngine:
             out["attention"] = {
                 "index_layers": self._index_layers,
                 "index_topk": self._index_topk,
+                # "walk": a step fetches a sequence's visible rows and
+                # weighs the selected ones; "dense": the table cannot hold
+                # a row that is not selected (None: no block pool)
+                "decode_read": self._decode_read,
                 "selected_positions": report["selected_positions"],
                 "visible_positions": report["visible_positions"],
             }
