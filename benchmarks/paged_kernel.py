@@ -16,6 +16,14 @@ what the rows hold:
   table of trash-block entries (what it handed before);
 - ``dead``: every row length 0 — what walking the batch costs by itself.
 
+``--queries N`` times the kernel with N queries a row instead (a decoder that
+generates by blocks: a block's N positions share the row's length and attend
+one another) at ``sdar_chat_fixed_length_decode``'s shape: 32 slots, GQA 32/4
+over a fused pool (a position's 4 key and 4 value heads in one row), a table
+163 blocks wide, 20 live rows of 300-2,300 positions; beside it the same
+rows with one query, so that what N queries cost over one is read off two
+lines. Table and lengths are arguments of every timed program.
+
 ``--rows N`` overrides the KV rows a kernel step takes (the module's
 ``_ROWS_PER_STEP`` and a VMEM budget to match), to re-derive them. Fails
 without a TPU unless ``--rehearse`` (tiny shapes, interpret mode: the
@@ -38,9 +46,11 @@ SHAPES = [
     ("mixtral_chat_decode", 32, 8, 101, 2861, 13, 260),
     ("olmo_hybrid_longgen_decode", 32, 32, 261, 1621, 12, 850),
 ]
+# --queries: the cell whose rows take several queries, over a fused pool
+BLOCK_SHAPE = ("sdar_chat_fixed_length_decode", 32, 4, 163, 3814, 20, 1300)
 
 
-def _case(rng, width, n_blocks, live, mean_len, stale):
+def _case(rng, width, n_blocks, live, mean_len, stale, spread=None):
     """Table, lengths and the live positions: ``live`` rows among the low
     slots (an engine takes the lowest free slot) at lengths around
     ``mean_len``; the rest over trash-block entries, at length 0 or, if
@@ -50,7 +60,10 @@ def _case(rng, width, n_blocks, live, mean_len, stale):
     blocks = iter(rng.permutation(np.arange(1, n_blocks)))
     rows = rng.choice(min(SLOTS, 2 * live), size=live, replace=False)
     for b in rows:
-        lengths[b] = np.clip(rng.normal(mean_len, 0.4 * mean_len), 16, width * BLOCK)
+        if spread is None:
+            lengths[b] = np.clip(rng.normal(mean_len, 0.4 * mean_len), 16, width * BLOCK)
+        else:
+            lengths[b] = rng.integers(*spread)
         need = -(-int(lengths[b]) // BLOCK)
         table[b, :need] = [next(blocks) for _ in range(need)]
     return jnp.asarray(table), jnp.asarray(lengths), int(lengths[rows].sum())
@@ -61,6 +74,7 @@ def main():
     ap.add_argument("--reps", type=int, default=200)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--rows", type=int, default=0)
+    ap.add_argument("--queries", type=int, default=0)
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
     device = jax.devices()[0]
@@ -71,14 +85,25 @@ def main():
         pa._KV_BUFFER_BYTES = max(pa._KV_BUFFER_BYTES, 4 * args.rows * 32 * HEAD_DIM * 2)
     reps = 2 if args.rehearse else args.reps
 
-    for name, q_heads, kv_heads, width, n_blocks, live, mean_len in SHAPES:
+    shapes = [shape + (0,) for shape in SHAPES]
+    if args.queries:
+        shapes = [BLOCK_SHAPE + (args.queries,), BLOCK_SHAPE + (1,)]
+    for name, q_heads, kv_heads, width, n_blocks, live, mean_len, queries in shapes:
+        spread = (300, 2300) if queries else None
         if args.rehearse:
-            width, n_blocks, mean_len = 40, 200, 60
+            width, n_blocks, mean_len, spread = 40, 200, 60, (30, 90) if queries else None
         rng = np.random.default_rng(args.seed)
         pool_shape = (n_blocks, BLOCK, kv_heads, HEAD_DIM)
-        k = jnp.asarray(rng.standard_normal(pool_shape), jnp.bfloat16)
-        v = jnp.asarray(rng.standard_normal(pool_shape), jnp.bfloat16)
-        q = jnp.asarray(rng.standard_normal((SLOTS, q_heads, HEAD_DIM)), jnp.bfloat16)
+        if queries:
+            # one pool of fused rows: the key heads, the value heads behind them
+            fused_shape = (n_blocks, BLOCK, 2 * kv_heads, HEAD_DIM)
+            k = jnp.asarray(rng.standard_normal(fused_shape), jnp.bfloat16)
+            v = None
+            q = jnp.asarray(rng.standard_normal((SLOTS, queries, q_heads, HEAD_DIM)), jnp.bfloat16)
+        else:
+            k = jnp.asarray(rng.standard_normal(pool_shape), jnp.bfloat16)
+            v = jnp.asarray(rng.standard_normal(pool_shape), jnp.bfloat16)
+            q = jnp.asarray(rng.standard_normal((SLOTS, q_heads, HEAD_DIM)), jnp.bfloat16)
 
         @jax.jit
         def loop(q, k, v, table, lengths):
@@ -89,7 +114,7 @@ def main():
         cases = (("live+zero", live, False), ("live+stale", live, True), ("dead", 0, False))
         for what, n_live, stale in cases:
             table, lengths, positions = _case(
-                np.random.default_rng(args.seed + 1), width, n_blocks, n_live, mean_len, stale,
+                np.random.default_rng(args.seed + 1), width, n_blocks, n_live, mean_len, stale, spread,
             )
             loop(q, k, v, table, lengths).block_until_ready()
             times = []
@@ -98,7 +123,8 @@ def main():
                 loop(q, k, v, table, lengths).block_until_ready()
                 times.append((time.perf_counter() - t0) / reps)
             print(json.dumps({
-                "shape": name, "rows": what, "us_per_call": round(1e6 * float(np.median(times)), 2),
+                "shape": name, "rows": what, "queries": queries or 1,
+                "us_per_call": round(1e6 * float(np.median(times)), 2),
                 "us_min": round(1e6 * min(times), 2), "live_rows": n_live, "live_positions": positions,
                 "kv_mb": round(positions * 2 * kv_heads * HEAD_DIM * 2 / 1e6, 2),
                 "pages_per_step": pa._pages_per_step(BLOCK, kv_heads, HEAD_DIM, 2, width),

@@ -5,6 +5,7 @@
     python scripts/compiled_chunk.py chipbench/configs/mixtral-8x7b-int8.json \\
         --program prefill --bucket 256 [--match moe]
     python scripts/compiled_chunk.py chipbench/configs/glm-4.7-flash-int8.json [--match attn]
+    python scripts/compiled_chunk.py chipbench/configs/sdar-30b-a3b-chat-int8.json [--match sample]
 
 Builds the ``DecodeEngine`` a ``chipbench`` cell serves with (the
 configuration's adapter and its ``serving`` block, as
@@ -20,6 +21,8 @@ compiling if the file is there. A 32-layer model compiles in a quarter of a
 minute. Where no TPU compiler is installed it says so and compiles nothing.
 ``--program prefill --bucket N`` lists the prefill program of one prompt
 bucket the same way: it has no loop, so the whole entry computation is listed.
+A step of the chunk of a module that generates by blocks (the SDAR
+configuration) is one forward over every slot's open block.
 """
 
 from __future__ import annotations
@@ -133,6 +136,8 @@ def compiled_chunk_text(config_path: str, program: str = "decode", bucket: int |
                 jax.ShapeDtypeStruct((bucket // engine._kv_block_size,), jnp.int32),
                 jax.ShapeDtypeStruct((bucket,), jnp.int32), jax.ShapeDtypeStruct((), jnp.int32),
                 jax.eval_shape(lambda: jax.random.PRNGKey(0)),
+                # a module that generates by blocks is also told the tokens asked
+                *((jax.ShapeDtypeStruct((), jnp.int32),) if engine._blocks is not None else ()),
             )
         else:
             compiled, args = engine._decode_chunk, (

@@ -466,12 +466,16 @@ DEVICE_SCOPE_NAMES = (
     # models/keye_vl_moe.py IndexedSparseAttention and ops/sparse_attention.py,
     # under ``attn``; ops/paged_attention.py's plain form of the decode read
     "indexer", "select", "paged_sparse_attention",
+    # serving/programs.py block_chunk, under ``sample``: the confidences and
+    # the choice of the entries a denoising forward decides
+    "unmask",
 )
 # scopes that stand in no row of ``PART_TABLE``: they are only ever opened
 # under the module named here, whose row places them (``part_of`` reads the
 # whole path), so the benchmark's table needs no edit for them
 DEVICE_SCOPES_PLACED_BY_OWNER = {
     "indexer": "attn", "select": "attn", "paged_sparse_attention": "attn",
+    "unmask": "sample",
 }
 _DEVICE_SCOPE_DOC_BEGIN = "<!-- DEVICE_SCOPE_NAMES:begin -->"
 _DEVICE_SCOPE_DOC_END = "<!-- DEVICE_SCOPE_NAMES:end -->"

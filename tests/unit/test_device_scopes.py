@@ -27,8 +27,9 @@ from unionml_tpu.models.glm_moe_lite import GlmMoeLite, GlmMoeLiteConfig
 from unionml_tpu.models.keye_vl_moe import KeyeVLMoe, KeyeVLMoeConfig
 from unionml_tpu.models.llama import Llama, LlamaConfig
 from unionml_tpu.models.olmo_hybrid import OlmoHybrid, OlmoHybridConfig
+from unionml_tpu.models.sdar_moe import SdarMoe, SdarMoeConfig
 from unionml_tpu.models.train import TrainState, adamw
-from unionml_tpu.serving.programs import build_programs
+from unionml_tpu.serving.programs import build_programs, generation_scheme
 
 _LINT = Path(__file__).resolve().parents[2] / "scripts" / "lint_basics.py"
 _spec = importlib.util.spec_from_file_location("lint_basics_for_scopes", _LINT)
@@ -129,6 +130,7 @@ FAMILIES = {
     "olmo_hybrid": lambda: OlmoHybrid(OlmoHybridConfig.tiny(vocab_size=97)),
     "glm_moe_lite": lambda: GlmMoeLite(GlmMoeLiteConfig.tiny(vocab_size=97)),
     "keye_vl_moe": lambda: KeyeVLMoe(KeyeVLMoeConfig.tiny(vocab_size=97, quantized=True)),
+    "sdar_moe": lambda: SdarMoe(SdarMoeConfig.tiny(vocab_size=97, quantized=True)),
     "vit": lambda: ViT(ViTConfig.tiny()),
 }
 MOE_SCOPES = {"router", "group_rows", "gather", "experts", "combine"}
@@ -155,6 +157,8 @@ def _served_text(module, program: str) -> str:
         lowered = progs.prefill.lower(
             params, state, jnp.int32(1), jnp.zeros((BUCKET // BLOCK,), jnp.int32),
             jnp.zeros((BUCKET,), jnp.int32), jnp.int32(5), key,
+            # a module that generates by blocks is also told how many tokens are asked
+            *((jnp.int32(8),) if generation_scheme(module) is not None else ()),
         )
     return lowered.compile().as_text()
 
@@ -180,7 +184,7 @@ def _train_text(module, accumulate_steps: int = 1) -> str:
 
 SERVED = [
     (family, program)
-    for family in ("dense_llama", "int8_moe_llama", "olmo_hybrid", "glm_moe_lite", "keye_vl_moe")
+    for family in ("dense_llama", "int8_moe_llama", "olmo_hybrid", "glm_moe_lite", "keye_vl_moe", "sdar_moe")
     for program in ("decode_chunk", "prefill")
 ]
 
@@ -198,9 +202,16 @@ def test_served_program_names_every_operation(family, program):
     assert len(names) > 50
     assert unowned(names, type(module).__name__) == []
     # the work no module owns, where the readers look for it
-    assert {"sample", "step_io"} <= top_scopes(names)
+    by_blocks = family == "sdar_moe"
+    assert "step_io" in top_scopes(names)
+    # (by blocks a prefill yields no token and samples nothing)
+    assert ("sample" in top_scopes(names)) != (by_blocks and program == "prefill")
     if program == "prefill":
         assert "commit" in top_scopes(names)
+    if by_blocks:
+        assert scopes_under(names, "moe") == MOE_SCOPES
+        # the confidences and the choice of the entries a forward decides
+        assert scopes_under(names, "sample") == ({"unmask"} if program == "decode_chunk" else set())
     if family == "int8_moe_llama":
         assert scopes_under(names, "moe") == MOE_SCOPES
     if family == "glm_moe_lite":
@@ -215,6 +226,16 @@ def test_served_program_names_every_operation(family, program):
         # in both programs; only the decode step walks a pool with it
         walk = {"paged_sparse_attention"} if program == "decode_chunk" else set()
         assert scopes_under(names, "attn") == {"indexer", "select"} | walk
+
+
+@pytest.mark.parametrize("family,program", [case for case in SERVED if case[0] != "sdar_moe"])
+def test_the_choice_of_entries_reaches_no_other_familys_program(family, program):
+    """``unmask`` has one caller, the chunk of a module that generates by
+    blocks: no other served program holds an operation under it, nor the
+    open block's state."""
+    text = _family_text(family, program)
+    parts = {part for name in program_op_names(text) for part in name.split("/")[:-1]}
+    assert "unmask" not in parts and "blk_und" not in text
 
 
 @pytest.mark.parametrize("family,program", [case for case in SERVED if case[0] != "keye_vl_moe"])

@@ -86,3 +86,44 @@ def test_programs_take_one_signature_and_keep_the_state(kind):
             assert n_rows == {BUCKET}
     finally:
         engine.close()
+
+
+def test_a_module_that_generates_by_blocks_keeps_the_signature_and_adds_what_it_asks():
+    """The block pool's third chunk: the state also holds every slot's open
+    block, a prefill takes the tokens asked behind its key and returns the
+    entries held back where the others return a token, and a step returns a
+    block's tokens, the forward that decided each and four counts a slot."""
+    from unionml_tpu.models.sdar_moe import SdarMoe, SdarMoeConfig
+
+    module = SdarMoe(SdarMoeConfig.tiny(vocab_size=97))
+    engine = DecodeEngine(
+        module, slots=3, max_new_tokens=8, prompt_buckets=(BUCKET,), chunk_steps=2,
+        registry=telemetry.MetricsRegistry(), tracer=telemetry.TraceRecorder(),
+        paged=True, kv_pool_bytes=1 << 20, kv_block_size=BLOCK,
+    )
+    try:
+        params = _params(module)
+        ids, table = jnp.zeros((BUCKET // BLOCK,), jnp.int32), jnp.asarray(engine._table)
+        slot, key = jnp.int32(1), jax.random.PRNGKey(0)
+        state = jax.eval_shape(engine._init_state)
+        want = _shapes(state)
+        assert {"fill", "done", "stop", "blk_tok", "blk_und", "blk_gen", "blk_at", "blk_fwd"} < set(state)
+        assert state["blk_tok"].shape == state["blk_und"].shape == (3, 4)
+        new_state, held = jax.eval_shape(
+            engine._prefill, params, state, slot, ids, jnp.zeros((BUCKET,), jnp.int32), jnp.int32(5), key,
+            jnp.int32(8),
+        )
+        assert _shapes(new_state) == want and held.shape == ()
+        keys = jnp.stack([key] * engine.chunk_steps)
+        new_state, (tokens, decided_at, info) = jax.eval_shape(
+            engine._decode_chunk, params, state, jnp.ones((3,), bool), table, keys
+        )
+        assert _shapes(new_state) == want
+        assert tokens.shape == decided_at.shape == (2, 3, 4) and info.shape == (2, 3, 4)
+        # the trace readers find a served cell's chunk by this name
+        lowered = getattr(engine._decode_chunk, "__wrapped__", engine._decode_chunk).lower(
+            params, state, jnp.ones((3,), bool), table, keys
+        )
+        assert "jit_decode_chunk" in lowered.as_text()[:400] or "decode_chunk" in lowered.as_text()[:400]
+    finally:
+        engine.close()

@@ -168,6 +168,73 @@ def test_pallas_matches_reference_int8(width, heads, layout):
     assert _gap(args, kw, rows) < 1e-5
 
 
+# ---- several queries a row that share its length (a block of positions
+# that attend one another: a decoder that generates by blocks)
+
+
+def _with_queries(args, queries, seed=11):
+    """``_ragged_setup``'s arguments with ``queries`` queries a row."""
+    q = args[0]
+    rng = np.random.default_rng(seed)
+    many = jnp.asarray(rng.standard_normal((q.shape[0], queries) + q.shape[1:]), q.dtype)
+    return (many.at[:, 0].set(q),) + args[1:]
+
+
+@LAYOUTS
+@HEADS
+@pytest.mark.parametrize("queries", [1, 4, 8])
+def test_pallas_matches_reference_with_several_queries_a_row(queries, heads, layout):
+    """Rows of unequal length, rows that see nothing and a dead row, each
+    with 1, 4 or 8 queries: the kernel's rows are the gather's."""
+    args, kw, rows = _ragged_setup(11, heads, jnp.float32, False, seed=6, layout=layout)
+    args = _with_queries(args, queries)
+    ref = paged_attention(*args, impl="reference", **kw)
+    pal = paged_attention(*args, impl="pallas", **kw)
+    assert ref.shape == pal.shape == args[0].shape
+    assert bool(jnp.all(jnp.isfinite(pal))) and not bool(jnp.any(pal[np.asarray(args[4]) == 0]))
+    assert float(jnp.max(jnp.abs(pal - ref)[rows])) < 1e-6
+
+
+@pytest.mark.parametrize("impl", ["reference", "pallas"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["float", "int8"])
+def test_one_query_a_row_is_todays_output_bit_for_bit(impl, quantized):
+    """``q [B, 1, Hq, D]`` runs the program ``q [B, Hq, D]`` runs."""
+    args, kw, _ = _ragged_setup(11, (8, 2), jnp.float32, quantized, seed=7)
+    one = paged_attention(*args, impl=impl, **kw)
+    many = paged_attention(args[0][:, None], *args[1:], impl=impl, **kw)
+    assert many.shape == (one.shape[0], 1) + one.shape[1:]
+    assert np.array_equal(np.asarray(many[:, 0]), np.asarray(one))
+
+
+@LAYOUTS
+@pytest.mark.parametrize("queries", [0, 4], ids=["one-query", "four-queries"])
+@pytest.mark.parametrize("impl", ["reference", "pallas"])
+def test_a_fused_pool_is_read_as_its_two_halves(impl, queries, layout):
+    """One pool of rows that hold a position's key heads and its value
+    heads behind them serves what the two pools serve."""
+    args, kw, rows = _ragged_setup(11, (8, 2), jnp.float32, False, seed=9, layout=layout)
+    if queries:
+        args = _with_queries(args, queries)
+    q, k, v, table, lengths = args
+    want = paged_attention(q, k, v, table, lengths, impl="reference")
+    got = paged_attention(q, jnp.concatenate([k, v], axis=2), None, table, lengths, impl=impl)
+    assert got.shape == want.shape and bool(jnp.all(jnp.isfinite(got)))
+    assert float(jnp.max(jnp.abs(got - want)[rows])) < 1e-6
+    with pytest.raises(ValueError, match="fused pool"):
+        paged_attention(q, k[:, :, :1], None, table, lengths)
+
+
+def test_every_query_of_a_row_sees_all_of_its_rows():
+    """The queries of a row are not masked against one another: each sees
+    the row's whole length, as a query alone with that length does."""
+    args, kw, rows = _ragged_setup(11, (8, 2), jnp.float32, False, seed=8)
+    args = _with_queries(args, 4)
+    together = paged_attention(*args, impl="reference", **kw)
+    for j in range(4):
+        alone = paged_attention(args[0][:, j], *args[1:], impl="reference", **kw)
+        assert float(jnp.max(jnp.abs(together[:, j] - alone)[rows])) < 1e-6
+
+
 @pytest.mark.parametrize(
     "block,kv_heads,head_dim,itemsize,width,pages",
     [

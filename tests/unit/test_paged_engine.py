@@ -370,6 +370,36 @@ def test_table_growth_across_max_new_boundary(tiny_llama):
         engine.close()
 
 
+def test_table_growth_by_blocks_of_positions():
+    """A module that generates by blocks moves a slot on a block of four
+    positions a commit and writes the open block's rows past its fill: the
+    table is grown ahead of both from a reservation of prompt + asked + a
+    block, across pool blocks of 8, and nothing leaks. A stream abandoned
+    mid-way frees its blocks too."""
+    from unionml_tpu.models.sdar_moe import SdarMoe, SdarMoeConfig
+
+    module = SdarMoe(SdarMoeConfig.tiny(vocab_size=97, dtype="float32", cache_dtype="float32"))
+    params = module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    engine = _paged_engine(
+        module, slots=2, max_new_tokens=30, prompt_buckets=(8,), chunk_steps=3, kv_block_size=8,
+    )
+    try:
+        rng = np.random.default_rng(8)
+        prompts = [rng.integers(1, 97, size=n).tolist() for n in (6, 7)]
+        out = engine.generate(params, prompts)
+        assert [len(t) for t in out] == [30, 30]
+        st = _assert_pool_drained(engine)
+        # 6 + 30 = 36 positions, the last block whole: 5 blocks of 8 a request
+        assert st["allocated_blocks"] >= 10
+        assert engine.generate(params, [prompts[0]])[0] == out[0]
+        gen = engine.generate_stream(params, prompts[1])
+        next(gen)
+        gen.close()
+        _assert_pool_drained(engine)
+    finally:
+        engine.close()
+
+
 def test_no_leaked_blocks_after_abandoned_stream(tiny_llama):
     module, params = tiny_llama
     engine = _paged_engine(

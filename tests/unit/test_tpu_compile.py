@@ -587,3 +587,45 @@ def test_a_whole_prompts_sparse_attention_compiles_as_two_kernels(chip, seq):
     assert not re.search(rf"f32\[(?:1,)?(?:(?:32|4,8|16),)?{seq},{seq}\]", text)
     # the mask goes from one kernel to the other in tiles, as int8
     assert re.search(rf"s8\[1,{seq // 128},{seq // 512},128,512\]", text)
+
+
+def test_a_block_forward_reads_the_pool_once_for_its_four_queries(chip):
+    """The decode chunk of a module that generates by blocks, at the cell's
+    head widths (32 queries over 4 keys of 128, blocks of 4, 32 slots, pool
+    blocks of 16; two layers of eight experts and a small vocabulary, so
+    that it compiles in seconds): each layer's read of the pool is the
+    kernel ``paged_attention`` with four queries a row, 4 x 32 query rows of
+    one score tile, and the pool goes to it as it lies: no gather of its
+    blocks, no copy, transpose or fusion of it."""
+    from unionml_tpu.models.generate import make_sampler
+    from unionml_tpu.models.sdar_moe import SdarMoe, SdarMoeConfig
+    from unionml_tpu.serving.programs import build_programs
+
+    slots, blocks, block, width, steps = 32, 512, 16, 40, 2
+    module = SdarMoe(SdarMoeConfig(
+        vocab_size=2048, num_hidden_layers=2, num_experts=8, num_experts_per_tok=2, quantized=True,
+        mask_token_id=2047, remasking_strategy="low_confidence_static",
+    ))
+    progs = build_programs(
+        module, slots=slots, rows=width * block, pool_blocks=blocks, block=block, chunk_steps=steps,
+        sample=make_sampler(), eos_id=None, pad_id=0,
+    )
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)
+
+    params = jax.eval_shape(
+        lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    )
+    args = (
+        params, jax.eval_shape(progs.init_state), jax.ShapeDtypeStruct((slots,), jnp.bool_),
+        jax.ShapeDtypeStruct((slots, width), jnp.int32), jax.ShapeDtypeStruct((steps, 2), jnp.uint32),
+    )
+    text = progs.decode_chunk.lower(*on_chip(args)).compile().as_text()
+    assert text.lstrip().startswith("HloModule jit_decode_chunk")
+    calls = re.findall(r"%paged_attention(?:\.\d+)* = (\S+) custom-call\(", text)
+    assert calls and all(c.startswith("bf16[32,128,128]") for c in calls)   # [slots, 4 x 32 query rows, 128]
+    pool = rf"bf16\[{blocks},(?:{block},8|{block * 8}),128\]"     # fused rows: 4 key + 4 value heads
+    assert not re.search(rf"{pool}\S* (?:copy|transpose|gather)\(", text)
+    assert not [line for line in text.splitlines() if " gather(" in line and re.search(pool, line)]
+    assert len(re.findall(r"%paged_attention(?:\.\d+)* = \S+ custom-call\(", text)) == 2

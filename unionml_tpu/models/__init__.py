@@ -37,6 +37,11 @@ from unionml_tpu.models.keye_vl_moe import (
     KeyeVLMoe,
     KeyeVLMoeConfig,
 )
+from unionml_tpu.models.sdar_moe import (
+    SDAR_MOE_QUANT_PATTERNS,
+    SdarMoe,
+    SdarMoeConfig,
+)
 from unionml_tpu.models.encdec import (
     ENCDEC_PARTITION_RULES,
     EncDecConfig,
@@ -113,6 +118,7 @@ __all__ = [
     "OlmoHybrid", "OlmoHybridConfig",
     "GlmMoeLite", "GlmMoeLiteConfig", "GLM_MOE_LITE_QUANT_PATTERNS",
     "KeyeVLMoe", "KeyeVLMoeConfig", "KEYE_VL_MOE_QUANT_PATTERNS",
+    "SdarMoe", "SdarMoeConfig", "SDAR_MOE_QUANT_PATTERNS",
     "EncoderDecoder", "EncDecConfig", "ENCDEC_PARTITION_RULES",
     "init_decoder_cache", "make_seq2seq_generator", "make_seq2seq_predictor", "seq2seq_step",
     "LLAMA_QUANT_PARTITION_RULES", "LLAMA_MOE_PARTITION_RULES",

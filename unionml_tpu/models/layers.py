@@ -55,20 +55,39 @@ class KVRows:
     kv_heads: int
     head_dim: int
     quantized: bool = False
+    # the rows' dtype where the module says (float32 in tests that compare
+    # logits); None: bfloat16, or what ``init`` is handed
+    dtype: Optional[str] = None
+    # one buffer ``[.., 2 * kv_heads, head_dim]``, a position's key heads
+    # and its value heads behind them, in the two buffers' place: with 4 + 4
+    # heads of 128 a position is one whole bfloat16 tile of 8 x 128, where a
+    # ``[.., 4, 128]`` buffer is tiled over four rows and re-laid out round
+    # every scatter (as :class:`IndexedKVRows`' first buffer)
+    fused: bool = False
     owns_rows = True  # a row a token: a paged engine's pool holds them
     kind = "kv"
+
+    def __post_init__(self):
+        if self.fused and self.quantized:
+            raise ValueError("a fused row of keys and values is not built for an int8 cache")
 
     def init(self, batch: int, rows: int, dtype: Dtype = jnp.bfloat16):
         shape = (batch, rows, self.kv_heads, self.head_dim)
         if self.quantized:
             q, s = jnp.zeros(shape, jnp.int8), jnp.ones(shape[:-1], jnp.float32)
             return (q, q, s, s)
+        dtype = dtype if self.dtype is None else jnp.dtype(self.dtype)
+        if self.fused:
+            return (jnp.zeros((batch, rows, 2 * self.kv_heads, self.head_dim), dtype),)
         zeros = jnp.zeros(shape, dtype)
         return (zeros, zeros)
 
+    def _itemsize(self) -> int:
+        return 1 if self.quantized else jnp.dtype(self.dtype or jnp.bfloat16).itemsize
+
     def row_nbytes(self) -> int:
         """Bytes one cached position takes in this layer."""
-        per_head = (self.head_dim + 4) if self.quantized else 2 * self.head_dim
+        per_head = (self.head_dim + 4) if self.quantized else self._itemsize() * self.head_dim
         return 2 * self.kv_heads * per_head
 
     def pool_row_nbytes(self) -> int:
@@ -80,7 +99,7 @@ class KVRows:
     def pool_row(self) -> Tuple[int, int, int]:
         """(heads, width, bytes a value) of a pool buffer's row: what the
         paged kernel's group size follows from."""
-        return self.kv_heads, self.head_dim, 1 if self.quantized else 2
+        return self.kv_heads, self.head_dim, self._itemsize()
 
 
 @dataclass(frozen=True)
@@ -218,6 +237,79 @@ class SlotState:
             int(np.prod(shape)) * jnp.dtype(dt).itemsize
             for shape, dt in zip(self.shapes, self.dtypes)
         )
+
+
+# ---- what a decoder tells a serving engine about how it generates ----
+# A decoder that does not emit one token a forward has a
+# ``generation_scheme()`` method beside ``cache_layout()``; without one the
+# engine decodes a token a step.
+
+REMASKING_STRATEGIES = ("low_confidence_static", "low_confidence_dynamic")
+
+
+@dataclass(frozen=True)
+class BlockDiffusion:
+    """Generation by diffusion over blocks: every position belongs to block
+    ``t // block_length`` (aligned from position 0, prompt included);
+    attention is causal across blocks and bidirectional inside one; a
+    position's logits predict the token *at* that position. An open block's
+    undecided entries hold ``mask_token_id``; one denoising forward over the
+    block decides ``block_length // denoising_steps`` of them (the most
+    confident; under ``low_confidence_dynamic`` every entry whose confidence
+    passes ``threshold`` where at least that many do), and once none is
+    left one commit forward writes the block's rows from its final
+    tokens."""
+
+    block_length: int
+    denoising_steps: int
+    remasking: str = "low_confidence_static"
+    threshold: float = 0.9
+    mask_token_id: int = 0
+
+    def __post_init__(self):
+        bk = self.block_length
+        if bk < 1 or bk & (bk - 1):
+            raise ValueError(f"block_length {bk} must be a power of two")
+        if not 1 <= self.denoising_steps <= bk or bk % self.denoising_steps:
+            raise ValueError(
+                f"denoising_steps {self.denoising_steps} must divide block_length {bk}"
+            )
+        if self.remasking not in REMASKING_STRATEGIES:
+            raise ValueError(
+                f"remasking {self.remasking!r} is not one of {REMASKING_STRATEGIES}"
+            )
+
+    @property
+    def per_forward(self) -> int:
+        """Entries a denoising forward decides at the least."""
+        return self.block_length // self.denoising_steps
+
+    @property
+    def forwards_per_block(self) -> int:
+        """Forwards a whole block takes at the most: its denoising
+        forwards and the commit."""
+        return self.denoising_steps + 1
+
+    def choose(self, confidence, candidates):
+        """bool like ``candidates`` [..., block_length]: the entries this
+        forward decides, from each candidate's ``confidence`` (float32):
+        the ``per_forward`` most confident, ties towards the lower
+        position; under the dynamic rule every candidate over ``threshold``
+        where at least ``per_forward`` are."""
+        n = self.per_forward
+        conf = jnp.where(candidates, confidence.astype(jnp.float32), -1.0)
+        # rank = how many candidates come before this one (more confident,
+        # or as confident at a lower position)
+        ahead = (conf[..., None, :] > conf[..., :, None]) | (
+            (conf[..., None, :] == conf[..., :, None])
+            & (jnp.arange(self.block_length)[None, :] < jnp.arange(self.block_length)[:, None])
+        )
+        rank = jnp.sum(ahead & candidates[..., None, :], axis=-1)
+        chosen = candidates & (rank < n)
+        if self.remasking == "low_confidence_dynamic":
+            over = candidates & (conf > self.threshold)
+            chosen = jnp.where(jnp.sum(over, axis=-1, keepdims=True) >= n, over, chosen)
+        return chosen
 
 
 def merged_dot_general(lhs, rhs, dimension_numbers, precision=None, preferred_element_type=None):

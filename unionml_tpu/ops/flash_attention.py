@@ -39,7 +39,7 @@ NEG_INF = -1e30
 
 
 def _tile_masks(q_start, kv_start, block_q, block_kv, q_len, kv_len, causal,
-                kv_start_valid=None):
+                kv_start_valid=None, causal_block=1):
     """Validity (+ causal) mask for one [BQ, BKV] score tile.
 
     Causal alignment is bottom-right (the KV-cache decode convention,
@@ -49,19 +49,27 @@ def _tile_masks(q_start, kv_start, block_q, block_kv, q_len, kv_len, causal,
 
     ``kv_start_valid``: optional traced scalar — kv positions BELOW it are
     masked out (left-padded prompt slots in generation prefill).
+
+    ``causal_block`` (a power of two): causal across blocks of that many
+    positions and bidirectional inside one: a query sees every kv position
+    up to the end of its own block, ``kv_pos <= (q_pos | (block - 1))``.
     """
     q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 0)
     kv_pos = kv_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 1)
     mask = jnp.logical_and(q_pos < q_len, kv_pos < kv_len)
     if causal:
-        mask = jnp.logical_and(mask, q_pos + (kv_len - q_len) >= kv_pos)
+        q_end = q_pos + (kv_len - q_len)
+        if causal_block > 1:
+            q_end = q_end | (causal_block - 1)
+        mask = jnp.logical_and(mask, q_end >= kv_pos)
     if kv_start_valid is not None:
         mask = jnp.logical_and(mask, kv_pos >= kv_start_valid)
     return mask
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_kv,
-                num_kv_blocks, q_len, kv_len, padded=False, pad_div=1):
+                num_kv_blocks, q_len, kv_len, padded=False, pad_div=1,
+                causal_block=1):
     if padded:
         # the padded path is forward-only (generation prefill): no
         # backward ever reads the lse, so it is neither declared nor
@@ -86,9 +94,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_kv,
     # causal: skip kv blocks entirely in the future of this q block
     # bottom-right causal: query block's last GLOBAL position is
     # q_start + block_q - 1 + (kv_len - q_len)
+    # (under block-causal masking the tile's last query sees to the end of
+    # its block: at most causal_block - 1 positions further)
     run = jnp.logical_or(
         jnp.logical_not(causal),
-        kv_start <= q_start + block_q - 1 + (kv_len - q_len),
+        kv_start <= (
+            q_start + block_q - 1 + (kv_len - q_len) if causal_block == 1
+            else (q_start + block_q - 1 + (kv_len - q_len)) | (causal_block - 1)
+        ),
     )
     # pad lives in SMEM as a whole per-BATCH vector (a (1,1) VMEM block
     # would break Mosaic's (8,128) minimum-tile rule); the grid row is
@@ -111,7 +124,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_kv,
         ) * scale                                  # [BQ, BKV] fp32
 
         mask = _tile_masks(q_start, kv_start, block_q, block_kv, q_len, kv_len,
-                           causal, kv_start_valid=pad)
+                           causal, kv_start_valid=pad, causal_block=causal_block)
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_ref[:]                          # [BQ, 1]
@@ -190,7 +203,7 @@ def _flash_fwd_bhsd(q, k, v, *, causal, scale, block_q, block_kv, interpret):
 
 
 def _flash_fwd_padded(q, k, v, pad_b, *, causal, scale, block_q, block_kv,
-                      interpret):
+                      interpret, causal_block=1):
     """Forward-only padded flash over UNREPEATED GQA heads.
 
     q: [B, S, H, D]; k/v: [B, S, KVH, D] — the kv operands stay at
@@ -230,6 +243,7 @@ def _flash_fwd_padded(q, k, v, pad_b, *, causal, scale, block_q, block_kv,
         kv_len=kv_len,
         padded=True,
         pad_div=h,
+        causal_block=causal_block,
     )
     grid = (b * h, num_q_blocks, num_kv_blocks)
     out = pl.pallas_call(
@@ -494,6 +508,7 @@ def flash_attention(
     block_q: int = 512,
     block_kv: Optional[int] = None,
     kv_valid_start: Optional[jnp.ndarray] = None,
+    causal_block: int = 1,
 ) -> jnp.ndarray:
     """Flash attention over [B,S,H,D] tensors (GQA-aware, differentiable).
 
@@ -513,9 +528,22 @@ def flash_attention(
     in generation prefill). FORWARD-ONLY: this path has no backward
     (generation never differentiates); differentiating it raises.
     Fully-masked query rows (q inside the padding) return zeros.
+
+    ``causal_block`` (a power of two, with ``causal`` and
+    ``kv_valid_start``): the block-causal mask of a model that generates by
+    blocks — causal across blocks of that many positions, bidirectional
+    inside one. 1 is plain causal masking.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if causal_block != 1:
+        if causal_block < 1 or causal_block & (causal_block - 1):
+            raise ValueError(f"causal_block {causal_block} must be a power of two")
+        if not causal or kv_valid_start is None:
+            raise ValueError(
+                "causal_block is a mask of the forward-only path: pass causal=True "
+                "and kv_valid_start (zeros for a prompt that is not left-padded)"
+            )
     if kv_valid_start is None:
         # training/differentiable path: 512x512 is the measured optimum
         # (docstring above)
@@ -532,5 +560,5 @@ def flash_attention(
     return _flash_fwd_padded(
         q, k, v, kv_valid_start,
         causal=causal, scale=scale, block_q=block_q, block_kv=block_kv,
-        interpret=_interpret(),
+        interpret=_interpret(), causal_block=causal_block,
     )

@@ -95,8 +95,19 @@ def _interpret() -> bool:
 
 
 def _check_shapes(q, k, v, block_table, lengths, k_scale, v_scale):
-    if q.ndim != 3:
-        raise ValueError(f"q must be [batch, q_heads, head_dim], got {q.shape}")
+    if v is None:
+        # one pool of fused rows: a position's key heads, its value heads behind
+        if k.ndim != 4 or k.shape[2] % 2 or k_scale is not None or v_scale is not None:
+            raise ValueError(
+                "a fused pool (v=None) is [num_blocks, block_size, 2 * kv_heads, head_dim] "
+                f"without scales, got {k.shape}"
+            )
+        k, v = k[:, :, :k.shape[2] // 2], k[:, :, k.shape[2] // 2:]
+    if q.ndim not in (3, 4):
+        raise ValueError(
+            "q must be [batch, q_heads, head_dim] or [batch, queries, q_heads, "
+            f"head_dim], got {q.shape}"
+        )
     if k.ndim != 4 or v.shape != k.shape:
         raise ValueError(
             "k/v pools must be [num_blocks, block_size, kv_heads, "
@@ -113,9 +124,9 @@ def _check_shapes(q, k, v, block_table, lengths, k_scale, v_scale):
         )
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale come together (int8 pools)")
-    if q.shape[1] % k.shape[2]:
+    if q.shape[-2] % k.shape[2]:
         raise ValueError(
-            f"q heads {q.shape[1]} must be a multiple of kv heads "
+            f"q heads {q.shape[-2]} must be a multiple of kv heads "
             f"{k.shape[2]}"
         )
 
@@ -144,11 +155,16 @@ def paged_attention_reference(
     fp32 ``k_scale``/``v_scale`` [N, block, Hk]); ``block_table``
     [B, W] int32; ``lengths`` [B] int32 (visible rows per batch row —
     a decode step passes ``fill + 1`` so the just-written row sees
-    itself). Returns [B, Hq, D] in ``q.dtype``.
+    itself). Returns [B, Hq, D] in ``q.dtype``. ``q`` [B, Q, Hq, D] is Q
+    queries a row that share the row's length (every one sees all of its
+    visible rows): returns [B, Q, Hq, D]. ``v=None``: ``k`` is one pool of
+    fused rows [N, block, 2 Hk, D], the key heads first.
     """
     from unionml_tpu.ops.attention import _grouped_cache_attention
 
     _check_shapes(q, k, v, block_table, lengths, k_scale, v_scale)
+    if v is None:
+        k, v = k[:, :, :k.shape[2] // 2], k[:, :, k.shape[2] // 2:]
     batch, w = block_table.shape
     block = k.shape[1]
     flat = block_table.reshape(-1)
@@ -166,9 +182,10 @@ def paged_attention_reference(
     visible = kv_pos[None] <= (lengths.astype(jnp.int32) - 1)[:, None, None]
     bias = jnp.where(visible, 0.0, NEG_INF)[:, None]   # [B, 1, 1, W*block]
     out = _grouped_cache_attention(
-        q[:, None], gk, gv, k_scale=gks, v_scale=gvs, bias=bias, scale=scale,
+        q[:, None] if q.ndim == 3 else q, gk, gv,
+        k_scale=gks, v_scale=gvs, bias=bias, scale=scale,
     )
-    return out[:, 0]
+    return out[:, 0] if q.ndim == 3 else out
 
 
 # KV rows (positions) one group gathers and scores. A group costs a fixed
@@ -195,20 +212,24 @@ def _pages_per_step(block, kv_heads, head_dim, itemsize, width):
 
 
 def _paged_kernel(table_ref, len_ref, q_ref, *rest, scale, block, kv_heads,
-                  group, width, pages, quantized):
+                  group, width, pages, quantized, queries=1, fused=False):
     from jax.experimental.pallas import tpu as pltpu
 
     # K and V pools (and, for int8 pools, their scale planes) in HBM,
-    # the output, then one double-buffered gather buffer per pool
-    n = 4 if quantized else 2
+    # the output, then one double-buffered gather buffer per pool. A fused
+    # pool is one: a position's key heads, its value heads behind them.
+    n = 1 if fused else 4 if quantized else 2
     pools, o_ref, bufs = rest[:n], rest[n], rest[n + 1:2 * n + 1]
     sem, state, acc_ref, m_ref, l_ref = rest[2 * n + 1:]
-    k_buf, v_buf, *scale_bufs = bufs
+    k_buf, v_buf, *scale_bufs = bufs * 2 if fused else bufs
     b = pl.program_id(0)
     batch = pl.num_programs(0)
-    q_heads = kv_heads * group
+    # a row's queries lie head-major behind one another: query row
+    # j * Hq + h is head h of query j, and all of them share the length
+    q_heads = kv_heads * group * queries
     rows = pages * block                   # KV positions a group holds
-    cols = rows * kv_heads                 # (position, kv head) columns
+    stored = 2 * kv_heads if fused else kv_heads   # heads a position's row holds
+    cols = rows * stored                   # (position, stored head) columns
 
     def visible(row):
         # a stale length may not reach past the table
@@ -254,14 +275,27 @@ def _paged_kernel(table_ref, len_ref, q_ref, *rest, scale, block, kv_heads,
         v = v_buf[slot].reshape(cols, -1).astype(q.dtype)
         col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
         q_head = jax.lax.broadcasted_iota(jnp.int32, (q_heads, 1), 0)
+        if queries > 1:
+            q_head = q_head % (kv_heads * group)
         # pages past the row's last were not copied (the buffer holds an
         # earlier group's rows there): the length mask covers them
-        seen = g * rows + col // kv_heads < length  # [1, cols]
-        valid = (col % kv_heads == q_head // group) & seen  # [Hq, cols]
+        seen = g * rows + col // stored < length  # [1, cols]
+        # each q head keeps its own kv head's columns: of a fused row the
+        # value head's, where its key head's score is moved below
+        if fused:
+            valid = (col % stored == kv_heads + q_head // group) & seen  # [Hq, cols]
+        else:
+            valid = (col % kv_heads == q_head // group) & seen  # [Hq, cols]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale                                  # [Hq, cols] fp32
+        if fused:
+            # a key head's score to its value head's column (the last
+            # position's value columns wrap to the first's key columns,
+            # which nobody keeps): the weights then stand over the value
+            # rows of the one buffer, zero over its key rows
+            s = pltpu.roll(s, kv_heads, 1)
         if quantized:
             # int8 pool: per-(row, head) dequant scale folds into
             # the scores (k) and softmax weights (v) — the
@@ -287,7 +321,7 @@ def _paged_kernel(table_ref, len_ref, q_ref, *rest, scale, block, kv_heads,
         # The row-oriented mask comes from its own iota — reshaping
         # the [1, cols] one is a lane->sublane cast Mosaic refuses.
         row = jax.lax.broadcasted_iota(jnp.int32, (cols, 1), 0)
-        v = jnp.where(g * rows + row // kv_heads < length, v, 0)
+        v = jnp.where(g * rows + row // stored < length, v, 0)
         acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
             p.astype(q.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -339,10 +373,15 @@ def _paged_pallas(q, k, v, block_table, lengths, *, k_scale, v_scale,
                   scale, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
+    # Q queries a row ride as Q x Hq query rows of one score tile
+    out_shape, queries = q.shape, 1 if q.ndim == 3 else q.shape[1]
+    q = q.reshape(q.shape[0], -1, q.shape[-1])
     batch, q_heads, head_dim = q.shape
-    num_pool_blocks, block, kv_heads, _ = k.shape
+    fused = v is None
+    num_pool_blocks, block, stored, _ = k.shape
+    kv_heads = stored // 2 if fused else stored
     w = block_table.shape[1]
-    page_cols = block * kv_heads
+    page_cols = block * stored
     quantized = k_scale is not None
     pages = _pages_per_step(block, kv_heads, head_dim, k.dtype.itemsize, w)
 
@@ -354,16 +393,11 @@ def _paged_pallas(q, k, v, block_table, lengths, *, k_scale, v_scale,
     # middle dims under an unchanged minor dim: a bitcast of the pool on
     # TPU (checked in the compiled HLO at head_dim 128), never a copy
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [pl.BlockSpec((1, q_heads, head_dim), q_map), hbm, hbm]
-    operands = [
-        q,
-        k.reshape(num_pool_blocks, page_cols, head_dim),
-        v.reshape(num_pool_blocks, page_cols, head_dim),
+    in_specs = [pl.BlockSpec((1, q_heads, head_dim), q_map)] + [hbm] * (1 if fused else 2)
+    operands = [q] + [
+        pool.reshape(num_pool_blocks, page_cols, head_dim) for pool in ((k,) if fused else (k, v))
     ]
-    scratch = [
-        pltpu.VMEM((2, pages, page_cols, head_dim), k.dtype),
-        pltpu.VMEM((2, pages, page_cols, head_dim), v.dtype),
-    ]
+    scratch = [pltpu.VMEM((2, pages, page_cols, head_dim), k.dtype)] * (1 if fused else 2)
     if quantized:
         # scale planes ride as one lane-dense row per block, matching
         # the score columns
@@ -393,10 +427,12 @@ def _paged_pallas(q, k, v, block_table, lengths, *, k_scale, v_scale,
         scale=scale,
         block=block,
         kv_heads=kv_heads,
-        group=q_heads // kv_heads,
+        group=q_heads // queries // kv_heads,
         width=w,
         pages=pages,
         quantized=quantized,
+        queries=queries,
+        fused=fused,
     )
     return pl.pallas_call(
         kernel,
@@ -411,13 +447,13 @@ def _paged_pallas(q, k, v, block_table, lengths, *, k_scale, v_scale,
         name="paged_attention",
     )(
         block_table.astype(jnp.int32), lengths.astype(jnp.int32), *operands
-    )
+    ).reshape(out_shape)
 
 
 def paged_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
-    v: jnp.ndarray,
+    v: Optional[jnp.ndarray],
     block_table: jnp.ndarray,
     lengths: jnp.ndarray,
     *,
@@ -428,7 +464,15 @@ def paged_attention(
 ) -> jnp.ndarray:
     """Single-step decode attention over a block-paged KV pool.
 
-    Shapes: ``q`` [B, Hq, D] (one query per row — the decode step);
+    Shapes: ``q`` [B, Hq, D] (one query per row — the decode step), or
+    [B, Q, Hq, D] for Q queries a row that share its ``lengths`` entry and
+    see every visible row, each other's included (a block of positions
+    that attend one another: the pool's rows are read once for all Q, and
+    the result has ``q``'s shape). ``v=None``: ``k`` is one pool of fused
+    rows ``[num_blocks, block, 2 Hk, D]``, a position's key heads and its
+    value heads behind them (``KVRows(fused=True)``: with 4 + 4 heads of 128
+    one whole tile a position): the kernel copies a block once and reads
+    keys and values from the one buffer;
     ``k``/``v`` [num_blocks, block, Hk, D] pools (bf16, or int8 with
     fp32 ``k_scale``/``v_scale`` [num_blocks, block, Hk]);
     ``block_table`` [B, W] int32 (entries past a row's coverage point

@@ -112,7 +112,7 @@ from unionml_tpu.serving.faults import (
     current_deadline_ms,
 )
 from unionml_tpu.serving.kv_pool import KVBlockPool, PoolExhausted
-from unionml_tpu.serving.programs import build_programs, cache_layout
+from unionml_tpu.serving.programs import build_programs, cache_layout, generation_scheme
 from unionml_tpu.serving.scheduler import (
     DEFAULT_PRIORITY,
     PRIORITIES,
@@ -292,6 +292,11 @@ class _Request:
     _itl_anchor: float = 0.0
     _itl_sum_ms: float = 0.0
     _itl_n: int = 0
+    # generation by blocks: for each served token the forward of its block
+    # (0, 1, ...) that decided it, and the forwards dispatched for this
+    # request so far (what the dispatcher reckons the tokens due from)
+    decided_at: List[int] = field(default_factory=list)
+    _forwards: int = 0
 
     def emit(self, chunk: List[int]) -> None:
         if self.stream is not None and chunk:
@@ -312,7 +317,11 @@ class DecodeEngine:
     transport).
 
     Args:
-        module: a cache-capable decoder (``unionml_tpu.models.Llama``).
+        module: a cache-capable decoder (``unionml_tpu.models.Llama``). What
+            its layers cache it says with ``cache_layout()``, and, where it
+            does not emit one token a forward, how it generates with
+            ``generation_scheme()`` (``models.SdarMoe``: by diffusion over
+            blocks; docs/serving.md "Decoders that generate by blocks").
         slots: resident batch size — the max concurrent decodes.
         max_new_tokens: per-request generation cap (requests may ask for
             fewer via ``generate(..., max_new_tokens=n)``).
@@ -501,6 +510,11 @@ class DecodeEngine:
             default config.
     """
 
+    # the block chunk built WRONGLY on purpose (it keeps a block's last
+    # denoising forward's rows): set on a subclass or patched by the tests
+    # and the benchmark's control, never by a caller
+    _block_stale_commit = False
+
     def __init__(
         self,
         module,
@@ -581,6 +595,21 @@ class DecodeEngine:
             # nor can a rejected proposal be rolled back out of a draft's
             # recurrent state (ROADMAP.md, Queue 2 M)
             self._refuse_recurrent("draft_module=", cache_layout(draft_module))
+        # how the module generates: a token a step, or by blocks (the
+        # module says: models/layers.py BlockDiffusion). By blocks a decode
+        # step is one forward over every slot's open block and yields a
+        # whole block's tokens or none.
+        self.module = module
+        self._blocks = generation_scheme(module)
+        for given, what in (
+            (prefix_cache not in (None, False), "prefix_cache="),
+            (system_prefix is not None, "system_prefix="),
+            (draft_module is not None, "draft_module="),
+            (prefill_chunk is not None, "prefill_chunk="),
+            (scheduler is not None and scheduler.preempt, "SchedulerConfig(preempt=True)"),
+        ):
+            if given:
+                self._refuse_blocks(what)
         # serving phase (docs/serving.md "Disaggregated serving"):
         # which half of a generative request this engine's pool owns.
         # The engine itself serves any request either way — the label
@@ -636,7 +665,15 @@ class DecodeEngine:
         # rows a dispatched chunk can advance a slot: 1 per decode step,
         # or k+1 per speculative round
         self._round_stride = 1 if self.draft is None else self.speculate_k + 1
-        self.module = module
+        # rows a dispatched chunk can move a slot's fill on by, and the rows
+        # past its fill that a step writes besides (the block tables grow
+        # ahead of both): a row a step and none; by blocks a block a commit,
+        # at most every second forward, and the open block's provisional rows
+        self._chunk_advance, self._open_rows = chunk_steps, 0
+        if self._blocks is not None:
+            # a forward runs block_length rows a slot
+            self._round_stride = self._open_rows = self._blocks.block_length
+            self._chunk_advance = self._open_rows * -(-chunk_steps // 2)
         self.cfg = module.config
         self.slots = slots
         self.max_new_tokens = max_new_tokens
@@ -746,6 +783,12 @@ class DecodeEngine:
         self.paged = bool(
             paged or kv_pool_bytes is not None or kv_pool_blocks is not None
         )
+        if self._blocks is not None and not self.paged:
+            raise ValueError(
+                f"{type(module).__name__} generates by blocks "
+                f"(block_length {self._blocks.block_length}), which this engine serves from "
+                "the block pool only: pass paged=True (or kv_pool_bytes= / kv_pool_blocks=)"
+            )
         if self.paged and self.draft is not None:
             raise ValueError(
                 "the speculative engine does not compose with the paged "
@@ -773,6 +816,12 @@ class DecodeEngine:
         # chunks), paged block scatters, and chunked prefill all keep
         # static, evenly-covered shapes
         self._kv_block_size, align = self._block_geometry()
+        if self._blocks is not None and self._kv_block_size % self._blocks.block_length:
+            raise ValueError(
+                f"kv_block_size {self._kv_block_size} must be a multiple of the module's "
+                f"block_length {self._blocks.block_length}: a block of positions is written "
+                "and committed whole"
+            )
         raw = sorted(set(int(b) for b in prompt_buckets))
         if self.prefix_len or self.prefix_cache is not None or self.paged:
             raw = sorted(set(
@@ -1429,6 +1478,21 @@ class DecodeEngine:
                 "(ROADMAP.md, Queue 2): serve this module without it"
             )
 
+    def _refuse_blocks(self, what: str) -> None:
+        """``what`` cuts a sequence at positions of its own choosing, or
+        rebuilds one from rows it did not compute; refuse it for a module
+        that generates by blocks, whose committed rows depend on their
+        whole block."""
+        if self._blocks is not None:
+            raise ValueError(
+                f"{what} is not built for a module that generates by blocks "
+                f"({type(self.module).__name__}: "
+                f"block_length {self._blocks.block_length}): a committed row depends on "
+                "its whole block, so every cut and every reused prefix would have to lie "
+                "on a block boundary, and an open block's decided entries would have to "
+                "travel with it (ROADMAP.md, Queue 2 M7): serve this module without it"
+            )
+
     # ------------------------------------------------------------------ #
     # device programs (serving/programs.py; compiled once per shape)
     # ------------------------------------------------------------------ #
@@ -1443,6 +1507,7 @@ class DecodeEngine:
             pool_blocks=None if self.kv_pool is None else self.kv_pool.num_blocks,
             block=self._kv_block_size, chunk_steps=self.chunk_steps,
             sample=self._sample, eos_id=self.eos_id, pad_id=self.pad_id,
+            stale_commit=self._blocks is not None and self._block_stale_commit,
         )
         self._init_state = progs.init_state
         self._init_fresh = progs.init_fresh
@@ -1571,6 +1636,9 @@ class DecodeEngine:
         chunks yields exactly ``generate(params, [prompt])[0]`` (tested
         in tests/unit/test_engine.py). Raises the engine's error, or
         ``TimeoutError`` when no chunk lands within ``submit_timeout``.
+        For a module that generates by blocks a prefill yields no token:
+        the first chunk is the first block's generated entries, and every
+        chunk carries whole blocks (the last one cut at the asked length).
         """
         self.bind(params)
         tenant = (
@@ -1673,6 +1741,7 @@ class DecodeEngine:
         1-token request's: the prefill window goes to the admitting
         tenant under this engine's ``phase`` label."""
         self._refuse_recurrent("prefill_export")
+        self._refuse_blocks("prefill_export")
         if self.prefix_cache is None:
             raise ValueError(
                 "prefill_export needs a prefix cache — the harvested "
@@ -1740,6 +1809,7 @@ class DecodeEngine:
         when the budget expires is exported (the decode side
         recomputes the rest: degrade, never error)."""
         self._refuse_recurrent("kv_export")
+        self._refuse_blocks("kv_export")
         cache = self.prefix_cache
         if cache is None:
             raise ValueError(
@@ -1760,6 +1830,7 @@ class DecodeEngine:
         rides the normal insert budget/eviction machinery; returns
         blocks newly attached."""
         self._refuse_recurrent("kv_import")
+        self._refuse_blocks("kv_import")
         cache = self.prefix_cache
         if cache is None:
             raise ValueError(
@@ -1870,6 +1941,21 @@ class DecodeEngine:
             "decode_steps": steps,
             "slot_occupancy": round(occupied / max(1, steps * self.slots), 3),
         }
+        out["generation"] = {"scheme": "next_token"}
+        if self._blocks is not None:
+            window = self._perf.report() if self._perf is not None else {}
+            fwd, commits = window.get("block_forwards", 0), window.get("block_commits", 0)
+            out["generation"] = {
+                "scheme": "block_diffusion",
+                "block_length": self._blocks.block_length,
+                "denoising_steps": self._blocks.denoising_steps,
+                "remasking": self._blocks.remasking,
+                "threshold": self._blocks.threshold,
+                # of the perf plane's window: tokens decided a live forward,
+                # and live forwards a block committed
+                "tokens_per_forward": round(window.get("tokens_decided", 0) / fwd, 4) if fwd else None,
+                "forwards_per_block": round(fwd / commits, 4) if commits else None,
+            }
         if self.draft is not None:
             spec_rounds = int(self._m_spec_rounds.value)
             spec_accepted = int(self._m_spec_accepted.value)
@@ -2105,6 +2191,23 @@ class DecodeEngine:
                 return b
         return self.buckets[-1]
 
+    def _asked_arg(self, req: _Request) -> tuple:
+        """What a prefill program takes behind its key: by blocks the
+        tokens the request asks for (the device ends it with the last of
+        them decided), otherwise nothing."""
+        return () if self._blocks is None else (jnp.int32(req.max_new_tokens),)
+
+    def _tokens_due(self, req: _Request) -> int:
+        """Generation by blocks: the tokens that the forwards dispatched
+        for ``req`` are sure to have emitted. A block is emitted by the
+        forward that decides its last entry, at the latest the
+        ``denoising_steps``-th of the ``forwards_per_block`` it takes, and
+        the first block may hold as little as one generated entry."""
+        per = self._blocks.forwards_per_block
+        blocks = (req._forwards + 1) // per
+        held = len(req.prompt) % self._blocks.block_length
+        return max(0, blocks * self._blocks.block_length - held)
+
     def _next_key(self, num: int = 1):
         self._key, *subs = jax.random.split(self._key, num + 1)
         return subs
@@ -2155,6 +2258,7 @@ class DecodeEngine:
             new_state, first = self._prefill(
                 self._params, st, jnp.int32(slot), _place(ids),
                 jnp.asarray(padded), jnp.int32(len(req.prompt)), key,
+                *self._asked_arg(req),
             )
         self._it_enqueue_s += sp.end_s - sp.start_s
         _start_host_copy(first)
@@ -2181,8 +2285,9 @@ class DecodeEngine:
             self._occupant[slot] = req
             self._slot_gen[slot] += 1
             # resumed streams already hold harvested tokens; dispatch
-            # accounting continues from them (fresh admissions: 0 + 1)
-            req._expected = len(req.tokens) + 1
+            # accounting continues from them (fresh admissions: 0 + 1;
+            # by blocks a prefill yields no token)
+            req._expected = len(req.tokens) + (self._blocks is None)
             self._m_slots_busy.set(self._slots_in_use_locked())
         self._admitted_since_chunk[0] += 1
         self._admitted_since_chunk[1] += req._prefilled_tokens
@@ -2373,7 +2478,9 @@ class DecodeEngine:
 
     def _grow_tables_locked(self) -> np.ndarray:
         """Grow every live slot's block table to cover the NEXT decode
-        chunk's worst-case advance (``chunk_steps`` rows), drawing from
+        chunk's worst-case advance (``chunk_steps`` rows; by blocks the
+        commits a chunk can hold, a block each, and the open block's
+        provisional rows behind them), drawing from
         each request's admission-time reservation — which is why growth
         can never fail — and return the table snapshot the chunk
         dispatch uploads. Rows past a request's reserved budget stay on
@@ -2384,7 +2491,8 @@ class DecodeEngine:
             if req is None:
                 continue
             target_rows = min(
-                self._slot_rows[slot] + self.chunk_steps, req._rows_cap
+                self._slot_rows[slot] + self._chunk_advance + self._open_rows,
+                req._rows_cap,
             )
             want = min(
                 self.kv_pool.blocks_for_rows(target_rows),
@@ -2504,6 +2612,12 @@ class DecodeEngine:
                 self._release_blocks_locked(req, slot)
             self._m_slots_busy.set(self._slots_in_use_locked())
             self._tracer.record_span(req.rid, "harvest", self._harvest_t0, now)
+            if self._blocks is not None:
+                # the trajectory: which forward of its block decided each
+                # served token (what a check rebuilds the block's states from)
+                self._tracer.record_event(
+                    req.rid, "decided_at", forwards=list(req.decided_at),
+                )
             self._tracer.finish_request(req.rid)
             if self._usage is not None:
                 if req.abandoned:
@@ -2664,6 +2778,8 @@ class DecodeEngine:
         ):
             if self.draft is not None:
                 self._process_spec_chunk(mask, gens, toks, dispatched)
+            elif self._blocks is not None:
+                self._process_block_chunk(mask, gens, toks, dispatched, seq)
             else:
                 self._process_chunk(mask, gens, toks, dispatched, seq)
 
@@ -2672,24 +2788,32 @@ class DecodeEngine:
         now = time.perf_counter()  # after the readback: prefill_ms
         with self._lock:           # includes its in-flight lag
             req.prefill_ms = (now - req._dispatch_t) * 1e3
-            if req.ttft_ms == 0.0:
-                # a RESUMED stream's first token already happened;
-                # its ttft must stay the first segment's
-                req.ttft_ms = (now - req.submitted) * 1e3
             req._prefill_end = now
-            # ITL anchor: the next decode chunk's harvest spacing
-            # measures from this first token (re-anchored here on
-            # resume too, so the evict→resume gap never counts)
-            req._itl_anchor = now
             self._tracer.record_span(
                 req.rid, "prefill", req._dispatch_t, now,
                 tokens=req._prefilled_tokens,
             )
-            req.tokens.append(tok)
-            req.emit([tok])
-            if self._perf is not None:
-                self._perf.note_tokens(1)
-            self._finish_if_done(slot, tok)
+            if self._blocks is not None:
+                # by blocks a prefill yields no token (what came back is
+                # the number of prompt entries held back for the first
+                # block): TTFT and the ITL anchor are the first block's,
+                # set where it is harvested
+                if req.abandoned:
+                    self._finish_if_done(slot, self.pad_id)
+            else:
+                if req.ttft_ms == 0.0:
+                    # a RESUMED stream's first token already happened;
+                    # its ttft must stay the first segment's
+                    req.ttft_ms = (now - req.submitted) * 1e3
+                # ITL anchor: the next decode chunk's harvest spacing
+                # measures from this first token (re-anchored here on
+                # resume too, so the evict→resume gap never counts)
+                req._itl_anchor = now
+                req.tokens.append(tok)
+                req.emit([tok])
+                if self._perf is not None:
+                    self._perf.note_tokens(1)
+                self._finish_if_done(slot, tok)
         if self._usage is not None:
             # the prefill's exclusive pipeline window (consecutive-
             # harvest spacing) + its dispatched programs' FLOPs,
@@ -2701,7 +2825,7 @@ class DecodeEngine:
             )
             self._last_harvest_end = now
             self._usage.attribute(
-                {req.tenant: 1}, device_s=device_s,
+                {req.tenant: int(self._blocks is None)}, device_s=device_s,
                 flops=req._attr_flops,
             )
             # drained: a resumed stream's next prefill segment
@@ -2758,6 +2882,89 @@ class DecodeEngine:
             # token share; a chunk whose every slot went stale still
             # counts toward the unattributed totals (the identity
             # denominator stays honest under slot churn)
+            device_s = max(
+                0.0, now - max(dispatched, self._last_harvest_end)
+            )
+            self._last_harvest_end = now
+            self._usage.attribute(
+                tenant_tokens, device_s=device_s,
+                flops=self._program_cost("engine.decode"),
+                slot_steps=self.chunk_steps * self.slots,
+            )
+
+    def _process_block_chunk(self, mask, gens, outs, dispatched, seq) -> None:
+        """Account one harvested chunk of a module that generates by
+        blocks: per forward each live slot emitted a whole block's
+        generated entries or nothing (``info``: programs.py
+        ``block_chunk``). A request's tokens of one chunk form one
+        streamed event, its first token is its first block's (TTFT), and
+        the gap between two harvests that brought tokens is spread over
+        the later one's (ITL, observed a burst). Budget and eos cut the
+        emission here as in the plain path's ``_req_done`` walk."""
+        toks, at, info = outs
+        now = time.perf_counter()  # readback complete: the chunk landed
+        self._h_harvest.observe((now - self._harvest_t0) * 1e3)
+        tenant_tokens: dict = {}
+        forwards = commits = decided = emitted = 0
+        with self._lock:
+            for slot in np.flatnonzero(mask):
+                req = self._occupant[slot]
+                if req is None or gens[slot] != self._slot_gen[slot]:
+                    continue  # stale: dispatched for a previous occupant
+                chunk: List[int] = []
+                n_fwd = n_commit = 0
+                finished = False
+                for r in range(info.shape[0]):
+                    n_emit, first, n_dec, kind = (int(x) for x in info[r, slot])
+                    if kind == 0:
+                        continue  # the slot ran nothing: done on the device
+                    n_fwd += 1
+                    n_commit += kind == 2
+                    decided += n_dec
+                    for i in range(first, first + n_emit):
+                        tok = int(toks[r, slot, i])
+                        req.tokens.append(tok)
+                        req.decided_at.append(int(at[r, slot, i]))
+                        chunk.append(tok)
+                        if self._req_done(req, tok):
+                            finished = True
+                            break
+                    if finished:
+                        break
+                forwards += n_fwd
+                commits += n_commit
+                emitted += len(chunk)
+                self._tracer.record_span(
+                    req.rid, f"decode-chunk[{req._chunk_i}]", dispatched, now,
+                    tokens=len(chunk), forwards=n_fwd, commits=n_commit,
+                )
+                self._flight_rec(
+                    "decode", rid=req.rid, tenant=req.tenant, slot=slot,
+                    chunk=req._chunk_i, tokens=len(chunk), forwards=n_fwd,
+                )
+                req._chunk_i += 1
+                req.emit(chunk)
+                if chunk and req.ttft_ms == 0.0:
+                    req.ttft_ms = (now - req.submitted) * 1e3
+                if self._perf is not None and chunk:
+                    self._observe_itl(req, now, len(chunk))
+                if self._usage is not None and chunk:
+                    tenant_tokens[req.tenant] = (
+                        tenant_tokens.get(req.tenant, 0) + len(chunk)
+                    )
+                if chunk:
+                    self._finish_if_done(slot, chunk[-1])
+                elif req.abandoned:
+                    self._finish_if_done(
+                        slot, req.tokens[-1] if req.tokens else self.pad_id
+                    )
+            self._harvest_seq = max(self._harvest_seq, seq)
+            self._sweep_deferred_locked()
+        if self._perf is not None:
+            self._perf.note_blocks(
+                forwards=forwards, commits=commits, decided=decided, emitted=emitted,
+            )
+        if self._usage is not None:
             device_s = max(
                 0.0, now - max(dispatched, self._last_harvest_end)
             )
@@ -2922,12 +3129,19 @@ class DecodeEngine:
                     # caught by test_spec_engine_matches_plain_greedy);
                     # over-dispatch at high acceptance is absorbed by the
                     # done mask + spare rows like any overshoot
-                    self._occupant[slot]._expected += self.chunk_steps
+                    occupant = self._occupant[slot]
+                    if self._blocks is None:
+                        occupant._expected += self.chunk_steps
+                    else:
+                        occupant._forwards += self.chunk_steps
+                        occupant._expected = max(
+                            occupant._expected, self._tokens_due(occupant)
+                        )
                     if self.paged:
                         # host upper bound of the slot's device fill:
                         # next growth pass covers the following chunk
                         self._slot_rows[slot] = min(
-                            self._slot_rows[slot] + self.chunk_steps,
+                            self._slot_rows[slot] + self._chunk_advance,
                             self.cache_len,
                         )
             gens = tuple(self._slot_gen)
@@ -3241,7 +3455,8 @@ class DecodeEngine:
                 # without the subtraction a resume could demand more
                 # than the whole pool and park forever
                 rows_cap = min(
-                    len(req.prompt) + req.max_new_tokens - len(req.tokens),
+                    len(req.prompt) + req.max_new_tokens - len(req.tokens)
+                    + self._open_rows,  # by blocks the last block is written whole
                     self.cache_len,
                 )
                 needed = self.kv_pool.blocks_for_rows(rows_cap)
@@ -3673,6 +3888,9 @@ class DecodeEngine:
         ) as sp:
             step(arg)
             sp.note(cached_tokens=req._saved_tokens)
+            if self._blocks is not None:
+                # the prompt entries that open the first block
+                sp.note(held_back=len(req.prompt) % self._blocks.block_length)
             if self._room.is_parked(req):
                 # pool exhausted: the admission is retried every pass;
                 # only the try that gets through is the request's span

@@ -403,6 +403,20 @@ class ServingPerfPlane:
         with self._lock:
             self._win_parked += 1
 
+    def note_blocks(
+        self, *, forwards: int, commits: int, decided: int, emitted: int,
+    ) -> None:
+        """One harvested chunk of a module that generates by blocks: the
+        forwards its live slots ran (a slot-step of such a chunk is one
+        forward over a block), the commit forwards among them, the entries
+        those forwards decided and the tokens the chunk's requests were
+        handed."""
+        with self._lock:
+            self._win_block_forwards += int(forwards)
+            self._win_block_commits += int(commits)
+            self._win_tokens_decided += int(decided)
+            self._win_tokens_emitted += int(emitted)
+
     def note_tokens(self, n: int) -> None:
         """``n`` tokens harvested (the achieved-throughput numerator)."""
         with self._lock:
@@ -430,6 +444,10 @@ class ServingPerfPlane:
         self._win_prefill_tokens = 0
         self._win_selected = 0
         self._win_visible = 0
+        self._win_block_forwards = 0
+        self._win_block_commits = 0
+        self._win_tokens_decided = 0
+        self._win_tokens_emitted = 0
         self._win_polls = {reason: 0 for reason in POLL_REASONS}
         self._win_dispatcher_s = {phase: 0.0 for phase in DISPATCHER_PHASES}
 
@@ -487,6 +505,10 @@ class ServingPerfPlane:
                 "prefill_tokens": self._win_prefill_tokens,
                 "selected_positions": self._win_selected,
                 "visible_positions": self._win_visible,
+                "block_forwards": self._win_block_forwards,
+                "block_commits": self._win_block_commits,
+                "tokens_decided": self._win_tokens_decided,
+                "tokens_emitted": self._win_tokens_emitted,
                 "polls": dict(self._win_polls),
                 "dispatcher_s": {
                     phase: round(s, 6)

@@ -25,6 +25,13 @@ the prefix cache. There are two:
   the layers with a state of fixed size keep one per slot (``rec``).
   Visibility is ``fill + 1``.
 
+A module that generates by blocks (``generation_scheme()`` returns a
+:class:`~unionml_tpu.models.layers.BlockDiffusion`) is served from the
+block pool by a chunk of its own, :func:`build_programs`'s
+``block_chunk``: a scan step is one forward over ``[slots, Bk]`` rows, the
+state also holds every slot's open block, and a prefill commits whole
+blocks and samples nothing.
+
 A cache with another lifetime (window layers' ring) is a third residency;
 a change to how prompts are prefilled is a change to the one prefill
 family below (``init_fresh``, ``prefill_step``, ``finish_prefill``,
@@ -48,7 +55,7 @@ import jax.numpy as jnp
 
 from unionml_tpu.models.speculative import greedy_acceptance
 
-__all__ = ["BlockPool", "SlotRows", "build_programs", "cache_layout"]
+__all__ = ["BlockPool", "SlotRows", "build_programs", "cache_layout", "generation_scheme"]
 
 
 def cache_layout(module):
@@ -63,6 +70,14 @@ def cache_layout(module):
             "(unionml_tpu.models.layers.KVRows / LatentRows / SlotState)"
         )
     return tuple(layout())
+
+
+def generation_scheme(module):
+    """How ``module`` generates: its :class:`~unionml_tpu.models.layers
+    .BlockDiffusion` declaration, or ``None`` for a decoder that emits one
+    token a forward (it declares nothing)."""
+    scheme = getattr(module, "generation_scheme", None)
+    return None if scheme is None else scheme()
 
 
 def _splice_rows(dst_tree, src_tree, b_start, r_start):
@@ -244,12 +259,16 @@ class BlockPool:
 def build_programs(
     module, *, draft=None, speculate_k: int = 0, slots: int, rows: int,
     pool_blocks=None, block=None, chunk_steps: int, sample, eos_id, pad_id,
+    stale_commit: bool = False,
 ) -> SimpleNamespace:
     """The jitted programs of one engine: ``init_state``, ``init_fresh``,
     ``prefill``, ``prefill_step``, ``prefill_final``, ``decode_chunk``,
     ``splice_block``, ``extract``. ``pool_blocks`` selects the block-pool
     residency; a ``draft`` makes the chunk a scan of speculative rounds
-    and ``params`` the bound ``{"target", "draft"}`` mapping."""
+    and ``params`` the bound ``{"target", "draft"}`` mapping; a module that
+    generates by blocks makes it a scan of forwards over every slot's open
+    block (``stale_commit`` builds that chunk wrongly on purpose: the
+    broken path that the tests and the benchmark's control hold up)."""
     # the served models, and where each finds its parameters in what
     # bind() was given; the first one's logits are the ones sampled
     if draft is None:
@@ -266,13 +285,39 @@ def build_programs(
         i for i, l in enumerate(layouts[0]) if l.owns_rows
     )
 
-    def init_state():
+    scheme = generation_scheme(module)
+    if scheme is not None and (draft is not None or pool_blocks is None):
+        raise ValueError(
+            f"{type(module).__name__} generates by blocks: its chunk runs over a block "
+            "pool and takes no draft"
+        )
+    Bk = 1 if scheme is None else scheme.block_length
+
+    def open_block():
+        """A slot's open block with every entry undecided (``blk_gen``:
+        the entries this sequence generates, the others being prompt)."""
         return {
+            "blk_tok": jnp.full((Bk,), pad_id, jnp.int32),
+            "blk_und": jnp.ones((Bk,), bool),
+            "blk_gen": jnp.ones((Bk,), bool),
+            "blk_at": jnp.zeros((Bk,), jnp.int32),
+            "blk_fwd": jnp.zeros((), jnp.int32),
+        }
+
+    def init_state():
+        state = {
             **residency.init(),
             "fill": jnp.zeros((B,), jnp.int32),
             "last_tok": jnp.zeros((B,), jnp.int32),
             "done": jnp.ones((B,), bool),
         }
+        if scheme is not None:
+            # every slot's open block, and the position its request ends at
+            state.update(jax.tree_util.tree_map(
+                lambda x: jnp.broadcast_to(x, (B,) + x.shape), open_block(),
+            ))
+            state["stop"] = jnp.zeros((B,), jnp.int32)
+        return state
 
     # ---- the prefill family. A prompt is computed against a transient
     # contiguous [1, bucket] fresh cache per model — one admission's
@@ -311,7 +356,7 @@ def build_programs(
         )
 
     def finish_prefill(params, state, fresh, slot, place, toks, start,
-                       true_len, key, *, full=False):
+                       true_len, key, asked=None, *, full=False):
         """The SINGLE home for the prefill tail (monolithic, chunked,
         and prefix-cached admissions of every residency trace it — a
         desynced invariant here would corrupt one path silently): run
@@ -321,7 +366,15 @@ def build_programs(
         ``slot``. ``full``: this call covers the whole visible history,
         so a model whose ``prefill_impl`` is ``"flash"`` may run it
         through the flash kernel (right-padded buckets need no pad mask:
-        causal alone hides the trailing garbage)."""
+        causal alone hides the trailing garbage).
+
+        A module that generates by blocks: the ``true_len // Bk`` whole
+        blocks of the prompt are committed (the rows of a trailing partial
+        block lie past ``fill`` and are overwritten), **no token is
+        sampled**, the ``true_len % Bk`` tokens held back open the slot's
+        first block as decided entries, and the request ends at position
+        ``true_len + asked``; what is returned in the first token's place
+        is the number of entries held back."""
         bucket = fresh[0][first_rows][0].shape[1]
         c = toks.shape[1]
         with jax.named_scope("step_io"):
@@ -342,6 +395,29 @@ def build_programs(
             )
             for (model, pick), cache in zip(models, fresh)
         ]
+        if scheme is not None:
+            with jax.named_scope("commit"):
+                resident = residency.commit(
+                    state, tuple(filled for _, filled in outs), slot, place, true_len
+                )
+                committed = true_len // Bk * Bk
+                held = true_len - committed
+                mine = jnp.arange(Bk) < held     # the prompt's entries of the first block
+                block = {
+                    **open_block(),
+                    # (a prompt that ends on a block's end holds nothing back,
+                    # and what a clamped slice reads is masked)
+                    "blk_tok": jax.lax.dynamic_slice(toks[0], (committed - start,), (Bk,)),
+                    "blk_und": ~mine, "blk_gen": ~mine,
+                }
+                return {
+                    **state,
+                    **resident,
+                    **{k: state[k].at[slot].set(v) for k, v in block.items()},
+                    "fill": state["fill"].at[slot].set(committed),
+                    "done": state["done"].at[slot].set(False),
+                    "stop": state["stop"].at[slot].set(true_len + asked),
+                }, held
         with jax.named_scope("sample"):
             first = sample(outs[0][0][:, 0], key)[0]
         with jax.named_scope("commit"):
@@ -355,12 +431,12 @@ def build_programs(
                 "done": state["done"].at[slot].set(False),
             }, first
 
-    def prefill(params, state, slot, place, tokens, true_len, key):
+    def prefill(params, state, slot, place, tokens, true_len, key, asked=None):
         """Monolithic admission: fresh build + full-bucket finish in
         ONE program (short buckets; one dispatch per admission)."""
         return finish_prefill(
             params, state, fresh_caches(tokens.shape[0]), slot, place,
-            tokens[None], jnp.int32(0), true_len, key, full=True,
+            tokens[None], jnp.int32(0), true_len, key, asked, full=True,
         )
 
     def splice_block(fresh, rows, start):
@@ -406,6 +482,107 @@ def build_programs(
 
         state, toks = jax.lax.scan(step, state, keys)
         return state, toks  # toks: [chunk_steps, slots]
+
+    def block_chunk(params, state, active, place, keys):
+        """``chunk_steps`` forwards over every slot's open block in one
+        scan. A forward runs a block's ``Bk`` entries (the mask token where
+        undecided) at positions ``fill .. fill + Bk - 1``: their keys and
+        values are written to the slot's rows there (provisional: the next
+        forward overwrites them) and attention reads ``fill + Bk`` rows.
+        While an asked entry is undecided the forward is a *denoising*
+        one: the scheme picks the entries it decides from their candidates'
+        confidences, and the forward that decides a block's last asked
+        entry emits the block's generated entries. A block that comes in
+        with none left takes a *commit* forward: what was just written are
+        the rows of its final tokens, ``fill`` moves on by ``Bk`` and the
+        next block opens. Slots denoise and commit side by side: one
+        program, per-slot flags. A request ends with its last asked entry
+        decided (``stop``; an ``eos_id`` among a block's emitted tokens
+        ends it there): its last block takes no commit forward, and the
+        entries past the asked length are never decided.
+
+        Returns per forward ``(tokens [R, B, Bk], decided_at [R, B, Bk],
+        info [R, B, 4])``: the block after this forward's decisions, the
+        forward of the block (0, 1, ...) that decided each entry, and
+        ``(n_emit, first, n_decided, kind)``: the entries ``first ..
+        first + n_emit - 1`` of ``tokens`` are emitted (0 unless this
+        forward completed the block), ``n_decided`` entries were decided,
+        and ``kind`` is 0 for a slot that ran nothing, 1 for a denoising
+        and 2 for a commit forward."""
+        ((model, pick),) = models
+
+        def step(state, key):
+            with jax.named_scope("step_io"):
+                offs = jnp.arange(Bk)[None, :]
+                live = active & ~state["done"]
+                fill, und = state["fill"], state["blk_und"]
+                asked = fill[:, None] + offs < state["stop"][:, None]
+                cand = und & asked
+                denoise = live & cand.any(-1)
+                commit = live & ~denoise       # it came in with nothing left to decide
+                ids = jnp.where(und, scheme.mask_token_id, state["blk_tok"])
+                args = residency.step_args(state, live, place)
+            logits, cache = model.apply(
+                {"params": pick(params)}, ids, cache_index=fill, live=live, **args,
+            )
+            with jax.named_scope("sample"):
+                flat = logits.reshape(B * Bk, -1)
+                choice = sample(flat, key)
+                with jax.named_scope("unmask"):
+                    # a candidate's confidence is its softmax probability
+                    picked = jnp.take_along_axis(flat, choice[:, None], axis=-1)[:, 0]
+                    conf = jnp.exp(picked - jax.nn.logsumexp(flat, axis=-1)).reshape(B, Bk)
+                    now = scheme.choose(conf, cand) & denoise[:, None]
+                choice = choice.reshape(B, Bk).astype(jnp.int32)
+            with jax.named_scope("step_io"):
+                resident = residency.step_result(args, cache)
+                tok = jnp.where(now, choice, state["blk_tok"])
+                und = und & ~now
+                at = jnp.where(now, state["blk_fwd"][:, None], state["blk_at"])
+                # the forward that decides a block's last asked entry emits it
+                complete = denoise & ~(und & asked).any(-1)
+                made = state["blk_gen"] & asked
+                done = state["done"]
+                if eos_id is not None:
+                    hit = made & (tok == eos_id)
+                    ends = complete & hit.any(-1)
+                    made = made & jnp.where(
+                        ends[:, None], offs <= jnp.argmax(hit, axis=-1)[:, None], True,
+                    )
+                    done = done | ends
+                n_emit = jnp.where(complete, made.sum(-1), 0)
+                # a request ends with its last block, and a slot at the cache's
+                # end (belt: the host's budget stops it first) with this one
+                done = done | (complete & (
+                    (fill + Bk >= state["stop"]) | (fill + 2 * Bk > L)
+                ))
+                info = jnp.stack([
+                    n_emit, jnp.argmax(made, axis=-1), now.sum(-1),
+                    denoise.astype(jnp.int32) + 2 * commit.astype(jnp.int32),
+                ], axis=-1).astype(jnp.int32)
+                if stale_commit:
+                    # WRONG on purpose: the rows this forward wrote, with the
+                    # mask token where it decided, are kept as the block's
+                    commit = complete
+                out = (jnp.where(live[:, None], tok, pad_id), at, info)
+                fresh = open_block()
+                opened = {
+                    "blk_tok": tok, "blk_und": und, "blk_gen": state["blk_gen"], "blk_at": at,
+                    "blk_fwd": state["blk_fwd"] + denoise.astype(jnp.int32),
+                }
+                return {
+                    **state,
+                    **resident,
+                    **{
+                        k: jnp.where(commit.reshape((B,) + (1,) * fresh[k].ndim), fresh[k], v)
+                        for k, v in opened.items()
+                    },
+                    "fill": fill + Bk * commit.astype(jnp.int32),
+                    "done": done,
+                }, out
+
+        state, outs = jax.lax.scan(step, state, keys)
+        return state, outs
 
     def spec_chunk(params, state, active, place, keys):
         """``chunk_steps`` speculative rounds in one scan over the two
@@ -509,6 +686,12 @@ def build_programs(
         )
         return state, outs
 
+    chunk = decode_chunk if draft is None else spec_chunk
+    if scheme is not None:
+        # the trace readers find a served cell's chunk by the name
+        # jit_decode_chunk, whatever a step of it is
+        chunk = block_chunk
+        chunk.__name__ = chunk.__qualname__ = "decode_chunk"
     # the resident state is donated through every program that returns
     # it, so the multi-GB cache never copies. prefill_final donates the
     # state only: no output matches the fresh cache's [1, bucket] shape,
@@ -519,9 +702,7 @@ def build_programs(
         prefill=jax.jit(prefill, donate_argnums=(1,)),
         prefill_step=jax.jit(prefill_step, donate_argnums=(1,)),
         prefill_final=jax.jit(finish_prefill, donate_argnums=(1,)),
-        decode_chunk=jax.jit(
-            decode_chunk if draft is None else spec_chunk, donate_argnums=(1,)
-        ),
+        decode_chunk=jax.jit(chunk, donate_argnums=(1,)),
         splice_block=jax.jit(splice_block, donate_argnums=(0,)),
         extract=jax.jit(residency.extract, static_argnames=("n",)),
     )
