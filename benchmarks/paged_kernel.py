@@ -22,7 +22,12 @@ one another) at ``sdar_chat_fixed_length_decode``'s shape: 32 slots, GQA 32/4
 over a fused pool (a position's 4 key and 4 value heads in one row), a table
 163 blocks wide, 20 live rows of 300-2,300 positions; beside it the same
 rows with one query, so that what N queries cost over one is read off two
-lines. Table and lengths are arguments of every timed program.
+lines. Table and lengths are arguments of every timed program. A line also
+says the ``[query rows, columns]`` of the score tile one group of the kernel
+works on (``score_tile``), what the live rows' keys and values take at the
+HBM's rate (``bytes_us``) and the call's time over that (``over_bytes``), and
+how far the kernel's output lies from the plain gather's on the live rows
+(``gap``).
 
 ``--rows N`` overrides the KV rows a kernel step takes (the module's
 ``_ROWS_PER_STEP`` and a VMEM budget to match), to re-derive them. Fails
@@ -41,6 +46,7 @@ import numpy as np
 from unionml_tpu.ops import paged_attention as pa
 
 BLOCK, HEAD_DIM, SLOTS = 16, 128, 32
+HBM_BYTES_PER_S = 819e9  # a v5e's (chipbench/peaks.json)
 # (name, q heads, kv heads, table width, pool blocks, live rows, mean live length)
 SHAPES = [
     ("mixtral_chat_decode", 32, 8, 101, 2861, 13, 260),
@@ -67,6 +73,17 @@ def _case(rng, width, n_blocks, live, mean_len, stale, spread=None):
         need = -(-int(lengths[b]) // BLOCK)
         table[b, :need] = [next(blocks) for _ in range(need)]
     return jnp.asarray(table), jnp.asarray(lengths), int(lengths[rows].sum())
+
+
+def score_tile(block, q_heads, kv_heads, head_dim, itemsize, width, *, queries, fused):
+    """The tree's own count; an older tree's kernel (``PYTHONPATH``) scored
+    every query row against every stored row of the group."""
+    if hasattr(pa, "score_tile"):
+        return pa.score_tile(
+            block, q_heads, kv_heads, head_dim, itemsize, width, queries=queries, fused=fused,
+        )
+    positions = pa._pages_per_step(block, kv_heads, head_dim, itemsize, width) * block
+    return [queries * q_heads, positions * kv_heads * (2 if fused else 1)]
 
 
 def main():
@@ -117,6 +134,14 @@ def main():
                 np.random.default_rng(args.seed + 1), width, n_blocks, n_live, mean_len, stale, spread,
             )
             loop(q, k, v, table, lengths).block_until_ready()
+            gap = None
+            if n_live and not stale:  # a stale row's output is garbage by contract
+                got, want = (
+                    pa.paged_attention(q, k, v, table, lengths, impl=impl).astype(jnp.float32)
+                    for impl in ("pallas", "reference")
+                )
+                gap = float(jnp.max(jnp.abs(got - want)[np.asarray(lengths) > 0]))
+            kv_bytes = positions * 2 * kv_heads * HEAD_DIM * 2
             times = []
             for _ in range(5):
                 t0 = time.perf_counter()
@@ -126,7 +151,13 @@ def main():
                 "shape": name, "rows": what, "queries": queries or 1,
                 "us_per_call": round(1e6 * float(np.median(times)), 2),
                 "us_min": round(1e6 * min(times), 2), "live_rows": n_live, "live_positions": positions,
-                "kv_mb": round(positions * 2 * kv_heads * HEAD_DIM * 2 / 1e6, 2),
+                "kv_mb": round(kv_bytes / 1e6, 2), "bytes_us": round(1e6 * kv_bytes / HBM_BYTES_PER_S, 2),
+                "over_bytes": round(float(np.median(times)) * HBM_BYTES_PER_S / kv_bytes, 2)
+                if positions else None,
+                "score_tile": score_tile(
+                    BLOCK, q_heads, kv_heads, HEAD_DIM, 2, width, queries=queries or 1, fused=v is None,
+                ),
+                "gap": gap,
                 "pages_per_step": pa._pages_per_step(BLOCK, kv_heads, HEAD_DIM, 2, width),
                 "device": device.device_kind, "platform": device.platform,
             }), flush=True)

@@ -13,11 +13,14 @@ import pytest
 import jax.numpy as jnp
 
 from unionml_tpu.ops.attention import cached_attention, quantized_cache_attention
+import jax
+
 from unionml_tpu.ops.paged_attention import (
     _ROWS_PER_STEP,
     _pages_per_step,
     paged_attention,
     paged_attention_reference,
+    score_tile,
 )
 
 B, H, KVH, D, BS, W, N = 3, 4, 2, 16, 8, 4, 12
@@ -222,6 +225,104 @@ def test_a_fused_pool_is_read_as_its_two_halves(impl, queries, layout):
     assert float(jnp.max(jnp.abs(got - want)[rows])) < 1e-6
     with pytest.raises(ValueError, match="fused pool"):
         paged_attention(q, k[:, :, :1], None, table, lengths)
+
+
+# ---- fused rows: the kernel scores a key head's rows against that head's
+# own query rows (group 8 as the served cell's 32 / 4, group 4, one kv head
+# a q head, and an odd number of kv heads: a 16-bit pool's 32-bit words
+# then hold the last key head beside the first value head)
+FUSED_HEADS = pytest.mark.parametrize(
+    "heads", [(16, 2), (8, 2), (4, 4), (3, 3)], ids=["group-8", "group-4", "group-1", "odd-kv-heads"]
+)
+
+
+def _fused(args):
+    q, k, v, table, lengths = args
+    return q, jnp.concatenate([k, v], axis=2), None, table, lengths
+
+
+@LAYOUTS
+@FUSED_HEADS
+@WIDTHS
+@pytest.mark.parametrize("queries", [0, 4], ids=["one-query", "four-queries"])
+def test_fused_rows_match_reference(queries, width, heads, layout):
+    """Rows of unequal length (a partial last group, a length inside a
+    block), rows of length 0 between live rows and a dead row, over a table
+    narrower and wider than one group: each key head's own score tile
+    gives what the gather of the two halves gives."""
+    args, kw, rows = _ragged_setup(width, heads, jnp.float32, False, seed=12, layout=layout)
+    if queries:
+        args = _with_queries(args, queries)
+    want = paged_attention(*args, impl="reference")
+    got = paged_attention(*_fused(args), impl="pallas")
+    assert got.shape == want.shape == args[0].shape
+    assert bool(jnp.all(jnp.isfinite(got))) and not bool(jnp.any(got[np.asarray(args[4]) == 0]))
+    # float summation order: a row of the wide table sums 560 positions in two groups
+    assert float(jnp.max(jnp.abs(got - want)[rows])) < (1e-6 if width == W else 2e-6)
+
+
+@FUSED_HEADS
+@pytest.mark.parametrize("queries", [0, 4], ids=["one-query", "four-queries"])
+def test_fused_rows_of_16_bits_are_read_two_heads_a_word(queries, heads):
+    """A bfloat16 pool: two stored heads of a position share a 32-bit word
+    of the gather buffer, and a head's rows are put together from halves of
+    the even and the odd positions' words."""
+    args, kw, rows = _ragged_setup(11, heads, jnp.bfloat16, False, seed=13, layout=_holes)
+    if queries:
+        args = _with_queries(args, queries)
+    want = paged_attention(*args, impl="reference").astype(jnp.float32)
+    got = paged_attention(*_fused(args), impl="pallas").astype(jnp.float32)
+    assert bool(jnp.all(jnp.isfinite(got))) and not bool(jnp.any(got[np.asarray(args[4]) == 0]))
+    assert float(jnp.max(jnp.abs(got - want)[rows])) < 2e-2
+
+
+def _kernel_dots(fn, *args):
+    """Result shapes of the matmuls in the kernels ``fn`` traces."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                yield eqn.outvars[0].aval.shape
+            for param in eqn.params.values():
+                for sub in param if isinstance(param, (list, tuple)) else [param]:
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from walk(sub)
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+@pytest.mark.parametrize("queries", [0, 4], ids=["one-query", "four-queries"])
+def test_the_score_tile_follows_the_pool(queries):
+    """Two pools trace the one-matmul scheme they traced (every query row
+    against every (position, kv head) row of a group); a fused pool a
+    matmul a key head, its own query rows against the group's positions."""
+    q_heads, kv_heads, width = 8, 2, 11
+    args, kw, _ = _ragged_setup(width, (q_heads, kv_heads), jnp.float32, False, seed=14)
+    if queries:
+        args = _with_queries(args, queries)
+    rows, positions = (queries or 1) * q_heads, width * BS
+
+    def tile(fused):
+        return score_tile(BS, q_heads, kv_heads, D, 4, width, queries=queries or 1, fused=fused)
+
+    two = _kernel_dots(lambda *a: paged_attention(*a, impl="pallas"), *args)
+    assert two == [(rows, positions * kv_heads), (rows, D)]
+    assert tile(False) == [rows, positions * kv_heads]
+    q, pool, _, table, lengths = _fused(args)
+    fused = _kernel_dots(
+        lambda *a: paged_attention(a[0], a[1], None, *a[2:], impl="pallas"), q, pool, table, lengths,
+    )
+    assert fused == [(rows // kv_heads, positions), (rows // kv_heads, D)] * kv_heads
+    assert tile(True) == [rows, positions]
+    # the served shapes: 4 queries x 32 heads over 4 + 4 stored heads; 32 heads over two pools of 8
+    assert score_tile(16, 32, 4, 128, 2, 163, queries=4, fused=True) == [128, 512]
+    assert score_tile(16, 32, 8, 128, 2, 101) == [32, 4096]
+
+
+def test_a_fused_pool_of_8_bit_values_is_refused():
+    q, k, v, table, lengths = _setup()
+    pool = jnp.concatenate([k, v], axis=2).astype(jnp.int8)
+    with pytest.raises(ValueError, match="16 or 32 bits"):
+        paged_attention(q, pool, None, table, lengths, impl="pallas")
 
 
 def test_every_query_of_a_row_sees_all_of_its_rows():

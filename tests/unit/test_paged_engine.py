@@ -610,5 +610,8 @@ def test_paged_stats_say_what_the_kernel_walks(tiny_llama):
         assert st["table_width"] == engine.cache_len // 8
         # 512 KV rows a group, never more than the table is wide
         assert st["kernel_blocks_per_group"] == min(64, st["table_width"])
+        # two pools: one score tile of the 4 query heads against every
+        # (position, kv head) row of a group
+        assert st["score_tile"] == [4, st["kernel_blocks_per_group"] * 8 * 2]
     finally:
         engine.close()

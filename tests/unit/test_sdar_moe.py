@@ -341,6 +341,8 @@ def test_engine_serves_the_references_forwards(monkeypatch, served, paged_impl, 
     pool = stats["kv_pool"]
     assert pool["row_layout"] == "kv" and pool["blocks_in_use"] == 0
     assert pool["freed_blocks"] == pool["allocated_blocks"] > 0
+    # fused rows: a block's 4 queries x 4 heads against a group's positions, a key head at a time
+    assert pool["score_tile"] == [4 * 4, pool["kernel_blocks_per_group"] * 8]
     assert stats["moe"]["decode_chunk"]["expert_rows_routed"] == 2 * 4 * 2     # slots x Bk x top-2
     admits = [s for s in spans if s["name"] == "admit"]
     assert admits and admits[-1]["args"]["held_back"] == 39 % 4

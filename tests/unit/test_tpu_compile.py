@@ -120,6 +120,32 @@ def test_paged_attention_compiles(
     assert not re.search(rf"\[{n_blocks},{block * kv_heads},{hd}\]\S* copy\(", text)
 
 
+# the sdar_chat_fixed_length_decode cell's kernel: 32 rows x 4 queries x 32
+# heads over one pool of fused rows (4 key + 4 value heads of 128 a
+# position), blocks of 16, a table 163 wide over 3,814 pool blocks; and the
+# same rows with one query
+@pytest.mark.parametrize("queries", [4, 1], ids=["cell-4-queries", "one-query"])
+def test_paged_attention_over_fused_rows_compiles(chip, queries):
+    """A key head's rows are read out of the gathered buffer by strided
+    32-bit loads (two bfloat16 heads a word) and scored against that head's
+    own query rows: Mosaic takes it, the kernel keeps the name the
+    benchmark's readers find it by, and the pool reaches it as it lies."""
+    batch, q_heads, kv_heads, hd, block, width, n_blocks = 32, 32, 4, 128, 16, 163, 3814
+    q = (batch, queries, q_heads, hd) if queries > 1 else (batch, q_heads, hd)
+
+    def fn(q, pool, table, lengths):
+        return paged_attention.paged_attention(q, pool, None, table, lengths, impl="pallas")
+
+    text = _assert_mosaic(
+        chip, fn, (q, jnp.bfloat16), ((n_blocks, block, 2 * kv_heads, hd), jnp.bfloat16),
+        ((batch, width), jnp.int32), ((batch,), jnp.int32),
+    )
+    calls = re.findall(r"%paged_attention(?:\.\d+)* = (\S+) custom-call\(", text)
+    assert len(calls) == 1 and calls[0].startswith(f"bf16[{batch},{queries * q_heads},{hd}]")
+    pool = rf"bf16\[{n_blocks},(?:{block},{2 * kv_heads}|{block * 2 * kv_heads}),{hd}\]"
+    assert not re.search(rf"{pool}\S* (?:copy|transpose|gather|fusion)\(", text)
+
+
 REPO = Path(__file__).resolve().parents[2]
 
 

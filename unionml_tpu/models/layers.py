@@ -23,7 +23,7 @@ Design notes (TPU):
 from __future__ import annotations
 
 import math
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Any, Optional, Tuple
 
 import jax
@@ -64,6 +64,10 @@ class KVRows:
     # ``[.., 4, 128]`` buffer is tiled over four rows and re-laid out round
     # every scatter (as :class:`IndexedKVRows`' first buffer)
     fused: bool = False
+    # the query heads that read the rows, as the module hands them to the
+    # paged kernel (0: not said); not part of what a row is: what
+    # ``engine.stats()["kv_pool"]["score_tile"]`` follows from
+    q_heads: int = field(default=0, compare=False)
     owns_rows = True  # a row a token: a paged engine's pool holds them
     kind = "kv"
 

@@ -206,7 +206,7 @@ class Llama(nn.Module):
     def cache_layout(self) -> Tuple[KVRows, ...]:
         """Every layer caches keys and values (see ``layers.KVRows``)."""
         cfg = self.config
-        return (KVRows(cfg.num_kv_heads, cfg.head_dim, cfg.kv_quant),) * cfg.num_layers
+        return (KVRows(cfg.num_kv_heads, cfg.head_dim, cfg.kv_quant, q_heads=cfg.num_heads),) * cfg.num_layers
 
     def moe_dispatch(self, tokens: int) -> Optional[dict]:
         """What a mixture layer does with a program of ``tokens`` rows

@@ -298,7 +298,7 @@ class OlmoHybrid(nn.Module):
             ),
             dtypes=(cfg.state_dtype, cfg.dtype),
         )
-        rows = KVRows(cfg.kv_cache_heads, cfg.head_dim)
+        rows = KVRows(cfg.kv_cache_heads, cfg.head_dim, q_heads=cfg.kv_cache_heads)  # q padded with the cache
         return tuple(state if kind == LINEAR else rows for kind in cfg.layer_types)
 
     def step_operand_bytes(self, batch: int) -> int:

@@ -280,7 +280,9 @@ class SdarMoe(nn.Module):
         """Every layer caches keys and values a token."""
         cfg = self.config
         dtype = None if cfg.cache_dtype == "bfloat16" else cfg.cache_dtype
-        row = KVRows(cfg.num_key_value_heads, cfg.head_dim, dtype=dtype, fused=True)
+        row = KVRows(
+            cfg.num_key_value_heads, cfg.head_dim, dtype=dtype, fused=True, q_heads=cfg.num_attention_heads,
+        )
         return (row,) * cfg.num_hidden_layers
 
     def generation_scheme(self) -> BlockDiffusion:

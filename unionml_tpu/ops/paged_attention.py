@@ -45,11 +45,25 @@ Two implementations behind one dispatcher:
   normalizer / weighted sum) live in VMEM scratch and carry across a
   row's groups, the same scheme as
   :mod:`~unionml_tpu.ops.flash_attention`. GQA reads the pool at
-  kv-head width (no head repeat): the gathered group is viewed as
-  ``[P * block * Hk, D]``, ONE matmul scores every q head against every
-  (position, kv head) row, and a mask keeps each q head's own kv head —
-  Mosaic cannot lay out per-head sublane slices of a ``[block, Hk, D]``
-  tile, and the MXU's time is the loading of the K tiles either way.
+  kv-head width (no head repeat), in one of two schemes, by what the call
+  hands over. **Two pools** (``k`` and ``v``): the gathered group is viewed
+  as ``[P * block * Hk, D]``, ONE matmul scores every q head against every
+  (position, kv head) row, and a mask keeps each q head's own kv head: a
+  ``[Hq, P * block * Hk]`` score tile (:func:`score_tile`). With one query
+  a row the tile is short and the copies bind, not the masked columns.
+  **One pool of fused rows** (``v=None``: a position's key heads and its
+  value heads behind them, :func:`_fused_kernel`): each key head's rows are
+  read out of the buffer and scored against that head's own ``queries x
+  group`` query rows, ``[queries * group, P * block]`` a key head, and its
+  value rows weighed by those scores. A head's rows lie ``2 Hk`` buffer
+  rows apart; Mosaic lays out no such slice of a *value*, but it does a
+  strided load of a *ref*'s 32-bit sublanes: a bfloat16 buffer is read as
+  words that hold two stored heads of one position, the group's first half
+  of positions and its second apart, and a shift, a mask and an or put a
+  head's rows of both halves together
+  (PERF.md, section 6, PR 45: with 4 queries a row over 4 + 4 stored heads
+  the one-matmul tile was ``[128, 4096]`` a group, seven columns of eight
+  masked away, and the vector unit bound it at four times its bytes' time).
   int8 KV pools fold their per-(row, head) dequant scales into the
   score/weight math in-kernel (never a dequantized pool copy; the fp32
   scale planes ride as one lane-dense ``[1, block * Hk]`` row per block,
@@ -86,7 +100,7 @@ __all__ = [
     "latent_attention", "paged_attention", "paged_attention_reference",
     "paged_index_scores", "paged_index_scores_reference",
     "paged_latent_attention", "paged_latent_attention_reference",
-    "paged_sparse_attention", "paged_sparse_attention_reference",
+    "paged_sparse_attention", "paged_sparse_attention_reference", "score_tile",
 ]
 
 
@@ -211,25 +225,34 @@ def _pages_per_step(block, kv_heads, head_dim, itemsize, width):
     return max(1, min(rows // block, width))
 
 
+def score_tile(block, q_heads, kv_heads, head_dim, itemsize, width, *, queries=1, fused=False):
+    """``[query rows, columns]`` of the float32 score tile one group of the
+    kernel works on, from the shapes of the call: two pools are scored by
+    one matmul of every query row with every (position, kv head) row of the
+    group; a fused pool a key head at a time, each against its own
+    ``queries x group`` query rows and the group's positions (the tile is
+    the key heads' together)."""
+    positions = _pages_per_step(block, kv_heads, head_dim, itemsize, width) * block
+    return [queries * q_heads, positions if fused else positions * kv_heads]
+
+
 def _paged_kernel(table_ref, len_ref, q_ref, *rest, scale, block, kv_heads,
-                  group, width, pages, quantized, queries=1, fused=False):
+                  group, width, pages, quantized, queries=1):
     from jax.experimental.pallas import tpu as pltpu
 
     # K and V pools (and, for int8 pools, their scale planes) in HBM,
-    # the output, then one double-buffered gather buffer per pool. A fused
-    # pool is one: a position's key heads, its value heads behind them.
-    n = 1 if fused else 4 if quantized else 2
+    # the output, then one double-buffered gather buffer per pool
+    n = 4 if quantized else 2
     pools, o_ref, bufs = rest[:n], rest[n], rest[n + 1:2 * n + 1]
     sem, state, acc_ref, m_ref, l_ref = rest[2 * n + 1:]
-    k_buf, v_buf, *scale_bufs = bufs * 2 if fused else bufs
+    k_buf, v_buf, *scale_bufs = bufs
     b = pl.program_id(0)
     batch = pl.num_programs(0)
     # a row's queries lie head-major behind one another: query row
     # j * Hq + h is head h of query j, and all of them share the length
     q_heads = kv_heads * group * queries
     rows = pages * block                   # KV positions a group holds
-    stored = 2 * kv_heads if fused else kv_heads   # heads a position's row holds
-    cols = rows * stored                   # (position, stored head) columns
+    cols = rows * kv_heads                 # (position, kv head) columns
 
     def visible(row):
         # a stale length may not reach past the table
@@ -279,23 +302,12 @@ def _paged_kernel(table_ref, len_ref, q_ref, *rest, scale, block, kv_heads,
             q_head = q_head % (kv_heads * group)
         # pages past the row's last were not copied (the buffer holds an
         # earlier group's rows there): the length mask covers them
-        seen = g * rows + col // stored < length  # [1, cols]
-        # each q head keeps its own kv head's columns: of a fused row the
-        # value head's, where its key head's score is moved below
-        if fused:
-            valid = (col % stored == kv_heads + q_head // group) & seen  # [Hq, cols]
-        else:
-            valid = (col % kv_heads == q_head // group) & seen  # [Hq, cols]
+        seen = g * rows + col // kv_heads < length  # [1, cols]
+        valid = (col % kv_heads == q_head // group) & seen  # [Hq, cols]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale                                  # [Hq, cols] fp32
-        if fused:
-            # a key head's score to its value head's column (the last
-            # position's value columns wrap to the first's key columns,
-            # which nobody keeps): the weights then stand over the value
-            # rows of the one buffer, zero over its key rows
-            s = pltpu.roll(s, kv_heads, 1)
         if quantized:
             # int8 pool: per-(row, head) dequant scale folds into
             # the scores (k) and softmax weights (v) — the
@@ -321,7 +333,7 @@ def _paged_kernel(table_ref, len_ref, q_ref, *rest, scale, block, kv_heads,
         # The row-oriented mask comes from its own iota — reshaping
         # the [1, cols] one is a lane->sublane cast Mosaic refuses.
         row = jax.lax.broadcasted_iota(jnp.int32, (cols, 1), 0)
-        v = jnp.where(g * rows + row // stored < length, v, 0)
+        v = jnp.where(g * rows + row // kv_heads < length, v, 0)
         acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
             p.astype(q.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -377,11 +389,9 @@ def _paged_pallas(q, k, v, block_table, lengths, *, k_scale, v_scale,
     out_shape, queries = q.shape, 1 if q.ndim == 3 else q.shape[1]
     q = q.reshape(q.shape[0], -1, q.shape[-1])
     batch, q_heads, head_dim = q.shape
-    fused = v is None
-    num_pool_blocks, block, stored, _ = k.shape
-    kv_heads = stored // 2 if fused else stored
+    num_pool_blocks, block, kv_heads, _ = k.shape
     w = block_table.shape[1]
-    page_cols = block * stored
+    page_cols = block * kv_heads
     quantized = k_scale is not None
     pages = _pages_per_step(block, kv_heads, head_dim, k.dtype.itemsize, w)
 
@@ -393,11 +403,16 @@ def _paged_pallas(q, k, v, block_table, lengths, *, k_scale, v_scale,
     # middle dims under an unchanged minor dim: a bitcast of the pool on
     # TPU (checked in the compiled HLO at head_dim 128), never a copy
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [pl.BlockSpec((1, q_heads, head_dim), q_map)] + [hbm] * (1 if fused else 2)
-    operands = [q] + [
-        pool.reshape(num_pool_blocks, page_cols, head_dim) for pool in ((k,) if fused else (k, v))
+    in_specs = [pl.BlockSpec((1, q_heads, head_dim), q_map), hbm, hbm]
+    operands = [
+        q,
+        k.reshape(num_pool_blocks, page_cols, head_dim),
+        v.reshape(num_pool_blocks, page_cols, head_dim),
     ]
-    scratch = [pltpu.VMEM((2, pages, page_cols, head_dim), k.dtype)] * (1 if fused else 2)
+    scratch = [
+        pltpu.VMEM((2, pages, page_cols, head_dim), k.dtype),
+        pltpu.VMEM((2, pages, page_cols, head_dim), v.dtype),
+    ]
     if quantized:
         # scale planes ride as one lane-dense row per block, matching
         # the score columns
@@ -432,7 +447,6 @@ def _paged_pallas(q, k, v, block_table, lengths, *, k_scale, v_scale,
         pages=pages,
         quantized=quantized,
         queries=queries,
-        fused=fused,
     )
     return pl.pallas_call(
         kernel,
@@ -450,6 +464,239 @@ def _paged_pallas(q, k, v, block_table, lengths, *, k_scale, v_scale,
     ).reshape(out_shape)
 
 
+def _fused_kernel(table_ref, len_ref, q_ref, pool, o_ref, buf, sem, state,
+                  acc_ref, m_ref, l_ref, *, scale, block, kv_heads, group,
+                  width, pages, queries):
+    """:func:`_paged_kernel`'s walk over ONE pool whose rows hold a
+    position's key heads and, behind them, its value heads. A group's blocks
+    lie in the buffer as they lie in the pool, ``[positions * 2 Hk, D]``:
+    row ``r`` is stored head ``r % (2 Hk)`` of position ``r // (2 Hk)``. Each
+    key head's rows are taken out of the buffer (:func:`stored_head`) and
+    scored against that head's own query rows alone: a ``[queries * group,
+    positions]`` score tile a key head, where one matmul of every query row
+    with every stored row makes ``[queries * Hq, positions * 2 Hk]`` and
+    masks all but one column in ``2 Hk`` away."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    batch = pl.num_programs(0)
+    q_heads = kv_heads * group
+    stored = 2 * kv_heads
+    rows = pages * block                   # KV positions a group holds
+    page_rows = block * stored             # buffer rows a pool block takes
+    # values a 32-bit word of the buffer holds: consecutive buffer rows,
+    # which are consecutive stored heads of one position
+    packing = 4 // buf.dtype.itemsize
+
+    def visible(row):
+        # a stale length may not reach past the table
+        return jnp.clip(len_ref[row], 0, width * block)
+
+    def copies(row, grp, slot, fn):
+        """``fn`` on the copy of every pool block of (row, grp) that holds
+        visible rows, into buffer ``slot``. A copy's descriptor is a chain
+        of ~40 scalar operations (the table entry, two addresses, their
+        bounds) that the scalar unit runs one behind the other: four
+        blocks a loop turn are four chains side by side."""
+        def page(j, carry):
+            src = table_ref[(row * width + grp * pages) + j]
+            at = pl.ds(pl.multiple_of(j * page_rows, page_rows), page_rows)
+            fn(pltpu.make_async_copy(pool.at[src], buf.at[slot, at], sem.at[slot]))
+            return carry
+
+        def four(i, carry):
+            for j in range(4):
+                page(4 * i + j, carry)
+            return carry
+        live_pages = jnp.minimum(pl.cdiv(visible(row), block) - grp * pages, pages)
+        jax.lax.fori_loop(0, live_pages // 4, four, 0)
+        jax.lax.fori_loop(live_pages // 4 * 4, live_pages, page, 0)
+
+    @pl.when(b == 0)
+    def _reset():
+        state[0] = 0
+        state[1] = 0
+
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    length = visible(b)
+    groups = pl.cdiv(length, rows)
+
+    def own_rows(head):
+        """Where key head ``head``'s query rows lie among the row's ``queries
+        x Hq`` (query ``j``'s head ``h`` is row ``j * Hq + h``)."""
+        return [pl.ds(j * q_heads + head * group, group) for j in range(queries)]
+
+    def own(ref, head):
+        return jnp.concatenate([ref[at] for at in own_rows(head)], axis=0)
+
+    def own_queries():
+        """The queries that read each key head, ``[queries * group, D]``:
+        picked in float32 (whole sublane tiles where ``group`` is 8 rows)."""
+        q_all = q_ref[0].astype(jnp.float32)
+        picked = [[q_all[at.start:at.start + group] for at in own_rows(h)] for h in range(kv_heads)]
+        return [jnp.concatenate(parts, axis=0).astype(q_ref.dtype) for parts in picked]
+
+    half = rows // 2
+
+    def position_of(index):
+        """The position of the group that row ``index`` of a stored head's
+        ``[positions, D]`` holds: of 16-bit rows the even ones come from the
+        group's first half of positions and the odd ones from its second."""
+        return index if packing == 1 else index % 2 * half + index // 2
+
+    def stored_head(slot, head, seen_from=None):
+        """``[positions, D]`` of stored head ``head`` of the group in buffer
+        ``slot``: every ``stored``-th row of the buffer. 32-bit rows are read
+        so. Of 16-bit rows two lie in one 32-bit sublane, so the buffer is
+        read as words that hold stored heads ``2i`` and ``2i + 1`` of one
+        position, the group's first half of positions and its second apart,
+        and this head's halves of a first-half and a second-half position's
+        word make one word: the rows come out in ``position_of``'s order,
+        which a softmax over them does not see. ``seen_from``: the group's
+        first position, and a position past the row's length then reads
+        zero."""
+        def seen(first, count):
+            at = jax.lax.broadcasted_iota(jnp.int32, (count, 1), 0)
+            return seen_from + first + at < length
+
+        if packing == 1:
+            out = buf[slot, pl.ds(head, rows, stride=stored)]
+            return out if seen_from is None else jnp.where(seen(0, rows), out, 0)
+        words = buf.bitcast(jnp.uint32)           # [2, positions * Hk, D]: row = position * Hk + head // 2
+        low, high = (
+            words[slot, pl.ds(first * kv_heads + head // 2, half, stride=kv_heads)] for first in (0, half)
+        )
+        if seen_from is not None:
+            low = jnp.where(seen(0, half), low, jnp.uint32(0))
+            high = jnp.where(seen(half, half), high, jnp.uint32(0))
+        if head % 2:
+            word = (low >> 16) | (high & jnp.uint32(0xFFFF0000))
+        else:
+            word = (low & jnp.uint32(0xFFFF)) | (high << 16)
+        return pltpu.bitcast(word, buf.dtype)     # row 2s: the low half of word s
+
+    def score(g, slot, q_own):
+        """Fold group ``g`` of this row, gathered in buffer ``slot``, into
+        the online-softmax state, a key head at a time."""
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
+        # pages past the row's last were not copied (the buffer holds an
+        # earlier group's rows there): the length mask covers them
+        seen = g * rows + position_of(col) < length    # [1, positions]
+        for head, q in enumerate(q_own):           # [queries * group, D]
+            k = stored_head(slot, head).astype(q.dtype)
+            # zero unseen value rows: 0-weight x garbage must stay 0
+            v = stored_head(slot, kv_heads + head, seen_from=g * rows).astype(q.dtype)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            ) * scale                              # [queries * group, positions]
+            s = jnp.where(seen, s, NEG_INF)
+            m_prev = own(m_ref, head)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
+            p = jnp.exp(s - m_safe)                # exactly 0 where unseen
+            corr = jnp.exp(jnp.where(m_prev == NEG_INF, NEG_INF, m_prev - m_safe))
+            l_new = own(l_ref, head) * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc = own(acc_ref, head) * corr + jax.lax.dot_general(
+                p.astype(q.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            )
+            for j, at in enumerate(own_rows(head)):
+                part = slice(j * group, (j + 1) * group)
+                m_ref[at], l_ref[at], acc_ref[at] = m_new[part], l_new[part], acc[part]
+
+    # a row that sees nothing starts no copy and runs no group
+    @pl.when(groups > 0)
+    def _walk():
+        first = state[0]
+
+        @pl.when(state[1] == 0)
+        def _start_own():
+            copies(b, 0, first, lambda c: c.start())
+
+        # the next row that sees anything: its first group is gathered
+        # while this row's last one is scored
+        nxt_b = jax.lax.while_loop(
+            lambda r: (r < batch) & (len_ref[jnp.minimum(r, batch - 1)] <= 0),
+            lambda r: r + 1,
+            b + 1,
+        )
+        has_next = nxt_b < batch
+        q_own = own_queries()                      # once a row
+
+        def one_group(g, slot):
+            last = g + 1 == groups
+
+            @pl.when(jnp.logical_not(last) | has_next)
+            def _start_next():
+                copies(
+                    jnp.where(last, nxt_b, b), jnp.where(last, 0, g + 1),
+                    1 - slot, lambda c: c.start(),
+                )
+
+            copies(b, g, slot, lambda c: c.wait())
+            score(g, slot, q_own)
+            return 1 - slot
+
+        state[0] = jax.lax.fori_loop(0, groups, one_group, first)
+        state[1] = has_next.astype(jnp.int32)
+
+    # zeros for a row that saw nothing
+    o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
+
+
+# one trace and one lowering for every layer of a program (see
+# ``_sparse_pallas``): the key heads are unrolled in the kernel's body
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _fused_pallas(q, pool, block_table, lengths, *, scale, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+
+    out_shape, queries = q.shape, 1 if q.ndim == 3 else q.shape[1]
+    q = q.reshape(q.shape[0], -1, q.shape[-1])
+    batch, q_rows, head_dim = q.shape
+    num_pool_blocks, block, stored, _ = pool.shape
+    kv_heads = stored // 2
+    w = block_table.shape[1]
+    pages = _pages_per_step(block, kv_heads, head_dim, pool.dtype.itemsize, w)
+
+    def q_map(b, table, lens):
+        return (b, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(batch,),
+        in_specs=[pl.BlockSpec((1, q_rows, head_dim), q_map), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, q_rows, head_dim), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((2, pages * block * stored, head_dim), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((2,), jnp.int32),
+            pltpu.VMEM((q_rows, head_dim), jnp.float32),
+            pltpu.VMEM((q_rows, 1), jnp.float32),
+            pltpu.VMEM((q_rows, 1), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _fused_kernel, scale=scale, block=block, kv_heads=kv_heads,
+        group=q_rows // queries // kv_heads, width=w, pages=pages, queries=queries,
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((batch, q_rows, head_dim), q.dtype),
+        # a row starts the gather of the next row's first group: in order
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_attention",
+    )(
+        # [N, block, 2 Hk, D] -> [N, block * 2 Hk, D] merges the two middle
+        # dims under an unchanged minor dim: the pool as it lies; the table
+        # flat, so that an entry is one scalar load
+        block_table.astype(jnp.int32).reshape(-1), lengths.astype(jnp.int32), q,
+        pool.reshape(num_pool_blocks, block * stored, head_dim),
+    ).reshape(out_shape)
+
+
 def paged_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -462,7 +709,10 @@ def paged_attention(
     scale: Optional[float] = None,
     impl: str = "auto",
 ) -> jnp.ndarray:
-    """Single-step decode attention over a block-paged KV pool.
+    """Single-step decode attention over a block-paged KV pool: two pools
+    are scored a group by one matmul of every query row with every
+    (position, kv head) row and a mask, one pool of fused rows (``v=None``)
+    a key head at a time against that head's own query rows.
 
     Shapes: ``q`` [B, Hq, D] (one query per row — the decode step), or
     [B, Q, Hq, D] for Q queries a row that share its ``lengths`` entry and
@@ -471,8 +721,9 @@ def paged_attention(
     the result has ``q``'s shape). ``v=None``: ``k`` is one pool of fused
     rows ``[num_blocks, block, 2 Hk, D]``, a position's key heads and its
     value heads behind them (``KVRows(fused=True)``: with 4 + 4 heads of 128
-    one whole tile a position): the kernel copies a block once and reads
-    keys and values from the one buffer;
+    one whole tile a position; values of 16 or 32 bits): the kernel copies
+    a block once and scores each key head's rows against that head's own
+    query rows, at any number of queries;
     ``k``/``v`` [num_blocks, block, Hk, D] pools (bf16, or int8 with
     fp32 ``k_scale``/``v_scale`` [num_blocks, block, Hk]);
     ``block_table`` [B, W] int32 (entries past a row's coverage point
@@ -498,6 +749,13 @@ def paged_attention(
         )
     if impl != "pallas":
         raise ValueError(f"unknown paged attention impl {impl!r}")
+    if v is None:
+        if k.dtype.itemsize not in (2, 4) or k.shape[1] % 2:
+            raise ValueError(
+                "the kernel reads fused rows of 16 or 32 bits a value in blocks of an even number of "
+                f"positions, got {k.dtype} {k.shape}"
+            )
+        return _fused_pallas(q, k, block_table, lengths, scale=scale, interpret=_interpret())
     return _paged_pallas(
         q, k, v, block_table, lengths,
         k_scale=k_scale, v_scale=v_scale, scale=scale,

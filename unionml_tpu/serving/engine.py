@@ -1971,7 +1971,7 @@ class DecodeEngine:
         if self.prefix_cache is not None:
             out["prefix_cache"] = self.prefix_cache.stats()
         if self.kv_pool is not None:
-            from unionml_tpu.ops.paged_attention import _pages_per_step
+            from unionml_tpu.ops.paged_attention import _pages_per_step, score_tile
 
             rows = next(l for l in self._layout if l.owns_rows)
             out["kv_pool"] = {
@@ -1987,6 +1987,13 @@ class DecodeEngine:
                     self._kv_block_size, *rows.pool_row, self._table_width,
                 ),
             }
+            if getattr(rows, "q_heads", 0):
+                # [query rows, columns] of the score tile a group of the
+                # kernel ``paged_attention`` works on (rows of keys and values)
+                out["kv_pool"]["score_tile"] = score_tile(
+                    self._kv_block_size, rows.q_heads, *rows.pool_row, self._table_width,
+                    queries=1 if self._blocks is None else self._blocks.block_length, fused=rows.fused,
+                )
         if self._index_topk is not None and self._perf is not None:
             # a learned selection: of the cached rows the dispatched steps'
             # live sequences could see, those the selection lets their
