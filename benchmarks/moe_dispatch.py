@@ -13,7 +13,11 @@ hidden 4096, 8 experts of 14336, top-2, int8 weights, bfloat16 rows, unless
 another layer (``glm_flash_code_context_decode``'s: 64 experts of 1536 on
 2048, top-4, the sigmoid router). ``--live N``: only the first N rows are
 distinct and the rest repeat one row, as a decode chunk's dead slots all hold
-the pad token and route alike (the experts touched are then the live rows'). Every
+the pad token and route alike (the experts touched are then the live rows').
+``--valid N``: the rows past the first N are handed to the grouped dispatch as
+``valid=False`` (sent to no expert, as the dead rows of a block forward that
+carries two blocks a slot: PR 46); the dense dispatch has no such argument
+and computes every row. Every
 layer has its own weights (1.41 GB; eight of them, as the cell holds): one
 layer's weights carried through a loop are not what a model reads. A layer
 is the router, the routing, and the experts' SwiGLU, its input made from the
@@ -124,6 +128,8 @@ def main():
     ap.add_argument("--width", type=int, default=14336, help="an expert's width")
     ap.add_argument("--router", choices=("softmax", "sigmoid"), default="softmax")
     ap.add_argument("--live", type=int, default=0, help="distinct rows; the rest repeat one (0: all)")
+    ap.add_argument("--valid", type=int, default=0,
+                    help="rows the grouped dispatch sends to experts; the rest valid=False (0: all)")
     ap.add_argument("--chunk", type=int, nargs="*", default=[], help="row tiles to try (0: the op's own)")
     ap.add_argument("--tiles", nargs="*", default=[], help="k,n,b[/k,n,b] to try, not the op's own")
     ap.add_argument("--ragged-dot", action="store_true")
@@ -153,6 +159,8 @@ def main():
         if args.live:
             x = jnp.where(jnp.arange(tokens)[:, None] < args.live, x, x[-1:])
         for what, mlp, chunk, tile in cases:
+            if args.valid and what != "dense":
+                mlp = functools.partial(mlp, valid=jnp.arange(tokens) < args.valid)
             moe._row_chunk = row_chunk if chunk is None else (lambda rows, e, chunk=chunk: chunk)
             moe._grouped_matmul_pallas = (
                 pallas if tile is None else functools.partial(_with_tiles, pallas, tile)
@@ -174,7 +182,8 @@ def main():
                     .astype(jnp.float32)
                     for m in (mlp, moe.dense_expert_mlp)
                 )
-                off = round(float(jnp.max(jnp.abs(got - want)) / jnp.std(want)), 4)
+                sent = slice(0, args.valid or None)      # a row sent to no expert comes back zero
+                off = round(float(jnp.max(jnp.abs(got - want)[sent]) / jnp.std(want[sent])), 4)
             except Exception as exc:  # a tile the compiler refuses: say so and go on
                 us, us_min, off = None, str(exc)[:300], None
             chunk = chunk or (moe._row_chunk(tokens * k, experts) if what == "grouped" else None)
@@ -187,10 +196,11 @@ def main():
                 }.get(what) or round(moe._padded_rows(tokens * k, experts, chunk) / (tokens * k), 3),
                 "weights_us_at_hbm_rate": round(1e6 * layer_bytes / HBM_BYTES_PER_S, 1),
                 "experts_touched_expected": round(
-                    moe.expected_experts_touched(args.live or tokens, experts, k), 1
+                    moe.expected_experts_touched(
+                        args.live or (args.valid if what != "dense" else 0) or tokens, experts, k), 1
                 ),
                 "experts": experts, "top_k": k, "hidden": d, "width": h, "router": args.router,
-                "live": args.live,
+                "live": args.live, "valid": (args.valid or tokens) if what != "dense" else tokens,
                 "routed_flops_us_at_peak": round(1e6 * 2 * tokens * k * 3 * d * h / MXU_FLOPS, 1),
                 "layers": layers, "device": device.device_kind, "platform": device.platform,
             }), flush=True)

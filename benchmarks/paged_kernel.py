@@ -22,7 +22,14 @@ one another) at ``sdar_chat_fixed_length_decode``'s shape: 32 slots, GQA 32/4
 over a fused pool (a position's 4 key and 4 value heads in one row), a table
 163 blocks wide, 20 live rows of 300-2,300 positions; beside it the same
 rows with one query, so that what N queries cost over one is read off two
-lines. Table and lengths are arguments of every timed program. A line also
+lines. ``--two-limits`` (with an even ``--queries N``; PR 46) times what a
+forward that carries two blocks a row hands the kernel: the row's first
+``N / 2`` queries see ``length - N / 2`` positions and the others ``length``
+(``paged_attention(limits=)``), beside ``N / 2`` queries that share the
+length (the forward of one block) and one query; ``--live R --positions LO
+HI`` set the live rows and the range their lengths are drawn from (the cell
+after PR 45: ~27 rows of ~300-700). Table, lengths and limits are arguments
+of every timed program. A line also
 says the ``[query rows, columns]`` of the score tile one group of the kernel
 works on (``score_tile``), what the live rows' keys and values take at the
 HBM's rate (``bytes_us``) and the call's time over that (``over_bytes``), and
@@ -92,6 +99,11 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--rows", type=int, default=0)
     ap.add_argument("--queries", type=int, default=0)
+    ap.add_argument("--two-limits", action="store_true",
+                    help="the first half of a row's queries sees length - queries / 2 positions")
+    ap.add_argument("--live", type=int, default=0, help="live rows of the --queries shape")
+    ap.add_argument("--positions", type=int, nargs=2, default=(300, 2300),
+                    help="the range a live row's length is drawn from (--queries)")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
     device = jax.devices()[0]
@@ -102,11 +114,14 @@ def main():
         pa._KV_BUFFER_BYTES = max(pa._KV_BUFFER_BYTES, 4 * args.rows * 32 * HEAD_DIM * 2)
     reps = 2 if args.rehearse else args.reps
 
-    shapes = [shape + (0,) for shape in SHAPES]
+    shapes = [shape + (0, False) for shape in SHAPES]
     if args.queries:
-        shapes = [BLOCK_SHAPE + (args.queries,), BLOCK_SHAPE + (1,)]
-    for name, q_heads, kv_heads, width, n_blocks, live, mean_len, queries in shapes:
-        spread = (300, 2300) if queries else None
+        shape = BLOCK_SHAPE[:5] + (args.live or BLOCK_SHAPE[5],) + BLOCK_SHAPE[6:]
+        shapes = [shape + (args.queries, False), shape + (1, False)]
+        if args.two_limits:
+            shapes = [shape + (args.queries, True), shape + (args.queries // 2, False), shape + (1, False)]
+    for name, q_heads, kv_heads, width, n_blocks, live, mean_len, queries, limited in shapes:
+        spread = tuple(args.positions) if queries else None
         if args.rehearse:
             width, n_blocks, mean_len, spread = 40, 200, 60, (30, 90) if queries else None
         rng = np.random.default_rng(args.seed)
@@ -122,10 +137,20 @@ def main():
             v = jnp.asarray(rng.standard_normal(pool_shape), jnp.bfloat16)
             q = jnp.asarray(rng.standard_normal((SLOTS, q_heads, HEAD_DIM)), jnp.bfloat16)
 
+        def limits_of(lengths):
+            """[rows, queries]: the first half of a row's queries sees the
+            positions before the second half's own."""
+            if not limited:
+                return None
+            back = jnp.repeat(jnp.asarray([queries // 2, 0], jnp.int32), queries // 2)
+            return jnp.maximum(lengths[:, None] - back[None, :], 0)
+
         @jax.jit
-        def loop(q, k, v, table, lengths):
+        def loop(q, k, v, table, lengths, limits):
+            kw = {} if limits is None else {"limits": limits}
+
             def body(_, x):
-                return pa.paged_attention(x, k, v, table, lengths, impl="pallas")
+                return pa.paged_attention(x, k, v, table, lengths, impl="pallas", **kw)
             return jax.lax.fori_loop(0, reps, body, q)
 
         cases = (("live+zero", live, False), ("live+stale", live, True), ("dead", 0, False))
@@ -133,11 +158,13 @@ def main():
             table, lengths, positions = _case(
                 np.random.default_rng(args.seed + 1), width, n_blocks, n_live, mean_len, stale, spread,
             )
-            loop(q, k, v, table, lengths).block_until_ready()
+            limits = limits_of(lengths)
+            kw = {} if limits is None else {"limits": limits}
+            loop(q, k, v, table, lengths, limits).block_until_ready()
             gap = None
             if n_live and not stale:  # a stale row's output is garbage by contract
                 got, want = (
-                    pa.paged_attention(q, k, v, table, lengths, impl=impl).astype(jnp.float32)
+                    pa.paged_attention(q, k, v, table, lengths, impl=impl, **kw).astype(jnp.float32)
                     for impl in ("pallas", "reference")
                 )
                 gap = float(jnp.max(jnp.abs(got - want)[np.asarray(lengths) > 0]))
@@ -145,10 +172,10 @@ def main():
             times = []
             for _ in range(5):
                 t0 = time.perf_counter()
-                loop(q, k, v, table, lengths).block_until_ready()
+                loop(q, k, v, table, lengths, limits).block_until_ready()
                 times.append((time.perf_counter() - t0) / reps)
             print(json.dumps({
-                "shape": name, "rows": what, "queries": queries or 1,
+                "shape": name, "rows": what, "queries": queries or 1, "limits": 2 if limited else 1,
                 "us_per_call": round(1e6 * float(np.median(times)), 2),
                 "us_min": round(1e6 * min(times), 2), "live_rows": n_live, "live_positions": positions,
                 "kv_mb": round(kv_bytes / 1e6, 2), "bytes_us": round(1e6 * kv_bytes / HBM_BYTES_PER_S, 2),

@@ -336,6 +336,115 @@ def test_every_query_of_a_row_sees_all_of_its_rows():
         assert float(jnp.max(jnp.abs(together[:, j] - alone)[rows])) < 1e-6
 
 
+# ---- a limit a query (two blocks of positions in one call: the earlier
+# blind to the later)
+
+
+def _two_halves(width, heads, dtype, seed):
+    """Eight queries a row over a fused pool, the first four of which see
+    four positions fewer than the others: a row that sees nothing, a dead
+    row with a stale length, a row whose second half alone sees anything,
+    rows whose halves end in different pool blocks (a block boundary between
+    them) and in different groups (the group's last position between them),
+    rows inside a block, and the whole table. Returns the call's arguments,
+    the limits, and the ``[rows, queries]`` to compare (a query that sees
+    nothing reads zero from the kernel and an average of the table's rows
+    from the gather)."""
+    edge = min(P, width - 1) * BS          # the first group's end (a block's end on the short table)
+
+    def layout(width):
+        lengths = [0, 4, edge + 4, 21, edge + 2, BS + 4, width * BS, 0, 13]
+        return lengths, [False] * 8 + [True]
+
+    args, _, rows = _ragged_setup(width, heads, dtype, False, seed=seed, layout=layout)
+    q, pool, _, table, lengths = _fused(_with_queries(args, 8))
+    first = jnp.maximum(lengths - 4, 0)
+    limits = jnp.concatenate([jnp.tile(first[:, None], (1, 4)), jnp.tile(lengths[:, None], (1, 4))], axis=1)
+    return (q, pool, None, table, lengths), limits, rows[:, None] & (np.asarray(limits) > 0)
+
+
+def _half_by_half(args, limits):
+    """The gather's answer without its ``limits``: each half of a row's
+    queries alone, at the length it may see."""
+    q, pool, _, table, lengths = args
+    halves = [
+        paged_attention(
+            q[:, at], pool, None, table, jnp.minimum(lengths, limits[:, at.start]), impl="reference",
+        )
+        for at in (slice(0, 4), slice(4, 8))
+    ]
+    return jnp.concatenate(halves, axis=1)
+
+
+@FUSED_HEADS
+@WIDTHS
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_fused_rows_under_a_limit_a_query_match_the_gather_half_by_half(dtype, width, heads):
+    args, limits, rows = _two_halves(width, heads, dtype, seed=21)
+    want = _half_by_half(args, limits).astype(jnp.float32)
+    lengths = np.asarray(args[4])
+    # (the gather's einsums over eight queries sum in another order than over four)
+    for impl, tol in (("reference", 1e-6), ("pallas", 2e-6)):
+        tol = tol if dtype == jnp.float32 else 2e-2
+        got = paged_attention(*args, impl=impl, limits=limits).astype(jnp.float32)
+        assert got.shape == args[0].shape and bool(jnp.all(jnp.isfinite(got)))
+        assert float(jnp.max(jnp.abs(got - want)[rows])) <= tol
+        if impl == "pallas":
+            assert not bool(jnp.any(got[lengths == 0]))
+            # a query that sees nothing (the first half of a row of 4 positions) comes out zero
+            assert not bool(jnp.any(got[1, :4])) and bool(jnp.any(got[1, 4:]))
+
+
+def test_a_limit_never_reaches_past_the_rows_length():
+    """What is copied and walked goes by ``lengths``: a limit beyond it sees
+    the row's length (a slot in the middle of a block, whose second half is
+    dead and whose limits run on)."""
+    args, limits, rows = _two_halves(W, (8, 2), jnp.float32, seed=22)
+    beyond = limits + jnp.asarray([0, 0, 0, 0, 4, 4, 4, 4])[None, :]
+    for impl in ("reference", "pallas"):
+        got = paged_attention(*args, impl=impl, limits=beyond)
+        want = paged_attention(*args, impl=impl, limits=limits)
+        assert float(jnp.max(jnp.abs(got - want)[rows])) == 0.0
+
+
+# sha256 of ``str(jax.make_jaxpr(...))`` of the fused kernel's call with no
+# limits, read on the tree before ``limits=`` existed (PR 45's), on the CPU
+# (interpret mode), at the shapes below
+_TEXT_BEFORE_LIMITS = {
+    1: "aee3b6ca2a0655b8a437720d3ab0c583683370fb7645bcf77bf340803b100f77",
+    4: "984f23bcf7bdf7c746c569ea9c8ded99941cbbda173e14de310d396a2d525ba3",
+}
+
+
+@pytest.mark.parametrize("queries", [1, 4], ids=["one-query", "four-queries"])
+def test_without_limits_the_fused_kernel_traces_what_it_traced(queries):
+    import hashlib
+
+    shape = (3, 32, 128) if queries == 1 else (3, queries, 32, 128)
+    text = str(jax.make_jaxpr(
+        lambda q, pool, table, lens: paged_attention(q, pool, None, table, lens, impl="pallas")
+    )(
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16), jax.ShapeDtypeStruct((40, 16, 8, 128), jnp.bfloat16),
+        jax.ShapeDtypeStruct((3, 12), jnp.int32), jax.ShapeDtypeStruct((3,), jnp.int32),
+    ))
+    assert hashlib.sha256(text.encode()).hexdigest() == _TEXT_BEFORE_LIMITS[queries]
+
+
+def test_limits_are_a_fused_pools_and_come_a_query():
+    q, k, v, table, lengths = _setup()
+    many = jnp.stack([q, q], axis=1)
+    with pytest.raises(ValueError, match="limits are \\[batch, queries\\]"):
+        paged_attention(q, k, v, table, lengths, limits=jnp.ones((B, 1), jnp.int32))
+    with pytest.raises(ValueError, match="limits are \\[batch, queries\\]"):
+        paged_attention(many, k, v, table, lengths, limits=jnp.ones((B, 3), jnp.int32))
+    with pytest.raises(ValueError, match="one pool of fused rows"):
+        paged_attention(many, k, v, table, lengths, impl="pallas", limits=jnp.ones((B, 2), jnp.int32))
+    # the plain gather takes them over two pools too
+    out = paged_attention(many, k, v, table, lengths, impl="reference", limits=jnp.ones((B, 2), jnp.int32))
+    alone = paged_attention(many, k, v, table, jnp.minimum(lengths, 1), impl="reference")
+    assert bool(jnp.all(out == alone))
+
+
 @pytest.mark.parametrize(
     "block,kv_heads,head_dim,itemsize,width,pages",
     [

@@ -113,7 +113,7 @@ def test_config_reads_the_published_keys_and_refuses_what_it_cannot_run():
     assert (cfg.hidden_size, cfg.num_experts, cfg.head_dim, cfg.rope_theta) == (2048, 128, 128, 1e6)
     scheme = SdarMoe(cfg).generation_scheme()
     assert scheme == BlockDiffusion(4, 4, "low_confidence_static", 0.9, 151669)
-    assert scheme.per_forward == 1 and scheme.forwards_per_block == 5
+    assert scheme.per_forward == 1 and scheme.forwards_per_block == 4
     assert cfg.to_hf()["generation"]["mask_token_id"] == 151669
     # bfloat16 rows of 4 key and 4 value heads, one whole 8 x 128 tile: 2,048 B a position
     assert SdarMoe(cfg).cache_layout() == (KVRows(4, 128, fused=True),) * 48
@@ -233,19 +233,12 @@ def _serve(monkeypatch, module, params, prompts, *, asked=None, slots=2, new_tok
                 ]
                 out = [(f.result()[0], None, None) for f in futures]
         else:
-            def settle():
-                # a chunk dispatched while the last request retired may still run
-                deadline = time.monotonic() + 30
-                while not engine._engine_empty() and time.monotonic() < deadline:
-                    time.sleep(0.01)
-                jax.effects_barrier()
-
             for prompt, n in zip(prompts, asked):
-                settle()
+                _settle(engine)
                 del seen[:]
                 done.clear()
                 tokens = engine.generate(params, [prompt], max_new_tokens=n)[0]
-                settle()
+                _settle(engine)
                 (meta, _), = [v for v in done.values() if v[0].get("events")]
                 (event,) = [e for e in meta["events"] if e["name"] == "decided_at"]
                 rows = list(seen)
@@ -312,9 +305,7 @@ def _check_every_forward(params, cfg, prompt, tokens, decided_at, logits, slot=0
             ranked = sorted(conf[und], reverse=True)
             near_tie = len(ranked) > len(now) and ranked[len(now) - 1] - ranked[len(now)] < 1e-4 * ranked[0]
             assert ref_now == now or near_tie
-            i += 1
-        if b + 1 < len(blocks):
-            i += 1      # the commit forward between two blocks
+            i += 1      # (a block's commit rides with the next block's first forward)
     return i
 
 
@@ -341,9 +332,9 @@ def test_engine_serves_the_references_forwards(monkeypatch, served, paged_impl, 
     pool = stats["kv_pool"]
     assert pool["row_layout"] == "kv" and pool["blocks_in_use"] == 0
     assert pool["freed_blocks"] == pool["allocated_blocks"] > 0
-    # fused rows: a block's 4 queries x 4 heads against a group's positions, a key head at a time
-    assert pool["score_tile"] == [4 * 4, pool["kernel_blocks_per_group"] * 8]
-    assert stats["moe"]["decode_chunk"]["expert_rows_routed"] == 2 * 4 * 2     # slots x Bk x top-2
+    # fused rows: a forward's 8 queries x 4 heads against a group's positions, a key head at a time
+    assert pool["score_tile"] == [8 * 4, pool["kernel_blocks_per_group"] * 8]
+    assert stats["moe"]["decode_chunk"]["expert_rows_routed"] == 2 * 8 * 2     # slots x 2 Bk x top-2
     admits = [s for s in spans if s["name"] == "admit"]
     assert admits and admits[-1]["args"]["held_back"] == 39 % 4
     chunks = [s for s in spans if s["name"].startswith("decode-chunk")]
@@ -378,7 +369,175 @@ def test_any_number_of_tokens_for_prompts_of_every_residue(monkeypatch, served, 
         _check_every_forward(params, module.config, prompt, tokens, decided_at, logits)
     goodput = stats["goodput"]
     assert goodput["tokens_emitted"] == goodput["tokens_decided"] == 4 * asked
-    assert goodput["block_forwards"] >= goodput["tokens_decided"] + goodput["block_commits"]
+    # under the static rule a live forward decides one entry, and every block
+    # but a request's last is committed by the next block's first forward:
+    # no forward is left that decides nothing
+    assert goodput["block_forwards"] == goodput["tokens_decided"]
+    closed = sum(-(-(len(p) + asked) // BK) - len(p) // BK - 1 for p in prompts)
+    assert goodput["block_commits"] == goodput["block_fused_commits"] == closed
+
+
+# ------------------------------- (k) the commit rides with the next block's first forward
+
+
+def _reference_rows(params, seq, cfg):
+    """Every layer's cached rows of ``seq`` as the plain reference computes
+    them: ``[layers, S, 2 Hk, D]``, a position's key heads (normalised,
+    rotated) and its value heads behind them."""
+    hf = cfg.to_hf()
+    eps, theta = hf["rms_norm_eps"], float(cfg.rope_theta)
+    kv_heads, hd, pos = cfg.num_key_value_heads, cfg.head_dim, jnp.arange(len(seq))
+    out = []
+    with jax.default_matmul_precision("highest"):
+        x = params["embed"]["embedding"].astype(jnp.float32)[jnp.asarray(seq)]
+        for i in range(cfg.num_hidden_layers):
+            blk = params[f"block_{i}"]
+            h = reference._rms_norm(x, blk["attn_norm"]["scale"], eps)
+            k, v = (
+                reference._mm(h, reference._weight(blk["attn"][name], h.shape[-1]))
+                .reshape(len(seq), kv_heads, hd)
+                for name in ("k", "v")
+            )
+            k = reference._rope(reference._rms_norm(k, blk["attn"]["k_norm"]["scale"], eps), pos, theta)
+            out.append(np.concatenate([np.asarray(k), np.asarray(v)], axis=1))
+            x = reference.layer(x, blk, hf, pos)
+    return np.stack(out)
+
+
+def _engine_watched(monkeypatch, module, **kw):
+    """An engine whose harvested chunks' ``info`` (``[forwards, slots, 4]``
+    a chunk, with the slots it was dispatched for) and released requests'
+    pool blocks are recorded."""
+    infos, released = [], []
+    process, release = DecodeEngine._process_block_chunk, DecodeEngine._release_blocks_locked
+
+    def watch_chunk(self, mask, gens, outs, dispatched, seq):
+        infos.append((np.asarray(mask).copy(), np.asarray(outs[2]).copy()))
+        return process(self, mask, gens, outs, dispatched, seq)
+
+    def watch_release(self, req, slot=None):
+        if req._block_ids:
+            released.append((list(req.prompt), list(req._block_ids)))
+        return release(self, req, slot)
+
+    monkeypatch.setattr(DecodeEngine, "_process_block_chunk", watch_chunk)
+    monkeypatch.setattr(DecodeEngine, "_release_blocks_locked", watch_release)
+    kw = {**dict(slots=2, max_new_tokens=24, prompt_buckets=(16, 64), paged=True, kv_block_size=8,
+                 chunk_steps=4, pipeline_depth=2), **kw}
+    engine = DecodeEngine(
+        module, registry=telemetry.MetricsRegistry(), tracer=telemetry.TraceRecorder(), **kw,
+    )
+    return engine, infos, released
+
+
+def _settle(engine):
+    """Wait out a chunk dispatched while the last request retired."""
+    deadline = time.monotonic() + 30
+    while not engine._engine_empty() and time.monotonic() < deadline:
+        time.sleep(0.01)
+    jax.effects_barrier()
+
+
+@pytest.mark.parametrize("paged_impl", ["reference", "pallas"], ids=["plain", "kernel"])
+@pytest.mark.parametrize("length,asked", [(8, 12), (9, 11), (10, 5), (11, 9), (20, 4), (5, 16)])
+def test_committed_rows_are_the_references_rows_of_the_final_tokens(
+    monkeypatch, served, paged_impl, length, asked,
+):
+    """(a) Prompts of every residue, several asked lengths: what the pool
+    holds for every block but the request's last (the prompt's whole blocks
+    by the prefill, the generated ones each by the first half of the forward
+    that opened the next) equals, row for row, the reference's keys and
+    values of the final tokens; tokens and ``decided_at`` are the
+    reference's loop's."""
+    _, params = served
+    module = SdarMoe(_tiny(paged_impl=paged_impl))
+    cfg = module.config
+    prompt = _prompts(length, seed=100 + length)[0]
+    engine, infos, released = _engine_watched(monkeypatch, module)
+    try:
+        timeline = []
+        engine._tracer.add_listener(lambda rid, meta, spans: timeline.append(meta))
+        tokens = engine.generate(params, [prompt], max_new_tokens=asked)[0]
+        _settle(engine)
+        pool = [np.asarray(layer[0]) for layer in engine._state["pool"]]
+    finally:
+        engine.close()
+    (decided_at,) = [
+        e["args"]["forwards"] for m in timeline for e in m.get("events", []) if e["name"] == "decided_at"
+    ]
+    with jax.default_matmul_precision("highest"):
+        want, want_at = reference.generate(params, prompt, cfg.to_hf(), asked)
+    assert (tokens, decided_at) == (want, want_at)
+    ((_, block_ids),) = released
+    final = (length + asked - 1) // BK * BK          # the last block's first row: it is never committed
+    want_rows = _reference_rows(params, prompt + tokens, cfg)[:, :final]
+    got_rows = np.stack([layer[block_ids].reshape((-1,) + layer.shape[2:])[:final] for layer in pool])
+    assert got_rows.shape == want_rows.shape and final >= 8
+    assert np.abs(got_rows - want_rows).max() < LOGIT_TOL
+    # every block that was closed was closed by a forward that denoised the next
+    kinds = np.concatenate([info[:, :, 3][:, mask].reshape(-1) for mask, info in infos])
+    assert set(kinds.tolist()) <= {0, 1, 3}
+    assert (kinds == 3).sum() == final // BK - length // BK
+
+
+def test_a_block_decided_in_one_forward_is_closed_by_the_very_next(monkeypatch, served):
+    """(b) The dynamic rule with a threshold that every confidence passes:
+    each forward decides a whole block, and from the second on each also
+    closes the block before it, so a chunk of four forwards moves ``fill``
+    on by four blocks, which the host's bound (``_chunk_advance``) allows
+    for: every block's rows land in the request's own pool blocks and
+    every forward's logits are the reference's."""
+    _, params = served
+    module = SdarMoe(_tiny(remasking_strategy="low_confidence_dynamic", confidence_threshold=0.0))
+    prompts = _prompts(8, 13, seed=51)
+    results, stats, _ = _serve(monkeypatch, module, params, prompts, new_tokens=24)
+    for prompt, (tokens, decided_at, logits) in zip(prompts, results):
+        assert len(tokens) == 24 and set(decided_at) == {0}
+        _check_every_forward(params, module.config, prompt, tokens, decided_at, logits)
+        with jax.default_matmul_precision("highest"):
+            assert (tokens, decided_at) == reference.generate(params, prompt, module.config.to_hf(), 24)
+    goodput = stats["goodput"]
+    # 6 and 7 blocks, a forward each; all but each request's last closed, by the next one's forward
+    sums = (goodput["block_forwards"], goodput["block_commits"], goodput["block_fused_commits"])
+    assert sums == (13, 11, 11)
+    assert stats["generation"]["tokens_per_forward"] > 3.5
+
+    engine, infos, _ = _engine_watched(monkeypatch, module)
+    try:
+        assert engine._chunk_advance == BK * engine.chunk_steps and engine._step_rows == 2 * BK
+        engine.generate(params, [prompts[0]], max_new_tokens=24)
+        _settle(engine)
+    finally:
+        engine.close()
+    kinds = np.concatenate([info[:, 0, 3] for mask, info in infos if mask[0]])
+    assert kinds[kinds > 0].tolist() == [1, 3, 3, 3, 3, 3]
+    # a chunk whose forwards closed a block each but the first: past a bound of one every second forward
+    assert max(int((info[:, 0, 3] == 3).sum()) for _, info in infos) > -(-engine.chunk_steps // 2)
+
+
+def test_a_slot_in_the_middle_of_a_block_beside_slots_that_close_one(monkeypatch, served):
+    """(c) Prompts of residue 0, 1 and 2 admitted together run out of step
+    (their first blocks take 4, 3 and 2 forwards): forwards in which one slot
+    denoises alone (its second half dead) while another closes a block and
+    opens the next. Each request's tokens are those it is served alone."""
+    module, params = served
+    prompts = _prompts(8, 9, 10, seed=61)
+    alone, _, _ = _serve(monkeypatch, module, params, prompts, new_tokens=12)
+    engine, infos, _ = _engine_watched(monkeypatch, module, slots=3)
+    try:
+        import concurrent.futures as cf
+
+        with cf.ThreadPoolExecutor(3) as pool:
+            futures = [pool.submit(engine.generate, params, [p], max_new_tokens=12) for p in prompts]
+            together = [f.result()[0] for f in futures]
+        _settle(engine)
+    finally:
+        engine.close()
+    assert together == [t for t, _, _ in alone]
+    mixed = sum(
+        1 for _, info in infos for kinds in info[:, :, 3].tolist() if 1 in kinds and 3 in kinds
+    )
+    assert mixed > 0
 
 
 # ------------------------------------------- (e) committed rows are final rows
@@ -445,7 +604,8 @@ def test_a_sampler_that_only_ever_returns_the_mask_id_is_served_like_any_other(m
         engine.close()
     (at,) = [e["args"]["forwards"] for m in done for e in m.get("events", []) if e["name"] == "decided_at"]
     assert [sorted(at[i:i + 4]) for i in (0, 4, 8)] == [[0, 1, 2, 3]] * 3
-    assert (report["block_forwards"], report["block_commits"], report["tokens_decided"]) == (14, 2, 12)
+    assert (report["block_forwards"], report["block_commits"], report["tokens_decided"]) == (12, 2, 12)
+    assert report["block_fused_commits"] == 2
 
 
 # ---------------------------------------------------- (g) the dynamic rule
@@ -471,7 +631,7 @@ def test_the_dynamic_rule_decides_several_entries_where_confidences_pass(monkeyp
         several += sum(decided_at.count(f) > 1 for f in (0, 1))
     assert several > 0
     gen = stats["generation"]
-    assert gen["tokens_per_forward"] > 0.8 and gen["forwards_per_block"] < 5
+    assert gen["tokens_per_forward"] > 1.0 and gen["forwards_per_block"] < 4
 
 
 # -------------------------------------------- (i) neighbours, joining and leaving
@@ -544,6 +704,71 @@ def test_handoff_and_an_engine_without_a_pool_are_refused_by_name(served):
                 call()
     finally:
         engine.close()
+
+
+@pytest.mark.parametrize("running", [False, True], ids=["no-slot-runs", "a-slot-in-the-middle-of-a-block"])
+def test_the_mixture_is_always_handed_a_row(served, running):
+    """A forward over a pool hands the mixture the rows that run, per row;
+    where no slot runs (the steps of a chunk after its last request ended)
+    it still hands the first row: with none the grouped kernel's tile maps
+    point before the first tile, which no CPU path notices and the chip
+    halts on."""
+    from flax import linen as nn
+
+    from unionml_tpu.ops.moe import MoEMlp
+
+    module, params = served
+    cfg, slots, rows = module.config, 2, 2 * BK
+    live = jnp.zeros((slots, rows), bool).at[1, :BK].set(running)
+    handed = []
+
+    def watch(next_fun, args, kwargs, context):
+        if isinstance(context.module, MoEMlp) and context.method_name == "__call__":
+            handed.append(np.asarray(args[1]))
+        return next_fun(*args, **kwargs)
+
+    cache = tuple(layer.init(6, 8) for layer in module.cache_layout())       # a pool of six blocks of 8
+    with nn.intercept_methods(watch):
+        module.apply(
+            {"params": params}, jnp.ones((slots, rows), jnp.int32), cache=cache,
+            cache_index=jnp.asarray([8, 16]), block_table=jnp.asarray([[1, 2, 3], [4, 5, 0]]), live=live,
+        )
+    want = np.asarray(live).copy()
+    want[0, 0] = True
+    assert len(handed) == cfg.num_hidden_layers and all((v == want).all() for v in handed)
+
+
+def test_the_kernel_is_handed_a_length_a_slot_and_a_limit_a_query(monkeypatch, served):
+    """A slot that closes a block reads ``fill + 2 Bk`` rows, one in the
+    middle of a block ``fill + Bk``, one that runs nothing **none** (at a
+    stale ``fill`` it would walk the trash block); a query sees to the end
+    of its own block; a dead row's keys and values go to the trash block."""
+    from unionml_tpu.models import sdar_moe
+
+    module, params = served
+    calls, plain = [], sdar_moe.paged_attention
+
+    def watch(q, rows, v, table, lengths, **kw):
+        calls.append((np.asarray(lengths), np.asarray(kw["limits"]), np.asarray(rows)))
+        return plain(q, rows, v, table, lengths, **kw)
+
+    monkeypatch.setattr(sdar_moe, "paged_attention", watch)
+    # three slots: one closes a block, one is in the middle of one, one runs nothing
+    live = jnp.zeros((3, 2 * BK), bool).at[0].set(True).at[1, :BK].set(True)
+    cache = tuple(layer.init(8, 8) for layer in module.cache_layout())      # a pool of eight blocks of 8
+    _, new_cache = module.apply(
+        {"params": params}, jnp.ones((3, 2 * BK), jnp.int32), cache=cache,
+        cache_index=jnp.asarray([8, 20, 16]), live=live,
+        block_table=jnp.asarray([[1, 2, 0, 0], [3, 4, 5, 6], [0, 0, 0, 0]]),
+    )
+    assert len(calls) == module.config.num_hidden_layers
+    for lengths, limits, _ in calls:
+        assert lengths.tolist() == [16, 24, 0]
+        assert limits[0].tolist() == [12] * 4 + [16] * 4 and limits[1].tolist() == [24] * 4 + [28] * 4
+    (pool,) = (np.asarray(layer[0]) for layer in new_cache[:1])
+    assert np.abs(pool[2]).sum() > 0 and np.abs(pool[5, 4:]).sum() > 0  # slot 0 rows 8-15, slot 1 rows 20-23
+    assert not np.abs(pool[6]).sum() and not np.abs(pool[7]).sum()         # slot 1's dead rows 24-27: nowhere
+    assert np.abs(pool[0]).sum() > 0                                       # ... but in the trash block
 
 
 def test_every_other_module_generates_a_token_a_step():

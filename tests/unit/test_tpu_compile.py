@@ -122,10 +122,12 @@ def test_paged_attention_compiles(
 
 # the sdar_chat_fixed_length_decode cell's kernel: 32 rows x 4 queries x 32
 # heads over one pool of fused rows (4 key + 4 value heads of 128 a
-# position), blocks of 16, a table 163 wide over 3,814 pool blocks; and the
-# same rows with one query
-@pytest.mark.parametrize("queries", [4, 1], ids=["cell-4-queries", "one-query"])
-def test_paged_attention_over_fused_rows_compiles(chip, queries):
+# position), blocks of 16, a table 163 wide over 3,814 pool blocks: a forward's
+# two blocks of 4 queries a row, each query under a limit of its own; four
+# queries that share the row's length; and the same rows with one query
+@pytest.mark.parametrize("queries,limited", [(8, True), (4, False), (1, False)],
+                         ids=["cell-8-queries-a-limit-each", "4-queries", "one-query"])
+def test_paged_attention_over_fused_rows_compiles(chip, queries, limited):
     """A key head's rows are read out of the gathered buffer by strided
     32-bit loads (two bfloat16 heads a word) and scored against that head's
     own query rows: Mosaic takes it, the kernel keeps the name the
@@ -133,12 +135,13 @@ def test_paged_attention_over_fused_rows_compiles(chip, queries):
     batch, q_heads, kv_heads, hd, block, width, n_blocks = 32, 32, 4, 128, 16, 163, 3814
     q = (batch, queries, q_heads, hd) if queries > 1 else (batch, q_heads, hd)
 
-    def fn(q, pool, table, lengths):
-        return paged_attention.paged_attention(q, pool, None, table, lengths, impl="pallas")
+    def fn(q, pool, table, lengths, limits=None):
+        return paged_attention.paged_attention(q, pool, None, table, lengths, impl="pallas", limits=limits)
 
     text = _assert_mosaic(
         chip, fn, (q, jnp.bfloat16), ((n_blocks, block, 2 * kv_heads, hd), jnp.bfloat16),
         ((batch, width), jnp.int32), ((batch,), jnp.int32),
+        *([((batch, queries), jnp.int32)] if limited else []),
     )
     calls = re.findall(r"%paged_attention(?:\.\d+)* = (\S+) custom-call\(", text)
     assert len(calls) == 1 and calls[0].startswith(f"bf16[{batch},{queries * q_heads},{hd}]")
@@ -615,22 +618,24 @@ def test_a_whole_prompts_sparse_attention_compiles_as_two_kernels(chip, seq):
     assert re.search(rf"s8\[1,{seq // 128},{seq // 512},128,512\]", text)
 
 
-def test_a_block_forward_reads_the_pool_once_for_its_four_queries(chip):
+def test_a_block_forward_reads_the_pool_once_for_its_eight_queries(chip):
     """The decode chunk of a module that generates by blocks, at the cell's
     head widths (32 queries over 4 keys of 128, blocks of 4, 32 slots, pool
     blocks of 16; two layers of eight experts and a small vocabulary, so
     that it compiles in seconds): each layer's read of the pool is the
-    kernel ``paged_attention`` with four queries a row, 4 x 32 query rows of
-    one score tile, and the pool goes to it as it lies: no gather of its
-    blocks, no copy, transpose or fusion of it."""
+    kernel ``paged_attention`` with eight queries a row (the block a slot
+    closes and the next one's first pass), 8 x 32 query rows, and the pool
+    goes to it as it lies: no gather of its blocks, no copy, transpose or
+    fusion of it, round the scatter of two blocks' rows a slot either; the
+    head runs over the open block's four rows of each slot."""
     from unionml_tpu.models.generate import make_sampler
     from unionml_tpu.models.sdar_moe import SdarMoe, SdarMoeConfig
     from unionml_tpu.serving.programs import build_programs
 
     slots, blocks, block, width, steps = 32, 512, 16, 40, 2
     module = SdarMoe(SdarMoeConfig(
-        vocab_size=2048, num_hidden_layers=2, num_experts=8, num_experts_per_tok=2, quantized=True,
-        mask_token_id=2047, remasking_strategy="low_confidence_static",
+        vocab_size=8192, num_hidden_layers=2, num_experts=8, num_experts_per_tok=2, quantized=True,
+        mask_token_id=8191, remasking_strategy="low_confidence_static",
     ))
     progs = build_programs(
         module, slots=slots, rows=width * block, pool_blocks=blocks, block=block, chunk_steps=steps,
@@ -650,7 +655,9 @@ def test_a_block_forward_reads_the_pool_once_for_its_four_queries(chip):
     text = progs.decode_chunk.lower(*on_chip(args)).compile().as_text()
     assert text.lstrip().startswith("HloModule jit_decode_chunk")
     calls = re.findall(r"%paged_attention(?:\.\d+)* = (\S+) custom-call\(", text)
-    assert calls and all(c.startswith("bf16[32,128,128]") for c in calls)   # [slots, 4 x 32 query rows, 128]
+    assert calls and all(c.startswith("bf16[32,256,128]") for c in calls)   # [slots, 8 x 32 query rows, 128]
+    # logits of four rows a slot
+    assert re.search(r"f32\[(?:128|32,4),8192\]", text) and not re.search(r"f32\[(?:256|32,8),8192\]", text)
     pool = rf"bf16\[{blocks},(?:{block},8|{block * 8}),128\]"     # fused rows: 4 key + 4 value heads
     assert not re.search(rf"{pool}\S* (?:copy|transpose|gather)\(", text)
     assert not [line for line in text.splitlines() if " gather(" in line and re.search(pool, line)]

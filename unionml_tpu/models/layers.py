@@ -261,8 +261,8 @@ class BlockDiffusion:
     block decides ``block_length // denoising_steps`` of them (the most
     confident; under ``low_confidence_dynamic`` every entry whose confidence
     passes ``threshold`` where at least that many do), and once none is
-    left one commit forward writes the block's rows from its final
-    tokens."""
+    left the block's rows are written from its final tokens (the commit),
+    in the forward that is the next block's first denoising one."""
 
     block_length: int
     denoising_steps: int
@@ -291,8 +291,9 @@ class BlockDiffusion:
     @property
     def forwards_per_block(self) -> int:
         """Forwards a whole block takes at the most: its denoising
-        forwards and the commit."""
-        return self.denoising_steps + 1
+        forwards (the commit of its final tokens' rows rides with the next
+        block's first)."""
+        return self.denoising_steps
 
     def choose(self, confidence, candidates):
         """bool like ``candidates`` [..., block_length]: the entries this

@@ -17,11 +17,15 @@ indexer and with plain rotary.
   :class:`~.layers.KVRows` a layer: keys and values of a position in one
   row, a whole tile at 4 + 4 heads of 128): a whole prompt over contiguous rows by
   the flash kernel with that mask (``prefill_impl="flash"``) or by masked
-  scores; and a **denoising forward** over a block pool, which writes the
-  block's ``Bk`` rows at ``fill .. fill + Bk - 1`` and reads ``fill + Bk``
-  rows with ``Bk`` queries a sequence and no mask among the block's own
+  scores; and a **forward over a block pool**, which writes its ``S`` rows
+  (one block, or several behind one another) at ``fill .. fill + S - 1``
+  and reads ``fill + S`` rows with ``S`` queries a sequence
   (:func:`~unionml_tpu.ops.paged_attention.paged_attention` with a query
-  axis): the pool's rows are read once a forward for ``Bk`` positions.
+  axis): the pool's rows are read once a forward for all ``S`` positions. A
+  block's queries see one another and, where the forward carries more than
+  one block, no later block's rows (a limit a query); rows that ``live``
+  [B, S] leaves out are written to the pool's trash block, sent to no
+  expert, and seen by no live row.
 - **Mixture**: :class:`~unionml_tpu.ops.moe.MoEMlp` with the softmax router
   (top-k of the softmax, renormalised: ``norm_topk_prob``), no shared
   expert, in every layer.
@@ -132,7 +136,9 @@ class BlockCausalAttention(nn.Module):
     """The attention block. ``cache`` is a layer's entry of
     ``KVRows(fused=True).init``: one buffer of rows that hold a position's
     key heads and its value heads behind them, ``[B, L, 2 Hk, D]`` or, with
-    ``block_table``, the pool's ``[num_blocks, block, 2 Hk, D]``."""
+    ``block_table``, the pool's ``[num_blocks, block, 2 Hk, D]``. With
+    ``block_table``, ``live`` is [B] (the sequences that run) or [B, S] (the
+    rows that do: a prefix of each sequence's)."""
 
     config: SdarMoeConfig
 
@@ -181,9 +187,10 @@ class BlockCausalAttention(nn.Module):
             row = jnp.concatenate([k, v], axis=2).astype(rows.dtype)
             index = jnp.asarray(cache_index)
             if block_table is not None:
-                # one forward over a block: its rows are written where the
-                # table says (provisional until the block's commit forward
-                # writes them from its final tokens) and all of them are read
+                # one forward over a block or two: their rows are written
+                # where the table says (an open block's are provisional until
+                # a forward writes them from its final tokens) and all of
+                # them are read
                 if index.ndim != 1:
                     raise ValueError(
                         f"block-paged caches take a vector cache_index, got ndim {index.ndim}"
@@ -192,10 +199,22 @@ class BlockCausalAttention(nn.Module):
                     raise ValueError("kv_mask is incompatible with block_table")
                 blk = rows.shape[1]
                 at = index[:, None] + jnp.arange(seq)[None, :]
-                pid = jnp.take_along_axis(block_table, at // blk, axis=1)
+                # (a row that does not run may lie past the table)
+                entry = jnp.minimum(at // blk, block_table.shape[1] - 1)
+                pid, lengths = jnp.take_along_axis(block_table, entry, axis=1), index + seq
+                if live is not None:
+                    # a row that does not run goes to the trash block and no
+                    # query sees it; a slot that runs nothing reads nothing
+                    runs = jnp.broadcast_to(live[:, None] if live.ndim == 1 else live, (batch, seq))
+                    pid = jnp.where(runs, pid, 0)
+                    ran = jnp.sum(runs, axis=-1, dtype=index.dtype)
+                    lengths = jnp.where(ran > 0, index + ran, 0)
                 rows = rows.at[pid, at % blk].set(row)
-                lengths = index + seq if live is None else jnp.where(live, index + seq, 0)
-                out = paged_attention(q, rows, None, block_table, lengths, scale=scale, impl=cfg.paged_impl)
+                # more than one block: a query sees to the end of its own
+                limits = (at | (bk - 1)) + 1 if seq > bk else None
+                out = paged_attention(
+                    q, rows, None, block_table, lengths, scale=scale, impl=cfg.paged_impl, limits=limits,
+                )
             else:
                 if index.ndim == 1:
                     def put(c, n):
@@ -263,8 +282,15 @@ class SdarMoeBlock(nn.Module):
             own = jnp.clip(jnp.broadcast_to(own, (batch, seq)), 0, hidden.shape[1] - 1)
             real = jnp.take_along_axis(hidden, own, axis=1)
         elif block_table is not None and live is not None:
-            # nor are the rows of a slot that holds no unfinished request
-            real = jnp.broadcast_to(live[:, None], (batch, seq))
+            # nor are the rows of a slot that holds no unfinished request,
+            # nor the rows of a forward that its slot does not run
+            real = jnp.broadcast_to(live[:, None] if live.ndim == 1 else live, (batch, seq))
+            # ... but the first row always is: a forward that no slot runs (a
+            # chunk's steps after its last request ended) would hand the
+            # grouped kernel no row at all, and its tile maps then point
+            # before the first tile (``ops.moe._grouped_matmul_pallas``:
+            # ``tiles_used - 1``), an out-of-bounds copy that halts the chip
+            real = real.at[0, 0].set(True)
         routed, _ = MoEMlp(
             num_experts=cfg.num_experts, num_selected=cfg.num_experts_per_tok,
             hidden_dim=cfg.moe_intermediate_size, model_dim=cfg.hidden_size, quantized=cfg.quantized,
@@ -321,8 +347,11 @@ class SdarMoe(nn.Module):
         """logits [B, S, V]: row ``t`` predicts the token at ``t``. With
         ``cache`` (one ``KVRows`` entry per layer) returns ``(logits,
         new_cache)``. The arguments are ``Llama``'s; with ``block_table``
-        the ``S`` tokens are one block a sequence, written at ``cache_index
-        .. cache_index + S - 1`` and attending one another."""
+        the ``S`` tokens are whole blocks of a sequence, written at
+        ``cache_index .. cache_index + S - 1`` (a multiple of the block
+        length), a block's attending one another and the blocks before;
+        ``live`` may then be [B, S], and ``logit_index`` [B, K] asks for
+        the logits of K rows a sequence (``[B, K, V]``)."""
         cfg = self.config
         dtype = jnp.dtype(cfg.dtype)
         x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=dtype, name="embed")(tokens)
@@ -335,7 +364,11 @@ class SdarMoe(nn.Module):
             )
             new_cache.append(c)
         if logit_index is not None:
-            x = x[jnp.arange(x.shape[0]), jnp.asarray(logit_index)][:, None, :]
+            at = jnp.asarray(logit_index)
+            if at.ndim == 2:
+                x = jnp.take_along_axis(x, at[:, :, None], axis=1)
+            else:
+                x = x[jnp.arange(x.shape[0]), at][:, None, :]
         x = RMSNorm(eps=cfg.rms_norm_eps, dtype=dtype, name="final_norm")(x)
         logits = make_dense(
             quantized=cfg.quantized, features=cfg.vocab_size, dtype=jnp.float32, name="lm_head",

@@ -64,6 +64,10 @@ Two implementations behind one dispatcher:
   (PERF.md, section 6, PR 45: with 4 queries a row over 4 + 4 stored heads
   the one-matmul tile was ``[128, 4096]`` a group, seven columns of eight
   masked away, and the vector unit bound it at four times its bytes' time).
+  Over fused rows the queries of a row may each come with a limit of their
+  own (``limits=``: two blocks of positions in one call, the earlier blind
+  to the later; a third scalar-prefetched operand, applied where the
+  length's mask is, while the copies and the walk go by the row's length).
   int8 KV pools fold their per-(row, head) dequant scales into the
   score/weight math in-kernel (never a dequantized pool copy; the fp32
   scale planes ride as one lane-dense ``[1, block * Hk]`` row per block,
@@ -108,7 +112,12 @@ def _interpret() -> bool:
     return jax.devices()[0].platform != "tpu"
 
 
-def _check_shapes(q, k, v, block_table, lengths, k_scale, v_scale):
+def _check_shapes(q, k, v, block_table, lengths, k_scale, v_scale, limits=None):
+    if limits is not None and (q.ndim != 4 or limits.shape != q.shape[:2]):
+        raise ValueError(
+            f"limits are [batch, queries] beside q [batch, queries, q_heads, head_dim], got {limits.shape} "
+            f"for q {q.shape}"
+        )
     if v is None:
         # one pool of fused rows: a position's key heads, its value heads behind
         if k.ndim != 4 or k.shape[2] % 2 or k_scale is not None or v_scale is not None:
@@ -155,6 +164,7 @@ def paged_attention_reference(
     k_scale: Optional[jnp.ndarray] = None,
     v_scale: Optional[jnp.ndarray] = None,
     scale: Optional[float] = None,
+    limits: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Pure-JAX paged decode attention (the parity/CPU path).
 
@@ -172,11 +182,13 @@ def paged_attention_reference(
     itself). Returns [B, Hq, D] in ``q.dtype``. ``q`` [B, Q, Hq, D] is Q
     queries a row that share the row's length (every one sees all of its
     visible rows): returns [B, Q, Hq, D]. ``v=None``: ``k`` is one pool of
-    fused rows [N, block, 2 Hk, D], the key heads first.
+    fused rows [N, block, 2 Hk, D], the key heads first. ``limits`` [B, Q]
+    int32: query ``j`` of row ``b`` sees the first ``min(lengths[b],
+    limits[b, j])`` rows.
     """
     from unionml_tpu.ops.attention import _grouped_cache_attention
 
-    _check_shapes(q, k, v, block_table, lengths, k_scale, v_scale)
+    _check_shapes(q, k, v, block_table, lengths, k_scale, v_scale, limits)
     if v is None:
         k, v = k[:, :, :k.shape[2] // 2], k[:, :, k.shape[2] // 2:]
     batch, w = block_table.shape
@@ -193,8 +205,11 @@ def paged_attention_reference(
     # the engine's contiguous decode bias, verbatim: kv slot j visible
     # to the (single) query iff j <= q_pos, with q_pos = lengths - 1
     kv_pos = jnp.arange(w * block)[None, :]
-    visible = kv_pos[None] <= (lengths.astype(jnp.int32) - 1)[:, None, None]
-    bias = jnp.where(visible, 0.0, NEG_INF)[:, None]   # [B, 1, 1, W*block]
+    last = (lengths.astype(jnp.int32) - 1)[:, None]
+    if limits is not None:
+        last = jnp.minimum(last, limits.astype(jnp.int32) - 1)
+    visible = kv_pos[None] <= last[:, :, None]
+    bias = jnp.where(visible, 0.0, NEG_INF)[:, None]   # [B, 1, 1 or Q, W*block]
     out = _grouped_cache_attention(
         q[:, None] if q.ndim == 3 else q, gk, gv,
         k_scale=gks, v_scale=gvs, bias=bias, scale=scale,
@@ -464,9 +479,8 @@ def _paged_pallas(q, k, v, block_table, lengths, *, k_scale, v_scale,
     ).reshape(out_shape)
 
 
-def _fused_kernel(table_ref, len_ref, q_ref, pool, o_ref, buf, sem, state,
-                  acc_ref, m_ref, l_ref, *, scale, block, kv_heads, group,
-                  width, pages, queries):
+def _fused_kernel(table_ref, len_ref, *rest, scale, block, kv_heads, group,
+                  width, pages, queries, limited=False):
     """:func:`_paged_kernel`'s walk over ONE pool whose rows hold a
     position's key heads and, behind them, its value heads. A group's blocks
     lie in the buffer as they lie in the pool, ``[positions * 2 Hk, D]``:
@@ -475,9 +489,14 @@ def _fused_kernel(table_ref, len_ref, q_ref, pool, o_ref, buf, sem, state,
     scored against that head's own query rows alone: a ``[queries * group,
     positions]`` score tile a key head, where one matmul of every query row
     with every stored row makes ``[queries * Hq, positions * 2 Hk]`` and
-    masks all but one column in ``2 Hk`` away."""
+    masks all but one column in ``2 Hk`` away. ``limited``: a third
+    scalar-prefetched operand holds a limit a query (``[batch * queries]``),
+    and query ``j`` of a row sees the positions under ``min(length,
+    limit[j])``; the copies and the walk go by the row's length alone."""
     from jax.experimental.pallas import tpu as pltpu
 
+    lim_ref, rest = (rest[0], rest[1:]) if limited else (None, rest)
+    q_ref, pool, o_ref, buf, sem, state, acc_ref, m_ref, l_ref = rest
     b = pl.program_id(0)
     batch = pl.num_programs(0)
     q_heads = kv_heads * group
@@ -538,6 +557,16 @@ def _fused_kernel(table_ref, len_ref, q_ref, pool, o_ref, buf, sem, state,
         picked = [[q_all[at.start:at.start + group] for at in own_rows(h)] for h in range(kv_heads)]
         return [jnp.concatenate(parts, axis=0).astype(q_ref.dtype) for parts in picked]
 
+    def own_limits():
+        """``[queries * group, 1]``: the positions each of a key head's query
+        rows sees (query ``j``'s rows are ``j * group .. (j + 1) * group -
+        1`` of them, for every head)."""
+        at = jax.lax.broadcasted_iota(jnp.int32, (queries * group, 1), 0)
+        limit = jnp.zeros_like(at)
+        for j in range(queries):
+            limit = jnp.where((at >= j * group) & (at < (j + 1) * group), lim_ref[b * queries + j], limit)
+        return jnp.minimum(limit, length)
+
     half = rows // 2
 
     def position_of(index):
@@ -577,13 +606,14 @@ def _fused_kernel(table_ref, len_ref, q_ref, pool, o_ref, buf, sem, state,
             word = (low & jnp.uint32(0xFFFF)) | (high << 16)
         return pltpu.bitcast(word, buf.dtype)     # row 2s: the low half of word s
 
-    def score(g, slot, q_own):
+    def score(g, slot, q_own, limit):
         """Fold group ``g`` of this row, gathered in buffer ``slot``, into
         the online-softmax state, a key head at a time."""
         col = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
         # pages past the row's last were not copied (the buffer holds an
         # earlier group's rows there): the length mask covers them
-        seen = g * rows + position_of(col) < length    # [1, positions]
+        # [1, positions]; with a limit a query [queries * group, positions]
+        seen = g * rows + position_of(col) < (length if limit is None else limit)
         for head, q in enumerate(q_own):           # [queries * group, D]
             k = stored_head(slot, head).astype(q.dtype)
             # zero unseen value rows: 0-weight x garbage must stay 0
@@ -623,6 +653,7 @@ def _fused_kernel(table_ref, len_ref, q_ref, pool, o_ref, buf, sem, state,
         )
         has_next = nxt_b < batch
         q_own = own_queries()                      # once a row
+        limit = own_limits() if limited else None
 
         def one_group(g, slot):
             last = g + 1 == groups
@@ -635,7 +666,7 @@ def _fused_kernel(table_ref, len_ref, q_ref, pool, o_ref, buf, sem, state,
                 )
 
             copies(b, g, slot, lambda c: c.wait())
-            score(g, slot, q_own)
+            score(g, slot, q_own, limit)
             return 1 - slot
 
         state[0] = jax.lax.fori_loop(0, groups, one_group, first)
@@ -648,7 +679,7 @@ def _fused_kernel(table_ref, len_ref, q_ref, pool, o_ref, buf, sem, state,
 # one trace and one lowering for every layer of a program (see
 # ``_sparse_pallas``): the key heads are unrolled in the kernel's body
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _fused_pallas(q, pool, block_table, lengths, *, scale, interpret):
+def _fused_pallas(q, pool, block_table, lengths, limits=None, *, scale, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
     out_shape, queries = q.shape, 1 if q.ndim == 3 else q.shape[1]
@@ -659,11 +690,15 @@ def _fused_pallas(q, pool, block_table, lengths, *, scale, interpret):
     w = block_table.shape[1]
     pages = _pages_per_step(block, kv_heads, head_dim, pool.dtype.itemsize, w)
 
-    def q_map(b, table, lens):
+    def q_map(b, *prefetched):
         return (b, 0, 0)
 
+    # the table flat, so that an entry is one scalar load; so the limits
+    prefetched = [block_table.astype(jnp.int32).reshape(-1), lengths.astype(jnp.int32)]
+    if limits is not None:
+        prefetched.append(limits.astype(jnp.int32).reshape(-1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(prefetched),
         grid=(batch,),
         in_specs=[pl.BlockSpec((1, q_rows, head_dim), q_map), pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, q_rows, head_dim), q_map),
@@ -679,6 +714,7 @@ def _fused_pallas(q, pool, block_table, lengths, *, scale, interpret):
     kernel = functools.partial(
         _fused_kernel, scale=scale, block=block, kv_heads=kv_heads,
         group=q_rows // queries // kv_heads, width=w, pages=pages, queries=queries,
+        limited=limits is not None,
     )
     return pl.pallas_call(
         kernel,
@@ -690,10 +726,8 @@ def _fused_pallas(q, pool, block_table, lengths, *, scale, interpret):
         name="paged_attention",
     )(
         # [N, block, 2 Hk, D] -> [N, block * 2 Hk, D] merges the two middle
-        # dims under an unchanged minor dim: the pool as it lies; the table
-        # flat, so that an entry is one scalar load
-        block_table.astype(jnp.int32).reshape(-1), lengths.astype(jnp.int32), q,
-        pool.reshape(num_pool_blocks, block * stored, head_dim),
+        # dims under an unchanged minor dim: the pool as it lies
+        *prefetched, q, pool.reshape(num_pool_blocks, block * stored, head_dim),
     ).reshape(out_shape)
 
 
@@ -708,6 +742,7 @@ def paged_attention(
     v_scale: Optional[jnp.ndarray] = None,
     scale: Optional[float] = None,
     impl: str = "auto",
+    limits: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Single-step decode attention over a block-paged KV pool: two pools
     are scored a group by one matmul of every query row with every
@@ -718,7 +753,11 @@ def paged_attention(
     [B, Q, Hq, D] for Q queries a row that share its ``lengths`` entry and
     see every visible row, each other's included (a block of positions
     that attend one another: the pool's rows are read once for all Q, and
-    the result has ``q``'s shape). ``v=None``: ``k`` is one pool of fused
+    the result has ``q``'s shape). ``limits`` [B, Q] int32, over one fused
+    pool: the queries of a row do not share one limit, query ``j`` sees the
+    row's first ``min(lengths[b], limits[b, j])`` positions (two blocks of
+    positions in one call, the earlier blind to the later; what is copied
+    and walked goes by ``lengths`` alone). ``v=None``: ``k`` is one pool of fused
     rows ``[num_blocks, block, 2 Hk, D]``, a position's key heads and its
     value heads behind them (``KVRows(fused=True)``: with 4 + 4 heads of 128
     one whole tile a position; values of 16 or 32 bits): the kernel copies
@@ -737,7 +776,7 @@ def paged_attention(
     scalar-prefetch kernel; interpreter mode off-TPU), or ``"auto"``
     (pallas on TPU, reference elsewhere).
     """
-    _check_shapes(q, k, v, block_table, lengths, k_scale, v_scale)
+    _check_shapes(q, k, v, block_table, lengths, k_scale, v_scale, limits)
     if impl == "auto":
         impl = "reference" if _interpret() else "pallas"
     if scale is None:
@@ -745,7 +784,7 @@ def paged_attention(
     if impl == "reference":
         return paged_attention_reference(
             q, k, v, block_table, lengths,
-            k_scale=k_scale, v_scale=v_scale, scale=scale,
+            k_scale=k_scale, v_scale=v_scale, scale=scale, limits=limits,
         )
     if impl != "pallas":
         raise ValueError(f"unknown paged attention impl {impl!r}")
@@ -755,7 +794,9 @@ def paged_attention(
                 "the kernel reads fused rows of 16 or 32 bits a value in blocks of an even number of "
                 f"positions, got {k.dtype} {k.shape}"
             )
-        return _fused_pallas(q, k, block_table, lengths, scale=scale, interpret=_interpret())
+        return _fused_pallas(q, k, block_table, lengths, limits, scale=scale, interpret=_interpret())
+    if limits is not None:
+        raise ValueError("the kernel takes a limit a query over one pool of fused rows only (v=None)")
     return _paged_pallas(
         q, k, v, block_table, lengths,
         k_scale=k_scale, v_scale=v_scale, scale=scale,

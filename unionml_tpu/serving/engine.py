@@ -665,15 +665,18 @@ class DecodeEngine:
         # rows a dispatched chunk can advance a slot: 1 per decode step,
         # or k+1 per speculative round
         self._round_stride = 1 if self.draft is None else self.speculate_k + 1
-        # rows a dispatched chunk can move a slot's fill on by, and the rows
-        # past its fill that a step writes besides (the block tables grow
-        # ahead of both): a row a step and none; by blocks a block a commit,
-        # at most every second forward, and the open block's provisional rows
-        self._chunk_advance, self._open_rows = chunk_steps, 0
+        # rows a dispatched chunk can move a slot's fill on by, the rows past
+        # its fill that a step writes besides (the block tables grow ahead
+        # of both) and those a request holds past its asked length: a row a
+        # step and none. By blocks a forward runs two blocks' rows a slot
+        # (the block it closes and the next one's first pass), a block may
+        # close every forward (the dynamic rule), and the last block is
+        # written whole
+        self._chunk_advance, self._step_rows, self._open_rows = chunk_steps, 0, 0
         if self._blocks is not None:
-            # a forward runs block_length rows a slot
             self._round_stride = self._open_rows = self._blocks.block_length
-            self._chunk_advance = self._open_rows * -(-chunk_steps // 2)
+            self._step_rows = 2 * self._blocks.block_length
+            self._chunk_advance = self._blocks.block_length * chunk_steps
         self.cfg = module.config
         self.slots = slots
         self.max_new_tokens = max_new_tokens
@@ -1952,7 +1955,9 @@ class DecodeEngine:
                 "remasking": self._blocks.remasking,
                 "threshold": self._blocks.threshold,
                 # of the perf plane's window: tokens decided a live forward,
-                # and live forwards a block committed
+                # and live forwards a block committed (denoising_steps at the
+                # most, a commit riding with the next block's first forward;
+                # a request's last block takes none)
                 "tokens_per_forward": round(window.get("tokens_decided", 0) / fwd, 4) if fwd else None,
                 "forwards_per_block": round(fwd / commits, 4) if commits else None,
             }
@@ -1992,7 +1997,7 @@ class DecodeEngine:
                 # kernel ``paged_attention`` works on (rows of keys and values)
                 out["kv_pool"]["score_tile"] = score_tile(
                     self._kv_block_size, rows.q_heads, *rows.pool_row, self._table_width,
-                    queries=1 if self._blocks is None else self._blocks.block_length, fused=rows.fused,
+                    queries=self._step_rows or 1, fused=rows.fused,
                 )
         if self._index_topk is not None and self._perf is not None:
             # a learned selection: of the cached rows the dispatched steps'
@@ -2026,7 +2031,7 @@ class DecodeEngine:
             # the dispatch, and the rows computed over the rows routed
             chunk = self.prefill_chunk or self.buckets[-1]
             out["moe"] = {
-                "decode_chunk": moe_dispatch(self.slots * self._round_stride),
+                "decode_chunk": moe_dispatch(self.slots * (self._step_rows or self._round_stride)),
                 **{f"prefill_{b}": moe_dispatch(min(b, chunk)) for b in self.buckets},
             }
         if self._usage is not None:
@@ -2207,11 +2212,11 @@ class DecodeEngine:
     def _tokens_due(self, req: _Request) -> int:
         """Generation by blocks: the tokens that the forwards dispatched
         for ``req`` are sure to have emitted. A block is emitted by the
-        forward that decides its last entry, at the latest the
-        ``denoising_steps``-th of the ``forwards_per_block`` it takes, and
-        the first block may hold as little as one generated entry."""
-        per = self._blocks.forwards_per_block
-        blocks = (req._forwards + 1) // per
+        forward that decides its last entry, at the latest the last of the
+        ``forwards_per_block`` it takes (the next block's first forward
+        commits it besides), and the first block may hold as little as one
+        generated entry."""
+        blocks = req._forwards // self._blocks.forwards_per_block
         held = len(req.prompt) % self._blocks.block_length
         return max(0, blocks * self._blocks.block_length - held)
 
@@ -2485,9 +2490,9 @@ class DecodeEngine:
 
     def _grow_tables_locked(self) -> np.ndarray:
         """Grow every live slot's block table to cover the NEXT decode
-        chunk's worst-case advance (``chunk_steps`` rows; by blocks the
-        commits a chunk can hold, a block each, and the open block's
-        provisional rows behind them), drawing from
+        chunk's worst-case advance (``chunk_steps`` rows; by blocks a
+        commit a forward, a block each, and behind them the two blocks'
+        rows that a forward writes), drawing from
         each request's admission-time reservation — which is why growth
         can never fail — and return the table snapshot the chunk
         dispatch uploads. Rows past a request's reserved budget stay on
@@ -2498,7 +2503,7 @@ class DecodeEngine:
             if req is None:
                 continue
             target_rows = min(
-                self._slot_rows[slot] + self._chunk_advance + self._open_rows,
+                self._slot_rows[slot] + self._chunk_advance + self._step_rows,
                 req._rows_cap,
             )
             want = min(
@@ -2912,21 +2917,22 @@ class DecodeEngine:
         now = time.perf_counter()  # readback complete: the chunk landed
         self._h_harvest.observe((now - self._harvest_t0) * 1e3)
         tenant_tokens: dict = {}
-        forwards = commits = decided = emitted = 0
+        forwards = commits = fused = decided = emitted = 0
         with self._lock:
             for slot in np.flatnonzero(mask):
                 req = self._occupant[slot]
                 if req is None or gens[slot] != self._slot_gen[slot]:
                     continue  # stale: dispatched for a previous occupant
                 chunk: List[int] = []
-                n_fwd = n_commit = 0
+                n_fwd = n_commit = n_fused = 0
                 finished = False
                 for r in range(info.shape[0]):
                     n_emit, first, n_dec, kind = (int(x) for x in info[r, slot])
                     if kind == 0:
                         continue  # the slot ran nothing: done on the device
                     n_fwd += 1
-                    n_commit += kind == 2
+                    n_commit += kind >> 1 & 1      # it closed the block before
+                    n_fused += kind == 3           # and denoised the next
                     decided += n_dec
                     for i in range(first, first + n_emit):
                         tok = int(toks[r, slot, i])
@@ -2940,6 +2946,7 @@ class DecodeEngine:
                         break
                 forwards += n_fwd
                 commits += n_commit
+                fused += n_fused
                 emitted += len(chunk)
                 self._tracer.record_span(
                     req.rid, f"decode-chunk[{req._chunk_i}]", dispatched, now,
@@ -2969,7 +2976,7 @@ class DecodeEngine:
             self._sweep_deferred_locked()
         if self._perf is not None:
             self._perf.note_blocks(
-                forwards=forwards, commits=commits, decided=decided, emitted=emitted,
+                forwards=forwards, commits=commits, fused_commits=fused, decided=decided, emitted=emitted,
             )
         if self._usage is not None:
             device_s = max(

@@ -404,16 +404,19 @@ class ServingPerfPlane:
             self._win_parked += 1
 
     def note_blocks(
-        self, *, forwards: int, commits: int, decided: int, emitted: int,
+        self, *, forwards: int, commits: int, fused_commits: int, decided: int, emitted: int,
     ) -> None:
         """One harvested chunk of a module that generates by blocks: the
         forwards its live slots ran (a slot-step of such a chunk is one
-        forward over a block), the commit forwards among them, the entries
-        those forwards decided and the tokens the chunk's requests were
-        handed."""
+        forward over a slot's open block), those among them that committed
+        a block (wrote its final tokens' rows), those of these that in the
+        same forward denoised the next block (``fused_commits``: every one,
+        as the chunk is built), the entries the forwards decided and the
+        tokens the chunk's requests were handed."""
         with self._lock:
             self._win_block_forwards += int(forwards)
             self._win_block_commits += int(commits)
+            self._win_block_fused_commits += int(fused_commits)
             self._win_tokens_decided += int(decided)
             self._win_tokens_emitted += int(emitted)
 
@@ -446,6 +449,7 @@ class ServingPerfPlane:
         self._win_visible = 0
         self._win_block_forwards = 0
         self._win_block_commits = 0
+        self._win_block_fused_commits = 0
         self._win_tokens_decided = 0
         self._win_tokens_emitted = 0
         self._win_polls = {reason: 0 for reason in POLL_REASONS}
@@ -507,6 +511,7 @@ class ServingPerfPlane:
                 "visible_positions": self._win_visible,
                 "block_forwards": self._win_block_forwards,
                 "block_commits": self._win_block_commits,
+                "block_fused_commits": self._win_block_fused_commits,
                 "tokens_decided": self._win_tokens_decided,
                 "tokens_emitted": self._win_tokens_emitted,
                 "polls": dict(self._win_polls),

@@ -28,7 +28,8 @@ the prefix cache. There are two:
 A module that generates by blocks (``generation_scheme()`` returns a
 :class:`~unionml_tpu.models.layers.BlockDiffusion`) is served from the
 block pool by a chunk of its own, :func:`build_programs`'s
-``block_chunk``: a scan step is one forward over ``[slots, Bk]`` rows, the
+``block_chunk``: a scan step is one forward over ``[slots, 2 Bk]`` rows (a
+slot's open block, or the block it closes and the next one behind it), the
 state also holds every slot's open block, and a prefill commits whole
 blocks and samples nothing.
 
@@ -485,45 +486,72 @@ def build_programs(
 
     def block_chunk(params, state, active, place, keys):
         """``chunk_steps`` forwards over every slot's open block in one
-        scan. A forward runs a block's ``Bk`` entries (the mask token where
-        undecided) at positions ``fill .. fill + Bk - 1``: their keys and
-        values are written to the slot's rows there (provisional: the next
-        forward overwrites them) and attention reads ``fill + Bk`` rows.
-        While an asked entry is undecided the forward is a *denoising*
-        one: the scheme picks the entries it decides from their candidates'
-        confidences, and the forward that decides a block's last asked
-        entry emits the block's generated entries. A block that comes in
-        with none left takes a *commit* forward: what was just written are
-        the rows of its final tokens, ``fill`` moves on by ``Bk`` and the
-        next block opens. Slots denoise and commit side by side: one
-        program, per-slot flags. A request ends with its last asked entry
-        decided (``stop``; an ``eos_id`` among a block's emitted tokens
-        ends it there): its last block takes no commit forward, and the
-        entries past the asked length are never decided.
+        scan. A forward runs ``2 Bk`` rows a slot at positions ``fill ..
+        fill + 2 Bk - 1``, and every live slot *denoises* in it: the scheme
+        picks the entries of the open block that it decides from their
+        candidates' confidences, and the forward that decides a block's
+        last asked entry emits the block's generated entries. A slot in the
+        middle of a block runs it in the forward's first ``Bk`` rows (the
+        mask token where undecided; their keys and values are provisional:
+        the next forward overwrites them) and its second ``Bk`` rows are
+        dead: written to the trash block, sent to no expert, seen by
+        nobody. A slot whose block came in with none left to decide
+        *closes* it in the same forward: the first ``Bk`` rows are the
+        block's final tokens, whose keys and values are the block's for
+        good (the *commit*), ``fill`` moves on by ``Bk``, and the next block
+        opens in the second ``Bk`` rows, every entry the mask token, for
+        its first denoising pass; a query of the closing half sees ``fill +
+        Bk`` rows and one of the opening half ``fill + 2 Bk``. The head runs
+        over the open block's ``Bk`` rows of each slot only. Slots denoise
+        and close side by side: one program, per-slot flags. A request ends
+        with its last asked entry decided (``stop``; an ``eos_id`` among a
+        block's emitted tokens ends it there): its last block is never
+        closed, and the entries past the asked length are never decided.
 
         Returns per forward ``(tokens [R, B, Bk], decided_at [R, B, Bk],
-        info [R, B, 4])``: the block after this forward's decisions, the
-        forward of the block (0, 1, ...) that decided each entry, and
+        info [R, B, 4])``: the open block after this forward's decisions,
+        the forward of the block (0, 1, ...) that decided each entry, and
         ``(n_emit, first, n_decided, kind)``: the entries ``first ..
         first + n_emit - 1`` of ``tokens`` are emitted (0 unless this
         forward completed the block), ``n_decided`` entries were decided,
-        and ``kind`` is 0 for a slot that ran nothing, 1 for a denoising
-        and 2 for a commit forward."""
+        and ``kind`` is 0 for a slot that ran nothing, 1 for a forward
+        that denoised and 3 for one that also closed the block before
+        (bit 1: a commit)."""
         ((model, pick),) = models
+
+        def per_slot(flag, ndim):
+            return flag.reshape((B,) + (1,) * ndim)
 
         def step(state, key):
             with jax.named_scope("step_io"):
                 offs = jnp.arange(Bk)[None, :]
                 live = active & ~state["done"]
-                fill, und = state["fill"], state["blk_und"]
-                asked = fill[:, None] + offs < state["stop"][:, None]
+                stop = state["stop"][:, None]
+                fresh = open_block()
+                came = {k: state[k] for k in fresh}
+                # the forward's first half: the block as it came in
+                first = jnp.where(came["blk_und"], scheme.mask_token_id, came["blk_tok"])
+                closing = live & ~(came["blk_und"] & (state["fill"][:, None] + offs < stop)).any(-1)
+                if stale_commit:
+                    closing = jnp.zeros_like(live)      # this chunk's blocks move on below
+                # the open block: where one closes, the next, in the second half
+                blk = {
+                    k: jnp.where(per_slot(closing, fresh[k].ndim), fresh[k], v) for k, v in came.items()
+                }
+                fill = state["fill"] + Bk * closing.astype(jnp.int32)
+                und = blk["blk_und"]
+                asked = fill[:, None] + offs < stop
                 cand = und & asked
-                denoise = live & cand.any(-1)
-                commit = live & ~denoise       # it came in with nothing left to decide
-                ids = jnp.where(und, scheme.mask_token_id, state["blk_tok"])
+                ids = jnp.concatenate([first, jnp.full((B, Bk), scheme.mask_token_id, jnp.int32)], axis=1)
+                runs = live[:, None] & jnp.concatenate(
+                    [jnp.ones((B, Bk), bool), jnp.broadcast_to(closing[:, None], (B, Bk))], axis=1,
+                )
+                # the head runs over the open block's rows of each slot
+                heads = Bk * closing.astype(jnp.int32)[:, None] + offs
                 args = residency.step_args(state, live, place)
             logits, cache = model.apply(
-                {"params": pick(params)}, ids, cache_index=fill, live=live, **args,
+                {"params": pick(params)}, ids, cache_index=state["fill"], live=runs, logit_index=heads,
+                **args,
             )
             with jax.named_scope("sample"):
                 flat = logits.reshape(B * Bk, -1)
@@ -532,16 +560,16 @@ def build_programs(
                     # a candidate's confidence is its softmax probability
                     picked = jnp.take_along_axis(flat, choice[:, None], axis=-1)[:, 0]
                     conf = jnp.exp(picked - jax.nn.logsumexp(flat, axis=-1)).reshape(B, Bk)
-                    now = scheme.choose(conf, cand) & denoise[:, None]
+                    now = scheme.choose(conf, cand) & live[:, None]
                 choice = choice.reshape(B, Bk).astype(jnp.int32)
             with jax.named_scope("step_io"):
                 resident = residency.step_result(args, cache)
-                tok = jnp.where(now, choice, state["blk_tok"])
+                tok = jnp.where(now, choice, blk["blk_tok"])
                 und = und & ~now
-                at = jnp.where(now, state["blk_fwd"][:, None], state["blk_at"])
+                at = jnp.where(now, blk["blk_fwd"][:, None], blk["blk_at"])
                 # the forward that decides a block's last asked entry emits it
-                complete = denoise & ~(und & asked).any(-1)
-                made = state["blk_gen"] & asked
+                complete = live & ~(und & asked).any(-1)
+                made = blk["blk_gen"] & asked
                 done = state["done"]
                 if eos_id is not None:
                     hit = made & (tok == eos_id)
@@ -558,28 +586,22 @@ def build_programs(
                 ))
                 info = jnp.stack([
                     n_emit, jnp.argmax(made, axis=-1), now.sum(-1),
-                    denoise.astype(jnp.int32) + 2 * commit.astype(jnp.int32),
+                    live.astype(jnp.int32) + 2 * closing.astype(jnp.int32),
                 ], axis=-1).astype(jnp.int32)
+                out = (jnp.where(live[:, None], tok, pad_id), at, info)
+                opened = {
+                    "blk_tok": tok, "blk_und": und, "blk_gen": blk["blk_gen"], "blk_at": at,
+                    "blk_fwd": blk["blk_fwd"] + live.astype(jnp.int32),
+                }
                 if stale_commit:
                     # WRONG on purpose: the rows this forward wrote, with the
                     # mask token where it decided, are kept as the block's
-                    commit = complete
-                out = (jnp.where(live[:, None], tok, pad_id), at, info)
-                fresh = open_block()
-                opened = {
-                    "blk_tok": tok, "blk_und": und, "blk_gen": state["blk_gen"], "blk_at": at,
-                    "blk_fwd": state["blk_fwd"] + denoise.astype(jnp.int32),
-                }
-                return {
-                    **state,
-                    **resident,
-                    **{
-                        k: jnp.where(commit.reshape((B,) + (1,) * fresh[k].ndim), fresh[k], v)
+                    opened = {
+                        k: jnp.where(per_slot(complete, fresh[k].ndim), fresh[k], v)
                         for k, v in opened.items()
-                    },
-                    "fill": fill + Bk * commit.astype(jnp.int32),
-                    "done": done,
-                }, out
+                    }
+                    fill = fill + Bk * complete.astype(jnp.int32)
+                return {**state, **resident, **opened, "fill": fill, "done": done}, out
 
         state, outs = jax.lax.scan(step, state, keys)
         return state, outs
